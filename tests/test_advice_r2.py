@@ -1,4 +1,4 @@
-"""Regression tests for the round-2 advisor findings (ADVICE.md r2).
+"""Regression tests for the round-2 advisor findings.
 
 Each test pins the FIXED behavior:
   1. snapshot meta rides inside the .npz → single-rename atomic save
@@ -108,16 +108,39 @@ def test_parallel_h_edit_triggers_rebuild(tmp_path, monkeypatch):
     monkeypatch.setattr(rec, "_native_tried", False)
     assert rec._native() is not None
     so = os.path.join(sandbox, "libznr_reader.so")
-    # backdate the .so (sub-second builds would hide the rebuild), then
-    # touch ONLY parallel.h so it is the lone newer input
-    past = time.time() - 100
-    os.utime(so, (past, past))
-    now = time.time()
-    os.utime(os.path.join(sandbox, "parallel.h"), (now, now))
-    os.utime(os.path.join(sandbox, "znr_reader.cpp"),
-             (past - 10, past - 10))
+    header = os.path.join(sandbox, "parallel.h")
+    with open(so + ".digest") as f:
+        built_from = f.read()
+    # edit ONLY parallel.h, and push the .so's mtime past every source:
+    # a copied or unpacked tree keeps no mtime order, so the content
+    # alone must decide (native_build keys freshness on a digest)
+    with open(header, "a") as f:
+        f.write("\n// edited\n")
+    future = time.time() + 100
+    os.utime(so, (future, future))
     monkeypatch.setattr(rec, "_native_lib", None)
     monkeypatch.setattr(rec, "_native_tried", False)
     assert rec._native() is not None
-    assert os.path.getmtime(so) > past + 50, \
-        "parallel.h-only edit did not trigger a rebuild"
+    with open(so + ".digest") as f:
+        assert f.read() != built_from, \
+            "parallel.h-only edit did not trigger a rebuild"
+    # ... and unchanged sources stay fresh whatever their mtimes say
+    from znicz_tpu import native_build
+    os.utime(header, (future + 10, future + 10))
+    assert native_build.is_fresh(
+        so, [os.path.join(sandbox, "znr_reader.cpp"), header])
+
+
+def test_failed_native_build_raises(tmp_path):
+    """A failed ``make`` is not swallowed: the caller without a
+    fallback (the C++ inference engine) must never dlopen a stale
+    .so after an edit whose rebuild failed."""
+    if not shutil.which("make"):
+        pytest.skip("no make")
+    from znicz_tpu import native_build
+    src = tmp_path / "x.cpp"
+    src.write_text("int main() { return 0; }\n")
+    (tmp_path / "Makefile").write_text("libx.so:\n\tfalse\n")
+    with pytest.raises(native_build.NativeBuildError, match="rc="):
+        native_build.ensure_built(str(tmp_path / "libx.so"), [str(src)],
+                                  str(tmp_path), "libx.so")

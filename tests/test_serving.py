@@ -516,6 +516,10 @@ class TestServingEndToEnd:
                 health = json.loads(r.read())
             assert health["status"] == "ok"
             assert health["backend"] == "jax"
+            # the devices behind that backend, as this process sees
+            # them (conftest: 8 virtual CPU devices)
+            assert (health["platform"], health["device_kind"],
+                    health["device_count"]) == ("cpu", "cpu", 8)
             assert health["n_layers"] == 3     # fc + fc + softmax head
         finally:
             server.stop()

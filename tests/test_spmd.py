@@ -171,6 +171,66 @@ class TestMeshTrainEntrypoint:
         assert len(w0.sharding.device_set) == 8
 
 
+class TestPallasTierUnderMesh:
+    def test_kernels_shard_map_over_the_mesh(self, monkeypatch):
+        """Mosaic cannot partition a kernel automatically, so under a
+        mesh the Pallas tier runs each batch-parallel kernel in a
+        shard_map (tuning.batch_sharded).  Interpret mode takes that
+        exact path on the virtual CPU mesh: conv -> fused LRN+pool ->
+        max-pool -> LRN -> softmax-CE must reproduce the single-device
+        loss under pure-dp and dp x tp."""
+        import jax
+        import jax.numpy as jnp
+
+        from znicz_tpu.ops import tuning
+        from znicz_tpu.parallel import FusedTrainer, fused, make_mesh
+        monkeypatch.setattr(tuning, "_INTERPRET", True)
+        assert tuning.kernel_tier() == "pallas-interpret"
+        hyp = (0.01, 0.0, 0.0, 0.9)
+        lrn = (("alpha", 1e-4), ("beta", 0.75), ("k", 2.0), ("n", 5))
+        pool = (("ksize", (2, 2)), ("padding", (0, 0)),
+                ("stride", (2, 2)))
+        spec = fused.ModelSpec(layers=(
+            fused.LayerSpec("conv", "tanh", True, hyp, hyp,
+                            (("padding", (1, 1)), ("stride", (1, 1)))),
+            fused.LayerSpec("lrn_pool", "linear", False, hyp, hyp,
+                            tuple(sorted(lrn + pool
+                                         + (("use_abs", False),)))),
+            fused.LayerSpec("max_pool", "linear", False, hyp, hyp, pool),
+            fused.LayerSpec("lrn", "linear", False, hyp, hyp, lrn),
+            fused.LayerSpec("fc", "linear", True, hyp, hyp)),
+            loss="softmax")
+        size, c1, classes, batch = 12, 8, 12, 8
+        gen = prng.get("pallas_mesh")
+        params = [(gen.normal(0, 0.1, (3, 3, 3, c1)),
+                   np.zeros(c1, np.float32)),
+                  (None, None), (None, None), (None, None),
+                  (gen.normal(0, 0.05, (3 * 3 * c1, classes)),
+                   np.zeros(classes, np.float32))]
+        data = jnp.asarray(gen.normal(0, 1.0, (2 * batch, size, size, 3)))
+        labels = jnp.asarray(
+            gen.randint(0, classes, 2 * batch).astype(np.int32))
+        idx = np.arange(2 * batch)
+
+        def loss(mesh):
+            # fresh copies: the epoch fn donates its buffers
+            ps = [(None if w is None else np.array(w),
+                   None if b is None else np.array(b))
+                  for w, b in params]
+            vs = [(None if w is None else np.zeros_like(w),
+                   None if b is None else np.zeros_like(b))
+                  for w, b in params]
+            tr = FusedTrainer(spec=spec, params=ps, vels=vs, mesh=mesh)
+            return tr.train_epoch(data, labels, idx, batch)["loss"]
+
+        want = loss(None)
+        assert np.isfinite(want).all()
+        for dp, tp in ((2, 1), (2, 2)):
+            np.testing.assert_allclose(
+                loss(make_mesh(dp, tp, jax.devices()[:dp * tp])), want,
+                rtol=1e-5, atol=1e-6, err_msg=f"mesh {dp}x{tp}")
+
+
 class TestMeshTrainEdgeCases:
     def _spec_params(self, widths=(8, 10, 5)):
         from znicz_tpu.parallel import fused
@@ -479,6 +539,8 @@ class TestPersistentCompileCache:
         env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "PYTHONPATH": REPO + os.pathsep
                + os.environ.get("PYTHONPATH", "")}
+        # a cache placed from outside would win over the argument
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
 
         def cold_start():
             out = subprocess.run(
@@ -496,10 +558,50 @@ class TestPersistentCompileCache:
             f"warm-cache start ({second:.0f} ms) not cheaper than the "
             f"cold one ({first:.0f} ms)")
 
-    def test_unconfigured_cache_is_a_noop(self, monkeypatch):
+    @pytest.fixture
+    def config_updates(self, monkeypatch):
+        """enable() with jax.config.update recorded instead of
+        applied — the pytest process keeps its own cache setting."""
+        import jax
         from znicz_tpu import compilecache
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: calls.append((k, v)))
+        monkeypatch.setattr(compilecache, "_active_dir", None)
+        return calls
+
+    def test_placed_from_outside_sets_no_directory(self, monkeypatch,
+                                                   config_updates):
+        """With $JAX_COMPILATION_CACHE_DIR set the program sets no
+        cache directory in code: neither the argument nor
+        $ZNICZ_COMPILE_CACHE may override it (the floors still
+        zero)."""
+        from znicz_tpu import compilecache
+        monkeypatch.setenv(compilecache.JAX_ENV_VAR, "/placed/outside")
+        monkeypatch.setenv(compilecache.ENV_VAR, "/from/znicz/env")
+        assert compilecache.enable("/from/flag") == "/placed/outside"
+        keys = [k for k, _ in config_updates]
+        assert "jax_compilation_cache_dir" not in keys
+        assert "jax_persistent_cache_min_compile_time_secs" in keys
+
+    def test_default_is_one_fixed_path_in_the_checkout(
+            self, monkeypatch, config_updates):
+        """Unconfigured, the cache is ON at <checkout>/.cache/xla —
+        the same path in every process (the directory is part of the
+        cache key, so a derived one would never hit)."""
+        from znicz_tpu import compilecache
+        monkeypatch.delenv(compilecache.JAX_ENV_VAR, raising=False)
         monkeypatch.delenv(compilecache.ENV_VAR, raising=False)
-        assert compilecache.enable(None) is None
+        want = os.path.join(REPO, ".cache", "xla")
+        assert compilecache.enable(None) == want
+        assert ("jax_compilation_cache_dir", want) in config_updates
+        other = subprocess.run(
+            [sys.executable, "-c", "from znicz_tpu import compilecache;"
+             "print(compilecache.default_dir())"],
+            capture_output=True, text=True, timeout=60, cwd=REPO,
+            env={**os.environ, "JAX_PLATFORMS": "cpu",
+                 "PYTHONPATH": REPO})
+        assert other.stdout.strip() == want, other.stderr[-500:]
 
 
 # -- census-driven warmup ---------------------------------------------------

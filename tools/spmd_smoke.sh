@@ -81,8 +81,7 @@ with tempfile.TemporaryDirectory(prefix="znicz_spmd_smoke_") as tmp:
     proc = subprocess.Popen(
         [sys.executable, "-m", "znicz_tpu", "serve", "--model", model,
          "--port", str(port), "--max-wait-ms", "1",
-         "--replicas", "2", "--tp", "2", "--warmup-shape", "4",
-         "--compile-cache-dir", os.path.join(tmp, "xla-cache")],
+         "--replicas", "2", "--tp", "2", "--warmup-shape", "4"],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         env={**os.environ, "JAX_PLATFORMS": "cpu",
              "XLA_FLAGS": "--xla_force_host_platform_device_count=8"})
@@ -142,8 +141,9 @@ with tempfile.TemporaryDirectory(prefix="znicz_spmd_smoke_") as tmp:
                                       timeout=10).read().decode()
         check("replicas=2" in page and "tp=2" in page,
               "/statusz carries the mesh/replica topology")
-        check("compile_cache: " + os.path.join(tmp, "xla-cache")
-              in page, "/statusz names the persistent compile cache")
+        from znicz_tpu import compilecache
+        check("compile_cache: " + compilecache.resolve_dir() in page,
+              "/statusz names the persistent compile cache")
     finally:
         proc.terminate()
         try:

@@ -224,10 +224,11 @@ def make_parser() -> argparse.ArgumentParser:
                         "'1,1' or omitted = single-device jit "
                         "(docs/distributed.md)")
     p.add_argument("--compile-cache-dir", default=None, metavar="DIR",
-                   help="persistent on-disk XLA compilation cache: "
-                        "restarts reuse executables across processes "
-                        "(also: $ZNICZ_COMPILE_CACHE; "
-                        "docs/performance.md)")
+                   help="where the persistent XLA compilation cache "
+                        "lives (also: $ZNICZ_COMPILE_CACHE; default "
+                        "<checkout>/.cache/xla).  A set "
+                        "$JAX_COMPILATION_CACHE_DIR wins over both "
+                        "(docs/performance.md)")
     return p
 
 

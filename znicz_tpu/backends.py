@@ -23,6 +23,17 @@ import numpy as np
 from .logger import Logger
 
 
+def device_report() -> dict:
+    """The process's own devices as JAX reports them — the one
+    definition behind the trainer's start line and timeline rows,
+    ``/healthz``, the bench rows and ``chip_smoke.py``.  Initialises
+    the backend."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
 class Device(Logger):
     """Base device; knows how to move arrays and run compute."""
 
@@ -37,8 +48,15 @@ class Device(Logger):
             backend = "xla"
         if backend == "numpy":
             return NumpyDevice()
-        if backend in ("xla", "tpu", "jax"):
+        if backend in ("xla", "jax"):
             return XLADevice()
+        if backend == "tpu":
+            device = XLADevice()
+            if not device.is_tpu:
+                raise RuntimeError(
+                    f"backend 'tpu' requested but JAX's default device "
+                    f"is {device.platform!r}")
+            return device
         raise ValueError(f"unknown backend {backend!r}")
 
     def put(self, array):
@@ -81,7 +99,7 @@ class XLADevice(Device):
 
     @property
     def is_tpu(self) -> bool:
-        return self.platform not in ("cpu", "gpu")
+        return self.platform == "tpu"
 
     def put(self, array):
         return jax.device_put(array, self.jax_device)

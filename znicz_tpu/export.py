@@ -332,10 +332,8 @@ def build_native(force: bool = False) -> str:
             os.path.join(_NATIVE_DIR, "parallel.h")]
     if force and os.path.exists(so):
         os.unlink(so)
-    if not ensure_built(so, srcs, _NATIVE_DIR, "libznicz_infer.so"):
-        # unlike the record reader (which has a numpy fallback and
-        # returns None), serving has no fallback: a STALE .so must not
-        # be silently dlopened after an edit whose rebuild failed
-        raise RuntimeError("libznicz_infer.so build failed or is stale; "
-                           f"see `make -C {_NATIVE_DIR}` output")
+    # unlike the record reader (which has a numpy fallback), serving
+    # has none: a failed build raises NativeBuildError from here, so a
+    # STALE .so is never dlopened after an edit whose rebuild failed
+    ensure_built(so, srcs, _NATIVE_DIR, "libznicz_infer.so")
     return so

@@ -98,13 +98,11 @@ class Launcher:
                 os.environ["ZNICZ_TIMELINE_JSONL"] = prev
 
     def _trace_ctx(self):
-        """``jax.profiler.trace`` around the whole run when --profile DIR
-        is set (SURVEY.md §5 tracing row: the TPU-level complement to the
-        per-unit wall-clock time table, which is kept)."""
-        if not self.profile:
-            return contextlib.nullcontext()
-        import jax
-        return jax.profiler.trace(self.profile)
+        """A ``jax.profiler`` trace around the whole run when --profile
+        DIR is set (SURVEY.md §5 tracing row: the TPU-level complement
+        to the per-unit wall-clock time table, which is kept)."""
+        from .telemetry import profiler
+        return profiler.trace(self.profile)
 
     # -- distributed bootstrap (replaces Server/Client) --------------------
     def init_distributed(self) -> None:
@@ -126,7 +124,7 @@ class Launcher:
         resolve once the module's default structures exist."""
         self.init_distributed()
         # the persistent XLA compile cache must activate before any
-        # jit compile of the run (env default: $ZNICZ_COMPILE_CACHE)
+        # jit compile of the run
         from . import compilecache
         compilecache.enable(self.compile_cache_dir)
         if self.config_path:

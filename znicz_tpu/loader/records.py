@@ -59,12 +59,12 @@ def _native() -> ctypes.CDLL | None:
         so = os.path.join(d, "libznr_reader.so")
         # every build input the Makefile lists — a parallel.h-only edit
         # must trigger a rebuild too; exclusion + staleness live in the
-        # shared driver (native_build.py), same as the inference engine
+        # shared driver (native_build.py), same as the inference engine.
+        # A failed build raises into the numpy fallback below
         from ..native_build import ensure_built
-        if not ensure_built(so, [os.path.join(d, "znr_reader.cpp"),
-                                 os.path.join(d, "parallel.h")],
-                            d, "libznr_reader.so"):
-            return None                       # keep the numpy fallback
+        ensure_built(so, [os.path.join(d, "znr_reader.cpp"),
+                          os.path.join(d, "parallel.h")],
+                     d, "libznr_reader.so")
         lib = ctypes.CDLL(so)
         lib.znr_open.restype = ctypes.c_void_p
         lib.znr_open.argtypes = [ctypes.c_char_p] + [ctypes.c_int64] * 5

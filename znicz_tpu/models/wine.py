@@ -10,7 +10,7 @@ TPU-first: same StandardWorkflow assembly as every other sample; the
 loader reads the classic ``wine.data`` CSV when present and falls back
 to a deterministic synthetic stand-in with the real dataset's geometry
 (13 features, 3 classes) otherwise — this environment ships no
-datasets (BASELINE.md provenance note).
+datasets.
 
 Run: ``python -m znicz_tpu.models.wine [--backend=…] [--epochs=N]``
 """

@@ -9,7 +9,7 @@ TPU-native design decisions:
 * **Layout is NHWC / HWIO** — channels on the 128-lane minor dimension,
   which is what the TPU vector unit and XLA's conv emitter want.  (The
   reference used flattened row-major sample buffers; NCHW-era layouts pay
-  a relayout on TPU.)
+  a layout change on TPU.)
 * **XLA tier** uses ``lax.conv_general_dilated`` — XLA lowers convs
   straight onto the MXU with its own implicit im2col, fused with adjacent
   elementwise ops; this is the production path.

@@ -130,26 +130,30 @@ def model_flops(spec, params, input_shape) -> dict:
     return {"forward": fwd, "train_step": train, "params": n_params}
 
 
-#: Peak dense-matmul TFLOP/s per chip by device_kind substring, bf16
-#: (MXU native) and f32 rates.  Public figures from cloud.google.com TPU
-#: docs; used only to derive MFU, never asserted in tests.
-_PEAK_TFLOPS = (
-    ("v6e", 918.0, 459.0),
-    ("v6", 918.0, 459.0),
-    ("v5p", 459.0, 229.5),
-    ("v5e", 197.0, 98.5),
-    ("v5lite", 197.0, 98.5),      # device_kind "TPU v5 lite" (v5e)
-    ("v4", 275.0, 137.5),
-    ("v3", 123.0, 61.5),
-    ("v2", 45.0, 22.5),
+#: Published peak dense bf16 TFLOP/s per chip, by device_kind
+#: substring (cloud.google.com TPU docs).  The one MFU denominator:
+#: XLA runs f32 convs and dots as bf16 MXU passes at default precision,
+#: and no f32 peak is published.
+_PEAK_BF16_TFLOPS = (
+    ("v6e", 918.0),
+    ("v6", 918.0),
+    ("v5p", 459.0),
+    ("v5e", 197.0),
+    ("v5lite", 197.0),            # device_kind "TPU v5 lite" (v5e)
+    ("v4", 275.0),
+    ("v3", 123.0),
+    ("v2", 45.0),
 )
 
 
-def peak_tflops(device_kind: str, dtype: str = "float32"):
-    """Best-effort peak TFLOP/s for an MFU denominator, or None when the
-    chip generation can't be recognised from ``device_kind``."""
+def peak_tflops(device_kind: str) -> float:
+    """Published bf16 peak TFLOP/s of ``device_kind`` for an MFU
+    denominator.  A device that is not in the table is an error, not a
+    default."""
     kind = (device_kind or "").lower().replace(" ", "")
-    for tag, bf16, f32 in _PEAK_TFLOPS:
+    for tag, bf16 in _PEAK_BF16_TFLOPS:
         if tag in kind:
-            return bf16 if "bf16" in dtype or "bfloat16" in dtype else f32
-    return None
+            return bf16
+    raise ValueError(f"no published peak for device_kind "
+                     f"{device_kind!r}; add it to ops/flops.py with its "
+                     f"source before asking for an MFU")

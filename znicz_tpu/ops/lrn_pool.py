@@ -344,8 +344,9 @@ def pallas_gd_lrn_maxpool_split(errp, offsets, xe, xo, n, alpha, beta,
 def lrn_maxpool(x, n, alpha, beta, k, ksize, stride, padding,
                 use_abs=False):
     if tuning.use_pallas() and fusable(ksize, stride, padding):
-        return pallas_lrn_maxpool(x, n, alpha, beta, k, ksize, stride,
-                                  padding, use_abs)
+        return tuning.batch_sharded(
+            lambda x: pallas_lrn_maxpool(x, n, alpha, beta, k, ksize,
+                                         stride, padding, use_abs), x)
     return xla_lrn_maxpool(x, n, alpha, beta, k, ksize, stride, padding,
                            use_abs)
 
@@ -353,8 +354,10 @@ def lrn_maxpool(x, n, alpha, beta, k, ksize, stride, padding,
 def gd_lrn_maxpool(errp, offsets, x, n, alpha, beta, k, ksize, stride,
                    padding, fold_act=None):
     if tuning.use_pallas() and fusable(ksize, stride, padding):
-        return pallas_gd_lrn_maxpool(errp, offsets, x, n, alpha, beta, k,
-                                     ksize, stride, padding, fold_act)
+        return tuning.batch_sharded(
+            lambda errp, offsets, x: pallas_gd_lrn_maxpool(
+                errp, offsets, x, n, alpha, beta, k, ksize, stride,
+                padding, fold_act), errp, offsets, x)
     return xla_gd_lrn_maxpool(errp, offsets, x, n, alpha, beta, k, ksize,
                               stride, padding, fold_act)
 
@@ -365,8 +368,10 @@ def lrn_maxpool_split(xe, xo, n, alpha, beta, k, ksize, stride, padding,
     forward consumes and the backward reuses xe/xo, so x is never
     re-split).  The XLA tier re-interleaves — it has no split gain."""
     if tuning.use_pallas() and fusable(ksize, stride, padding):
-        return pallas_lrn_maxpool_split(xe, xo, n, alpha, beta, k,
-                                        ksize, stride, padding, use_abs)
+        return tuning.batch_sharded(
+            lambda xe, xo: pallas_lrn_maxpool_split(
+                xe, xo, n, alpha, beta, k, ksize, stride, padding,
+                use_abs), xe, xo)
     w = xe.shape[2] + xo.shape[2]
     return xla_lrn_maxpool(interleave_cols(xe, xo, w), n, alpha, beta,
                            k, ksize, stride, padding, use_abs)
@@ -376,10 +381,11 @@ def gd_lrn_maxpool_split(errp, offsets, xe, xo, n, alpha, beta, k,
                          ksize, stride, padding, fold_act=None,
                          return_split=False):
     if tuning.use_pallas() and fusable(ksize, stride, padding):
-        return pallas_gd_lrn_maxpool_split(errp, offsets, xe, xo, n,
-                                           alpha, beta, k, ksize,
-                                           stride, padding, fold_act,
-                                           return_split)
+        return tuning.batch_sharded(
+            lambda errp, offsets, xe, xo: pallas_gd_lrn_maxpool_split(
+                errp, offsets, xe, xo, n, alpha, beta, k, ksize, stride,
+                padding, fold_act, return_split),
+            errp, offsets, xe, xo)
     w = xe.shape[2] + xo.shape[2]
     dx = xla_gd_lrn_maxpool(errp, offsets,
                             interleave_cols(xe, xo, w), n, alpha, beta,

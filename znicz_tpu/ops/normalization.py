@@ -133,7 +133,8 @@ def lrn_y(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
     from . import tuning
     if tuning.use_pallas():
         from . import elementwise
-        return elementwise.pallas_lrn_y(x, n, alpha, beta, k)
+        return tuning.batch_sharded(
+            lambda x: elementwise.pallas_lrn_y(x, n, alpha, beta, k), x)
     return xla_lrn(x, n, alpha, beta, k)[0]
 
 
@@ -142,5 +143,7 @@ def gd_lrn_x(err, x, n=5, alpha=1e-4, beta=0.75, k=2.0):
     from . import tuning
     if tuning.use_pallas():
         from . import elementwise
-        return elementwise.pallas_gd_lrn_x(err, x, n, alpha, beta, k)
+        return tuning.batch_sharded(
+            lambda err, x: elementwise.pallas_gd_lrn_x(
+                err, x, n, alpha, beta, k), err, x)
     return xla_gd_lrn_x(err, x, n, alpha, beta, k)

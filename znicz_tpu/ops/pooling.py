@@ -116,14 +116,18 @@ def max_pooling(x, ksize, stride=None, padding=0):
     """Dispatcher: Pallas winner-select kernel on TPU, XLA otherwise."""
     from . import tuning
     if tuning.use_pallas():
-        return _pallas_max_pool(x, ksize, stride or ksize, padding, False)
+        return tuning.batch_sharded(
+            lambda x: _pallas_max_pool(x, ksize, stride or ksize,
+                                       padding, False), x)
     return xla_max_pooling(x, ksize, stride, padding)
 
 
 def maxabs_pooling(x, ksize, stride=None, padding=0):
     from . import tuning
     if tuning.use_pallas():
-        return _pallas_max_pool(x, ksize, stride or ksize, padding, True)
+        return tuning.batch_sharded(
+            lambda x: _pallas_max_pool(x, ksize, stride or ksize,
+                                       padding, True), x)
     return xla_maxabs_pooling(x, ksize, stride, padding)
 
 
@@ -237,8 +241,8 @@ def _pallas_gd_max_pool(err, offsets, x_shape, ksize, stride, padding):
     from . import elementwise
     (kh, kw), (sh, sw), (ph, pw) = _norm2(ksize), \
         _norm2(stride or ksize), _norm2(padding)
-    b, h, w, c = x_shape
-    _, oh, ow, _ = err.shape
+    _, h, w, c = x_shape
+    b, oh, ow, _ = err.shape      # b from the operand: batch_sharded
     taps = elementwise.pallas_pool_scatter(
         err.reshape(-1, c), offsets.reshape(-1, c), kh * kw)
     taps = taps.reshape(kh * kw, b, oh, ow, c)
@@ -252,8 +256,10 @@ def gd_max_pooling(err, offsets, x_shape, ksize, stride=None, padding=0):
     """Dispatcher: Pallas scatter kernel on TPU, XLA otherwise."""
     from . import tuning
     if tuning.use_pallas():
-        return _pallas_gd_max_pool(err, offsets, x_shape, ksize, stride,
-                                   padding)
+        return tuning.batch_sharded(
+            lambda err, offsets: _pallas_gd_max_pool(
+                err, offsets, x_shape, ksize, stride, padding),
+            err, offsets)
     return xla_gd_max_pooling(err, offsets, x_shape, ksize, stride,
                               padding)
 
@@ -296,8 +302,10 @@ def depooling(x, offsets, out_shape, ksize, stride=None, padding=0):
     """Dispatcher for the decoder-path scatter (same core as gd_max)."""
     from . import tuning
     if tuning.use_pallas():
-        return _pallas_gd_max_pool(x, offsets, out_shape, ksize, stride,
-                                   padding)
+        return tuning.batch_sharded(
+            lambda x, offsets: _pallas_gd_max_pool(
+                x, offsets, out_shape, ksize, stride, padding),
+            x, offsets)
     return xla_depooling(x, offsets, out_shape, ksize, stride, padding)
 
 
@@ -318,11 +326,15 @@ def gd_depooling(err, offsets, ksize, stride=None, padding=0):
     from . import elementwise, tuning
     if not tuning.use_pallas():
         return xla_gd_depooling(err, offsets, ksize, stride, padding)
-    b, oh, ow, c = offsets.shape
-    taps = _tap_stack(err, (oh, ow), ksize, stride, padding, 0.0, jnp)
-    out = elementwise.pallas_pool_gather(
-        taps.reshape(taps.shape[0], -1, c), offsets.reshape(-1, c))
-    return out.reshape(b, oh, ow, c)
+
+    def gather(err, offsets):
+        b, oh, ow, c = offsets.shape
+        taps = _tap_stack(err, (oh, ow), ksize, stride, padding, 0.0,
+                          jnp)
+        out = elementwise.pallas_pool_gather(
+            taps.reshape(taps.shape[0], -1, c), offsets.reshape(-1, c))
+        return out.reshape(b, oh, ow, c)
+    return tuning.batch_sharded(gather, err, offsets)
 
 
 def np_gd_avg_pooling(err, x_shape, ksize, stride=None, padding=0):

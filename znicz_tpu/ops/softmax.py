@@ -151,5 +151,6 @@ def softmax(x):
 
 def softmax_ce_from_logits(logits, labels):
     if tuning.use_pallas():
-        return pallas_softmax_ce_from_logits(logits, labels)
+        return tuning.batch_sharded(pallas_softmax_ce_from_logits,
+                                    logits, labels)
     return xla_softmax_ce_from_logits(logits, labels)

@@ -128,10 +128,7 @@ class TrainerCheckpointer:
         ownership rule as the save-side manifests; other processes
         verify read-only and land on the same answer (they skip the
         same corrupt steps)."""
-        try:
-            steps = sorted(self._mngr.all_steps(read=True), reverse=True)
-        except TypeError:                  # older orbax: no read kwarg
-            steps = sorted(self._mngr.all_steps(), reverse=True)
+        steps = sorted(self._mngr.all_steps(read=True), reverse=True)
         owner = jax.process_index() == 0
         found = durability.newest_verified(
             (self._step_dir(s) for s in steps),

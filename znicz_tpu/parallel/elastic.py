@@ -82,7 +82,7 @@ class ElasticRunner(Logger):
 
     Restart pacing: a dead fleet restarts after a bounded-exponential
     jittered backoff (``backoff_base_s * 2**n`` capped at
-    ``backoff_max_s`` — a hot restart loop against a dead relay/DCN
+    ``backoff_max_s`` — a hot restart loop against a dead coordinator/DCN
     just burns the restart budget in seconds), and
     ``crash_loop_threshold`` failures inside ``crash_loop_window_s``
     fail FAST with every worker's log tail aggregated — a

@@ -126,10 +126,12 @@ class StreamTrainer(FusedTrainer):
         # separable in compile_time_ms
         from ..telemetry import compilestats
         self._step_fn = compilestats.first_call_timed(
-            jax.jit(step, donate_argnums=(0, 1), **jit_kw),
+            jax.jit(self._mesh_scoped(step), donate_argnums=(0, 1),
+                    **jit_kw),
             site="train.stream", cause="cold")
         self._eval_fn = compilestats.first_call_timed(
-            jax.jit(estep, **ejit_kw), site="train.stream", cause="cold")
+            jax.jit(self._mesh_scoped(estep), **ejit_kw),
+            site="train.stream", cause="cold")
         if self.accum_steps > 1:
             # gradient accumulation over the streamed step loop: grads
             # per micro-batch, one update per group — the host-loop
@@ -170,7 +172,7 @@ class StreamTrainer(FusedTrainer):
                 gkw["out_shardings"] = (gsh, self._repl)
                 akw["out_shardings"] = (psh, psh)
                 ckw["out_shardings"] = gsh
-            self._grad_fn = jax.jit(gstep, **gkw)
+            self._grad_fn = jax.jit(self._mesh_scoped(gstep), **gkw)
             # donate only the velocity/accumulator buffers: params are
             # read by every layer's decay term before their new value
             # exists, so XLA can't reuse them and warns

@@ -1,7 +1,7 @@
 """Fault injection, retry/backoff, and circuit breaking.
 
 The robustness half of the serving story (PR 1 shipped backpressure;
-this package ships degradation): preemption, relay drops, and transient
+this package ships degradation): preemption, coordinator drops, and transient
 device errors are the steady state on shared TPU fleets, so every layer
 that talks to a device, the filesystem, or another process goes through
 one of three small primitives:

@@ -1,6 +1,6 @@
 """Seeded, deterministic fault injection at named sites.
 
-Serving heavy traffic on TPUs means preemption, relay drops, and
+Serving heavy traffic on TPUs means preemption, coordinator drops, and
 transient device errors are the steady state (ROADMAP north star;
 SURVEY.md §5 — the reference's master/slave protocol existed largely to
 survive lost slaves).  Testing the recovery machinery therefore needs a

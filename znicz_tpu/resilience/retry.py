@@ -1,6 +1,6 @@
 """Reusable retry policy: bounded attempts, exponential backoff with
 deterministic jitter, optional per-attempt timeout, and an exception
-classifier separating transient faults (device hiccup, relay drop,
+classifier separating transient faults (device hiccup, connection drop,
 filesystem blip — retry) from deterministic bugs (bad geometry, type
 errors — fail immediately; retrying a ValueError just repeats it).
 

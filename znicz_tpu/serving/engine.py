@@ -15,9 +15,10 @@ bounded LRU — steady-state traffic hits a handful of executables, and
 an evicted bucket simply recompiles on next use.  Oversized batches
 chunk through the largest bucket.
 
-Backend: ``auto`` uses JAX when a backend initializes and falls back to
-``export.NativeEngine`` (the C++ CPU engine) otherwise, so a host with
-no usable JAX devices can still serve.  The JAX forward deliberately
+Backend: ``auto`` uses JAX wherever JAX can be imported and
+``export.NativeEngine`` (the C++ CPU engine) on a JAX-less host; a JAX
+backend that fails to initialise is an error, not a reason to answer
+from the CPU (``_jax_usable``).  The JAX forward deliberately
 sticks to the XLA op tier (``ops/*.xla_*``) — serving wants the
 portable, numerically-pinned path, not the Pallas training kernels.
 
@@ -452,13 +453,30 @@ def quantize_layers(layers: list[ZnnLayer]) -> tuple[list, int]:
 
 
 def _jax_usable() -> bool:
-    """Whether this host has an initializable JAX backend at all —
-    the fallback trigger the engine's ``backend="auto"`` keys on."""
+    """Whether ``backend="auto"`` serves through JAX.  False only on
+    the JAX-less host the native engine exists for: JAX cannot be
+    imported.  A JAX that imports but whose backend will not
+    initialise — the chip is held by another process, the platform
+    ``JAX_PLATFORMS`` names is missing — raises from here: answering
+    from the CPU engine instead would hide the device."""
     try:
         import jax
-        return len(jax.devices()) > 0
-    except Exception:
+    except ImportError:
         return False
+    jax.devices()
+    return True
+
+
+def device_report(backend: str) -> dict:
+    """``platform`` / ``device_kind`` / ``device_count`` of the devices
+    behind an engine of ``backend``, for ``/healthz`` and ``/statusz``:
+    the process's own JAX devices, or the host CPU with no JAX device
+    for the native engine."""
+    if backend == "jax":
+        from ..backends import device_report as jax_report
+        return jax_report()
+    return {"platform": "cpu", "device_kind": "native",
+            "device_count": 0}
 
 
 class ServingEngine:

@@ -49,7 +49,9 @@ docs/serving.md "Wire protocol"):
   the engine's resilience state — ``ok`` | ``degraded`` (circuit open,
   native CPU fallback serving) | ``open`` (circuit open, no fallback:
   predicts answer 503 + Retry-After) — so a load balancer can rotate a
-  degraded replica out BEFORE clients see 503s.  Also carries
+  degraded replica out BEFORE clients see 503s.  ``platform`` /
+  ``device_kind`` / ``device_count`` name the devices behind
+  ``backend`` as the process itself sees them.  Also carries
   ``model_generation`` and ``last_reload`` (outcome of the most recent
   hot reload), so a rollout driver can poll whether its swap landed;
   with an in-process promotion controller attached
@@ -143,7 +145,7 @@ from ..telemetry.registry import (PROMETHEUS_CONTENT_TYPE, REGISTRY,
 from . import wire
 from . import zoo as zoo_mod
 from .batcher import DeadlineExceeded, MicroBatcher, QueueFull
-from .engine import ServingEngine
+from .engine import ServingEngine, device_report
 from .memo import ResponseCache
 
 #: routes with their own label value in requests_total/errors_total —
@@ -1459,6 +1461,10 @@ class ServingServer:
             # refusals reach clients — the probe is how balancers learn
             state = "draining"
         out = {"status": state, "backend": self.engine.backend,
+               # the devices behind that backend, as the process sees
+               # them: a JAX engine that came up on XLA:CPU reads
+               # backend "jax" too, and only the platform tells
+               **device_report(self.engine.backend),
                "n_layers": self.engine.n_layers,
                "buckets": list(self.engine.buckets),
                "queue_depth": self.batcher.queue_depth(),
@@ -1871,10 +1877,11 @@ def main(argv=None) -> int:
                         "and generation; round-robin dispatch routes "
                         "around a replica whose breaker is open")
     p.add_argument("--compile-cache-dir", default=None, metavar="DIR",
-                   help="persistent on-disk XLA compilation cache: "
-                        "restarts and hot reloads reuse executables "
-                        "across processes (also: "
-                        "$ZNICZ_COMPILE_CACHE; docs/performance.md)")
+                   help="where the persistent XLA compilation cache "
+                        "lives (also: $ZNICZ_COMPILE_CACHE; default "
+                        "<checkout>/.cache/xla).  A set "
+                        "$JAX_COMPILATION_CACHE_DIR wins over both "
+                        "(docs/performance.md)")
     p.add_argument("--slo", action="append", metavar="SPEC",
                    help="declare one SLO judged as rolling multi-"
                         "window burn rates: NAME[,model=M]"

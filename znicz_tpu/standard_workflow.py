@@ -400,6 +400,21 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                                        root.common.get("accum_steps")
                                        or 1))
         trainer.workflow = self
+        # where this run executes, stated by the program itself: once
+        # here and in every timeline row, so a run that came up on the
+        # wrong platform, mesh or kernel tier cannot pass for another
+        from .backends import device_report
+        from .ops import tuning
+        from .parallel.mesh import mesh_shape_of
+        where = {**device_report(),
+                 "mesh": "x".join(str(d) for d in mesh_shape_of(mesh)),
+                 # distinct devices the parameters are laid out over
+                 "param_devices": max(
+                     len(w.sharding.device_set)
+                     for w, _ in trainer.params if w is not None),
+                 "kernel_tier": tuning.kernel_tier()}
+        self.info("fused trainer on %s",
+                  " ".join(f"{k}={v!r}" for k, v in where.items()))
         # host-vs-device time split (telemetry): everything spent
         # inside trainer.train_epoch/eval_epoch calls is device-bound
         # work (dispatch + compute + readback; epoch 0 also carries
@@ -592,7 +607,7 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             _flightrecorder.RECORDER.record(
                 "train_step", duration_ms=epoch_s * 1e3, **step_row)
             if timeline is not None:
-                timeline.write({"at": time.time(), **step_row})
+                timeline.write({"at": time.time(), **step_row, **where})
             self.metrics_writer.write(kind="epoch", **metrics)
             if self.lr_adjuster is not None:
                 # keep the tick-path iteration counter current so

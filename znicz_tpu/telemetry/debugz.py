@@ -136,8 +136,10 @@ def statusz_text(server=None, *, recorder=None, extra: dict | None = None
         eng = server.engine
         em = eng.metrics()
         lines += ["", "serving", "-" * 7]
+        from ..serving.engine import device_report
         lines.append(_fmt_kv({
             "backend": eng.backend,
+            **device_report(eng.backend),
             "status": em.get("resilience_state"),
             "generation": em.get("generation"),
             "buckets": ",".join(str(b) for b in eng.buckets),

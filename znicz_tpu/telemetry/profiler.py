@@ -58,7 +58,6 @@ def _shutdown_signals_blocked():
 
 _lock = threading.Lock()
 _active_dir: str | None = None
-_session = None       # our own ProfilerSession when we manage one
 
 PROFILE_DIR_ENV = "ZNICZ_PROFILE_DIR"
 PROFILE_EVERY_ENV = "ZNICZ_PROFILE_EVERY"
@@ -79,10 +78,12 @@ def every_from_env() -> int | None:
         return None
 
 
-def _make_session():
-    """An XLA ``ProfilerSession`` with the **python tracer OFF**, or
-    None when this jaxlib doesn't expose the options (callers then
-    fall back to ``jax.profiler.start_trace``).
+def start_trace(trace_dir: str) -> bool:
+    """Begin capturing into ``trace_dir`` (created if needed), with the
+    **python tracer OFF**.  Returns False — never raises — when JAX is
+    unavailable or a capture is already running: profiling is
+    observability, and observability failing must not take the
+    workload down.
 
     Why off: the python tracer hooks every live Python thread via
     ``PyEval_SetProfile`` at session start — observed here to break
@@ -91,23 +92,7 @@ def _make_session():
     becomes unkillable except by SIGKILL).  The trace this repo wants
     is the host/device (XLA op) timeline; Python-side timing is
     already covered by telemetry.tracing spans and the step gauges."""
-    try:
-        import jax
-        from jax._src.lib import xla_client
-        jax.devices()     # backend must exist before the tracer does
-        opts = xla_client.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        return xla_client.profiler.ProfilerSession(opts)
-    except Exception:
-        return None
-
-
-def start_trace(trace_dir: str) -> bool:
-    """Begin capturing into ``trace_dir`` (created if needed).  Returns
-    False — never raises — when JAX is unavailable or a capture is
-    already running: profiling is observability, and observability
-    failing must not take the workload down."""
-    global _active_dir, _session
+    global _active_dir
     with _lock:
         if _active_dir is not None:
             _log.warning("profiler already tracing into %s; ignoring "
@@ -116,10 +101,11 @@ def start_trace(trace_dir: str) -> bool:
         try:
             import jax
             os.makedirs(trace_dir, exist_ok=True)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
             with _shutdown_signals_blocked():
-                _session = _make_session()
-                if _session is None:
-                    jax.profiler.start_trace(trace_dir)
+                jax.profiler.start_trace(trace_dir,
+                                         profiler_options=options)
         except Exception as e:
             _log.warning("jax.profiler unavailable (%s); profiling "
                          "disabled", e)
@@ -131,18 +117,14 @@ def start_trace(trace_dir: str) -> bool:
 def stop_trace() -> str | None:
     """End the active capture; returns its directory (None when no
     capture was running)."""
-    global _active_dir, _session
+    global _active_dir
     with _lock:
         if _active_dir is None:
             return None
         trace_dir, _active_dir = _active_dir, None
-        session, _session = _session, None
         try:
-            if session is not None:
-                session.stop_and_export(trace_dir)
-            else:
-                import jax
-                jax.profiler.stop_trace()
+            import jax
+            jax.profiler.stop_trace()
         except Exception as e:
             _log.warning("profiler trace export failed: %s", e)
         return trace_dir
