@@ -402,14 +402,20 @@ class TestTrainEquivalence:
         from znicz_tpu.models import alexnet
         from znicz_tpu.standard_workflow import StandardWorkflow
 
-        root.alexnet.synthetic.update({"n_train": 64, "n_valid": 32,
-                                       "n_test": 0})
-        root.alexnet.update({"minibatch_size": 32, "size": 67,
-                             "n_classes": 7})
-        root.alexnet.layers = alexnet.make_layers(
-            n_classes=7, widths=(8, 12, 8, 8, 8, 24, 16))
-        wf = alexnet.AlexNetWorkflow()
-        wf.initialize(device=Device.create("xla"))
+        # the global config tree is restored after the build: whichever
+        # test file shares this worker next reads root.alexnet too
+        saved = root.alexnet.to_dict()
+        try:
+            root.alexnet.synthetic.update({"n_train": 64, "n_valid": 32,
+                                           "n_test": 0})
+            root.alexnet.update({"minibatch_size": 32, "size": 67,
+                                 "n_classes": 7})
+            root.alexnet.layers = alexnet.make_layers(
+                n_classes=7, widths=(8, 12, 8, 8, 8, 24, 16))
+            wf = alexnet.AlexNetWorkflow()
+            wf.initialize(device=Device.create("xla"))
+        finally:
+            root.alexnet.update(saved)
         return wf
 
     def test_merged_equals_split(self, monkeypatch):

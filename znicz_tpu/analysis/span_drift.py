@@ -15,7 +15,8 @@ Cross-check, repo-wide:
 * **Registered names**: every string constant in walked code shaped
   like a stage/span name — dotted, rooted in one of the known stage
   namespaces (``router.`` / ``server.`` / ``batcher.`` / ``engine.`` /
-  ``net.``).  This covers ``tracing.span("batcher.dispatch", ...)``
+  ``net.``, and the fused trainer's ``train.`` / ``trainer.``).  This
+  covers ``tracing.span("batcher.dispatch", ...)``
   call sites, the ``tracestore.STAGES`` tuple, and the assembler's
   stage-key literals in one sweep.
 * **References**: backticked dotted tokens with the same namespace
@@ -41,7 +42,8 @@ DEFAULT_DOC_PATHS = ("docs/observability.md",)
 #: a token must be dotted AND rooted in a stage namespace to count —
 #: `np.asarray`, `lax.scan`, `znicz_tpu.telemetry` all stay prose
 _STAGE_SHAPE = re.compile(
-    r"^(?:router|server|batcher|engine|net)\.[a-z0-9_]+(?:\.[a-z0-9_]+)*$")
+    r"^(?:router|server|batcher|engine|net|train|trainer)"
+    r"\.[a-z0-9_]+(?:\.[a-z0-9_]+)*$")
 
 #: backticked dotted token, optionally carrying a label set
 _BACKTICK = re.compile(r"`([a-z][a-z0-9_.]*)(\{[^`]*\})?`")

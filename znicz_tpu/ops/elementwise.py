@@ -94,6 +94,7 @@ def pallas_act_fwd(name: str, x):
             in_specs=[pl.BlockSpec((br, c), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((rows_pad, c), x.dtype),
+            name=f"pallas_act_fwd_{name}",
             interpret=tuning.interpret_mode(),
         )(x2)
         return y[:rows].reshape(x.shape)
@@ -106,6 +107,7 @@ def pallas_act_fwd(name: str, x):
         in_specs=[pl.BlockSpec((br, _LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, _LANES), x.dtype),
+        name=f"pallas_act_fwd_{name}",
         interpret=tuning.interpret_mode(),
     )(x2)
     return y.reshape(-1)[:n].reshape(x.shape)
@@ -125,6 +127,7 @@ def pallas_act_bwd(name: str, err_y, y, x=None):
             grid=(rows_pad // br,),
             in_specs=[spec, spec, spec], out_specs=spec,
             out_shape=jax.ShapeDtypeStruct((rows_pad, c), err_y.dtype),
+            name=f"pallas_act_bwd_{name}",
             interpret=tuning.interpret_mode(),
         )(e2, y2, x2)
         return out[:rows].reshape(err_y.shape)
@@ -143,6 +146,7 @@ def pallas_act_bwd(name: str, err_y, y, x=None):
             in_specs=[spec, spec, spec], out_specs=spec,
             out_shape=jax.ShapeDtypeStruct((rows_pad, _LANES),
                                            err_y.dtype),
+            name=f"pallas_act_bwd_{name}",
             interpret=tuning.interpret_mode(),
         )(e2, y2, x2)
     else:
@@ -153,6 +157,7 @@ def pallas_act_bwd(name: str, err_y, y, x=None):
             in_specs=[spec, spec], out_specs=spec,
             out_shape=jax.ShapeDtypeStruct((rows_pad, _LANES),
                                            err_y.dtype),
+            name=f"pallas_act_bwd_{name}",
             interpret=tuning.interpret_mode(),
         )(e2, y2)
     return out.reshape(-1)[:n].reshape(err_y.shape)
@@ -194,6 +199,7 @@ def pallas_dropout(x, seed: int, counters, ratio: float):
             in_specs=[pl.BlockSpec((br, _LANES), lambda i, k: (i, 0))],
             out_specs=pl.BlockSpec((br, _LANES), lambda i, k: (i, 0))),
         out_shape=jax.ShapeDtypeStruct((rows_pad, _LANES), x.dtype),
+        name="pallas_dropout",
         interpret=tuning.interpret_mode(),
     )(key, x2)
     return out.reshape(-1)[:n].reshape(x.shape)
@@ -219,7 +225,7 @@ def _lrn_fwd_y_kernel(x_ref, y_ref, *, n, alpha, beta, k):
         y_ref.dtype)
 
 
-def _lrn_pallas(kernel, inputs, out_dtypes, n_operands):
+def _lrn_pallas(name, kernel, inputs, out_dtypes, n_operands):
     """Shared rows×channels tiling for the LRN kernel family: channels
     on the lane axis, row blocks budget-sized for ``n_operands`` live
     buffers; pads rows to the block, slices the pad back off."""
@@ -243,6 +249,7 @@ def _lrn_pallas(kernel, inputs, out_dtypes, n_operands):
         in_specs=[spec] * len(inputs),
         out_specs=[spec] * len(out_dtypes) if many else spec,
         out_shape=shapes if many else shapes[0],
+        name=name,
         interpret=tuning.interpret_mode(),
     )(*(to2(a) for a in inputs))
     res = tuple(o[:rows].reshape(*lead, c)
@@ -255,6 +262,7 @@ def pallas_lrn(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
     """Cross-channel LRN fwd: rows = every spatial position, channels on
     the lane axis; window sum + powers in one VMEM pass → (y, denom)."""
     return _lrn_pallas(
+        "pallas_lrn",
         functools.partial(_lrn_fwd_kernel, n=n, alpha=alpha, beta=beta,
                           k=k),
         (x,), (x.dtype, jnp.float32), 4)      # 1 in + 2 out + temps
@@ -282,6 +290,7 @@ def _lrn_bwd_x_kernel(e_ref, x_ref, o_ref, *, n, alpha, beta, k):
 @functools.partial(jax.jit, static_argnames=("n", "alpha", "beta", "k"))
 def pallas_gd_lrn(err, x, d, n=5, alpha=1e-4, beta=0.75, k=2.0):
     return _lrn_pallas(
+        "pallas_gd_lrn",
         functools.partial(_lrn_bwd_kernel, n=n, alpha=alpha, beta=beta),
         (err, x, d), (jnp.float32,), 5)       # 3 in + 1 out + temps
 
@@ -290,6 +299,7 @@ def pallas_gd_lrn(err, x, d, n=5, alpha=1e-4, beta=0.75, k=2.0):
 def pallas_lrn_y(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
     """LRN forward emitting only y — one HBM read + one write."""
     return _lrn_pallas(
+        "pallas_lrn_y",
         functools.partial(_lrn_fwd_y_kernel, n=n, alpha=alpha, beta=beta,
                           k=k),
         (x,), (x.dtype,), 3)                  # 1 in + 1 out + temps
@@ -299,6 +309,7 @@ def pallas_lrn_y(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
 def pallas_gd_lrn_x(err, x, n=5, alpha=1e-4, beta=0.75, k=2.0):
     """LRN backward recomputing the denominator from x in VMEM."""
     return _lrn_pallas(
+        "pallas_gd_lrn_x",
         functools.partial(_lrn_bwd_x_kernel, n=n, alpha=alpha, beta=beta,
                           k=k),
         (err, x), (jnp.float32,), 4)          # 2 in + 1 out + temps
@@ -338,6 +349,7 @@ def pallas_pool_select(taps, use_abs: bool = False):
                    pl.BlockSpec((br, c), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows_pad, c), taps.dtype),
                    jax.ShapeDtypeStruct((rows_pad, c), jnp.int32)],
+        name="pallas_pool_select",
         interpret=tuning.interpret_mode(),
     )(taps)
     return y[:rows], idx[:rows]
@@ -372,6 +384,7 @@ def pallas_pool_scatter(err, offsets, n_taps: int):
                   pl.BlockSpec((br, c), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((n_taps, br, c), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_taps, rows_pad, c), err.dtype),
+        name="pallas_pool_scatter",
         interpret=tuning.interpret_mode(),
     )(err, offsets)
     return out[:, :rows]
@@ -406,6 +419,7 @@ def pallas_pool_gather(taps, offsets):
                   pl.BlockSpec((br, c), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, c), taps.dtype),
+        name="pallas_pool_gather",
         interpret=tuning.interpret_mode(),
     )(taps, offsets)
     return out[:rows]

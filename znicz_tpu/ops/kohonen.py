@@ -173,6 +173,7 @@ def pallas_distance_argmin(x, w):
             jax.ShapeDtypeStruct((bp, 128), jnp.float32),
             jax.ShapeDtypeStruct((bp, 128), jnp.int32),
         ],
+        name="pallas_distance_argmin",
         interpret=tuning.interpret_mode(),
     )(x, w)
     return win[:b, 0], dmin[:b, 0]

@@ -219,6 +219,7 @@ def pallas_lrn_maxpool_split(xe, xo, n, alpha, beta, k, ksize, stride,
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((b, oh, ow, c), xe.dtype),
                    jax.ShapeDtypeStruct((b, oh, ow, c), jnp.int32)],
+        name="pallas_lrn_maxpool_split",
         interpret=tuning.interpret_mode(),
     )(*([xe] * kh + [xo] * kh))
     return y, idx
@@ -332,6 +333,7 @@ def pallas_gd_lrn_maxpool_split(errp, offsets, xe, xo, n, alpha, beta,
         out_specs=[row_spec(we), row_spec(wo)],
         out_shape=[jax.ShapeDtypeStruct((b, h, we, c), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, wo, c), jnp.float32)],
+        name="pallas_gd_lrn_maxpool_split",
         interpret=tuning.interpret_mode(),
     )(xe, xo, *([errp] * n_contrib + [offsets] * n_contrib))
     if return_split:

@@ -113,6 +113,7 @@ def pallas_matmul(x, w, block_m: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
+        name="pallas_matmul",
         interpret=tuning.interpret_mode(),
     )(x, w)
     return out[:m, :n]
@@ -178,6 +179,7 @@ def pallas_matmul_at_b(a, b, block_k: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((bk, bn), lambda i, j, mm: (i, j)),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((kp, np_), out_dtype),
+        name="pallas_matmul_at_b",
         interpret=tuning.interpret_mode(),
     )(a, b)
     return out[:k, :n]

@@ -95,6 +95,7 @@ def pallas_softmax(x, block_rows: int = 256):
                    pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((npad, c), x.dtype),
                    jax.ShapeDtypeStruct((npad, 1), jnp.int32)],
+        name="pallas_softmax",
         interpret=tuning.interpret_mode(),
     )(x)
     return y[:n], idx[:n, 0]
@@ -138,6 +139,7 @@ def pallas_softmax_ce_from_logits(logits, labels, block_rows: int = 256):
         out_shape=[jax.ShapeDtypeStruct((npad, c), logits.dtype),
                    jax.ShapeDtypeStruct((npad, 1), jnp.float32),
                    jax.ShapeDtypeStruct((npad, c), logits.dtype)],
+        name="pallas_softmax_ce_from_logits",
         interpret=tuning.interpret_mode(),
     )(logits, labels2d)
     return y[:n], loss[:n, 0], err[:n]

@@ -93,6 +93,7 @@ def pallas_sgd_update(w, grad, vel, hypers):
         ),
         out_shape=[jax.ShapeDtypeStruct((rows_pad, cols), dtype),
                    jax.ShapeDtypeStruct((rows_pad, cols), jnp.float32)],
+        name="pallas_sgd_update",
         interpret=tuning.interpret_mode(),
     )(hypers.astype(jnp.float32), wf, gf, vf)
     w_new = w_new.reshape(-1)[:n].reshape(shape)

@@ -33,6 +33,7 @@ from ..ops import (activations, conv as conv_ops, deconv as deconv_ops,
                    dropout as drop_ops, lrn_pool as lrn_pool_ops,
                    normalization as lrn_ops, pooling as pool_ops,
                    softmax as softmax_ops, tuning)
+from ..telemetry import compilestats, tracing
 from . import mesh as mesh_lib
 
 #: Layer kinds with trainable parameters.
@@ -319,6 +320,26 @@ def _merge_lrn_pool(layers, params, vels):
     return remapped, out_p, out_v, tuple(src)
 
 
+# -- names in the device trace ----------------------------------------------
+def layer_label(spec: ModelSpec, i: int) -> str:
+    """``L<unit>.<kind>`` of spec row ``i``: ``unit`` is the index of the
+    first workflow forward unit the row stands for (a merged ``lrn_pool``
+    row names its LRN), so a trace joins to the configuration's layer
+    list whatever ``_merge_lrn_pool`` did to the rows."""
+    unit = spec.unit_index[i] if spec.unit_index else i
+    return f"L{unit:02d}.{spec.layers[i].kind}"
+
+
+def layer_scope(phase: str, spec: ModelSpec, i: int):
+    """``jax.named_scope`` of one layer's work in one phase of the step
+    (``fwd`` | ``bwd`` | ``upd``): every operation traced inside carries
+    ``<phase>/L<unit>.<kind>`` in its HLO ``op_name``, which is what a
+    profile or the compiled text attributes device time by.  Costs trace
+    time only.  The other scopes of the step are ``input``, ``loss`` and
+    ``accum`` (docs/observability.md has the table)."""
+    return jax.named_scope(f"{phase}/{layer_label(spec, i)}")
+
+
 # -- pure math (all traced; spec is static) --------------------------------
 def forward(spec: ModelSpec, params, x, *, want_caches: bool,
             train: bool = False, epoch=0, ctr=0):
@@ -338,135 +359,136 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
     in_shapes = []   # decoder layers can reach their tied encoder layer
     n = len(spec.layers)
     for i, (layer, (w, b)) in enumerate(zip(spec.layers, params)):
-        x_in, aux = h, None
-        if isinstance(h, tuple):     # split-out conv → pair handoff:
-            b_, h_, we, c_ = h[0].shape          # record logical shape
-            in_shapes.append((b_, h_, we + h[1].shape[2], c_))
-        else:
-            in_shapes.append(tuple(h.shape))
-        cfg = layer.cfg
-        is_last = i == n - 1
-        if layer.kind == "fc":
-            pre = jnp.dot(h.reshape(h.shape[0], -1).astype(cdt),
-                          w.astype(cdt),
-                          preferred_element_type=jnp.float32)
-            if b is not None:
-                pre = pre + b
-            if is_last and spec.loss == "softmax":
-                h = pre                   # logits; softmax fused with CE
+        with layer_scope("fwd", spec, i):
+            x_in, aux = h, None
+            if isinstance(h, tuple):     # split-out conv → pair handoff:
+                b_, h_, we, c_ = h[0].shape          # record logical shape
+                in_shapes.append((b_, h_, we + h[1].shape[2], c_))
             else:
-                h = spec.act(i).fwd(pre, jnp)
-        elif layer.kind == "conv":
-            if cfg.get("split_out"):
-                # phase-2: emit the column-parity halves the merged
-                # pair consumes — the split pass over the conv output
-                # never exists (ops/conv.py parity decomposition)
-                pe, po = conv_ops.xla_conv2d_split(
-                    h.astype(cdt), w.astype(cdt), cfg["stride"],
-                    cfg["padding"], out_dtype=jnp.float32)
+                in_shapes.append(tuple(h.shape))
+            cfg = layer.cfg
+            is_last = i == n - 1
+            if layer.kind == "fc":
+                pre = jnp.dot(h.reshape(h.shape[0], -1).astype(cdt),
+                              w.astype(cdt),
+                              preferred_element_type=jnp.float32)
                 if b is not None:
-                    pe, po = pe + b, po + b
-                h = (spec.act(i).fwd(pe, jnp), spec.act(i).fwd(po, jnp))
-            else:
-                pre = conv_ops.conv2d(h.astype(cdt), w.astype(cdt),
-                                      cfg["stride"], cfg["padding"],
-                                      out_dtype=jnp.float32)
+                    pre = pre + b
+                if is_last and spec.loss == "softmax":
+                    h = pre                   # logits; softmax fused with CE
+                else:
+                    h = spec.act(i).fwd(pre, jnp)
+            elif layer.kind == "conv":
+                if cfg.get("split_out"):
+                    # phase-2: emit the column-parity halves the merged
+                    # pair consumes — the split pass over the conv output
+                    # never exists (ops/conv.py parity decomposition)
+                    pe, po = conv_ops.xla_conv2d_split(
+                        h.astype(cdt), w.astype(cdt), cfg["stride"],
+                        cfg["padding"], out_dtype=jnp.float32)
+                    if b is not None:
+                        pe, po = pe + b, po + b
+                    h = (spec.act(i).fwd(pe, jnp), spec.act(i).fwd(po, jnp))
+                else:
+                    pre = conv_ops.conv2d(h.astype(cdt), w.astype(cdt),
+                                          cfg["stride"], cfg["padding"],
+                                          out_dtype=jnp.float32)
+                    if b is not None:
+                        pre = pre + b
+                    h = spec.act(i).fwd(pre, jnp)
+            elif layer.kind == "deconv":
+                wt = w if w is not None else params[cfg["tie"]][0]
+                pre = deconv_ops.deconv2d(h.astype(cdt), wt.astype(cdt),
+                                          cfg["stride"], cfg["padding"],
+                                          out_dtype=jnp.float32)
                 if b is not None:
                     pre = pre + b
                 h = spec.act(i).fwd(pre, jnp)
-        elif layer.kind == "deconv":
-            wt = w if w is not None else params[cfg["tie"]][0]
-            pre = deconv_ops.deconv2d(h.astype(cdt), wt.astype(cdt),
-                                      cfg["stride"], cfg["padding"],
-                                      out_dtype=jnp.float32)
-            if b is not None:
-                pre = pre + b
-            h = spec.act(i).fwd(pre, jnp)
-        elif layer.kind == "depooling":
-            off = auxes[cfg["tie"]]
-            h = pool_ops.depooling(
-                h, off, in_shapes[cfg["tie"]], cfg["ksize"],
-                cfg["stride"], cfg["padding"])
-            aux = off
-        elif layer.kind == "max_pool":
-            h, aux = pool_ops.max_pooling(h, cfg["ksize"],
-                                          cfg["stride"], cfg["padding"])
-        elif layer.kind == "maxabs_pool":
-            h, aux = pool_ops.maxabs_pooling(h, cfg["ksize"],
-                                             cfg["stride"],
+            elif layer.kind == "depooling":
+                off = auxes[cfg["tie"]]
+                h = pool_ops.depooling(
+                    h, off, in_shapes[cfg["tie"]], cfg["ksize"],
+                    cfg["stride"], cfg["padding"])
+                aux = off
+            elif layer.kind == "max_pool":
+                h, aux = pool_ops.max_pooling(h, cfg["ksize"],
+                                              cfg["stride"], cfg["padding"])
+            elif layer.kind == "maxabs_pool":
+                h, aux = pool_ops.maxabs_pooling(h, cfg["ksize"],
+                                                 cfg["stride"],
+                                                 cfg["padding"])
+            elif layer.kind == "avg_pool":
+                h = pool_ops.xla_avg_pooling(h, cfg["ksize"], cfg["stride"],
                                              cfg["padding"])
-        elif layer.kind == "avg_pool":
-            h = pool_ops.xla_avg_pooling(h, cfg["ksize"], cfg["stride"],
-                                         cfg["padding"])
-        elif layer.kind in ("stochastic_pool", "stochastic_abs_pool"):
-            use_abs = layer.kind == "stochastic_abs_pool"
-            if train:
-                oshape = pool_ops.pool_out_shape(
-                    h.shape, cfg["ksize"], cfg["stride"], cfg["padding"])
-                u = pool_ops.stochastic_uniform(
-                    cfg["seed"], (cfg["unit_id"], epoch, ctr), oshape,
-                    jnp)
-                h, aux = pool_ops.xla_stochastic_pooling(
-                    h, cfg["ksize"], cfg["stride"], cfg["padding"], u,
-                    use_abs=use_abs, deterministic=False)
+            elif layer.kind in ("stochastic_pool", "stochastic_abs_pool"):
+                use_abs = layer.kind == "stochastic_abs_pool"
+                if train:
+                    oshape = pool_ops.pool_out_shape(
+                        h.shape, cfg["ksize"], cfg["stride"], cfg["padding"])
+                    u = pool_ops.stochastic_uniform(
+                        cfg["seed"], (cfg["unit_id"], epoch, ctr), oshape,
+                        jnp)
+                    h, aux = pool_ops.xla_stochastic_pooling(
+                        h, cfg["ksize"], cfg["stride"], cfg["padding"], u,
+                        use_abs=use_abs, deterministic=False)
+                else:
+                    h, aux = pool_ops.xla_stochastic_pooling(
+                        h, cfg["ksize"], cfg["stride"], cfg["padding"], None,
+                        use_abs=use_abs, deterministic=True)
+            elif layer.kind == "lrn":
+                # aux stays None: the backward recomputes the denominator
+                # from the cached x_in (LRN is HBM-bound; caching the
+                # activation-sized d costs more than the windowed VPU sum
+                # that rebuilds it — same remat rationale as dropout masks)
+                h = lrn_ops.lrn_y(h, cfg["n"], cfg["alpha"],
+                                  cfg["beta"], cfg["k"])
+            elif layer.kind == "lrn_pool":
+                # fused pair: the LRN output never touches HBM — the kernel
+                # normalizes in VMEM and pools in the same pass; aux is the
+                # pool's winner-offset tensor (depooling-tie compatible).
+                # With the activation folded, NOTHING downstream needs the
+                # unsplit x (the conv below skips its activation backward),
+                # so the cache keeps the column-parity halves the kernel
+                # consumed — the backward never re-splits x
+                if "fold_act" in cfg:
+                    xe, xo = (h if isinstance(h, tuple)   # split-out conv
+                              else lrn_pool_ops.split_cols(h))
+                    x_in = (xe, xo)
+                    h, aux = lrn_pool_ops.lrn_maxpool_split(
+                        xe, xo, cfg["n"], cfg["alpha"], cfg["beta"],
+                        cfg["k"], cfg["ksize"], cfg["stride"],
+                        cfg["padding"], cfg["use_abs"])
+                else:
+                    h, aux = lrn_pool_ops.lrn_maxpool(
+                        h, cfg["n"], cfg["alpha"], cfg["beta"], cfg["k"],
+                        cfg["ksize"], cfg["stride"], cfg["padding"],
+                        cfg["use_abs"])
+            elif layer.kind == "dropout":
+                if train:
+                    # aux stays None: the backward REGENERATES the mask from
+                    # the same (seed, counters) — a counter-RNG mask is pure
+                    # function of its coordinates, so caching an
+                    # activation-sized buffer through the scan would only
+                    # add HBM liveness (same fix as the unit path's Pallas
+                    # dropout, ADVICE round 1)
+                    h = h * drop_ops.make_mask(
+                        cfg["seed"], (cfg["unit_id"], epoch, ctr),
+                        tuple(h.shape), cfg["ratio"], jnp)
+                # eval: inverted dropout → identity
+            elif layer.kind == "activation":
+                h = spec.act(i).fwd(h, jnp)
             else:
-                h, aux = pool_ops.xla_stochastic_pooling(
-                    h, cfg["ksize"], cfg["stride"], cfg["padding"], None,
-                    use_abs=use_abs, deterministic=True)
-        elif layer.kind == "lrn":
-            # aux stays None: the backward recomputes the denominator
-            # from the cached x_in (LRN is HBM-bound; caching the
-            # activation-sized d costs more than the windowed VPU sum
-            # that rebuilds it — same remat rationale as dropout masks)
-            h = lrn_ops.lrn_y(h, cfg["n"], cfg["alpha"],
-                              cfg["beta"], cfg["k"])
-        elif layer.kind == "lrn_pool":
-            # fused pair: the LRN output never touches HBM — the kernel
-            # normalizes in VMEM and pools in the same pass; aux is the
-            # pool's winner-offset tensor (depooling-tie compatible).
-            # With the activation folded, NOTHING downstream needs the
-            # unsplit x (the conv below skips its activation backward),
-            # so the cache keeps the column-parity halves the kernel
-            # consumed — the backward never re-splits x
-            if "fold_act" in cfg:
-                xe, xo = (h if isinstance(h, tuple)   # split-out conv
-                          else lrn_pool_ops.split_cols(h))
-                x_in = (xe, xo)
-                h, aux = lrn_pool_ops.lrn_maxpool_split(
-                    xe, xo, cfg["n"], cfg["alpha"], cfg["beta"],
-                    cfg["k"], cfg["ksize"], cfg["stride"],
-                    cfg["padding"], cfg["use_abs"])
-            else:
-                h, aux = lrn_pool_ops.lrn_maxpool(
-                    h, cfg["n"], cfg["alpha"], cfg["beta"], cfg["k"],
-                    cfg["ksize"], cfg["stride"], cfg["padding"],
-                    cfg["use_abs"])
-        elif layer.kind == "dropout":
-            if train:
-                # aux stays None: the backward REGENERATES the mask from
-                # the same (seed, counters) — a counter-RNG mask is pure
-                # function of its coordinates, so caching an
-                # activation-sized buffer through the scan would only
-                # add HBM liveness (same fix as the unit path's Pallas
-                # dropout, ADVICE round 1)
-                h = h * drop_ops.make_mask(
-                    cfg["seed"], (cfg["unit_id"], epoch, ctr),
-                    tuple(h.shape), cfg["ratio"], jnp)
-            # eval: inverted dropout → identity
-        elif layer.kind == "activation":
-            h = spec.act(i).fwd(h, jnp)
-        else:
-            raise NotImplementedError(layer.kind)
-        if sdt != jnp.float32 and not is_last:
-            # storage cast between layers: the next layer's input (and
-            # its backward cache) live in sdt; the last layer's output
-            # stays f32 so the loss head and its error are full
-            # precision
-            h = (tuple(t.astype(sdt) for t in h)
-                 if isinstance(h, tuple) else h.astype(sdt))
-        auxes.append(aux)
-        if want_caches:
-            caches.append((x_in, aux))
+                raise NotImplementedError(layer.kind)
+            if sdt != jnp.float32 and not is_last:
+                # storage cast between layers: the next layer's input (and
+                # its backward cache) live in sdt; the last layer's output
+                # stays f32 so the loss head and its error are full
+                # precision
+                h = (tuple(t.astype(sdt) for t in h)
+                     if isinstance(h, tuple) else h.astype(sdt))
+            auxes.append(aux)
+            if want_caches:
+                caches.append((x_in, aux))
     return h, caches
 
 
@@ -477,6 +499,7 @@ def predict(spec: ModelSpec, params, x):
     return out
 
 
+@jax.named_scope("loss")
 def _loss_and_err(spec: ModelSpec, out, target, mask):
     """(mean loss, err w.r.t. last pre-activation, n_err); ``mask`` is a
     per-row 0/1 vector zeroing the wrap-padded tail of a short final
@@ -511,114 +534,115 @@ def backward(spec: ModelSpec, params, caches, out, err, epoch=0, ctr=0,
     grads = [None] * len(spec.layers)
     n = len(spec.layers)
     for i in reversed(range(n)):
-        layer = spec.layers[i]
-        w, b = params[i]
-        x_in, aux = caches[i]
-        y_i = caches[i + 1][0] if i < n - 1 else out
-        cfg = layer.cfg
-        slot = _grad_slot(layer, params, i)
-        if slot is not None:
-            w = slot[0]                # tied deconv: encoder weights
-            # fold through the fused activation (last layer already is
-            # pre-activation — see docstring); act_folded: the merged
-            # lrn_pool ABOVE already applied this derivative in-kernel
-            # and returned a full-shape dx (y_i may be its split-halves
-            # cache tuple — never consumed here)
-            if i == n - 1 or cfg.get("act_folded"):
-                err_pre = err
-            else:
-                err_pre = spec.act(i).bwd(err.reshape(y_i.shape), y_i,
-                                          None, jnp)
-            if layer.kind == "fc":
-                x2 = x_in.reshape(x_in.shape[0], -1)
-                err2 = err_pre.reshape(x2.shape[0], -1)
-                gw = jnp.dot(x2.astype(cdt).T, err2.astype(cdt),
-                             preferred_element_type=jnp.float32)
-                gb = jnp.sum(err2, axis=0) if b is not None else None
-                err = jnp.dot(err2.astype(cdt), w.astype(cdt).T,
-                              preferred_element_type=jnp.float32
-                              ).reshape(x_in.shape)
-            elif layer.kind == "conv":
-                # grads accumulate in f32 (preferred_element_type inside
-                # the conv ops); cdt only feeds the MXU operands
-                if cfg.get("split_out"):
-                    # phase-2: err arrives as the pair's parity halves
-                    # (never interleaved) — parity-decomposed grads
-                    ee, eo = (e.astype(cdt) for e in err_pre)
-                    gw = conv_ops.xla_conv2d_grad_weights_split(
-                        x_in.astype(cdt), ee, eo, w.shape,
-                        cfg["stride"], cfg["padding"])
-                    gb = (jnp.sum(err_pre[0], axis=(0, 1, 2))
-                          + jnp.sum(err_pre[1], axis=(0, 1, 2))
-                          if b is not None else None)
-                    err = conv_ops.xla_conv2d_grad_input_split(
-                        ee, eo, w.astype(cdt), x_in.shape,
-                        cfg["stride"], cfg["padding"])
+        with layer_scope("bwd", spec, i):
+            layer = spec.layers[i]
+            w, b = params[i]
+            x_in, aux = caches[i]
+            y_i = caches[i + 1][0] if i < n - 1 else out
+            cfg = layer.cfg
+            slot = _grad_slot(layer, params, i)
+            if slot is not None:
+                w = slot[0]                # tied deconv: encoder weights
+                # fold through the fused activation (last layer already is
+                # pre-activation — see docstring); act_folded: the merged
+                # lrn_pool ABOVE already applied this derivative in-kernel
+                # and returned a full-shape dx (y_i may be its split-halves
+                # cache tuple — never consumed here)
+                if i == n - 1 or cfg.get("act_folded"):
+                    err_pre = err
                 else:
-                    gw = conv_ops.conv2d_grad_weights(
-                        x_in.astype(cdt), err_pre.astype(cdt), w.shape,
+                    err_pre = spec.act(i).bwd(err.reshape(y_i.shape), y_i,
+                                              None, jnp)
+                if layer.kind == "fc":
+                    x2 = x_in.reshape(x_in.shape[0], -1)
+                    err2 = err_pre.reshape(x2.shape[0], -1)
+                    gw = jnp.dot(x2.astype(cdt).T, err2.astype(cdt),
+                                 preferred_element_type=jnp.float32)
+                    gb = jnp.sum(err2, axis=0) if b is not None else None
+                    err = jnp.dot(err2.astype(cdt), w.astype(cdt).T,
+                                  preferred_element_type=jnp.float32
+                                  ).reshape(x_in.shape)
+                elif layer.kind == "conv":
+                    # grads accumulate in f32 (preferred_element_type inside
+                    # the conv ops); cdt only feeds the MXU operands
+                    if cfg.get("split_out"):
+                        # phase-2: err arrives as the pair's parity halves
+                        # (never interleaved) — parity-decomposed grads
+                        ee, eo = (e.astype(cdt) for e in err_pre)
+                        gw = conv_ops.xla_conv2d_grad_weights_split(
+                            x_in.astype(cdt), ee, eo, w.shape,
+                            cfg["stride"], cfg["padding"])
+                        gb = (jnp.sum(err_pre[0], axis=(0, 1, 2))
+                              + jnp.sum(err_pre[1], axis=(0, 1, 2))
+                              if b is not None else None)
+                        err = conv_ops.xla_conv2d_grad_input_split(
+                            ee, eo, w.astype(cdt), x_in.shape,
+                            cfg["stride"], cfg["padding"])
+                    else:
+                        gw = conv_ops.conv2d_grad_weights(
+                            x_in.astype(cdt), err_pre.astype(cdt), w.shape,
+                            cfg["stride"], cfg["padding"])
+                        gb = (jnp.sum(err_pre, axis=(0, 1, 2))
+                              if b is not None else None)
+                        err = conv_ops.conv2d_grad_input(
+                            err_pre.astype(cdt), w.astype(cdt), x_in.shape,
+                            cfg["stride"], cfg["padding"])
+                else:                                         # deconv
+                    gw = deconv_ops.deconv2d_grad_weights(
+                        err_pre.astype(cdt), x_in.astype(cdt), w.shape,
                         cfg["stride"], cfg["padding"])
                     gb = (jnp.sum(err_pre, axis=(0, 1, 2))
                           if b is not None else None)
-                    err = conv_ops.conv2d_grad_input(
-                        err_pre.astype(cdt), w.astype(cdt), x_in.shape,
-                        cfg["stride"], cfg["padding"])
-            else:                                         # deconv
-                gw = deconv_ops.deconv2d_grad_weights(
-                    err_pre.astype(cdt), x_in.astype(cdt), w.shape,
+                    err = deconv_ops.deconv2d_grad_input(
+                        err_pre.astype(cdt), w.astype(cdt), cfg["stride"],
+                        cfg["padding"])
+                grads[i] = (gw, gb)
+            elif layer.kind in ("max_pool", "maxabs_pool", "stochastic_pool",
+                               "stochastic_abs_pool"):
+                err = pool_ops.gd_max_pooling(
+                    err.reshape(y_i.shape), aux, x_in.shape, cfg["ksize"],
                     cfg["stride"], cfg["padding"])
-                gb = (jnp.sum(err_pre, axis=(0, 1, 2))
-                      if b is not None else None)
-                err = deconv_ops.deconv2d_grad_input(
-                    err_pre.astype(cdt), w.astype(cdt), cfg["stride"],
+            elif layer.kind == "avg_pool":
+                err = pool_ops.xla_gd_avg_pooling(
+                    err.reshape(y_i.shape), x_in.shape, cfg["ksize"],
+                    cfg["stride"], cfg["padding"])
+            elif layer.kind == "lrn":
+                err = lrn_ops.gd_lrn_x(err.reshape(y_i.shape), x_in,
+                                       cfg["n"], cfg["alpha"], cfg["beta"],
+                                       cfg["k"])
+            elif layer.kind == "lrn_pool":
+                # fused pair backward: pooled err scatters through the
+                # winner offsets and folds through the LRN derivative (and
+                # optionally the preceding conv's activation derivative) in
+                # one kernel — err_y never materializes
+                if isinstance(x_in, tuple):      # split-halves cache (fold)
+                    err = lrn_pool_ops.gd_lrn_maxpool_split(
+                        err.reshape(y_i.shape), aux, x_in[0], x_in[1],
+                        cfg["n"], cfg["alpha"], cfg["beta"], cfg["k"],
+                        cfg["ksize"], cfg["stride"], cfg["padding"],
+                        cfg.get("fold_act"),
+                        return_split=bool(cfg.get("emit_split")))
+                else:
+                    err = lrn_pool_ops.gd_lrn_maxpool(
+                        err.reshape(y_i.shape), aux, x_in, cfg["n"],
+                        cfg["alpha"], cfg["beta"], cfg["k"], cfg["ksize"],
+                        cfg["stride"], cfg["padding"],
+                        cfg.get("fold_act"))
+            elif layer.kind == "depooling":
+                err = pool_ops.gd_depooling(
+                    err.reshape(y_i.shape), aux, cfg["ksize"], cfg["stride"],
                     cfg["padding"])
-            grads[i] = (gw, gb)
-        elif layer.kind in ("max_pool", "maxabs_pool", "stochastic_pool",
-                           "stochastic_abs_pool"):
-            err = pool_ops.gd_max_pooling(
-                err.reshape(y_i.shape), aux, x_in.shape, cfg["ksize"],
-                cfg["stride"], cfg["padding"])
-        elif layer.kind == "avg_pool":
-            err = pool_ops.xla_gd_avg_pooling(
-                err.reshape(y_i.shape), x_in.shape, cfg["ksize"],
-                cfg["stride"], cfg["padding"])
-        elif layer.kind == "lrn":
-            err = lrn_ops.gd_lrn_x(err.reshape(y_i.shape), x_in,
-                                   cfg["n"], cfg["alpha"], cfg["beta"],
-                                   cfg["k"])
-        elif layer.kind == "lrn_pool":
-            # fused pair backward: pooled err scatters through the
-            # winner offsets and folds through the LRN derivative (and
-            # optionally the preceding conv's activation derivative) in
-            # one kernel — err_y never materializes
-            if isinstance(x_in, tuple):      # split-halves cache (fold)
-                err = lrn_pool_ops.gd_lrn_maxpool_split(
-                    err.reshape(y_i.shape), aux, x_in[0], x_in[1],
-                    cfg["n"], cfg["alpha"], cfg["beta"], cfg["k"],
-                    cfg["ksize"], cfg["stride"], cfg["padding"],
-                    cfg.get("fold_act"),
-                    return_split=bool(cfg.get("emit_split")))
+            elif layer.kind == "dropout":
+                if train:
+                    # regenerate the forward's mask (identical counters →
+                    # bit-identical draw)
+                    err = err.reshape(x_in.shape) * drop_ops.make_mask(
+                        cfg["seed"], (cfg["unit_id"], epoch, ctr),
+                        tuple(x_in.shape), cfg["ratio"], jnp)
+            elif layer.kind == "activation":
+                err = spec.act(i).bwd(err.reshape(y_i.shape), y_i, x_in, jnp)
             else:
-                err = lrn_pool_ops.gd_lrn_maxpool(
-                    err.reshape(y_i.shape), aux, x_in, cfg["n"],
-                    cfg["alpha"], cfg["beta"], cfg["k"], cfg["ksize"],
-                    cfg["stride"], cfg["padding"],
-                    cfg.get("fold_act"))
-        elif layer.kind == "depooling":
-            err = pool_ops.gd_depooling(
-                err.reshape(y_i.shape), aux, cfg["ksize"], cfg["stride"],
-                cfg["padding"])
-        elif layer.kind == "dropout":
-            if train:
-                # regenerate the forward's mask (identical counters →
-                # bit-identical draw)
-                err = err.reshape(x_in.shape) * drop_ops.make_mask(
-                    cfg["seed"], (cfg["unit_id"], epoch, ctr),
-                    tuple(x_in.shape), cfg["ratio"], jnp)
-        elif layer.kind == "activation":
-            err = spec.act(i).bwd(err.reshape(y_i.shape), y_i, x_in, jnp)
-        else:
-            raise NotImplementedError(layer.kind)
+                raise NotImplementedError(layer.kind)
     return grads
 
 
@@ -652,17 +676,18 @@ def apply_updates(spec: ModelSpec, params, vels, grads, lr_scale=1.0,
             continue
         gw, gb = grad
         vw, vb = vels[i]
-        lr, wd, l1, mom = layer.hypers
-        reg = wd * ((1.0 - l1) * w + 0.5 * l1 * jnp.sign(w))
-        vw2 = mom * vw - lr * lr_scale * (gw + reg)
-        cur_w[tgt] = w + vw2
-        new_v[i][0] = vw2
-        if b is not None:
-            lrb, wdb, l1b, momb = layer.hypers_bias
-            regb = wdb * ((1.0 - l1b) * b + 0.5 * l1b * jnp.sign(b))
-            vb2 = momb * vb - lrb * lr_scale_bias * (gb + regb)
-            cur_b[i] = b + vb2
-            new_v[i][1] = vb2
+        with layer_scope("upd", spec, i):
+            lr, wd, l1, mom = layer.hypers
+            reg = wd * ((1.0 - l1) * w + 0.5 * l1 * jnp.sign(w))
+            vw2 = mom * vw - lr * lr_scale * (gw + reg)
+            cur_w[tgt] = w + vw2
+            new_v[i][0] = vw2
+            if b is not None:
+                lrb, wdb, l1b, momb = layer.hypers_bias
+                regb = wdb * ((1.0 - l1b) * b + 0.5 * l1b * jnp.sign(b))
+                vb2 = momb * vb - lrb * lr_scale_bias * (gb + regb)
+                cur_b[i] = b + vb2
+                new_v[i][1] = vb2
     return ([(w, b) for w, b in zip(cur_w, cur_b)],
             [tuple(v) for v in new_v])
 
@@ -680,7 +705,8 @@ def grad_minibatch(spec: ModelSpec, params, x, target, mask=None,
     if spec.loss == "mse" and spec.layers[last].kind in PARAM_KINDS:
         # backward() expects pre-activation err at a param layer; other
         # last-layer kinds fold their own activation in backward()
-        err = spec.act(last).bwd(err, out, None, jnp)
+        with jax.named_scope("loss"):
+            err = spec.act(last).bwd(err, out, None, jnp)
     grads = backward(spec, params, caches, out, err, epoch=epoch,
                      ctr=ctr)
     return grads, {"loss": loss, "n_err": n_err}
@@ -826,11 +852,21 @@ class FusedTrainer:
         return scoped
 
     # -- epoch-granular compiled drivers ----------------------------------
+    @jax.named_scope("input")
+    def _gather(self, data, target, step_idx, epoch, train: bool):
+        """One minibatch out of the resident set (traced): the rows by
+        index, laid over the mesh's ``data`` axis, through the on-device
+        augmentation (``train=False``: its centre crop)."""
+        x = jnp.take(data, step_idx, axis=0)
+        if self._batch_sharding is not None:
+            x = jax.lax.with_sharding_constraint(x, self._batch_sharding)
+        if self.augment is not None:
+            x = self.augment.device_apply(x, step_idx, epoch, train=train)
+        return x, jnp.take(target, step_idx, axis=0)
+
     def _build(self):
         spec = self.spec
         accum = self.accum_steps
-
-        aug = self.augment
 
         def train_epoch(params, vels, data, target, idx, mask, ctrs,
                         epoch, scales, scales_b):
@@ -838,20 +874,12 @@ class FusedTrainer:
             # and biases (scalar schedules broadcast host-side), so
             # per-minibatch policies (lr_adjust by_epoch=False) and
             # separate bias policies trace in without recompiles
-            def gather(step_idx):
-                x = jnp.take(data, step_idx, axis=0)
-                if self._batch_sharding is not None:
-                    x = jax.lax.with_sharding_constraint(
-                        x, self._batch_sharding)
-                if aug is not None:
-                    x = aug.device_apply(x, step_idx, epoch, train=True)
-                return x, jnp.take(target, step_idx, axis=0)
-
             if accum == 1:
                 def body(carry, step):
                     params, vels = carry
                     step_idx, step_mask, step_ctr, s_w, s_b = step
-                    x, t = gather(step_idx)
+                    x, t = self._gather(data, target, step_idx, epoch,
+                                        True)
                     params, vels, m = train_minibatch(
                         spec, params, vels, x, t, step_mask,
                         epoch=epoch, ctr=step_ctr, lr_scale=s_w,
@@ -876,10 +904,10 @@ class FusedTrainer:
                 params, vels, acc = carry
                 (step_i, step_idx, step_mask, step_ctr, s_w,
                  s_b) = step
-                x, t = gather(step_idx)
+                x, t = self._gather(data, target, step_idx, epoch,
+                                        True)
                 grads, m = grad_minibatch(spec, params, x, t, step_mask,
                                           epoch=epoch, ctr=step_ctr)
-                acc = jax.tree_util.tree_map(jnp.add, acc, grads)
                 last_of_group = ((step_i + 1) % accum == 0) | (
                     step_i + 1 == n_steps)
 
@@ -889,9 +917,11 @@ class FusedTrainer:
                     return p, v, jax.tree_util.tree_map(
                         jnp.zeros_like, a)
 
-                params, vels, acc = jax.lax.cond(
-                    last_of_group, apply, lambda ops: ops,
-                    (params, vels, acc))
+                with jax.named_scope("accum"):
+                    acc = jax.tree_util.tree_map(jnp.add, acc, grads)
+                    params, vels, acc = jax.lax.cond(
+                        last_of_group, apply, lambda ops: ops,
+                        (params, vels, acc))
                 return (params, vels, acc), m
             (params, vels, _), ms = jax.lax.scan(
                 body, (params, vels, zeros),
@@ -902,13 +932,7 @@ class FusedTrainer:
         def eval_epoch(params, data, target, idx, mask):
             def body(_, step):
                 step_idx, step_mask = step
-                x = jnp.take(data, step_idx, axis=0)
-                t = jnp.take(target, step_idx, axis=0)
-                if self._batch_sharding is not None:
-                    x = jax.lax.with_sharding_constraint(
-                        x, self._batch_sharding)
-                if aug is not None:        # eval: center crop
-                    x = aug.device_apply(x, step_idx, 0, train=False)
+                x, t = self._gather(data, target, step_idx, 0, False)
                 return None, eval_minibatch(spec, params, x, t, step_mask)
             _, ms = jax.lax.scan(body, None, (idx, mask))
             return ms
@@ -927,16 +951,15 @@ class FusedTrainer:
             jit_kw["out_shardings"] = (psh, psh, self._repl)
             ejit_kw["out_shardings"] = self._repl
         # compile accounting (telemetry.compilestats): jit compiles
-        # lazily, so the first train/eval call of a run is where the
-        # whole-epoch XLA compile actually lands — time it into
-        # compile_time_ms{site="train.fused"} so the MFU work can
-        # subtract compile from measured step time
-        from ..telemetry import compilestats
-        self._train_epoch_fn = compilestats.first_call_timed(
+        # lazily, once a shape — the head's (k, b), the deferred tail's
+        # (1, b), each evaluated set's — so every call that builds an
+        # executable is timed into compile_time_ms{site="train.fused"}
+        # and the MFU work can subtract compile from measured step time
+        self._train_epoch_fn = compilestats.build_timed(
             jax.jit(self._mesh_scoped(train_epoch),
                     donate_argnums=(0, 1), **jit_kw),
             site="train.fused", cause="cold")
-        self._eval_epoch_fn = compilestats.first_call_timed(
+        self._eval_epoch_fn = compilestats.build_timed(
             jax.jit(self._mesh_scoped(eval_epoch), **ejit_kw),
             site="train.fused", cause="cold")
 
@@ -999,6 +1022,15 @@ class FusedTrainer:
         return (padded.reshape(steps, batch).astype(np.int32),
                 mask.reshape(steps, batch), ctrs)
 
+    @staticmethod
+    def _readback(ms: dict, sync: bool) -> dict:
+        """The call's metrics on the host (waits for the device), or
+        the device arrays as they are with ``sync=False``."""
+        if not sync:
+            return ms
+        with tracing.span("trainer.readback"):
+            return {k: np.asarray(v) for k, v in ms.items()}
+
     def train_epoch(self, data, target, indices, batch: int,
                     sync: bool = True, epoch: int | None = None,
                     lr_scale=1.0, ctr_base: int = 0,
@@ -1020,25 +1052,29 @@ class FusedTrainer:
         self._auto_epoch = epoch + 1
         if self._train_epoch_fn is None:
             self._build()
-        data, target = self._mesh_place(data), self._mesh_place(target)
-        idx, mask, ctrs = self._idx_matrix(np.asarray(indices), batch,
-                                           ctr_base)
-        scales, scales_b = self._step_scales(lr_scale, lr_scale_bias,
-                                             idx.shape[0])
-        self.params, self.vels, ms = self._train_epoch_fn(
-            self.params, self.vels, data, target, idx, mask, ctrs,
-            jnp.uint32(epoch), jnp.asarray(scales),
-            jnp.asarray(scales_b))
-        return {k: np.asarray(v) for k, v in ms.items()} if sync else ms
+        with tracing.span("trainer.prep"):
+            data, target = self._mesh_place(data), self._mesh_place(target)
+            idx, mask, ctrs = self._idx_matrix(np.asarray(indices), batch,
+                                               ctr_base)
+            scales, scales_b = self._step_scales(lr_scale, lr_scale_bias,
+                                                 idx.shape[0])
+            step_args = (idx, mask, ctrs, jnp.uint32(epoch),
+                         jnp.asarray(scales), jnp.asarray(scales_b))
+        with tracing.span("trainer.dispatch"):
+            self.params, self.vels, ms = self._train_epoch_fn(
+                self.params, self.vels, data, target, *step_args)
+        return self._readback(ms, sync)
 
     def eval_epoch(self, data, target, indices, batch: int,
                    sync: bool = True) -> dict:
         if self._eval_epoch_fn is None:
             self._build()
-        data, target = self._mesh_place(data), self._mesh_place(target)
-        idx, mask, _ = self._idx_matrix(np.asarray(indices), batch)
-        ms = self._eval_epoch_fn(self.params, data, target, idx, mask)
-        return {k: np.asarray(v) for k, v in ms.items()} if sync else ms
+        with tracing.span("trainer.prep"):
+            data, target = self._mesh_place(data), self._mesh_place(target)
+            idx, mask, _ = self._idx_matrix(np.asarray(indices), batch)
+        with tracing.span("trainer.dispatch"):
+            ms = self._eval_epoch_fn(self.params, data, target, idx, mask)
+        return self._readback(ms, sync)
 
     # -- sync back into the unit graph ------------------------------------
     def write_back(self) -> None:

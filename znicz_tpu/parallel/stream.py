@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..loader.streaming import BatchPrefetcher, StreamingLoader
+from ..telemetry import compilestats
 from .fused import FusedTrainer, eval_minibatch, train_minibatch
 
 
@@ -87,13 +88,20 @@ class StreamTrainer(FusedTrainer):
         x_is_target = self._x_is_target
         aug = self.loader.augment if self.device_augment else None
 
-        def step(params, vels, x, t, mask, epoch, ctr, lr_scale,
-                 lr_scale_bias, rows):
+        @jax.named_scope("input")
+        def placed(x, rows, epoch, train):
+            """The streamed minibatch laid over the mesh's ``data``
+            axis, through the on-device augmentation."""
             if self._batch_sharding is not None:
                 x = jax.lax.with_sharding_constraint(
                     x, self._batch_sharding)
             if aug is not None:
-                x = aug.device_apply(x, rows, epoch, train=True)
+                x = aug.device_apply(x, rows, epoch, train=train)
+            return x
+
+        def step(params, vels, x, t, mask, epoch, ctr, lr_scale,
+                 lr_scale_bias, rows):
+            x = placed(x, rows, epoch, True)
             return train_minibatch(spec, params, vels, x,
                                    x if x_is_target else t, mask,
                                    epoch=epoch, ctr=ctr,
@@ -101,11 +109,7 @@ class StreamTrainer(FusedTrainer):
                                    lr_scale_bias=lr_scale_bias)
 
         def estep(params, x, t, mask, rows):
-            if self._batch_sharding is not None:
-                x = jax.lax.with_sharding_constraint(
-                    x, self._batch_sharding)
-            if aug is not None:
-                x = aug.device_apply(x, rows, 0, train=False)
+            x = placed(x, rows, 0, False)
             return eval_minibatch(spec, params, x,
                                   x if x_is_target else t, mask)
 
@@ -121,15 +125,14 @@ class StreamTrainer(FusedTrainer):
             jit_kw["out_shardings"] = (psh, psh, self._repl)
             ejit_kw["out_shardings"] = self._repl
         # compile accounting: same contract as FusedTrainer._build —
-        # the first streamed step call pays the XLA compile, recorded
-        # under its own site so resident and streaming runs are
-        # separable in compile_time_ms
-        from ..telemetry import compilestats
-        self._step_fn = compilestats.first_call_timed(
+        # every streamed step call that builds an executable is
+        # recorded, under its own site so resident and streaming runs
+        # are separable in compile_time_ms
+        self._step_fn = compilestats.build_timed(
             jax.jit(self._mesh_scoped(step), donate_argnums=(0, 1),
                     **jit_kw),
             site="train.stream", cause="cold")
-        self._eval_fn = compilestats.first_call_timed(
+        self._eval_fn = compilestats.build_timed(
             jax.jit(self._mesh_scoped(estep), **ejit_kw),
             site="train.stream", cause="cold")
         if self.accum_steps > 1:
@@ -140,19 +143,17 @@ class StreamTrainer(FusedTrainer):
             from .fused import apply_updates, grad_minibatch
 
             def gstep(params, x, t, mask, epoch, ctr, rows):
-                if self._batch_sharding is not None:
-                    x = jax.lax.with_sharding_constraint(
-                        x, self._batch_sharding)
-                if aug is not None:
-                    x = aug.device_apply(x, rows, epoch, train=True)
+                x = placed(x, rows, epoch, True)
                 return grad_minibatch(spec, params, x,
                                       x if x_is_target else t, mask,
                                       epoch=epoch, ctr=ctr)
 
+            @jax.named_scope("accum")
             def gapply(params, vels, acc, lr_scale, lr_scale_bias):
                 return apply_updates(spec, params, vels, acc, lr_scale,
                                      lr_scale_bias)
 
+            @jax.named_scope("accum")
             def gadd(acc, grads):
                 return jax.tree_util.tree_map(jnp.add, acc, grads)
 

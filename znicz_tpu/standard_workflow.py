@@ -32,6 +32,7 @@ from .accelerated_units import AcceleratedWorkflow
 from .logger import MetricsWriter
 from .telemetry import flightrecorder as _flightrecorder
 from .telemetry import profiler as _profiler
+from .telemetry import tracing as _tracing
 from .telemetry.registry import REGISTRY
 from .mutable import DerivedBool
 from .loader.base import TRAIN
@@ -415,21 +416,19 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                  "kernel_tier": tuning.kernel_tier()}
         self.info("fused trainer on %s",
                   " ".join(f"{k}={v!r}" for k, v in where.items()))
-        # host-vs-device time split (telemetry): everything spent
-        # inside trainer.train_epoch/eval_epoch calls is device-bound
-        # work (dispatch + compute + readback; epoch 0 also carries
-        # the XLA compile, separately visible in compile_time_ms);
-        # the rest of the epoch wall is host work — loader shuffle,
-        # metrics, decision, checkpoint admin.  A host-dominated step
-        # is a pipeline problem no profiler trace is needed to see.
-        _dev_acc = [0.0]
-
-        def _on_device(fn, *a, **kw):
-            t0 = time.monotonic()
-            try:
-                return fn(*a, **kw)
-            finally:
-                _dev_acc[0] += time.monotonic() - t0
+        # host-vs-device time split (telemetry): every call of
+        # trainer.train_epoch/eval_epoch is one child span of the
+        # epoch's ``train.epoch`` span, and the time inside them is
+        # device-bound work (prep + dispatch + compute + readback, which
+        # the trainer's own grandchild spans split; an epoch that built
+        # an executable carries a ``compile`` span too); the rest of
+        # the epoch's wall up to its row is host work — the loader's
+        # shuffle and plan, the learning-rate scales, the metrics'
+        # arithmetic.  A host-dominated step is a pipeline problem no
+        # profiler trace is needed to see; with a profiler on, the same
+        # spans lie in the trace's host plane (telemetry/tracing.py).
+        eval_spans = {VALID: "train.eval.validation",
+                      TEST: "train.eval.test"}
 
         timeline = (_flightrecorder.TimelineWriter(timeline_jsonl)
                     if timeline_jsonl else None)
@@ -495,18 +494,26 @@ class StandardWorkflowBase(AcceleratedWorkflow):
         g_dev_ms = REGISTRY.gauge(
             "train_device_ms",
             "wall time of the last host step spent inside device "
-            "calls (dispatch + compute + readback; the first step "
-            "also carries the XLA compile — see compile_time_ms)")
+            "calls (prep + dispatch + compute + readback; a step that "
+            "built an executable also carries that compile — see "
+            "compile_time_ms)")
         g_host_ms = REGISTRY.gauge(
             "train_host_ms",
-            "wall time of the last host step NOT inside device calls "
-            "(loader shuffle, metrics, decision, checkpoint admin) — "
-            "host-dominated steps are a pipeline problem")
-        for epoch in range(loader.epoch_number, epochs):
-            if profile_hook is not None:
-                profile_hook.on_step(epoch)
-            t_epoch0 = time.monotonic()
-            dev0 = _dev_acc[0]
+            "wall time of the last host step NOT inside device calls, "
+            "up to the step's row (loader shuffle and plan, lr scales, "
+            "metric arithmetic; the metrics writer, the decision and "
+            "snapshot/checkpoint run after the row is cut: the next "
+            "row's prev_tail_ms) — host-dominated steps are a pipeline "
+            "problem")
+        # the metrics writer, the decision and the saves of the epoch
+        # before: they run after that epoch's row is cut, so the next
+        # row carries them (None in a run's first row)
+        prev_tail_ms = None
+
+        def run_epoch(epoch, epoch_span, spans) -> bool:
+            """One epoch under its ``train.epoch`` span; ``spans`` fills
+            with the epoch's finished spans.  True ends the run."""
+            nonlocal first, pending
             loader.epoch_number = epoch
             if not first:   # initialize() already built epoch 0's plan —
                 loader._build_epoch_plan()   # reuse the loader's shuffle
@@ -536,18 +543,19 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                                      if bias_policy is not None
                                      else (None, None))
             if pending is not None:
-                _on_device(trainer.train_epoch, data, target,
-                           pending[0], batch,
-                           epoch=pending[1], lr_scale=pending[2],
-                           ctr_base=pending[3], sync=False,
-                           lr_scale_bias=pending[4])
+                with _tracing.span("train.tail_update"):
+                    trainer.train_epoch(data, target, pending[0], batch,
+                                        epoch=pending[1],
+                                        lr_scale=pending[2],
+                                        ctr_base=pending[3], sync=False,
+                                        lr_scale_bias=pending[4])
             split = ((n_train - 1) // batch) * batch
             head, tail = perm[:split], perm[split:]
             if len(head):
-                tm = _on_device(trainer.train_epoch, data, target,
-                                head, batch,
-                                epoch=epoch, lr_scale=scale,
-                                lr_scale_bias=scale_b)
+                with _tracing.span("train.head"):
+                    tm = trainer.train_epoch(data, target, head, batch,
+                                             epoch=epoch, lr_scale=scale,
+                                             lr_scale_bias=scale_b)
             else:
                 tm = {"loss": np.zeros((0,)), "n_err": np.zeros((0,))}
             # the tail minibatch's metrics come from a forward pass over
@@ -557,8 +565,8 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             # stochastic layers (dropout) the tail step's train metrics
             # differ slightly from the unit graph's dropout-active ones;
             # weights stay exactly equal either way
-            em_tail = _on_device(trainer.eval_epoch, data, target,
-                                 tail, batch)
+            with _tracing.span("train.eval_tail"):
+                em_tail = trainer.eval_epoch(data, target, tail, batch)
             pending = (tail, epoch, tail_scale, split, tail_scale_b)
             metrics["train_loss"] = float(
                 np.concatenate([tm["loss"], em_tail["loss"]]).mean())
@@ -569,9 +577,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             for k in (VALID, TEST):
                 if len(cls_idx[k]) == 0:
                     continue
-                em = _on_device(trainer.eval_epoch, data, target,
-                                cls_idx[k], batch)
                 name = CLASS_NAMES[k]
+                with _tracing.span(eval_spans[k]):
+                    em = trainer.eval_epoch(data, target, cls_idx[k],
+                                            batch)
                 metrics[f"{name}_loss"] = float(em["loss"].mean())
                 metrics[f"{name}_n_err"] = int(em["n_err"].sum())
                 metrics[f"{name}_err_pct"] = (100.0
@@ -583,8 +592,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                     metrics["validation_mse"] = metrics["validation_loss"]
             decision.epoch_metrics.append(metrics)
             loader.epoch_number = epoch + 1
-            epoch_s = time.monotonic() - t_epoch0
-            device_s = _dev_acc[0] - dev0
+            # the row is cut here, from the epoch's spans
+            epoch_s = epoch_span.elapsed_ms() / 1e3
+            parts = _flightrecorder.train_breakdown(spans)
+            device_s = parts.pop("device_ms") / 1e3
             host_s = max(0.0, epoch_s - device_s)
             if epoch_s > 0:
                 # gauges only — the metrics dict stays timing-free so
@@ -603,25 +614,27 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                         "device_ms": round(device_s * 1e3, 3),
                         "host_ms": round(host_s * 1e3, 3),
                         "examples_per_sec": (round(n_train / epoch_s, 1)
-                                             if epoch_s > 0 else None)}
+                                             if epoch_s > 0 else None),
+                        **parts, "prev_tail_ms": prev_tail_ms}
             _flightrecorder.RECORDER.record(
                 "train_step", duration_ms=epoch_s * 1e3, **step_row)
             if timeline is not None:
                 timeline.write({"at": time.time(), **step_row, **where})
-            self.metrics_writer.write(kind="epoch", **metrics)
-            if self.lr_adjuster is not None:
-                # keep the tick-path iteration counter current so
-                # snapshots persist the TRUE schedule position (a
-                # tick-path resume of a fused run must continue the
-                # by_epoch=False schedule, not restart it)
-                self.lr_adjuster._minibatches = \
-                    (epoch + 1) * steps_per_epoch
-            improved = decision.better_than_best(metrics)
-            if improved:
-                decision.improved.set(True)
-                decision._fails = 0
-            else:
-                decision._fails += 1
+            with _tracing.span("train.decision"):
+                self.metrics_writer.write(kind="epoch", **metrics)
+                if self.lr_adjuster is not None:
+                    # keep the tick-path iteration counter current so
+                    # snapshots persist the TRUE schedule position (a
+                    # tick-path resume of a fused run must continue the
+                    # by_epoch=False schedule, not restart it)
+                    self.lr_adjuster._minibatches = \
+                        (epoch + 1) * steps_per_epoch
+                improved = decision.better_than_best(metrics)
+                if improved:
+                    decision.improved.set(True)
+                    decision._fails = 0
+                else:
+                    decision._fails += 1
             snap = getattr(self, "snapshotter", None)
             # Deferred-tail correctness: a mid-training snapshot OR
             # device checkpoint must include this epoch's tail update
@@ -645,16 +658,32 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                     pending = None
                 trainer.write_back()
 
-            if snap is not None:
-                snap.epoch_end(improved, before_save=_sync_weights)
-            if ckpt is not None and ((epoch + 1) % ckpt_every == 0
-                                     or is_final):
-                # async device-state save: IO overlaps the next epoch,
-                # and the step's manifest (its bless mark) commits at
-                # the next save/wait/close once the bytes are down
-                _sync_weights()
-                ckpt.save(trainer, epoch, block=False)
-            if decision._fails >= decision.fail_iterations:
+            ckpt_due = ckpt is not None and ((epoch + 1) % ckpt_every == 0
+                                             or is_final)
+            if snap is not None or ckpt_due:
+                with _tracing.span("train.save"):
+                    if snap is not None:
+                        snap.epoch_end(improved, before_save=_sync_weights)
+                    if ckpt_due:
+                        # async device-state save: IO overlaps the next
+                        # epoch, and the step's manifest (its bless
+                        # mark) commits at the next save/wait/close
+                        # once the bytes are down
+                        _sync_weights()
+                        ckpt.save(trainer, epoch, block=False)
+            return decision._fails >= decision.fail_iterations
+
+        for epoch in range(loader.epoch_number, epochs):
+            if profile_hook is not None:
+                profile_hook.on_step(epoch)
+            # one request id an epoch: every span of the epoch carries
+            # it, and collect() hands the finished ones to the row
+            with _tracing.request() as rid, _tracing.collect(rid) as spans:
+                with _tracing.span("train.epoch", step_num=epoch,
+                                   epoch=epoch) as epoch_span:
+                    stop = run_epoch(epoch, epoch_span, spans)
+            prev_tail_ms = _flightrecorder.train_tail_ms(spans)
+            if stop:
                 break
         decision.complete.set(True)
         trainer.write_back()

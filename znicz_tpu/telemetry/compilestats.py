@@ -8,12 +8,17 @@ layer every executable-creation site reports through:
 
 * ``compile_time_ms{site}`` — histogram of executable build cost per
   site (``serving.engine`` = the bucket LRU, ``serving.canary`` = the
-  hot-reload canary, ``train.fused`` = the fused-train jit).  Measured
-  as **first-invocation wall time** of the fresh jitted callable
-  (trace + XLA compile + the first execution): jitted functions compile
-  lazily, so the first call is where the cost actually lands on a
-  request or a train step.  Coarse-bucketed up to minutes — cold
-  compiles of big models are multi-second events.
+  hot-reload canary, ``train.fused`` / ``train.stream`` = the trainers'
+  jits).  Measured as the **wall time of the call that built the
+  executable** (trace + XLA compile or cache load + that first
+  execution): jitted functions compile lazily, so that call is where
+  the cost actually lands on a request or a train step.  Serving wraps
+  one callable a shape and times its first call
+  (:func:`first_call_timed`); the trainers call one jitted function
+  with several shapes (the head ``(k, b)``, the deferred tail
+  ``(1, b)``, one evaluation shape a set) and account every one of them
+  (:func:`build_timed`).  Coarse-bucketed up to minutes — cold compiles
+  of big models are multi-second events.
 * ``compiles_total{site, cause}`` — why the executable had to be
   built: ``cold`` (explicit warmup / first engine construction, off
   the request path), ``new_bucket`` (request-path compile for a
@@ -26,9 +31,11 @@ layer every executable-creation site reports through:
 * ``executable_cache_hits_total{site}`` / ``_misses_total{site}`` —
   the cache behavior those causes summarize.
 
-Each timed first call also records a ``compile`` span
-(:mod:`~znicz_tpu.telemetry.tracing`), so a request that paid for a
-compile shows the stage in its flight-recorder span tree.
+Each accounted build also records a ``compile`` span
+(:mod:`~znicz_tpu.telemetry.tracing`) under the span that was open, so
+a request that paid for a compile shows the stage in its
+flight-recorder span tree, and a training epoch that recompiled shows
+it under its ``train.*`` span and in its row's ``compiles``.
 
 Everything is stdlib-only and never raises into the instrumented path:
 accounting must not take the hot path down.
@@ -52,8 +59,8 @@ COMPILE_BUCKETS_MS = (5.0, 25.0, 100.0, 250.0, 500.0, 1000.0, 2500.0,
 
 _compile_ms = REGISTRY.histogram(
     "compile_time_ms",
-    "executable build cost by site (first-invocation wall time of a "
-    "fresh jitted callable: trace + XLA compile + first run), "
+    "executable build cost by site (wall time of the call that built "
+    "the executable: trace + XLA compile or cache load + first run), "
     "milliseconds", buckets=COMPILE_BUCKETS_MS)
 _compiles = REGISTRY.counter(
     "compiles_total",
@@ -143,12 +150,47 @@ class FirstCallTimed:
         return out
 
 
-def first_call_timed(fn, site: str, cause: str,
-                     on_first=None) -> FirstCallTimed:
+def _check_cause(cause: str) -> None:
     if cause not in CAUSES:
         raise ValueError(f"unknown compile cause {cause!r}; "
                          f"expected one of {CAUSES}")
+
+
+def first_call_timed(fn, site: str, cause: str,
+                     on_first=None) -> FirstCallTimed:
+    _check_cause(cause)
     return FirstCallTimed(fn, site, cause, on_first)
+
+
+class BuildTimed:
+    """Wrap a ``jax.jit`` function so EVERY call that builds an
+    executable is recorded, not only the first: a build shows as the
+    function's own executable cache growing across the call (a new
+    shape, dtype or sharding; loaded from the persistent cache or
+    compiled).  Two reads of a counter a call."""
+
+    __slots__ = ("fn", "site", "cause")
+
+    def __init__(self, fn, site: str, cause: str):
+        self.fn = fn
+        self.site = site
+        self.cause = cause
+
+    def __call__(self, *args, **kwargs):
+        size = self.fn._cache_size
+        before = size()
+        sp = tracing.Span("compile", {"site": self.site,
+                                      "cause": self.cause})
+        out = self.fn(*args, **kwargs)
+        if size() > before:
+            tracing.record(sp.finish())
+            record_compile(self.site, self.cause, sp.duration_ms)
+        return out
+
+
+def build_timed(fn, site: str, cause: str) -> BuildTimed:
+    _check_cause(cause)
+    return BuildTimed(fn, site, cause)
 
 
 def snapshot() -> dict:

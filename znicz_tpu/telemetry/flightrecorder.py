@@ -22,7 +22,8 @@ carries the request id, HTTP code, input shape/rows, the span tree the
 request touched (``server.predict`` → ``batcher.dispatch`` →
 ``engine.forward``, plus ``compile`` when it paid for one) and the
 stage breakdown derived from it; a train-step record carries the
-host-vs-device wall split the MFU work needs.
+host-vs-device wall split the MFU work needs and, out of the epoch's
+own span tree (:func:`train_breakdown`), what its launches cost.
 
 Lock discipline: every ring mutation AND read happens under one lock;
 ``snapshot`` copies out under the lock and serializes outside it, so a
@@ -163,6 +164,60 @@ def stage_breakdown(spans: list, rows: int | None = None) -> dict:
                 max(0.0, by_name["server.predict"]
                     - by_name["batcher.dispatch"]), 3)
     return out
+
+
+#: the epoch loop's device calls (children of ``train.epoch``): the
+#: row's device_ms
+_TRAIN_CALL_SPANS = ("train.tail_update", "train.head", "train.eval_tail",
+                     "train.eval.validation", "train.eval.test")
+#: what runs after an epoch's row is cut: the NEXT row's prev_tail_ms
+_TRAIN_TAIL_SPANS = ("train.decision", "train.save")
+
+
+def train_breakdown(spans: list) -> dict:
+    """A ``train_step`` row's fields out of the epoch's finished
+    :class:`~znicz_tpu.telemetry.tracing.Span` objects, read where the
+    row is cut.  ``device_ms``: wall time inside the epoch loop's device
+    calls.  ``launches``: executables dispatched (``trainer.dispatch``
+    spans); ``prep_ms`` / ``dispatch_ms`` / ``readback_ms``: what the
+    trainer did inside those calls on the host — indices, scales and
+    placement; the jitted call until it returned; the wait for the
+    result — so their sum is at most ``device_ms``, and prep + dispatch
+    is launch cost the device may idle behind.  ``compiles``:
+    executables built or loaded (``compile`` spans).  A trainer that
+    opens no ``trainer.*`` spans (the streamed one) reads None for the
+    four."""
+    device = prep = dispatch = readback = 0.0
+    launches = compiles = 0
+    for s in spans:
+        ms = s.duration_ms or 0.0
+        if s.name in _TRAIN_CALL_SPANS:
+            device += ms
+        elif s.name == "trainer.dispatch":
+            dispatch += ms
+            launches += 1
+        elif s.name == "trainer.prep":
+            prep += ms
+        elif s.name == "trainer.readback":
+            readback += ms
+        elif s.name == "compile":
+            compiles += 1
+    out = {"device_ms": round(device, 3), "launches": None,
+           "prep_ms": None, "dispatch_ms": None, "readback_ms": None,
+           "compiles": compiles}
+    if launches:
+        out.update(launches=launches, prep_ms=round(prep, 3),
+                   dispatch_ms=round(dispatch, 3),
+                   readback_ms=round(readback, 3))
+    return out
+
+
+def train_tail_ms(spans: list) -> float:
+    """Milliseconds an epoch spent after its row was cut: the metrics
+    writer and the decision (``train.decision``), snapshot and
+    checkpoint with the weights' sync (``train.save``)."""
+    return round(sum(s.duration_ms or 0.0 for s in spans
+                     if s.name in _TRAIN_TAIL_SPANS), 3)
 
 
 class FlightRecorder:

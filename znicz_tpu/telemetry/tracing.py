@@ -38,6 +38,17 @@ no clock-skew correction (hop timings are computed from span GAPS on
 one process's monotonic clock, never by subtracting stamps across
 machines), and the wire format is two headers, not a collector
 protocol.
+
+Every span has a ``span_id`` and the ``parent_id`` of the span that was
+open in the same context when it started, so the ring holds trees, not
+a flat list (the fused trainer's ``train.epoch`` → ``train.head`` →
+``trainer.dispatch``; serving's per-thread stages).  A span opened
+through :func:`span` is also a ``jax.profiler.TraceAnnotation`` of the
+same name wherever JAX is already loaded in the process: while a
+profile is being captured the program's spans lie in the host plane of
+the same ``.xplane.pb``, on the same clock as the device's operations;
+while none is, the annotation is a flag test.  This module never
+imports JAX itself — a router process without it stays without it.
 """
 
 from __future__ import annotations
@@ -47,6 +58,7 @@ import contextlib
 import contextvars
 import hashlib
 import itertools
+import sys
 import threading
 import time
 import uuid
@@ -64,6 +76,10 @@ _request_ids: contextvars.ContextVar[tuple] = contextvars.ContextVar(
 #: never pays for tracing when no hop stamped a context
 _trace_ctxs: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "znicz_trace_ctxs", default=())
+
+#: id of the span open in the current context (a new span's parent)
+_open_span: contextvars.ContextVar = contextvars.ContextVar(
+    "znicz_open_span", default=None)
 
 _MAX_ID_LEN = 120
 
@@ -244,11 +260,14 @@ def request(request_id: str | None = None,
 class Span:
     """One finished (or in-flight) timing record."""
 
-    __slots__ = ("name", "request_ids", "trace_ids", "attrs",
-                 "started_at", "_t0", "duration_ms", "status", "error")
+    __slots__ = ("name", "span_id", "parent_id", "request_ids",
+                 "trace_ids", "attrs", "started_at", "_t0", "duration_ms",
+                 "status", "error")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
+        self.span_id = new_span_id()
+        self.parent_id = _open_span.get()
         self.request_ids = current_request_ids()
         self.trace_ids = tuple(c.trace_id
                                for c in current_traces() if c)
@@ -266,8 +285,14 @@ class Span:
             self.error = f"{type(error).__name__}: {error}"[:300]
         return self
 
+    def elapsed_ms(self) -> float:
+        """Milliseconds since the span started (it may still be open)."""
+        return (time.monotonic() - self._t0) * 1e3
+
     def to_dict(self) -> dict:
-        d = {"name": self.name, "request_ids": list(self.request_ids),
+        d = {"name": self.name, "span_id": self.span_id,
+             "parent_id": self.parent_id,
+             "request_ids": list(self.request_ids),
              "started_at": self.started_at,
              "duration_ms": self.duration_ms, "status": self.status,
              "error": self.error, **self.attrs}
@@ -281,21 +306,43 @@ class Span:
                 f"ids={list(self.request_ids)}>")
 
 
+def _annotation(name: str, step_num: int | None):
+    """The profiler's view of a span: a ``TraceAnnotation`` (a step
+    annotation where ``step_num`` is given), or nothing in a process
+    that has not loaded JAX."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    if step_num is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+
+
 @contextlib.contextmanager
-def span(name: str, **attrs):
+def span(name: str, step_num: int | None = None, **attrs):
     """Time a stage; record it on exit (status ``error`` when the body
-    raises — the exception itself propagates unchanged)."""
+    raises — the exception itself propagates unchanged).  Spans opened
+    inside name this one as their parent.  ``step_num`` marks the span
+    as one step of a loop for the profiler (the trainer's epochs)."""
     sp = Span(name, attrs)
+    token = _open_span.set(sp.span_id)
     try:
-        yield sp
+        with _annotation(name, step_num):
+            yield sp
     except BaseException as e:
-        _record(sp.finish(error=e))
+        record(sp.finish(error=e))
         raise
     else:
-        _record(sp.finish())
+        record(sp.finish())
+    finally:
+        _open_span.reset(token)
 
 
-def _record(sp: Span) -> None:
+def record(sp: Span) -> None:
+    """Put a finished span into the ring, the collectors and the
+    histogram (:func:`span` does this itself; a caller that only knows
+    afterwards whether a stage happened — a compile — times a bare
+    :class:`Span` and records it here)."""
     with _lock:
         _recent.append(sp)
         if _collectors:
