@@ -12,10 +12,14 @@ lane axis and does its n-tap window sum on the loaded block; dropout
 evaluates the counter-RNG hash (``ops.rngbits`` murmur3 finalizer —
 bit-identical to the numpy golden path) *inside* the kernel from the
 block's global element offset, so mask generation + scale + apply is one
-HBM pass; pooling's winner select consumes XLA-stacked window taps
-(T, rows, C) and emits value + dense slot index in one pass (the
-strided tap gather/scatter stays in XLA — data movement the compiler
-pipelines well, SURVEY.md §7 hard part (a))."""
+HBM pass; pooling has two kernel families, picked by
+``pooling.windowed`` from the operands: windows that tile their input
+exactly are pooled on a windowed view, one pass a direction with the
+taps taken inside VMEM (``pallas_pool_window`` /
+``pallas_gd_pool_window``); every other pool's winner select consumes
+XLA-stacked window taps (T, rows, C) and emits value + dense slot index
+in one pass, and there the strided tap gather/scatter stays in XLA
+(SURVEY.md §7 hard part (a))."""
 
 from __future__ import annotations
 
@@ -316,17 +320,26 @@ def pallas_gd_lrn_x(err, x, n=5, alpha=1e-4, beta=0.75, k=2.0):
 
 
 # -- pooling winner select -------------------------------------------------
-def _pool_select_kernel(taps_ref, y_ref, idx_ref, *, n_taps, use_abs):
-    best_val = taps_ref[0]
+def _winner(tap, n_taps, use_abs):
+    """(value, slot index) of the winning tap, ``tap(t)`` loading the
+    t-th: THE winner rule of every pooling kernel — taps in row-major
+    order, strict ``>`` so ties keep the first, max-abs keeps the sign —
+    bit-identical to ``pooling._max_pool``."""
+    best_val = tap(0)
     best = jnp.abs(best_val) if use_abs else best_val
     idx = jnp.zeros(best.shape, jnp.int32)
     for t in range(1, n_taps):
-        sl = taps_ref[t]
+        sl = tap(t)
         score = jnp.abs(sl) if use_abs else sl
         take = score > best
         best = jnp.where(take, score, best)
         best_val = jnp.where(take, sl, best_val)
         idx = jnp.where(take, jnp.int32(t), idx)
+    return best_val, idx
+
+
+def _pool_select_kernel(taps_ref, y_ref, idx_ref, *, n_taps, use_abs):
+    best_val, idx = _winner(lambda t: taps_ref[t], n_taps, use_abs)
     y_ref[:] = best_val.astype(y_ref.dtype)
     idx_ref[:] = idx
 
@@ -423,3 +436,100 @@ def pallas_pool_gather(taps, offsets):
         interpret=tuning.interpret_mode(),
     )(taps, offsets)
     return out[:rows]
+
+
+# -- non-overlapping pooling on a windowed view ----------------------------
+#: VMEM the windowed pool kernels give their blocks (every operand,
+#: double-buffered).  A kernel's temporaries come on top: at 12 MiB the
+#: 56 px pool of VGG-A overran the 16 MiB a v5e kernel may scope, and
+#: 2 MiB was 1-3% slower than 6 (on-chip, PERF.md section 6, PR 27).
+_WINDOW_VMEM = 6 << 20
+
+
+def _window_blocks(ow, n_taps, b, c):
+    """(columns, batch rows, lanes) of one block of the windowed pool
+    kernels: a whole (batch, channel) tile group a pooled column, as
+    many columns as the VMEM budget holds — ``n_taps`` input slabs and
+    two output slabs a column forward, the reverse backward."""
+    cb, bb = min(c, _LANES), min(b, 256)
+    per_col = (n_taps + 2) * tuning.round_up(bb, 8) * _LANES * 4
+    return max(1, min(ow, _WINDOW_VMEM // (2 * per_col))), bb, cb
+
+
+def _window_view(a):
+    """(B, H, W, C) → (H, W, B, C): the pooled axes lead and the
+    (8, 128) tiles lie over (batch, channel), which is the layout
+    XLA's TPU convolutions emit and consume at a batch that fills the
+    sublanes — inside the step this transpose is a bitcast, and no
+    layout copy stands beside the kernels (PERF.md section 6, PR 27)."""
+    return jnp.transpose(a, (1, 2, 0, 3))
+
+
+def _window_unview(a):
+    return jnp.transpose(a, (2, 0, 1, 3))
+
+
+def _pool_window_kernel(x_ref, y_ref, idx_ref, *, kh, kw, use_abs):
+    # both tap indices are leading dims of the block: the taps never
+    # exist outside VMEM, and none is a strided access
+    y_ref[0], idx_ref[0] = _winner(
+        lambda t: x_ref[0, t // kw, :, t % kw], kh * kw, use_abs)
+
+
+@functools.partial(jax.jit, static_argnames=("ksize", "use_abs"))
+def pallas_pool_window(x, ksize, use_abs: bool = False):
+    """Max / max-abs pool whose windows do not overlap (stride = ksize,
+    no padding, H and W whole multiples) in ONE pass: x is viewed as
+    (OH, kh, OW, kw, B, C) — the window axes split off leading dims, so
+    no data moves — and each block's kh·kw taps are taken inside VMEM.
+    Same winner rule as ``pooling._max_pool`` → (y, window-slot index)."""
+    kh, kw = ksize
+    b, h, w, c = x.shape
+    oh, ow = h // kh, w // kw
+    wb, bb, cb = _window_blocks(ow, kh * kw, b, c)
+    out = pl.BlockSpec((1, wb, bb, cb), lambda r, q, n, l: (r, q, n, l))
+    y, idx = pl.pallas_call(
+        functools.partial(_pool_window_kernel, kh=kh, kw=kw,
+                          use_abs=use_abs),
+        grid=(oh, pl.cdiv(ow, wb), pl.cdiv(b, bb), pl.cdiv(c, cb)),
+        in_specs=[pl.BlockSpec((1, kh, wb, kw, bb, cb),
+                               lambda r, q, n, l: (r, 0, q, 0, n, l))],
+        out_specs=[out, out],
+        out_shape=[jax.ShapeDtypeStruct((oh, ow, b, c), x.dtype),
+                   jax.ShapeDtypeStruct((oh, ow, b, c), jnp.int32)],
+        name="pallas_pool_window",
+        interpret=tuning.interpret_mode(),
+    )(_window_view(x).reshape(oh, kh, ow, kw, b, c))
+    return _window_unview(y), _window_unview(idx)
+
+
+def _gd_pool_window_kernel(e_ref, i_ref, dx_ref, *, kh, kw):
+    err = e_ref[0].astype(jnp.float32)
+    idx = i_ref[0]
+    for t in range(kh * kw):
+        dx_ref[0, t // kw, :, t % kw] = jnp.where(
+            idx == jnp.int32(t), err, jnp.float32(0.0))
+
+
+@functools.partial(jax.jit, static_argnames=("ksize",))
+def pallas_gd_pool_window(err, offsets, ksize):
+    """Backward (and depooling) of the non-overlapping pool in ONE
+    pass: every dx element belongs to one window, so it is written
+    once — err where its slot won, 0 elsewhere — with no zero fill, no
+    tap stack and no add.  → dx (B, OH·kh, OW·kw, C) float32."""
+    kh, kw = ksize
+    b, oh, ow, c = err.shape
+    wb, bb, cb = _window_blocks(ow, kh * kw, b, c)
+    inp = pl.BlockSpec((1, wb, bb, cb), lambda r, q, n, l: (r, q, n, l))
+    dx = pl.pallas_call(
+        functools.partial(_gd_pool_window_kernel, kh=kh, kw=kw),
+        grid=(oh, pl.cdiv(ow, wb), pl.cdiv(b, bb), pl.cdiv(c, cb)),
+        in_specs=[inp, inp],
+        out_specs=pl.BlockSpec((1, kh, wb, kw, bb, cb),
+                               lambda r, q, n, l: (r, 0, q, 0, n, l)),
+        out_shape=jax.ShapeDtypeStruct((oh, kh, ow, kw, b, c),
+                                       jnp.float32),
+        name="pallas_gd_pool_window",
+        interpret=tuning.interpret_mode(),
+    )(_window_view(err), _window_view(offsets))
+    return _window_unview(dx.reshape(oh * kh, ow * kw, b, c))

@@ -15,6 +15,22 @@ TPU-native design (SURVEY.md §7 hard part (a) — irregular scatter):
 * Backward scatters by equality-select against the stored slot index and
   strided ``.at[].add`` — dense compare+add, no gather/scatter engine
   needed, MXU-free and VPU-friendly.
+* On the Pallas tier max / max-abs pooling, its backward and depooling
+  have TWO paths, picked by :func:`windowed` from the operands alone (no
+  option).  *Windowed*: where the windows tile the input exactly
+  (stride = ksize, no padding, H and W whole multiples; VGG's 2×2/2)
+  each input element belongs to one window, so x is viewed as
+  (OH, KH, OW, KW, B, C) and ONE kernel pass a direction reads x and
+  writes y + idx, or reads err + idx and writes every dx element once
+  (``elementwise.pallas_pool_window`` / ``pallas_gd_pool_window``): no
+  tap stack, no zero fill, no add.  *Tap stack*: overlapping, padded or
+  ragged windows (AlexNet's 3×3/2), a batch that is not a multiple of 8
+  and packed activations stack the KH·KW strided taps in XLA and select /
+  scatter in the kernels below them, then ``.at[].add`` into dx — which
+  overlapping windows need and non-overlapping ones do not.  The two
+  share only the winner rule (row-major taps, strict ``>``, ties keep
+  the first tap), so ``idx`` and the float32 gradient are bit-equal
+  either way.
 * Max pooling pads with −∞ (a padded zero must never win); avg pooling
   pads with 0 and divides by the full window area (reference semantics).
 
@@ -99,10 +115,32 @@ def xla_maxabs_pooling(x, ksize, stride=None, padding=0):
     return _max_pool(x, ksize, stride or ksize, padding, jnp, True)
 
 
+def windowed(x_shape, ksize, stride=None, padding=0,
+             dtype=jnp.float32) -> bool:
+    """Whether the Pallas tier pools ``x_shape`` on the windowed view
+    (header): the windows tile the input exactly — stride equal to the
+    window, no padding, H and W whole multiples of it — so every input
+    element belongs to one window; the batch fills whole sublane tiles
+    (a multiple of 8: the view's tiles lie over batch × channel); and
+    the activations are float32 (packed bfloat16 does not lower: Mosaic
+    refuses the relayout of its compare mask, as in ROADMAP Speed 2).
+    Everything else keeps the tap stack."""
+    (kh, kw), (ph, pw) = _norm2(ksize), _norm2(padding)
+    (sh, sw) = _norm2(stride if stride is not None else ksize)
+    b, h, w, _ = x_shape
+    return ((sh, sw) == (kh, kw) and ph == pw == 0
+            and h % kh == 0 and w % kw == 0 and b % 8 == 0
+            and jnp.dtype(dtype) == jnp.float32)
+
+
 def _pallas_max_pool(x, ksize, stride, padding, use_abs):
-    """Stack the window taps in XLA, run the winner select in the Pallas
-    kernel (SURVEY.md §2.3 pooling row; §7 hard part (a) split)."""
+    """Windows that tile x: one kernel pass on the windowed view.
+    Otherwise stack the window taps in XLA and run the winner select in
+    the Pallas kernel (SURVEY.md §2.3 pooling row; §7 hard part (a)
+    split)."""
     from . import elementwise
+    if windowed(x.shape, ksize, stride, padding, x.dtype):
+        return elementwise.pallas_pool_window(x, _norm2(ksize), use_abs)
     b, h, w, c = x.shape
     _, oh, ow, _ = pool_out_shape(x.shape, ksize, stride, padding)
     taps = _tap_stack(x, (oh, ow), ksize, stride, padding,
@@ -235,14 +273,18 @@ def xla_gd_max_pooling(err, offsets, x_shape, ksize, stride=None,
 
 
 def _pallas_gd_max_pool(err, offsets, x_shape, ksize, stride, padding):
-    """Pallas offset-scatter backward: the per-tap equality select runs
-    in one kernel pass (elementwise.pallas_pool_scatter); the regular
-    strided placement of each tap into dx stays in XLA."""
+    """Pallas offset-scatter backward.  Windows that tile dx: one
+    kernel pass writes every element once.  Otherwise the per-tap
+    equality select runs in one kernel pass
+    (elementwise.pallas_pool_scatter) and the regular strided placement
+    of each tap into dx stays in XLA."""
     from . import elementwise
     (kh, kw), (sh, sw), (ph, pw) = _norm2(ksize), \
         _norm2(stride or ksize), _norm2(padding)
     _, h, w, c = x_shape
     b, oh, ow, _ = err.shape      # b from the operand: batch_sharded
+    if windowed((b, h, w, c), ksize, stride, padding, err.dtype):
+        return elementwise.pallas_gd_pool_window(err, offsets, (kh, kw))
     taps = elementwise.pallas_pool_scatter(
         err.reshape(-1, c), offsets.reshape(-1, c), kh * kw)
     taps = taps.reshape(kh * kw, b, oh, ow, c)

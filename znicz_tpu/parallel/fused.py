@@ -340,6 +340,38 @@ def layer_scope(phase: str, spec: ModelSpec, i: int):
     return jax.named_scope(f"{phase}/{layer_label(spec, i)}")
 
 
+#: spec rows whose forward or backward goes through the ``ops.pooling``
+#: dispatchers (a merged ``lrn_pool`` row has kernels of its own)
+POOL_ROUTED_KINDS = ("max_pool", "maxabs_pool", "stochastic_pool",
+                     "stochastic_abs_pool", "depooling")
+
+
+def pool_routes(spec: ModelSpec, forwards, mesh=None) -> str:
+    """``windowed:<n> taps:<m>``: how many pooling rows of ``spec`` take
+    the one-pass windowed kernels and how many the tap stack
+    (``ops/pooling.py`` header).  The choice is made as the step is
+    traced, from the operands' shapes, so it is counted here by the
+    same rule from the shapes of the workflow's ``forwards`` units, the
+    batch as ``tuning.batch_sharded`` hands it to one device of
+    ``mesh``.  Both read 0 off the Pallas tier."""
+    counts = {"windowed": 0, "taps": 0}
+    dp = mesh_lib.mesh_shape_of(mesh)[0]
+    routed = POOL_ROUTED_KINDS if tuning.use_pallas() else ()
+    for i, layer in enumerate(spec.layers):
+        if layer.kind not in routed:
+            continue
+        unit = forwards[spec.unit_index[i] if spec.unit_index else i]
+        # the pooled side's full-size array: a pool's input, a
+        # depooling's output
+        full = unit.output if layer.kind == "depooling" else unit.input
+        b, *rest = full.shape
+        cfg = layer.cfg
+        counts["windowed" if pool_ops.windowed(
+            (b // dp, *rest), cfg["ksize"], cfg["stride"], cfg["padding"],
+            spec.storage_dtype) else "taps"] += 1
+    return " ".join(f"{k}:{v}" for k, v in counts.items())
+
+
 # -- pure math (all traced; spec is static) --------------------------------
 def forward(spec: ModelSpec, params, x, *, want_caches: bool,
             train: bool = False, epoch=0, ctr=0):

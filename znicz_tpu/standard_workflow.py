@@ -413,7 +413,11 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                  "param_devices": max(
                      len(w.sharding.device_set)
                      for w, _ in trainer.params if w is not None),
-                 "kernel_tier": tuning.kernel_tier()}
+                 "kernel_tier": tuning.kernel_tier(),
+                 # pooling rows on the one-pass windowed kernels, and on
+                 # the tap stack (ops/pooling.py)
+                 "pool_routes": fused.pool_routes(spec, self.forwards,
+                                                  mesh)}
         self.info("fused trainer on %s",
                   " ".join(f"{k}={v!r}" for k, v in where.items()))
         # host-vs-device time split (telemetry): every call of
