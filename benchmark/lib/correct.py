@@ -13,9 +13,18 @@ import numpy as np
 QUIET_LEAF = 1e-3
 
 
-def _flat(tree) -> list[float]:
-    return [v for pair in tree if pair is not None for v in pair
+def _flat(tree) -> list:
+    """The leaves of a list, one entry a layer, of None or a tuple of
+    any number of leaves (a leaf may be None), in order."""
+    return [v for layer in tree if layer is not None for v in layer
             if v is not None]
+
+
+def _names(tree) -> list[str]:
+    """``<parameterised layer>.<leaf>`` for each leaf of ``_flat``."""
+    layers = [la for la in tree if la is not None]
+    return [f"{k}.{j}" for k, la in enumerate(layers)
+            for j, v in enumerate(la) if v is not None]
 
 
 def _worst_gap(prog: list[float], ref: list[float], keep=None) -> float:
@@ -36,14 +45,16 @@ def _diff_norms(prog: list, ref: list) -> list[float]:
             for p, r in zip(prog, ref)]
 
 
-def first_steps_numbers(first: dict, ref: dict) -> dict:
+def first_steps_numbers(first: dict, ref: dict, output_leaf: int) -> dict:
     """``first``: the probe's reading of the program; ``ref``: what
-    ``reference.follow`` returned for the same rows.
+    ``reference.follow`` returned for the same rows; ``output_leaf``:
+    the model file's ``output_leaf(cfg)``, a place among the leaves in
+    order.
 
     ``out_grad_diff`` is the control's number: the norm of the
     difference itself (from the two sketches) of the first gradient of
-    the output layer's weights, the leaf that operand rounding reaches
-    through one product, against the reference's norm of that leaf.
+    that leaf (the output layer's weights, which operand rounding
+    reaches through one product) against the reference's norm of it.
     Deeper leaves sum terms that cancel, so the stated precision already
     reads a tenth there and the control only three to four times that."""
     loss_gap = max(abs(p - r) / abs(r)
@@ -54,7 +65,7 @@ def first_steps_numbers(first: dict, ref: dict) -> dict:
     v0 = max(_flat(first.get("velocity0_norms", [])), default=0.0)
     diffs = _diff_norms(_flat(first["grad_sketches"]),
                         _flat(ref["grad_sketches"]))
-    out = len(ref_g) - 2            # leaves run w, b, w, b, ...
+    out = int(output_leaf)
     return {
         "loss_gap": loss_gap if v0 == 0.0 else float("inf"),
         "grad_norm_gap": _worst_gap(prog_g, ref_g),
@@ -62,7 +73,7 @@ def first_steps_numbers(first: dict, ref: dict) -> dict:
                                       _flat(ref["change_norms"]), keep),
         "out_grad_diff": (diffs[out] / ref_g[out]
                           if len(diffs) == len(ref_g) == len(prog_g)
-                          else float("inf")),
+                          and 0 <= out < len(ref_g) else float("inf")),
     }
 
 
@@ -77,7 +88,8 @@ def leaf_table(first: dict, ref: dict) -> list[str]:
     if not len(rg) == len(rc) == len(pg) == len(pc) == len(dg):
         return [f"leaves differ: {len(rg)} {len(rc)} {len(pg)} {len(pc)}"]
     mg, mc = statistics.median(rg), statistics.median(rc)
-    return [f"leaf {i // 2}.{'wb'[i % 2]}: ref grad {a:.4g} gap "
+    names = _names(ref["grad_norms"])
+    return [f"leaf {names[i]}: ref grad {a:.4g} gap "
             f"{abs(b - a) / max(a, mg):.3g} diff {e / max(a, mg):.3g}"
             f" | ref change {c:.4g} gap {abs(d - c) / max(c, mc):.3g}"
             + ("" if a >= QUIET_LEAF * mg else " (quiet)")

@@ -104,29 +104,10 @@ def sketch(a, leaf: int):
         SKETCH, -1).sum(axis=1)
 
 
-def param_shapes(layers: list, size: int, channels: int) -> list:
-    """[(w_shape, b_shape) or None] per layer of a configuration's layer
-    list: conv weights (ky, kx, c_in, c_out), fc weights (n_in, n_out)."""
-    from . import flops
-    out, shape = [], (size, size, channels)
-    for layer, nxt in zip(layers, flops.shapes_after(layers, shape)):
-        kind = layer["type"]
-        cfg = layer.get("->", {})
-        if kind.startswith("conv"):
-            out.append(((cfg["ky"], cfg["kx"], shape[2], cfg["n_kernels"]),
-                        (cfg["n_kernels"],)))
-        elif kind.startswith("all2all") or kind == "softmax":
-            n_in = int(np.prod(shape))
-            out.append(((n_in, nxt[0]), (nxt[0],)))
-        else:
-            out.append(None)
-        shape = nxt
-    return out
-
-
 def make_weights(seed: int, shapes: list) -> list:
-    """He-normal weights and zero biases for ``param_shapes`` output, on
-    the default device, in one jitted call."""
+    """He-normal weights and zero biases for a list of
+    (weights' shape, bias's shape) or None, on the default device, in
+    one jitted call."""
     @jax.jit
     def build(words):
         key = jax.random.fold_in(jax.random.fold_in(

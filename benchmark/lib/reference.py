@@ -168,11 +168,13 @@ def _lists(pairs):
     return [tuple(np.asarray(a).tolist() for a in pair) for pair in pairs]
 
 
-def follow(layers, params, images, labels, *, seed: int, epoch: int = 0,
+def follow(cfg, params, images, labels, *, seed: int, epoch: int = 0,
            steps: int = 3, operand=None, half_batch: bool = False,
            frozen: bool = False) -> dict:
-    """Train ``steps`` minibatches (``images``: (steps, batch, h, w, c))
-    from ``params`` with zero velocities.  ``seed`` keys the dropout
+    """Train ``steps`` minibatches (``images``: (steps, batch, h, w, c),
+    ``labels``: (steps, batch), as the model file's ``make_rows`` made
+    them) of the configuration ``cfg`` (its ``layers``) from ``params``
+    with zero velocities.  ``seed`` keys the dropout
     masks: the seed the program was launched with.  Returns the losses, the
     per-leaf norms of the first gradient and of the parameters' change,
     in the order of the parameterised layers.
@@ -180,6 +182,7 @@ def follow(layers, params, images, labels, *, seed: int, epoch: int = 0,
     ``half_batch`` and ``frozen`` plant the faults the check has to
     catch: half of each batch left out, and a step that returns its
     state unchanged."""
+    layers = cfg["layers"]
     batch = images.shape[1]
     mshapes = _mask_shapes(layers, images.shape[2:], batch)
     leaves = [i for i, p in enumerate(params) if p is not None]
@@ -240,3 +243,11 @@ def fp8_operand(t):
 def bf16_operand(t):
     """What the configuration states: one bfloat16 pass."""
     return t.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+#: what ``tests/limits_study.py`` reads beside the reference itself, as
+#: keywords of ``follow``: the control, the stated precision, the faults
+VARIANTS = {"control_fp8": {"operand": fp8_operand},
+            "stated_bf16": {"operand": bf16_operand},
+            "fault_half_batch": {"half_batch": True},
+            "fault_frozen": {"frozen": True}}

@@ -78,8 +78,10 @@ def reduce(planes: dict, top: int = 10) -> dict:
     """Busy time (union of the operations' intervals, containers left
     out, averaged over the device planes that ran anything), the traced
     window (first operation's start to last one's end, over all planes),
-    time per executable, the operations that took most time and the
-    longest idle gaps, named by the executables on either side."""
+    time per executable, every operation's summed time and count by
+    its ``short_name`` (``ops_s``), the ``top`` of them that took most
+    time (``device_ops``) and the longest idle gaps, named by the
+    executables on either side."""
     busy, spans = [], []
     ops: dict = {}
     modules: dict = {}
@@ -93,8 +95,9 @@ def reduce(planes: dict, top: int = 10) -> dict:
         busy.append(sum(b - a for a, b in merged))
         spans.append((merged[0][0], merged[-1][1]))
         for name, _, d in events:
-            name = short_name(name)
-            ops[name] = ops.get(name, 0) + d
+            op = ops.setdefault(short_name(name), [0, 0])
+            op[0] += d
+            op[1] += 1
         mods = sorted((s, s + d, name)
                       for name, s, d in lines.get(MODULES_LINE, []))
         for s, e, name in mods:
@@ -111,12 +114,16 @@ def reduce(planes: dict, top: int = 10) -> dict:
             gaps[key] = gaps.get(key, 0) + (b0 - a1)
     if not busy:
         return {"busy_s": 0.0, "window_s": 0.0, "modules": {},
-                "device_ops": [], "idle_gaps": [], "planes": 0}
+                "ops_s": {}, "device_ops": [], "idle_gaps": [],
+                "planes": 0}
     window = (max(e for _, e in spans) - min(s for s, _ in spans)) / 1e9
     rank = lambda d: [[k, v / 1e9] for k, v in sorted(      # noqa: E731
         d.items(), key=lambda kv: -kv[1])[:top]]
     return {"busy_s": sum(busy) / len(busy) / 1e9, "window_s": window,
-            "modules": modules, "device_ops": rank(ops),
+            "modules": modules,
+            "ops_s": {k: {"total_s": d / 1e9, "count": n}
+                      for k, (d, n) in ops.items()},
+            "device_ops": rank({k: d for k, (d, _) in ops.items()}),
             "idle_gaps": rank(gaps), "planes": len(busy)}
 
 

@@ -7,8 +7,10 @@ One process, one ``StandardWorkflow.train(fused=True)`` call reached
 through the program's ``Launcher``; the measured window is cut out of it
 by ``lib/window.py``.  A cell is found by name in ``BENCHMARK.json``: its
 configuration in ``configs/``, its traffic in ``traffic/``, its limits in
-``limits/``, each per-layer metric's reader in ``metrics/``.  The last
-line of standard output is the result (see ``README.md``)."""
+``limits/``, each per-layer metric's reader in ``metrics/``.  What a row
+and a parameter tree are is the business of the ``model`` file the
+configuration names, beside its ``workflow`` and its ``reference``.  The
+last line of standard output is the result (see ``README.md``)."""
 
 from __future__ import annotations
 
@@ -30,9 +32,14 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
+from benchmark.lib.errors import BenchError     # noqa: E402
 
-class BenchError(Exception):
-    """A fault of the benchmark's inputs; the run prints no result."""
+
+#: what a configuration's ``model`` file offers (``README.md`` has the
+#: types)
+MODEL_OFFERS = ("row", "overrides", "make_rows", "param_shapes", "hypers",
+                "make_weights", "install", "flops", "step_bytes",
+                "output_leaf")
 
 
 # -- finding things by name -------------------------------------------------
@@ -55,6 +62,14 @@ def load_module(path: str, what: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def import_file(path: str):
+    """A file a configuration names (its model file, its reference),
+    imported under its dotted name from the root of the checkout, so
+    that it can import its neighbours."""
+    return importlib.import_module(os.path.splitext(
+        os.path.relpath(path, ROOT))[0].replace(os.sep, "."))
 
 
 def find_cell(bench: dict, workload: str, base: str) -> dict:
@@ -91,9 +106,13 @@ def find_cell(bench: dict, workload: str, base: str) -> dict:
                           f"per-layer metric {m['name']!r}")
         readers[m["name"]] = (mod.read, m["unit"])
     config = load_json(cfg_json, "configuration")
-    # the configuration names its workflow file and its plain reference
+    # the configuration names its workflow file, its plain reference and
+    # its model file (what a row and a parameter tree are)
     files = {}
-    for key in ("workflow", "reference"):
+    for key in ("workflow", "reference", "model"):
+        if key not in config:
+            raise BenchError(f"configuration {cell['config']!r} names no "
+                             f"{key} file")
         files[key] = os.path.join(ROOT, config[key])
         if not os.path.isfile(files[key]):
             raise BenchError(f"configuration {cell['config']!r}: its "
@@ -161,6 +180,29 @@ def run_cell(args, bench: dict, base: str = ROOT,
     if traffic.get("mesh") is None and chips != 1:
         raise BenchError(f"traffic {traffic['name']!r} has no mesh but "
                          f"the cell asks for {chips} chips")
+    seed = int(args.seed)
+    batch = int(traffic["minibatch"])
+    model = import_file(found["files"]["model"])
+    lacks = [name for name in MODEL_OFFERS if not hasattr(model, name)]
+    if lacks:
+        raise BenchError(f"model file {cfg['model']} offers no "
+                         f"{', '.join(lacks)}")
+
+    def of_model(name, *a):
+        """A function of the model file; a count or a shape it cannot
+        give (``ValueError``) is a fault of the configuration."""
+        try:
+            return getattr(model, name)(*a)
+        except ValueError as e:
+            raise BenchError(f"configuration {cell['config']!r}, "
+                             f"{name} of {cfg['model']}: {e}") from e
+    # what needs no device first: a configuration the model file cannot
+    # count ends the run here, before the chip is taken
+    shapes = of_model("param_shapes", cfg)
+    counts = {"flops": of_model("flops", cfg, traffic),
+              "step_bytes": of_model("step_bytes", cfg, traffic, batch)}
+    output_leaf = of_model("output_leaf", cfg)
+    program_overrides = of_model("overrides", cfg, traffic, seed)
 
     import jax
     import numpy as np
@@ -188,26 +230,18 @@ def run_cell(args, bench: dict, base: str = ROOT,
     from znicz_tpu.backends import Device
     from znicz_tpu.launcher import Launcher
     from znicz_tpu.telemetry import flightrecorder
-    from benchmark.lib import correct, data, flops
+    from benchmark.lib import correct
     from benchmark.lib.probe import TrainerProbe
     from benchmark.lib.window import EpochClock
 
-    seed = int(args.seed)
     # The program bakes its dropout stream's seed into the compiled step,
     # so a new Launcher seed is a new compile of every training program.
-    # The Launcher keeps one seed; --seed makes the images, the labels,
-    # the weights and the shuffle, which are arguments of the programs.
+    # The Launcher keeps one seed; --seed makes the rows, the weights and
+    # the shuffle, which are arguments of the programs.
     program_seed = int(cfg["assumed"]["program_seed"])
-    batch = int(traffic["minibatch"])
     sizes = {k: int(traffic[k]) for k in ("n_train", "n_valid", "n_test")}
-    overrides = [
-        f"bench.seed={seed}",
-        f"alexnet.minibatch_size={batch}",
-        *(f"alexnet.synthetic.{k}={v}" for k, v in sizes.items()),
-        f"alexnet.synthetic.noise={cfg['assumed']['noise']}",
-        "alexnet.decision.max_epochs=1000000000",
-        "alexnet.decision.fail_iterations=1000000000",
-        *traffic.get("overrides", []), *args.override]
+    overrides = [*program_overrides, *traffic.get("overrides", []),
+                 *args.override]
     launcher = Launcher(
         workflow=found["files"]["workflow"],
         config=found["config_py"], backend="xla", fused=True,
@@ -222,20 +256,9 @@ def run_cell(args, bench: dict, base: str = ROOT,
     marks_s["initialized"] = time.monotonic() - T_START
 
     # the benchmark's weights, from the seed, in place of the program's
-    in_shape = (cfg["input_size"], cfg["input_size"],
-                cfg["input_channels"])
-    shapes = data.param_shapes(cfg["layers"], *in_shape[1:])
-    for unit, pair in zip(wf.forwards, data.make_weights(seed, shapes)):
-        if pair is None:
-            continue
-        if tuple(unit.weights.shape) != tuple(pair[0].shape):
-            raise BenchError(f"{unit.name}: the program's weights are "
-                             f"{unit.weights.shape}, the configuration's "
-                             f"{pair[0].shape}")
-        unit.weights.mem = np.asarray(pair[0])
-        unit.bias.mem = np.asarray(pair[1])
+    of_model("install", wf, of_model("make_weights", seed, shapes))
 
-    probe = TrainerProbe()
+    probe = TrainerProbe(of_model("hypers", cfg))
     tracer = (Tracer(os.path.join(base, ".cache", "bench_trace",
                                   args.workload))
               if args.trace else None)
@@ -295,12 +318,11 @@ def run_cell(args, bench: dict, base: str = ROOT,
               "epoch_walls_s": clock.epoch_walls_s(),
               "rows": clock.window_rows(),
               "train_rows": train_rows, "eval_rows": eval_rows}
-    f = flops.model_flops(cfg["layers"], in_shape)
     run = {"window": window, "compile_events": compile_events,
-           "dataset_s": dataset_s, "flops": f, "peaks": peaks,
-           "chips": chips, "batch": batch,
-           "step_bytes": flops.step_bytes(cfg["layers"], in_shape, batch),
-           "memory_peak_bytes": peak_bytes, "trace": None}
+           "dataset_s": dataset_s, **counts, "peaks": peaks,
+           "chips": chips, "batch": batch, "config": cfg,
+           "traffic": traffic, "memory_peak_bytes": peak_bytes,
+           "trace": None}
     if tracer is not None:
         run["trace"] = reduce_trace(tracer.directory, trace_calls, batch,
                                     args.keep_trace)
@@ -309,23 +331,22 @@ def run_cell(args, bench: dict, base: str = ROOT,
     t_ref = time.monotonic()
     reference_loss = None
     if first is not None:
-        reference = importlib.import_module(os.path.splitext(
-            os.path.relpath(found["files"]["reference"], ROOT))[0]
-            .replace(os.sep, "."))
+        reference = import_file(found["files"]["reference"])
         rows = first["rows"]
         lo = n_test + n_valid
         if (len(set(rows.tolist())) != len(rows) or rows.min() < lo
                 or rows.max() >= lo + n_train):
             numbers["rows_misfed"] += len(rows)
-        images, labels = data.make_rows(
-            seed, rows.astype(np.uint32), *in_shape[1:],
-            cfg["n_classes"], float(cfg["assumed"]["noise"]))
+        inputs, targets = of_model("make_rows", seed,
+                                   rows.astype(np.uint32), cfg, traffic)
         nb = first["batch"]
         ref = reference.follow(
-            cfg["layers"], data.make_weights(seed, shapes),
-            images.reshape(-1, nb, *in_shape), labels.reshape(-1, nb),
+            cfg, of_model("make_weights", seed, shapes),
+            inputs.reshape(-1, nb, *inputs.shape[1:]),
+            targets.reshape(-1, nb, *targets.shape[1:]),
             seed=program_seed, epoch=int(first["epoch"] or 0))
-        numbers.update(correct.first_steps_numbers(first, ref))
+        numbers.update(correct.first_steps_numbers(first, ref,
+                                                   output_leaf))
         print("\n".join(correct.leaf_table(first, ref)), file=sys.stderr)
         print("readings: " + json.dumps({
             side: {k: v for k, v in got.items() if k in ref
@@ -369,6 +390,7 @@ def run_cell(args, bench: dict, base: str = ROOT,
                                  run["trace"]["modules"].items()]
     result["window"] = {"seconds": window["seconds"],
                         "epochs": window["epochs"],
+                        "row": model.row,
                         # for a person who looks for a slow epoch's or
                         # a slow set-up's cause
                         "setup_marks_s": marks_s,
@@ -390,7 +412,7 @@ def run_cell(args, bench: dict, base: str = ROOT,
     return 0, result
 
 
-def main(argv=None) -> int:
+def main(argv=None, base: str = ROOT) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -407,9 +429,9 @@ def main(argv=None) -> int:
                          "of a peak (never the driver)")
     args = ap.parse_args(argv)
     try:
-        bench = load_json(os.path.join(ROOT, "BENCHMARK.json"),
+        bench = load_json(os.path.join(base, "BENCHMARK.json"),
                           "BENCHMARK.json")
-        rc, result = run_cell(args, bench,
+        rc, result = run_cell(args, bench, base=base,
                               require_chip=not args.rehearse)
     except (BenchError, KeyError) as e:
         print(f"benchmark: {e}", file=sys.stderr)

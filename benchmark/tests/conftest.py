@@ -17,25 +17,43 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("ZNICZ_TPU_PALLAS_INTERPRET", "1")
 
 
+#: a reader of the tests' own (``data/readers/``), listed in the scratch
+#: benchmark beside the real ones
+TEST_READER = "lrn_kernels_us_per_row"
+
+
 @pytest.fixture
 def tiny_bench(tmp_path):
-    """A whole benchmark of one test-size cell in a scratch directory:
-    its own configuration, traffic and limits files beside a copy of the
-    real metric readers.  Returns (bench dict, base directory)."""
+    """A whole benchmark of two test-size cells in a scratch directory
+    (``tiny-train``: images; ``tiny-vec-train``: rows that are not
+    images, with a model file, workflow file and reference of its own):
+    their configuration, traffic and limits files beside a copy of the
+    real metric readers, and the ``BENCHMARK.json`` that lists them.
+    Returns (bench dict, base directory)."""
     here = os.path.dirname(os.path.abspath(__file__))
     shutil.copytree(os.path.join(here, "data"), tmp_path / "bench")
     shutil.copytree(os.path.join(ROOT, "benchmark", "metrics"),
                     tmp_path / "bench" / "metrics")
+    shutil.copy(os.path.join(here, "data", "readers", TEST_READER + ".py"),
+                tmp_path / "bench" / "metrics")
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         real = json.load(fh)
     bench = dict(
         real, paths=["bench"],
-        configs=[{"name": "tiny", "source": "test", "reduced": [],
-                  "file": "bench/configs/tiny.json", "why": "test"}],
-        workloads=[{"name": "tiny-train", "config": "tiny",
-                    "traffic": "tiny-b8", "chips": 1, "why": "test"}])
+        configs=[{"name": name, "source": "test", "reduced": [],
+                  "file": f"bench/configs/{name}.json", "why": "test"}
+                 for name in ("tiny", "tiny-vec")],
+        workloads=[{"name": f"{name}-train", "config": name,
+                    "traffic": "tiny-b8", "chips": 1, "why": "test"}
+                   for name in ("tiny", "tiny-vec")])
+    bench["per_layer"] = bench["per_layer"] + [
+        {"name": TEST_READER, "unit": "us", "better": "lower",
+         "source": "device_trace", "layer": "kernels",
+         "moves": "train_images_per_s"}]
     for m in bench["per_layer"] + bench["end_to_end"]:
         m.pop("workloads", None)
+    with open(tmp_path / "BENCHMARK.json", "w") as fh:
+        json.dump(bench, fh)
     return bench, str(tmp_path)
 
 

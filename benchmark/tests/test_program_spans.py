@@ -12,10 +12,9 @@ from benchmark import run as runner
 from conftest import ROOT, cell_args
 
 
-def _reader(name):
+def _reader(name, directory=os.path.join(ROOT, "benchmark", "metrics")):
     spec = importlib.util.spec_from_file_location(
-        "bench_" + name, os.path.join(ROOT, "benchmark", "metrics",
-                                      name + ".py"))
+        "bench_" + name, os.path.join(directory, name + ".py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read

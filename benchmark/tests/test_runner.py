@@ -36,11 +36,34 @@ def test_window_result_and_correct(tiny_bench):
     assert res["checks"]["rows_misfed"] == {"value": 0, "limit": 0}
 
 
+@pytest.mark.parametrize("trace", [0, 1])
+def test_rows_that_are_not_images_run_to_a_correct_result(tiny_bench, trace):
+    """``tiny-vec``: a feature vector a row, with a model file, workflow
+    file and reference of its own, through the same ``run_cell``."""
+    bench, base = tiny_bench
+    rc, res = runner.run_cell(
+        cell_args(workload="tiny-vec-train", trace=trace), bench,
+        base=base, require_chip=False)
+    assert rc == 0 and res["correct"] is True, res["checks"]
+    assert res["window"]["row"] == {"kind": "vector"}
+    assert res["attempted"] == res["window"]["epochs"] * 6
+    for name in ("loss_gap", "grad_norm_gap", "change_norm_gap",
+                 "out_grad_diff"):
+        c = res["checks"][name]
+        assert c["value"] < 1e-5 < c["limit"]          # f32 on the CPU
+    assert res["checks"]["rows_misfed"] == {"value": 0, "limit": 0}
+    if trace:
+        assert {"compile_s", "epoch_launches"} <= set(res["metrics"])
+    else:
+        assert set(res["metrics"]) == {"train_images_per_s", "setup_s"}
+
+
 def test_traced_run_reports_per_layer_metrics_only(tiny_bench):
     bench, base = tiny_bench
     rc, res = runner.run_cell(cell_args(trace=1), bench, base=base,
                               require_chip=False)
     assert rc == 0 and res["correct"] is True
+    assert res["window"]["row"] == {"kind": "image"}
     names = set(res["metrics"])
     assert {"compile_s", "dataset_s", "window_compiles",
             "epoch_host_share", "epoch_wall_ms_max"} <= names
@@ -76,15 +99,17 @@ def _broken(monkeypatch, fault):
         monkeypatch.setattr(fused.FusedTrainer, "_idx_matrix", dup)
 
 
-@pytest.mark.parametrize("fault", ["state unchanged",
-                                   "half of the batch left out",
-                                   "rows fed twice"])
+@pytest.mark.parametrize("workload,fault", [
+    ("tiny-train", "state unchanged"),
+    ("tiny-train", "half of the batch left out"),
+    ("tiny-train", "rows fed twice"),
+    ("tiny-vec-train", "half of the batch left out")])
 def test_fault_under_the_timed_path_is_not_correct(tiny_bench, monkeypatch,
-                                                   fault):
+                                                   workload, fault):
     bench, base = tiny_bench
     _broken(monkeypatch, fault)
-    rc, res = runner.run_cell(cell_args(seconds=0.2), bench, base=base,
-                              require_chip=False)
+    rc, res = runner.run_cell(cell_args(workload=workload, seconds=0.2),
+                              bench, base=base, require_chip=False)
     assert rc == 0 and res["correct"] is False, (fault, res["checks"])
 
 
@@ -107,6 +132,74 @@ def test_missing_file_is_named(tiny_bench, missing):
     os.remove(os.path.join(base, "bench", missing))
     with pytest.raises(runner.BenchError, match=os.path.basename(missing)):
         runner.find_cell(bench, "tiny-train", base)
+
+
+@pytest.mark.parametrize("key", ["workflow", "reference", "model"])
+def test_file_a_configuration_names_is_checked(tiny_bench, key):
+    bench, base = tiny_bench
+    path = os.path.join(base, "bench", "configs", "tiny.json")
+    with open(path) as fh:
+        cfg = json.load(fh)
+    named = dict(cfg, **{key: "benchmark/lib/no_such_file.py"})
+    unnamed = {k: v for k, v in cfg.items() if k != key}
+    for config, says in ((named, "no_such_file.py"), (unnamed, key)):
+        with open(path, "w") as fh:
+            json.dump(config, fh)
+        with pytest.raises(runner.BenchError, match=says):
+            runner.find_cell(bench, "tiny-train", base)
+
+
+@pytest.mark.parametrize("workload,config", [("tiny-train", "tiny"),
+                                             ("tiny-vec-train", "tiny-vec")])
+def test_unknown_layer_kind_exits_2_with_one_line(tiny_bench, capsys,
+                                                  workload, config):
+    """A count or a shape the model file cannot give is a fault of the
+    configuration: one line, no result, no traceback, before the chip
+    is looked for."""
+    bench, base = tiny_bench
+    path = os.path.join(base, "bench", "configs", config + ".json")
+    with open(path) as fh:
+        cfg = json.load(fh)
+    cfg["layers"][0]["type"] = "attention"
+    with open(path, "w") as fh:
+        json.dump(cfg, fh)
+    rc = runner.main(["--workload", workload, "--seed", "5", "--seconds",
+                      "0.2", "--rehearse"], base=base)
+    out, err = capsys.readouterr()
+    assert rc == 2 and out == ""
+    assert len(err.strip().splitlines()) == 1 and "'attention'" in err
+
+
+@pytest.mark.parametrize("workload,variants", [
+    ("tiny-train", {"control_fp8", "stated_bf16", "fault_half_batch",
+                    "fault_frozen"}),
+    ("tiny-vec-train", {"fault_half_batch", "fault_frozen"})])
+def test_limits_study_reads_each_configuration_through_its_files(
+        tiny_bench, capsys, workload, variants):
+    """The chip's study of the limits, at test size: rows, weights and the
+    output leaf from the model file, the variants from the reference."""
+    import limits_study
+    _, base = tiny_bench
+    limits_study.main(["--workload", workload, "--seeds", "11"], base=base)
+    lines = [json.loads(line) for line in
+             capsys.readouterr().out.strip().splitlines()]
+    by_name = {line["variant"]: line for line in lines}
+    assert set(by_name) == variants | {"reference"}
+    assert by_name["fault_frozen"]["change_norm_gap"] == pytest.approx(1.0)
+    assert by_name["fault_half_batch"]["grad_norm_gap"] > 0.1
+
+
+def test_run_py_knows_no_image_and_no_config_tree():
+    with open(os.path.join(ROOT, "benchmark", "run.py")) as fh:
+        text = fh.read()
+    for word in ("input_size", "input_channels", "alexnet.", "n_classes",
+                 "noise", '"layers"'):
+        assert word not in text, word
+    for name in ("probe.py", "correct.py"):
+        with open(os.path.join(ROOT, "benchmark", "lib", name)) as fh:
+            text = fh.read()
+        for word in ("(w, b)", "len(ref_g) - 2", "hypers_bias"):
+            assert word not in text, (name, word)
 
 
 def test_unknown_device_kind_is_an_error():
