@@ -1,0 +1,342 @@
+"""The token-sequence layer kinds (``embed``, ``attn_block``, ``moe_block``,
+``lm_head``) at test widths on the CPU: d 64, 4 query heads over 2
+key/value heads of 16, window 8, T 32, 8 experts of width 32 with 2 a
+token, vocabulary 128, layers sliding x3 + full.  The fused trainer is
+held against the benchmark's plain reference
+(``benchmark/lib/decoder_reference.py``, which imports nothing of the
+program) with seeded weights."""
+
+import copy
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.lib import decoder_model as model          # noqa: E402
+from benchmark.lib import decoder_reference as reference  # noqa: E402
+from znicz_tpu.nn import decoder as units                 # noqa: E402
+from znicz_tpu.ops import attention, moe, tuning          # noqa: E402
+from znicz_tpu.parallel import fused                      # noqa: E402
+
+TRAFFIC = {"seq_len": 32, "minibatch": 2, "n_train": 12, "n_valid": 4,
+           "n_test": 0}
+SEED = 20261001
+
+
+def config(experts_held=(0, 8)) -> dict:
+    with open(os.path.join(ROOT, "benchmark", "tests", "data", "configs",
+                           "tiny-decoder.json")) as fh:
+        cfg = json.load(fh)
+    cfg["deployment"]["experts_held"] = list(experts_held)
+    cfg["num_experts"] = experts_held[1]
+    return cfg
+
+
+def spec_of(cfg: dict) -> fused.ModelSpec:
+    """The trainer's spec of the configuration, as ``extract_model``
+    makes it of the units."""
+    kinds = {cls.MAPPING[0]: cls for cls in (
+        units.Embedding, units.AttentionBlock, units.MoEBlock,
+        units.LMHead)}
+    layers = []
+    for la in model.layer_list(cfg):
+        unit = kinds[la["type"]](None, **la["->"])
+        h = la["<-"]
+        layers.append(fused.sequence_layer(unit, (
+            h["learning_rate"], h["weights_decay"], 0.0,
+            h["gradient_moment"])))
+    return fused.ModelSpec(tuple(layers), "softmax")
+
+
+def setup(experts_held):
+    cfg = config(experts_held)
+    weights = model.make_weights(SEED, model.param_shapes(cfg))
+    x, y = model.make_rows(SEED, np.arange(4, 10, dtype=np.uint32), cfg,
+                           TRAFFIC)
+    return cfg, spec_of(cfg), weights, x.reshape(3, 2, -1), y.reshape(
+        3, 2, -1)
+
+
+# -- the fused trainer against the reference ---------------------------------
+@pytest.mark.parametrize("experts_held", [(0, 8), (2, 2)],
+                         ids=["all_held", "quarter_held"])
+def test_three_steps_follow_the_reference(experts_held):
+    cfg, spec, weights, x, y = setup(experts_held)
+    ref = reference.follow(cfg, copy.deepcopy(weights), x, y)
+    want = jax.grad(lambda ps: jnp.mean(reference.token_losses(
+        cfg, ps, x[0], y[0])))([tuple(ls) for ls in weights])
+    grads, _ = jax.jit(lambda p, a, b: fused.grad_minibatch(
+        spec, p, a, b))(weights, x[0], y[0])
+    for got_layer, want_layer in zip(grads, want):
+        for got, exp in zip(got_layer, want_layer):
+            np.testing.assert_allclose(got, exp, rtol=2e-4, atol=2e-7)
+    p0 = jax.tree.map(np.asarray, weights)
+    trainer = fused.FusedTrainer(
+        spec=spec, params=weights,
+        vels=jax.tree.map(jnp.zeros_like, weights))
+    rows = jnp.concatenate(list(x)), jnp.concatenate(list(y))
+    losses = [float(trainer.train_epoch(
+        *rows, np.arange(2 * s, 2 * s + 2), 2, ctr_base=2 * s)["loss"][0])
+        for s in range(3)]
+    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-5)
+    assert losses[2] < losses[0]
+    change = [tuple(float(np.linalg.norm(np.asarray(a) - a0))
+                    for a, a0 in zip(ls, ls0))
+              for ls, ls0 in zip(trainer.params, p0)]
+    for got, exp in zip(change, ref["change_norms"]):
+        np.testing.assert_allclose(got, exp, rtol=2e-4)
+
+
+def test_counters_are_the_references_own_routing():
+    cfg, spec, weights, x, y = setup((2, 2))
+    got = jax.jit(lambda p, a, b: fused.eval_minibatch(spec, p, a, b))(
+        weights, x[0], y[0])
+    want = reference.routing(cfg, [tuple(ls) for ls in weights], x[0])
+    assert {k: int(got[k]) for k in want} == want
+    assert int(got["tokens"]) == x[0].size
+    assert want["moe_assignments"] == 4 * 2 * x[0].size      # layers x top_k
+
+
+# -- the expert layer ---------------------------------------------------------
+def _moe_case(held=(0, 8)):
+    cfg = config(held)
+    la = [la for la in model.layer_list(cfg) if la["type"] == "moe_block"][0]
+    mcfg = units.MoEBlock(None, **la["->"]).fused_config()
+    return cfg, mcfg
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """The ``moe_block`` outputs of the four quarters, the residual (and
+    the router, which every chip computes alike) counted once, are the
+    uncut layer of the reference."""
+    cfg, _ = _moe_case()
+    shapes = model.param_shapes(cfg)[2]
+    leaves = model.make_weights(SEED, [shapes])[0]
+    leaves = (leaves[0], leaves[1] * 30.0) + tuple(leaves[2:])   # decisive
+    x = jax.random.normal(jax.random.key(3), (2, 32, 64), jnp.float32)
+    whole = jnp.stack([reference.experts(cfg, leaves, row, None, False)[0]
+                       for row in x])
+    total = x
+    for first in range(0, 8, 2):
+        _, qcfg = _moe_case((first, 2))
+        g2, wr, wg, wu, wd = leaves
+        share = (g2, wr) + tuple(w[first:first + 2] for w in (wg, wu, wd))
+        out, counters = moe.moe_block_fwd(share, x, qcfg)
+        total = total + (out - x)
+        assert int(counters["moe_assignments"]) == 2 * 32 * 2
+    np.testing.assert_allclose(total, whole, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("expected", [1.0, 0.25],
+                         ids=["one_length", "two_lengths"])
+@pytest.mark.parametrize("target,held_pairs", [(3, 64), (6, 0)],
+                         ids=["all_on_one_held_expert", "none_held"])
+def test_worst_imbalance_drops_nothing(target, held_pairs, expected):
+    """Every token's first choice on ONE expert (held, then absent), its
+    second on an absent one: the held sum is the dense product of that
+    expert, and every pair is counted, whichever length of the sorted
+    buffers the count picks."""
+    n, d, f = 64, 64, 32
+    ks = jax.random.split(jax.random.key(5), 5)
+    xn = jax.random.normal(ks[0], (n, d))
+    wg, wu = (jax.random.normal(k, (2, d, f)) * 0.1 for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (2, f, d)) * 0.1
+    weights = jax.random.uniform(ks[4], (n, 2), minval=0.2, maxval=0.8)
+    experts = jnp.stack([jnp.full((n,), target), jnp.full((n,), 7)], 1)
+    out, counts = moe.held_expert_sum(xn, weights, experts, wg, wu, wd, 2,
+                                      jnp.float32, expected)
+    assert int(counts.sum()) == held_pairs
+    if held_pairs:
+        e = target - 2
+        dense = (jax.nn.silu(xn @ wg[e]) * (xn @ wu[e])) @ wd[e]
+        np.testing.assert_allclose(out, weights[:, :1] * dense, rtol=1e-4,
+                                   atol=1e-6)
+        assert int(counts[e]) == n
+    else:
+        assert not np.asarray(out).any()
+
+
+# -- attention -------------------------------------------------------------------
+def _attn_case(window):
+    cfg = config()
+    la = [la for la in model.layer_list(cfg)
+          if la["type"] == "attn_block"][0]
+    acfg = dict(units.AttentionBlock(None, **la["->"]).fused_config(),
+                window=window)
+    leaves = model.make_weights(SEED, [model.param_shapes(cfg)[1]])[0]
+    return acfg, leaves
+
+
+@pytest.mark.parametrize("seq_len,same", [(8, True), (32, False)])
+def test_a_sliding_layer_is_a_full_one_up_to_its_window(seq_len, same):
+    (sliding, leaves), (full, _) = _attn_case(8), _attn_case(None)
+    x = jax.random.normal(jax.random.key(7), (2, seq_len, 64))
+    a = attention.attn_block_fwd(leaves, x, sliding)[0]
+    b = attention.attn_block_fwd(leaves, x, full)[0]
+    if same:
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    else:
+        np.testing.assert_allclose(a[:, :8], b[:, :8], rtol=1e-5, atol=1e-6)
+        assert float(jnp.abs(a[:, 8:] - b[:, 8:]).max()) > 1e-3
+
+
+def test_yarn_frequencies_of_the_published_rope():
+    """``rope_parameters.full_attention`` of Mellum2: correction
+    dimensions 18 and 35 of 64; kept below, a sixteenth above, a linear
+    ramp between."""
+    rope = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782}
+    inv = attention.rope_inv_freq(128, rope)
+    want = {0: 1.0, 17: 0.030634520893224042, 18: 0.024955408670558694,
+            26: 0.0027043825167258223, 35: 4.7781061769823416e-05,
+            63: 1.5344629944572555e-07}
+    for i, value in want.items():
+        assert inv[i] == pytest.approx(value, rel=1e-12)
+    assert inv[17] == pytest.approx(500000 ** (-34 / 128))
+    assert inv[63] == pytest.approx(500000 ** (-126 / 128) / 16)
+    np.testing.assert_allclose(inv, reference.inv_freq(128, rope))
+    cos, _ = attention.rope_tables(4, 128, tuple(sorted(rope.items())))
+    assert cos[0, 0] == pytest.approx(1.2772588722239782)
+    plain = attention.rope_inv_freq(
+        128, {"rope_type": "default", "rope_theta": 500000})
+    assert plain[63] == pytest.approx(500000 ** (-126 / 128))
+
+
+# -- the kernel tier against its twin -------------------------------------------
+@pytest.fixture
+def interpreter(monkeypatch):
+    monkeypatch.setattr(tuning, "_INTERPRET", True)
+    attention._splash_kernel.cache_clear()
+    yield
+    attention._splash_kernel.cache_clear()
+
+
+@pytest.mark.parametrize("window", [128, None], ids=["sliding", "full"])
+def test_splash_attention_is_its_jnp_twin(interpreter, window):
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (1, 256, 2, 128)) * 0.3
+    k, v = (jax.random.normal(kk, (1, 256, 1, 128)) for kk in ks[1:])
+    assert attention.kernel_route(256, 128)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v, window)))
+    np.testing.assert_allclose(
+        attention.splash_attention(q, k, v, window),
+        attention.blocked_attention(q, k, v, window, block_q=128),
+        rtol=2e-3, atol=2e-3)
+    got = jax.grad(loss(attention.splash_attention), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(attention.blocked_attention), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-3)
+
+
+def test_megablox_products_are_ragged_dot(interpreter):
+    ks = jax.random.split(jax.random.key(13), 2)
+    lhs = jax.random.normal(ks[0], (512, 128))
+    rhs = jax.random.normal(ks[1], (3, 128, 256)) * 0.1
+    sizes = jnp.asarray([200, 0, 150], jnp.int32)       # 162 rows unheld
+    assert moe.kernel_route(512, 128, 256)
+
+    def run(impl):
+        def f(lhs, rhs):
+            out = impl(lhs, rhs, sizes)
+            return jnp.sum(jnp.sin(out)), out
+        (_, out), grads = jax.value_and_grad(f, (0, 1), has_aux=True)(
+            lhs, rhs)
+        return out, grads
+    out, grads = run(moe.pallas_grouped_matmul)
+    want, want_grads = run(moe.xla_grouped_matmul)
+    assert not np.asarray(out[350:]).any() \
+        and not np.asarray(grads[0][350:]).any()
+    np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2)
+
+
+# -- units, workflow, launcher ----------------------------------------------------
+@pytest.fixture
+def sample():
+    from znicz_tpu.config import root
+    saved = root.decoder_lm.to_dict()
+    yield root.decoder_lm
+    root.decoder_lm.update(saved)
+
+
+def test_five_leaf_layer_round_trip_and_refusals(sample, tmp_path):
+    from znicz_tpu.backends import Device
+    from znicz_tpu.export import export_workflow
+    from znicz_tpu.models.decoder_lm import DecoderLMWorkflow
+    from znicz_tpu.parallel.mesh import make_mesh
+    wf = DecoderLMWorkflow()
+    wf.initialize(device=Device.create("xla"))
+    spec, params, vels = fused.extract_model(wf)
+    assert [len(p) for p in params] == [1, 5, 5, 5, 5, 5, 5, 5, 5, 2]
+    assert spec.layers[2].kind == "moe_block" \
+        and spec.layers[-1].kind == "lm_head"
+    assert fused.attn_routes(spec) == "window:3 full:1"
+    trainer = fused.FusedTrainer(workflow=wf)
+    trainer.params = [tuple(a + 1.0 for a in ls) for ls in trainer.params]
+    trainer.vels = [tuple(a + 0.5 for a in ls) for ls in trainer.vels]
+    trainer.write_back()
+    moe_unit, moe_gd = wf.forwards[2], wf.gds[2]
+    for j, leaf in enumerate(moe_unit.LEAVES):
+        np.testing.assert_allclose(getattr(moe_unit, leaf).mem,
+                                   params[2][j] + 1.0)
+        np.testing.assert_allclose(
+            getattr(moe_gd, "velocity_" + leaf).mem, vels[2][j] + 0.5)
+    # the snapshotter saves the leaves like any Vector
+    from znicz_tpu.snapshotter import collect_state
+    arrays, _ = collect_state(wf)
+    assert {f"{moe_unit.name}/wg", f"{moe_gd.name}/velocity_wg"} \
+        <= set(arrays)
+    with pytest.raises(NotImplementedError, match="expert"):
+        fused.FusedTrainer(spec=spec, params=params, vels=vels,
+                           mesh=make_mesh(2, 1))
+    with pytest.raises(NotImplementedError, match="Reach 3"):
+        export_workflow(wf, str(tmp_path / "m.znn"))
+
+
+def _last_train_step(flightrecorder) -> dict:
+    return [r for r in flightrecorder.RECORDER.snapshot()["recent"]
+            if r.get("kind") == "train_step"][-1]
+
+
+def test_the_sample_trains_through_the_launcher(sample):
+    from znicz_tpu.launcher import Launcher
+    from znicz_tpu.telemetry import flightrecorder
+    wf = Launcher("znicz_tpu.models.decoder_lm", backend="xla", fused=True,
+                  epochs=3, seed=7).run()
+    metrics = wf.decision.epoch_metrics
+    assert len(metrics) == 3
+    assert metrics[-1]["train_loss"] < metrics[0]["train_loss"] < np.log(
+        128) + 0.2
+    for m in metrics:        # errors are counted a target, and so shared
+        assert 0.0 < m["train_err_pct"] <= 100.0
+        assert m["train_err_pct"] == pytest.approx(
+            100.0 * m["train_n_err"] / (32 * 32))
+        assert m["validation_err_pct"] == pytest.approx(
+            100.0 * m["validation_n_err"] / (8 * 32))
+    row = _last_train_step(flightrecorder)
+    assert row["tokens"] == 32 * 32
+    assert row["moe_assignments"] == row["moe_assignments_held"] \
+        == 32 * 32 * 2 * 4
+    assert 0 < row["moe_expert_load_max"] <= 4 * 32 * 2
+
+
+def test_a_model_without_the_kinds_has_no_counters():
+    from znicz_tpu.launcher import Launcher
+    from znicz_tpu.telemetry import flightrecorder
+    Launcher("znicz_tpu.models.wine", backend="xla", fused=True,
+             epochs=1).run()
+    row = _last_train_step(flightrecorder)
+    assert not {"tokens", "moe_assignments", "moe_assignments_held",
+                "moe_expert_load_max"} & set(row)
+    assert 0.0 <= row.get("examples", 1) and "wall_ms" in row

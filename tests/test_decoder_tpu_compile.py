@@ -1,0 +1,115 @@
+"""The decoder's kernels compiled for a described v5e at the published
+shapes of ``mellum2-12b-a2.5b`` (benchmark/configs): splash attention,
+sliding and full, forward and backward, at 8,192 tokens with 32 query
+heads over 4 key/value heads of 128; and the grouped expert products of
+16 held experts of 2304 x 896 over the 32,768 assignment rows of half a
+sequence (``ops/moe.CHUNK_TOKENS``), forward and backward.  The TPU's own Mosaic and XLA compilers run here, with no
+chip; nothing runs, so this says nothing of results or times.
+
+The topology is described inside a fixture, never at import: only the
+worker that is given this file loads the TPU's library."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from znicz_tpu.ops import attention, moe, tuning
+
+T, HEADS, KV_HEADS, HEAD_DIM, WINDOW = 8192, 32, 4, 128, 1024
+D, F, HELD, TOP_K = 2304, 896, 16, 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back: keep it out
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The dispatch a TPU process takes: the real kernels, not the
+    interpreter."""
+    monkeypatch.setattr(tuning, "on_tpu", lambda: True)
+    attention._splash_kernel.cache_clear()
+    yield
+    attention._splash_kernel.cache_clear()
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _qkv(one_chip):
+    def s(heads):
+        return jax.ShapeDtypeStruct((1, T, heads, HEAD_DIM), jnp.bfloat16,
+                                    sharding=one_chip)
+    return s(HEADS), s(KV_HEADS), s(KV_HEADS)
+
+
+@pytest.mark.parametrize("window", [WINDOW, None],
+                         ids=["sliding", "full"])
+def test_attention_forward_compiles_for_a_v5e(one_chip, mosaic, window):
+    assert attention.kernel_route(T, HEAD_DIM)
+    text = _compiled_text(
+        lambda q, k, v: attention.attention(q, k, v, window),
+        *_qkv(one_chip))
+    assert "tpu_custom_call" in text and "splash_mqa_fwd" in text
+
+
+@pytest.mark.parametrize("window", [WINDOW, None],
+                         ids=["sliding", "full"])
+def test_attention_backward_compiles_for_a_v5e(one_chip, mosaic, window):
+    def loss(q, k, v):
+        return jnp.sum(attention.attention(q, k, v, window)
+                       .astype(jnp.float32))
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_qkv(one_chip))
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+
+
+def _expert_shapes(one_chip):
+    m = moe.CHUNK_TOKENS * TOP_K
+    return (jax.ShapeDtypeStruct((m, D), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((HELD, D, F), jnp.bfloat16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((HELD, F, D), jnp.bfloat16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((HELD,), jnp.int32, sharding=one_chip))
+
+
+def _expert_products(xs, w_up, w_down, sizes):
+    h = moe.grouped_matmul(xs, w_up, sizes)
+    return moe.grouped_matmul(h.astype(xs.dtype), w_down, sizes)
+
+
+def test_grouped_products_compile_for_a_v5e(one_chip, mosaic):
+    assert moe.kernel_route(moe.CHUNK_TOKENS * TOP_K, D, F)
+    text = _compiled_text(_expert_products, *_expert_shapes(one_chip))
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_grouped_products_backward_compiles_for_a_v5e(one_chip, mosaic):
+    def loss(xs, w_up, w_down, sizes):
+        return jnp.sum(_expert_products(xs, w_up, w_down, sizes))
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_expert_shapes(one_chip))
+    # two products forward, and an input and a weight gradient of each
+    assert text.count("tpu_custom_call") >= 5

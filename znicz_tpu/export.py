@@ -188,6 +188,10 @@ def export_workflow(workflow, path: str) -> str:
         export_idx = {}   # forward unit -> its EXPORT-stream index
         n_out = 0
         for fwd in workflow.forwards:
+            if hasattr(fwd, "LEAVES"):
+                raise NotImplementedError(
+                    f"export and serving do not cover {fwd.name}: serving "
+                    "the token-sequence kinds is ROADMAP Reach 3")
             export_idx[id(fwd)] = n_out
             n_out += 1
             if isinstance(fwd, All2AllSoftmax):

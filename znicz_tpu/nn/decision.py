@@ -87,7 +87,8 @@ class DecisionGD(DecisionBase):
     def on_minibatch(self, klass: int) -> None:
         ev = self.evaluator
         self.epoch_n_err[klass] += ev.n_err
-        self.epoch_samples[klass] += self.loader.minibatch_size
+        self.epoch_samples[klass] += getattr(
+            ev, "n_targets", self.loader.minibatch_size)
         self.epoch_loss[klass] += ev.mean_loss
         self.minibatch_count[klass] += 1
 

@@ -1,0 +1,281 @@
+"""The token-sequence layer kinds of a decoder language model as units:
+``embedding``, ``attn_block``, ``moe_block`` and ``lm_head``, each with
+its gradient unit.
+
+A unit here holds any number of parameter ``Vector``s, named by its
+``LEAVES`` (the gradient unit holds ``velocity_<leaf>`` for each), where
+the znicz kinds hold ``weights`` and ``bias``.  The math is one pure
+function a kind in ``ops/attention.py`` / ``ops/moe.py``; the fused
+trainer (``parallel/fused.py``) calls it directly, and the tick path
+(``wf.run()``) calls the same function here and ``jax.vjp`` of it in the
+gradient unit, followed by the one momentum-SGD update of ``ops/update``.
+``"->"`` and ``"<-"`` of a layer are read as for the other kinds: one
+learning rate, decay and moment a layer."""
+
+from __future__ import annotations
+
+import functools
+
+import jax.numpy as jnp
+import numpy as np
+
+from ..memory import Vector
+from ..ops import attention as attn_ops, moe as moe_ops, softmax, update
+from .nn_units import Forward, GradientDescentBase
+
+
+class SequenceForward(Forward):
+    """Base of the sequence kinds' forward units."""
+
+    #: the kind's name in ``parallel/fused.py`` and its ``ops`` function
+    KIND = ""
+    FWD = None
+    #: the parameter Vectors, in the order of the function's ``leaves``
+    LEAVES: tuple[str, ...] = ()
+
+    def __init__(self, workflow=None, name=None, weights_stddev=0.02,
+                 rms_norm_eps=1e-6, **kwargs):
+        kwargs.setdefault("weights_filling", "gaussian")
+        super().__init__(workflow, name, weights_stddev=weights_stddev,
+                         include_bias=False, **kwargs)
+        self.rms_norm_eps = float(rms_norm_eps)
+        for leaf in self.LEAVES:
+            setattr(self, leaf, Vector())
+
+    #: what the snapshotter saves of this unit beside the znicz names
+    @property
+    def STATE_VECTORS(self) -> tuple[str, ...]:
+        return self.LEAVES
+
+    def fused_config(self) -> dict:
+        """The kind's static config (hashable values)."""
+        return {"eps": self.rms_norm_eps}
+
+    def leaf_shapes(self, in_shape: tuple) -> dict:
+        raise NotImplementedError
+
+    def out_shape(self, in_shape: tuple) -> tuple:
+        return tuple(in_shape)
+
+    def _fill_leaf(self, leaf: str, shape: tuple) -> np.ndarray:
+        if len(shape) == 1:                     # a norm's gain
+            return np.ones(shape, np.float32)
+        return np.asarray(self._fill(shape, self.weights_filling,
+                                     self.weights_stddev), np.float32)
+
+    def initialize(self, device=None, **kwargs) -> None:
+        super().initialize(device, **kwargs)
+        in_shape = tuple(self.input.shape)
+        for leaf, shape in self.leaf_shapes(in_shape).items():
+            vec = getattr(self, leaf)
+            if not vec:
+                vec.mem = self._fill_leaf(leaf, tuple(shape))
+            elif tuple(vec.shape) != tuple(shape):
+                raise ValueError(f"{self.name}: leaf {leaf} is "
+                                 f"{tuple(vec.shape)}, the layer's "
+                                 f"{tuple(shape)}")
+        if not self.output:
+            # the shape the next unit reads; on the host only, until a
+            # tick-path run puts the real output on the device (the fused
+            # trainer never does, and a head's logits are 0.8 GB)
+            self.output.mem = np.zeros(self.out_shape(in_shape), np.float32)
+        self.init_vectors(*(getattr(self, leaf) for leaf in self.LEAVES))
+        self.call = functools.partial(type(self).FWD,
+                                      cfg=self.fused_config())
+        self._fwd_fn = lambda leaves, x: self.call(leaves, x)[0]
+
+    def leaves_dev(self) -> tuple:
+        return tuple(getattr(self, leaf).devmem for leaf in self.LEAVES)
+
+    def numpy_run(self) -> None:
+        self.xla_run()
+
+    def xla_run(self) -> None:
+        self.output.devmem = self.jit(self._fwd_fn)(self.leaves_dev(),
+                                                    self.input.devmem)
+
+
+class Embedding(SequenceForward):
+    """ids ``(B, T)`` -> ``(B, T, d)``: rows of ``table (vocab, d)``."""
+
+    MAPPING = ("embedding",)
+    KIND = "embed"
+    FWD = staticmethod(attn_ops.embed_fwd)
+    LEAVES = ("table",)
+
+    def __init__(self, workflow=None, name=None, vocab=None, hidden=None,
+                 **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        self.vocab, self.hidden = int(vocab), int(hidden)
+
+    def leaf_shapes(self, in_shape):
+        return {"table": (self.vocab, self.hidden)}
+
+    def out_shape(self, in_shape):
+        return (*in_shape, self.hidden)
+
+
+class AttentionBlock(SequenceForward):
+    """``x + Attn(RMSNorm(x; g1))``: grouped-query attention with rotary
+    embeddings, causal, over a sliding ``window`` or (None) everything
+    before."""
+
+    MAPPING = ("attn_block",)
+    KIND = "attn_block"
+    FWD = staticmethod(attn_ops.attn_block_fwd)
+    LEAVES = ("g1", "wq", "wk", "wv", "wo")
+
+    def __init__(self, workflow=None, name=None, heads=None, kv_heads=None,
+                 head_dim=None, window=None, rope=None, **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        self.heads, self.kv_heads = int(heads), int(kv_heads)
+        self.head_dim = int(head_dim)
+        self.window = None if window is None else int(window)
+        self.rope = dict(rope or {"rope_type": "default",
+                                  "rope_theta": 10000.0})
+
+    def fused_config(self):
+        return {**super().fused_config(), "heads": self.heads,
+                "kv_heads": self.kv_heads, "head_dim": self.head_dim,
+                "window": self.window,
+                "rope": tuple(sorted(self.rope.items()))}
+
+    def leaf_shapes(self, in_shape):
+        d, hd = in_shape[-1], self.head_dim
+        return {"g1": (d,), "wq": (d, self.heads * hd),
+                "wk": (d, self.kv_heads * hd),
+                "wv": (d, self.kv_heads * hd),
+                "wo": (self.heads * hd, d)}
+
+
+class MoEBlock(SequenceForward):
+    """``x + MoE(RMSNorm(x; g2))`` for the ``experts_held = [first,
+    count]`` of the model's ``experts``: routed over all of them, the
+    terms of the held ones added (``ops/moe.py``)."""
+
+    MAPPING = ("moe_block",)
+    KIND = "moe_block"
+    FWD = staticmethod(moe_ops.moe_block_fwd)
+    LEAVES = ("g2", "wr", "wg", "wu", "wd")
+
+    def __init__(self, workflow=None, name=None, experts=None,
+                 experts_held=None, expert_width=None, top_k=None,
+                 norm_topk_prob=True, **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        self.experts = int(experts)
+        first, count = experts_held or (0, self.experts)
+        self.experts_held = (int(first), int(count))
+        if not 0 <= first <= first + count <= self.experts:
+            raise ValueError(f"{self.name}: experts_held {experts_held} "
+                             f"of {self.experts} experts")
+        self.expert_width, self.top_k = int(expert_width), int(top_k)
+        self.norm_topk_prob = bool(norm_topk_prob)
+
+    def fused_config(self):
+        return {**super().fused_config(), "experts": self.experts,
+                "experts_held": self.experts_held, "top_k": self.top_k,
+                "norm_topk_prob": self.norm_topk_prob}
+
+    def leaf_shapes(self, in_shape):
+        d, f, held = in_shape[-1], self.expert_width, self.experts_held[1]
+        return {"g2": (d,), "wr": (d, self.experts), "wg": (held, d, f),
+                "wu": (held, d, f), "wd": (held, f, d)}
+
+
+class LMHead(SequenceForward):
+    """``RMSNorm(x; gf) @ w``: ``(B, T, vocab)`` logits.  On the tick
+    path the unit's output is their softmax, with ``max_idx``, as
+    ``All2AllSoftmax`` gives the evaluator."""
+
+    MAPPING = ("lm_head",)
+    KIND = "lm_head"
+    FWD = staticmethod(attn_ops.lm_head_fwd)
+    LEAVES = ("gf", "w")
+
+    def __init__(self, workflow=None, name=None, vocab=None, **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        self.vocab = int(vocab)
+        self.max_idx = Vector()
+
+    def leaf_shapes(self, in_shape):
+        return {"gf": (in_shape[-1],), "w": (in_shape[-1], self.vocab)}
+
+    def out_shape(self, in_shape):
+        return (*in_shape[:-1], self.vocab)
+
+    def initialize(self, device=None, **kwargs) -> None:
+        super().initialize(device, **kwargs)
+        self.init_vectors(self.max_idx)
+
+        def probs(leaves, x):
+            logits = self.call(leaves, x)[0]
+            y, idx = softmax.xla_softmax(logits.reshape(-1, self.vocab))
+            return y.reshape(logits.shape), idx.reshape(logits.shape[:-1])
+        self._probs_fn = probs
+
+    def xla_run(self) -> None:
+        y, idx = self.jit(self._probs_fn)(self.leaves_dev(),
+                                          self.input.devmem)
+        self.output.devmem = y
+        self.max_idx.devmem = idx.astype(jnp.int32)
+
+
+class SequenceGD(GradientDescentBase):
+    """Gradient unit of a sequence kind: ``jax.vjp`` of the forward
+    unit's function at its input, then momentum SGD on every leaf with
+    the layer's one learning rate, decay and moment."""
+
+    MAPPING = ("embedding", "attn_block", "moe_block", "lm_head")
+
+    def setup_from_forward(self, fwd):
+        super().setup_from_forward(fwd)
+        for leaf in fwd.LEAVES:
+            setattr(self, "velocity_" + leaf, Vector())
+        return self
+
+    @property
+    def STATE_VECTORS(self) -> tuple[str, ...]:
+        return tuple("velocity_" + leaf
+                     for leaf in self.forward_unit.LEAVES)
+
+    def initialize(self, device=None, **kwargs) -> None:
+        super().initialize(device, **kwargs)
+        if self.accumulate_gradient or not self.apply_gradient:
+            raise NotImplementedError(
+                f"{self.name}: gradient accumulation schedules are not "
+                "offered for the sequence kinds")
+        fwd = self.forward_unit
+        for leaf in fwd.LEAVES:
+            vel = getattr(self, "velocity_" + leaf)
+            if not vel:
+                vel.mem = np.zeros(getattr(fwd, leaf).shape, np.float32)
+            self.init_vectors(vel)
+
+        def step(leaves, vels, x, err, hypers):
+            grads, err_in = attn_ops.block_vjp(fwd.call, leaves, x, err)
+            new = [update.sgd_update_h(w, g, v, hypers)
+                   for w, g, v in zip(leaves, grads, vels)]
+            return (tuple(w for w, _ in new), tuple(v for _, v in new),
+                    err_in)
+        self._step_fn = step
+
+    def numpy_run(self) -> None:
+        self.xla_run()
+
+    def xla_run(self) -> None:
+        fwd = self.forward_unit
+        vels = tuple(getattr(self, "velocity_" + leaf).devmem
+                     for leaf in fwd.LEAVES)
+        hypers = jnp.asarray((self.learning_rate, self.weights_decay,
+                              self.l1_vs_l2, self.gradient_moment),
+                             jnp.float32)
+        # the evaluator's error is with respect to the logits, as for
+        # the softmax layer of the znicz kinds
+        leaves, vels, err_in = self.jit(self._step_fn)(
+            fwd.leaves_dev(), vels, self.input.devmem,
+            self.err_output.devmem, hypers)
+        for leaf, w, v in zip(fwd.LEAVES, leaves, vels):
+            getattr(fwd, leaf).devmem = w
+            getattr(self, "velocity_" + leaf).devmem = v
+        if self.need_err_input and err_in is not None:
+            self.err_input.devmem = err_in

@@ -52,7 +52,7 @@ class EvaluatorSoftmax(EvaluatorBase):
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device, **kwargs)
-        n_classes = self.output.shape[1]
+        n_classes = self.output.shape[-1]
         if self.compute_confusion and not self.confusion_matrix:
             self.confusion_matrix.mem = np.zeros((n_classes, n_classes),
                                                  np.int64)
@@ -60,14 +60,17 @@ class EvaluatorSoftmax(EvaluatorBase):
         self._confusion_epoch = -1
 
     def numpy_run(self) -> None:
-        bs = self.batch_size
-        y = self.output.mem
-        labels = self.labels.mem.astype(np.int64)
+        # a target a position (``(B, T, V)`` probabilities, ``(B, T)``
+        # labels) is B*T rows of the same head
+        y = self.output.mem.reshape(-1, self.output.shape[-1])
+        bs = self.batch_size * (len(y) // len(self.output.mem))
+        self.n_targets = bs
+        labels = self.labels.mem.astype(np.int64).reshape(-1)
         loss, err = softmax_ops.np_softmax_ce(y[:bs], labels[:bs])
         full = np.zeros(y.shape, np.float32)
         full[:bs] = err / bs
-        self.err_output.mem = full
-        pred = self.max_idx.mem[:bs]
+        self.err_output.mem = full.reshape(self.output.shape)
+        pred = self.max_idx.mem.reshape(-1)[:bs]
         self.n_err = int(np.sum(pred != labels[:bs]))
         self.mean_loss = float(loss.mean())
         self.max_err_output_sum = float(np.abs(full).sum(axis=1).max())

@@ -151,8 +151,16 @@ def softmax(x):
     return xla_softmax(x)
 
 
+#: widest row the fused CE kernel takes: its three ``(256, classes)``
+#: float32 windows, double-buffered, have to fit VMEM (6 MiB at 1,000
+#: classes; a vocabulary of 24,576 would need 151 MiB), and it writes
+#: the probabilities, one ``(rows, classes)`` array more than the loss
+#: and the error need.  Wider rows take XLA's fusions
+KERNEL_MAX_CLASSES = 2048
+
+
 def softmax_ce_from_logits(logits, labels):
-    if tuning.use_pallas():
+    if tuning.use_pallas() and logits.shape[1] <= KERNEL_MAX_CLASSES:
         return tuning.batch_sharded(pallas_softmax_ce_from_logits,
                                     logits, labels)
     return xla_softmax_ce_from_logits(logits, labels)

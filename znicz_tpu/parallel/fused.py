@@ -29,15 +29,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import (activations, conv as conv_ops, deconv as deconv_ops,
-                   dropout as drop_ops, lrn_pool as lrn_pool_ops,
+from ..ops import (activations, attention as attn_ops, conv as conv_ops,
+                   deconv as deconv_ops, dropout as drop_ops,
+                   lrn_pool as lrn_pool_ops, moe as moe_ops,
                    normalization as lrn_ops, pooling as pool_ops,
                    softmax as softmax_ops, tuning)
 from ..telemetry import compilestats, tracing
 from . import mesh as mesh_lib
 
+#: The znicz kinds with trainable parameters: a ``(w, b)`` pair and a
+#: hand-written backward.
+PAIR_KINDS = ("fc", "conv", "deconv")
+
+#: The token-sequence kinds: ``fwd(leaves, x, cfg)`` of ``ops/`` with any
+#: number of leaves; their backward is ``jax.vjp`` of the same function
+#: over the cached block input (one rematerialisation a block).
+SEQUENCE_FWD = {"embed": attn_ops.embed_fwd,
+                "attn_block": attn_ops.attn_block_fwd,
+                "moe_block": moe_ops.moe_block_fwd,
+                "lm_head": attn_ops.lm_head_fwd}
+
 #: Layer kinds with trainable parameters.
-PARAM_KINDS = ("fc", "conv", "deconv")
+PARAM_KINDS = PAIR_KINDS + tuple(SEQUENCE_FWD)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,7 +58,8 @@ class LayerSpec:
     kind: str                     # fc | conv | max_pool | maxabs_pool |
     #                               avg_pool | stochastic_pool |
     #                               stochastic_abs_pool | lrn | lrn_pool |
-    #                               dropout | activation
+    #                               dropout | activation | embed |
+    #                               attn_block | moe_block | lm_head
     activation: str               # activations.BY_NAME key; last fc layer
     include_bias: bool            # of a softmax model keeps "linear"
     hypers: tuple                 # (lr, weights_decay, l1_vs_l2, momentum)
@@ -55,6 +69,16 @@ class LayerSpec:
     @property
     def cfg(self) -> dict:
         return dict(self.config)
+
+    def is_bias(self, leaf: int) -> bool:
+        """Whether leaf ``leaf`` of this layer's parameter tuple is a
+        bias: the second of a znicz pair.  A sequence kind's leaves are
+        all weights."""
+        return leaf == 1 and self.kind in PAIR_KINDS
+
+    def leaf_hypers(self, leaf: int) -> tuple:
+        """``(lr, weights_decay, l1_vs_l2, momentum)`` of one leaf."""
+        return self.hypers_bias if self.is_bias(leaf) else self.hypers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,14 +105,14 @@ class ModelSpec:
         # last layer a pre-activation error — only well-defined for a
         # final fc layer; the MSE head accepts any output shape
         if (self.loss == "softmax" and self.layers
-                and self.layers[-1].kind != "fc"):
+                and self.layers[-1].kind not in ("fc", "lm_head")):
             raise NotImplementedError(
-                f"the fused softmax path requires a final fc layer (got "
-                f"{self.layers[-1].kind!r}); use the unit-graph path for "
-                f"other heads")
+                f"the fused softmax path requires a final fc or lm_head "
+                f"layer (got {self.layers[-1].kind!r}); use the unit-graph "
+                f"path for other heads")
         for layer in self.layers:
             act = activations.BY_NAME[layer.activation]
-            if act.needs_input and layer.kind in PARAM_KINDS:
+            if act.needs_input and layer.kind in PAIR_KINDS:
                 # fc/conv cache only the layer *input*, not the
                 # pre-activation tensor these derivatives need; use a
                 # standalone activation layer (which is supported) or the
@@ -103,11 +127,23 @@ class ModelSpec:
         return activations.BY_NAME[self.layers[i].activation]
 
 
+def sequence_layer(unit, hypers: tuple) -> LayerSpec:
+    """The spec row of a sequence kind's forward unit (``nn/decoder.py``;
+    it need not be initialized): one ``(lr, weights_decay, l1_vs_l2,
+    momentum)`` for all its leaves."""
+    return LayerSpec(kind=unit.KIND, activation="linear",
+                     include_bias=False, hypers=hypers, hypers_bias=hypers,
+                     config=tuple(sorted(unit.fused_config().items())))
+
+
 def extract_model(workflow) -> tuple[ModelSpec, list, list]:
     """Read (spec, params, velocities) out of an initialized
-    StandardWorkflow.  params/velocities: list of (w, b) numpy pairs,
-    ``(None, None)`` for parameter-less layers."""
+    StandardWorkflow.  params/velocities: one tuple of numpy leaves a
+    layer: ``(w, b)`` for the znicz kinds (``(None, None)`` for
+    parameter-less layers), the unit's ``LEAVES`` for a sequence kind
+    (device arrays where the unit is on an XLA device)."""
     from ..nn import activation as act_units
+    from ..nn.decoder import SequenceForward
     from ..nn.all2all import All2All, All2AllSoftmax
     from ..nn.conv import Conv
     from ..nn.deconv import Deconv
@@ -142,6 +178,18 @@ def extract_model(workflow) -> tuple[ModelSpec, list, list]:
         act = "linear"
         config: dict = {}
         has_params = False
+        if isinstance(fwd, SequenceForward):
+            layers.append(sequence_layer(fwd, hypers))
+            # the Vectors' own device arrays where they have them (an
+            # XLA device), not host copies: these are the leaves that
+            # fill a chip, and a second copy of each beside the
+            # trainer's would not fit.  The trainer donates them to its
+            # first step, so the unit's Vectors are stale from then to
+            # the next write_back()
+            params.append(fwd.leaves_dev())
+            vels.append(tuple(getattr(gdu, "velocity_" + name).devmem
+                              for name in fwd.LEAVES))
+            continue
         if isinstance(fwd, All2All):
             kind = "fc"
             has_params = True
@@ -372,10 +420,40 @@ def pool_routes(spec: ModelSpec, forwards, mesh=None) -> str:
     return " ".join(f"{k}:{v}" for k, v in counts.items())
 
 
+def attn_routes(spec: ModelSpec) -> str:
+    """``window:<n> full:<m>``: the attention rows of ``spec`` over a
+    sliding window, and over everything before."""
+    counts = {"window": 0, "full": 0}
+    for layer in spec.layers:
+        if layer.kind == "attn_block":
+            counts[attn_ops.attn_route(layer.cfg)] += 1
+    return " ".join(f"{k}:{v}" for k, v in counts.items())
+
+
 # -- pure math (all traced; spec is static) --------------------------------
+def _sequence_call(spec: ModelSpec, layer: LayerSpec):
+    """``(leaves, x) -> (y, counters)`` of a sequence kind: the ``ops``
+    function with the layer's static config and the compute dtype."""
+    return functools.partial(SEQUENCE_FWD[layer.kind], cfg=layer.cfg,
+                             cdt=jnp.dtype(spec.compute_dtype))
+
+
+def _fold_counters(into: dict, new: dict) -> None:
+    """One layer's counters into the step's (``ops/moe.COUNTER_FOLDS``)."""
+    for name, value in new.items():
+        if name not in into:
+            into[name] = value
+        elif moe_ops.COUNTER_FOLDS[name] == "max":
+            into[name] = jnp.maximum(into[name], value)
+        else:
+            into[name] = into[name] + value
+
+
 def forward(spec: ModelSpec, params, x, *, want_caches: bool,
-            train: bool = False, epoch=0, ctr=0):
-    """Returns (net_output_pre_loss, caches).
+            train: bool = False, epoch=0, ctr=0, counters: dict | None = None):
+    """Returns (net_output_pre_loss, caches).  ``counters``, where given,
+    is filled with the step's device counters (the expert layers'
+    routing counts, folded over the layers).
 
     For softmax loss the last layer's output is the *logits* (loss fusion
     happens in the step).  ``caches[i]`` = (layer input, kind-specific
@@ -390,7 +468,8 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
     auxes = []       # per-layer residuals, kept even without caches so
     in_shapes = []   # decoder layers can reach their tied encoder layer
     n = len(spec.layers)
-    for i, (layer, (w, b)) in enumerate(zip(spec.layers, params)):
+    for i, (layer, leaves) in enumerate(zip(spec.layers, params)):
+        w, b = (None, None) if layer.kind in SEQUENCE_FWD else leaves
         with layer_scope("fwd", spec, i):
             x_in, aux = h, None
             if isinstance(h, tuple):     # split-out conv → pair handoff:
@@ -509,6 +588,10 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
                 # eval: inverted dropout → identity
             elif layer.kind == "activation":
                 h = spec.act(i).fwd(h, jnp)
+            elif layer.kind in SEQUENCE_FWD:
+                h, counted = _sequence_call(spec, layer)(leaves, h)
+                if counters is not None:
+                    _fold_counters(counters, counted)
             else:
                 raise NotImplementedError(layer.kind)
             if sdt != jnp.float32 and not is_last:
@@ -536,6 +619,15 @@ def _loss_and_err(spec: ModelSpec, out, target, mask):
     """(mean loss, err w.r.t. last pre-activation, n_err); ``mask`` is a
     per-row 0/1 vector zeroing the wrap-padded tail of a short final
     minibatch, so fused metrics/gradients match the unit-graph exactly."""
+    if spec.loss == "softmax" and out.ndim == 3:
+        # a target a position: ``(B, T, V)`` logits against ``(B, T)``
+        # ids are B*T rows of the same head, the row mask repeated over
+        # T; the mean is over the tokens that count, and so is ``n_err``
+        b, t, v = out.shape
+        loss, err, n_err = _loss_and_err(
+            spec, out.reshape(b * t, v), target.reshape(b * t),
+            jnp.repeat(mask, t))
+        return loss, err.reshape(b, t, v), n_err
     bs = jnp.maximum(jnp.sum(mask), 1.0)
     if spec.loss == "softmax":
         # dispatcher: fused Pallas softmax-CE kernel on TPU, XLA otherwise
@@ -568,10 +660,16 @@ def backward(spec: ModelSpec, params, caches, out, err, epoch=0, ctr=0,
     for i in reversed(range(n)):
         with layer_scope("bwd", spec, i):
             layer = spec.layers[i]
-            w, b = params[i]
             x_in, aux = caches[i]
             y_i = caches[i + 1][0] if i < n - 1 else out
             cfg = layer.cfg
+            if layer.kind in SEQUENCE_FWD:
+                # the block is run again from its cached input
+                grads[i], err = attn_ops.block_vjp(
+                    _sequence_call(spec, layer), params[i], x_in,
+                    err.reshape(y_i.shape))
+                continue
+            w, b = params[i]
             slot = _grad_slot(layer, params, i)
             if slot is not None:
                 w = slot[0]                # tied deconv: encoder weights
@@ -694,34 +792,38 @@ def apply_updates(spec: ModelSpec, params, vels, grads, lr_scale=1.0,
     # already-updated W.
     if lr_scale_bias is None:
         lr_scale_bias = lr_scale
-    n = len(spec.layers)
-    cur_w = [p[0] for p in params]
-    cur_b = [p[1] for p in params]
+    cur = [list(p) for p in params]
     new_v = [list(v) for v in vels]
-    for i in reversed(range(n)):
+    for i in reversed(range(len(spec.layers))):
         layer, grad = spec.layers[i], grads[i]
         if grad is None:
             continue
+        # a tied deconv's first leaf is the encoder conv's
         tgt = layer.cfg.get("tie", i) if layer.kind == "deconv" else i
-        w, b = cur_w[tgt], cur_b[i]
-        if w is None:
-            continue
-        gw, gb = grad
-        vw, vb = vels[i]
         with layer_scope("upd", spec, i):
-            lr, wd, l1, mom = layer.hypers
-            reg = wd * ((1.0 - l1) * w + 0.5 * l1 * jnp.sign(w))
-            vw2 = mom * vw - lr * lr_scale * (gw + reg)
-            cur_w[tgt] = w + vw2
-            new_v[i][0] = vw2
-            if b is not None:
-                lrb, wdb, l1b, momb = layer.hypers_bias
-                regb = wdb * ((1.0 - l1b) * b + 0.5 * l1b * jnp.sign(b))
-                vb2 = momb * vb - lrb * lr_scale_bias * (gb + regb)
-                cur_b[i] = b + vb2
-                new_v[i][1] = vb2
-    return ([(w, b) for w, b in zip(cur_w, cur_b)],
-            [tuple(v) for v in new_v])
+            for j, g in enumerate(grad):
+                at = tgt if j == 0 else i
+                w = cur[at][j]
+                if g is None or w is None:
+                    continue
+                lr, wd, l1, mom = layer.leaf_hypers(j)
+                scale = lr_scale_bias if layer.is_bias(j) else lr_scale
+                reg = wd * ((1.0 - l1) * w + 0.5 * l1 * jnp.sign(w))
+                v2 = mom * vels[i][j] - lr * scale * (g + reg)
+                cur[at][j] = w + v2
+                new_v[i][j] = v2
+    return [tuple(p) for p in cur], [tuple(v) for v in new_v]
+
+
+def _step_metrics(spec: ModelSpec, loss, n_err, counters: dict, mask,
+                  target) -> dict:
+    """A step's metrics: loss, n_err and, of a model with the sequence
+    kinds only, the device counters (``tokens``: the targets that
+    counted)."""
+    if spec.layers and spec.layers[-1].kind == "lm_head":
+        counters = {**counters, "tokens": (
+            jnp.sum(mask) * target.shape[1]).astype(jnp.int32)}
+    return {"loss": loss, "n_err": n_err, **counters}
 
 
 def grad_minibatch(spec: ModelSpec, params, x, target, mask=None,
@@ -730,25 +832,28 @@ def grad_minibatch(spec: ModelSpec, params, x, target, mask=None,
     update, the building block gradient accumulation composes."""
     if mask is None:
         mask = jnp.ones((x.shape[0],), jnp.float32)
+    counters: dict = {}
     out, caches = forward(spec, params, x, want_caches=True, train=True,
-                          epoch=epoch, ctr=ctr)
+                          epoch=epoch, ctr=ctr, counters=counters)
     loss, err, n_err = _loss_and_err(spec, out, target, mask)
     last = len(spec.layers) - 1
-    if spec.loss == "mse" and spec.layers[last].kind in PARAM_KINDS:
+    if spec.loss == "mse" and spec.layers[last].kind in PAIR_KINDS:
         # backward() expects pre-activation err at a param layer; other
         # last-layer kinds fold their own activation in backward()
         with jax.named_scope("loss"):
             err = spec.act(last).bwd(err, out, None, jnp)
     grads = backward(spec, params, caches, out, err, epoch=epoch,
                      ctr=ctr)
-    return grads, {"loss": loss, "n_err": n_err}
+    return grads, _step_metrics(spec, loss, n_err, counters, mask, target)
 
 
 def _grad_slot(layer: LayerSpec, params, i: int):
-    """(w, b) a layer's gradient entry is shaped like, or None for
+    """The leaves a layer's gradient entry is shaped like, or None for
     gradient-less layers — THE single definition of backward()'s
     gradient structure (tied deconv: grads live at the deconv's own
     index, shaped like the shared encoder weights)."""
+    if layer.kind in SEQUENCE_FWD:
+        return tuple(params[i])
     w, b = params[i]
     if layer.kind in PARAM_KINDS and (w is not None
                                       or layer.kind == "deconv"):
@@ -764,13 +869,9 @@ def grad_zeros(spec: ModelSpec, params):
     zs = []
     for i, layer in enumerate(spec.layers):
         slot = _grad_slot(layer, params, i)
-        if slot is None:
-            zs.append(None)
-        else:
-            w, b = slot
-            zs.append((jnp.zeros(w.shape, jnp.float32),
-                       jnp.zeros(b.shape, jnp.float32)
-                       if b is not None else None))
+        zs.append(None if slot is None else tuple(
+            None if leaf is None else jnp.zeros(leaf.shape, jnp.float32)
+            for leaf in slot))
     return zs
 
 
@@ -786,9 +887,11 @@ def train_minibatch(spec: ModelSpec, params, vels, x, target, mask=None,
 def eval_minibatch(spec: ModelSpec, params, x, target, mask=None):
     if mask is None:
         mask = jnp.ones((x.shape[0],), jnp.float32)
-    out, _ = forward(spec, params, x, want_caches=False, train=False)
+    counters: dict = {}
+    out, _ = forward(spec, params, x, want_caches=False, train=False,
+                     counters=counters)
     loss, _, n_err = _loss_and_err(spec, out, target, mask)
-    return {"loss": loss, "n_err": n_err}
+    return _step_metrics(spec, loss, n_err, counters, mask, target)
 
 
 class FusedTrainer:
@@ -830,34 +933,42 @@ class FusedTrainer:
                              f"{accum_steps!r}")
         self.accum_steps = accum_steps
         if mesh is not None:
+            held = [la.kind for la in spec.layers
+                    if la.kind in SEQUENCE_FWD]
+            if held:
+                raise NotImplementedError(
+                    f"layer kinds {sorted(set(held))} on a mesh need an "
+                    "'expert' axis in parallel/mesh.py and the all-to-all "
+                    "that exchanges tokens between the chips' experts "
+                    "(ROADMAP Reach 4); on one chip the expert layer runs "
+                    "the experts it holds without the exchange")
             self._param_shardings = []
             pidx = 0   # alternate TP axis over *parameterized* layers only
-            for (w, b) in params:
-                if w is None:
-                    self._param_shardings.append((None, None))
+            for leaves in params:
+                if leaves[0] is None:
+                    self._param_shardings.append((None,) * len(leaves))
                 else:
                     # plan_tp_sharding replicates (instead of crashing
                     # device_put) any layer whose split dim the model
                     # axis doesn't divide — the ONE policy serving's
-                    # _tp_shardings shares
+                    # _tp_shardings shares; every further leaf (a bias)
+                    # is replicated
                     sh, pidx = mesh_lib.plan_tp_sharding(
-                        mesh, pidx, w.shape)
+                        mesh, pidx, leaves[0].shape)
                     self._param_shardings.append(
-                        (sh, mesh_lib.replicated(mesh)))
+                        (sh,) + (mesh_lib.replicated(mesh),)
+                        * (len(leaves) - 1))
             for j, layer in enumerate(spec.layers):
                 # tied deconv: its velocity must shard like the shared W
                 if layer.kind == "deconv" and "tie" in layer.cfg:
                     self._param_shardings[j] = \
                         self._param_shardings[layer.cfg["tie"]]
-            put = lambda a, s: jax.device_put(a, s)      # noqa: E731
-            self.params = [
-                (put(w, sh[0]) if w is not None else None,
-                 put(b, sh[1]) if b is not None else None)
-                for (w, b), sh in zip(params, self._param_shardings)]
-            self.vels = [
-                (put(vw, sh[0]) if vw is not None else None,
-                 put(vb, sh[1]) if vb is not None else None)
-                for (vw, vb), sh in zip(vels, self._param_shardings)]
+
+            def put(tree):
+                return [tuple(None if a is None else jax.device_put(a, s)
+                              for a, s in zip(leaves, sh))
+                        for leaves, sh in zip(tree, self._param_shardings)]
+            self.params, self.vels = put(params), put(vels)
             self._batch_sharding = mesh_lib.shard_batch(mesh)
             self._repl = mesh_lib.replicated(mesh)
         else:
@@ -1120,13 +1231,11 @@ class FusedTrainer:
             return
         fwds, gds = self.workflow.forwards, self.workflow.gds
         umap = self.spec.unit_index or tuple(range(len(self.params)))
-        for ui, (w, b), (vw, vb) in zip(umap, self.params, self.vels):
+        for ui, leaves, leaf_vels in zip(umap, self.params, self.vels):
             fwd, gdu = fwds[ui], gds[ui]
-            if w is not None:
-                fwd.weights.mem = np.asarray(w)
-                if b is not None:
-                    fwd.bias.mem = np.asarray(b)
-            if vw is not None:   # tied deconv: own velocity, shared W
-                gdu.velocity_weights.mem = np.asarray(vw)
-            if vb is not None:
-                gdu.velocity_bias.mem = np.asarray(vb)
+            names = getattr(fwd, "LEAVES", ("weights", "bias"))
+            for name, leaf, vel in zip(names, leaves, leaf_vels):
+                if leaf is not None:
+                    getattr(fwd, name).mem = np.asarray(leaf)
+                if vel is not None:   # tied deconv: own velocity, shared W
+                    getattr(gdu, "velocity_" + name).mem = np.asarray(vel)

@@ -38,12 +38,18 @@ _STATE_VECTORS = ("weights", "bias", "velocity_weights", "velocity_bias",
                   "gradient_weights", "gradient_bias")
 
 
+def _state_vectors(unit) -> tuple[str, ...]:
+    """The znicz names, then the unit's own (a sequence kind's leaves
+    and their velocities: ``nn/decoder.py``)."""
+    return _STATE_VECTORS + tuple(getattr(unit, "STATE_VECTORS", ()))
+
+
 def collect_state(workflow) -> tuple[dict[str, np.ndarray], dict]:
     """(arrays keyed unit/vector, host-side counters)."""
     arrays: dict[str, np.ndarray] = {}
     seen_vectors: set[int] = set()
     for unit in workflow.units:
-        for attr in _STATE_VECTORS:
+        for attr in _state_vectors(unit):
             vec = unit.__dict__.get(attr)   # skip link_attrs aliases
             if vec is None or not vec:
                 continue
@@ -83,7 +89,7 @@ def collect_state(workflow) -> tuple[dict[str, np.ndarray], dict]:
 
 def restore_state(workflow, arrays: dict, meta: dict) -> None:
     for unit in workflow.units:
-        for attr in _STATE_VECTORS:
+        for attr in _state_vectors(unit):
             key = f"{unit.name}/{attr}"
             vec = unit.__dict__.get(attr)
             if key in arrays and vec is not None:

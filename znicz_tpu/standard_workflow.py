@@ -49,9 +49,10 @@ def _build_registries():
         from .nn import conv, gd_conv, pooling, gd_pooling  # noqa
         from .nn import normalization, dropout, activation  # noqa
         from .nn import cutter, deconv, gd_deconv, depooling  # noqa
+        from .nn import decoder  # noqa
         modules += [conv, gd_conv, pooling, gd_pooling, normalization,
                     dropout, activation, deconv, gd_deconv, depooling,
-                    cutter]
+                    cutter, decoder]
     except ImportError:
         pass
     from .nn.nn_units import Forward, GradientDescentBase
@@ -353,6 +354,7 @@ class StandardWorkflowBase(AcceleratedWorkflow):
         from .config import root
 
         from .loader.base import TEST, TRAIN, VALID
+        from .ops.moe import COUNTER_FOLDS
         from .parallel import FusedTrainer, fused
 
         assert self.initialized, "initialize() first"
@@ -411,13 +413,18 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                  "mesh": "x".join(str(d) for d in mesh_shape_of(mesh)),
                  # distinct devices the parameters are laid out over
                  "param_devices": max(
-                     len(w.sharding.device_set)
-                     for w, _ in trainer.params if w is not None),
+                     len(leaves[0].sharding.device_set)
+                     for leaves in trainer.params
+                     if leaves[0] is not None),
                  "kernel_tier": tuning.kernel_tier(),
                  # pooling rows on the one-pass windowed kernels, and on
                  # the tap stack (ops/pooling.py)
                  "pool_routes": fused.pool_routes(spec, self.forwards,
                                                   mesh)}
+        if any(la.kind == "attn_block" for la in spec.layers):
+            # attention rows over a sliding window, and over everything
+            # before (ops/attention.py)
+            where["attn_routes"] = fused.attn_routes(spec)
         self.info("fused trainer on %s",
                   " ".join(f"{k}={v!r}" for k, v in where.items()))
         # host-vs-device time split (telemetry): every call of
@@ -460,6 +467,11 @@ class StandardWorkflowBase(AcceleratedWorkflow):
         cls_idx = {k: np.arange(bounds[k], bounds[k + 1])
                    for k in (TEST, VALID, TRAIN)}
         batch = loader.max_minibatch_size
+        # the targets of one row: 1 for a label a row, T for a token
+        # sequence (n_err counts targets, so *_err_pct divides by them)
+        row_targets = (int(np.prod(target.shape[1:]))
+                       if self.loss_function == "softmax"
+                       and target is not None else 1)
         # an explicit 0 means "stop after the first evaluation", exactly
         # like the unit-graph decision — only None falls through
         epochs = max_epochs if max_epochs is not None \
@@ -577,7 +589,14 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             metrics["train_n_err"] = int(tm["n_err"].sum()
                                          + em_tail["n_err"].sum())
             metrics["train_err_pct"] = 100.0 * metrics["train_n_err"] \
-                / max(n_train, 1)
+                / max(n_train * row_targets, 1)
+            # the step's device counters (a model with the sequence
+            # kinds only), folded over the epoch's training rows
+            counted = {
+                name: int(getattr(np, COUNTER_FOLDS[name])(
+                    np.concatenate([np.ravel(tm.get(name, ())),
+                                    np.ravel(em_tail[name])])))
+                for name in em_tail if name not in ("loss", "n_err")}
             for k in (VALID, TEST):
                 if len(cls_idx[k]) == 0:
                     continue
@@ -587,9 +606,9 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                                             batch)
                 metrics[f"{name}_loss"] = float(em["loss"].mean())
                 metrics[f"{name}_n_err"] = int(em["n_err"].sum())
-                metrics[f"{name}_err_pct"] = (100.0
-                                              * metrics[f"{name}_n_err"]
-                                              / len(cls_idx[k]))
+                metrics[f"{name}_err_pct"] = (
+                    100.0 * metrics[f"{name}_n_err"]
+                    / (len(cls_idx[k]) * row_targets))
             if self.loss_function == "mse":
                 metrics["train_mse"] = metrics["train_loss"]
                 if "validation_loss" in metrics:
@@ -619,7 +638,11 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                         "host_ms": round(host_s * 1e3, 3),
                         "examples_per_sec": (round(n_train / epoch_s, 1)
                                              if epoch_s > 0 else None),
-                        **parts, "prev_tail_ms": prev_tail_ms}
+                        **parts, "prev_tail_ms": prev_tail_ms, **counted}
+            if counted:
+                gauges = _counter_gauges()
+                for name, value in counted.items():
+                    gauges[name].set(value)
             _flightrecorder.RECORDER.record(
                 "train_step", duration_ms=epoch_s * 1e3, **step_row)
             if timeline is not None:
@@ -701,6 +724,28 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             else:
                 ckpt.wait()
         return trainer
+
+
+def _counter_gauges() -> dict:
+    """The fused step's device counters as gauges, an epoch's fold each
+    (``ops/moe.COUNTER_FOLDS``); made when a model with the
+    token-sequence kinds first counts, so that no other model shows
+    them at 0."""
+    return {
+        "tokens": REGISTRY.gauge(
+            "train_tokens",
+            "targets trained in the last epoch (token-sequence models)"),
+        "moe_assignments": REGISTRY.gauge(
+            "train_moe_assignments",
+            "token-expert pairs the routers chose in the last epoch, all "
+            "expert layers"),
+        "moe_assignments_held": REGISTRY.gauge(
+            "train_moe_assignments_held",
+            "those of train_moe_assignments whose expert this chip holds"),
+        "moe_expert_load_max": REGISTRY.gauge(
+            "train_moe_expert_load_max",
+            "most pairs on one held expert in one layer of one step of "
+            "the last epoch")}
 
 
 def sample_snapshotter_config(tree, explicit):
