@@ -150,9 +150,11 @@ def test_worst_imbalance_drops_nothing(target, held_pairs, expected):
     wd = jax.random.normal(ks[3], (2, f, d)) * 0.1
     weights = jax.random.uniform(ks[4], (n, 2), minval=0.2, maxval=0.8)
     experts = jnp.stack([jnp.full((n,), target), jnp.full((n,), 7)], 1)
-    out, counts = moe.held_expert_sum(xn, weights, experts, wg, wu, wd, 2,
-                                      jnp.float32, expected)
+    out, counts, moved = moe.held_expert_sum(xn, weights, experts, wg, wu,
+                                             wd, 2, jnp.float32, expected)
     assert int(counts.sum()) == held_pairs
+    first, rest = moe.piece_rows(n * 2, expected)
+    assert int(moved) == first + (rest if held_pairs > first else 0)
     if held_pairs:
         e = target - 2
         dense = (jax.nn.silu(xn @ wg[e]) * (xn @ wu[e])) @ wd[e]
@@ -161,6 +163,163 @@ def test_worst_imbalance_drops_nothing(target, held_pairs, expected):
         assert int(counts[e]) == n
     else:
         assert not np.asarray(out).any()
+
+
+def _routed(case: str, n: int, top_k: int = 2):
+    """``(weights, experts)`` over 8 experts of which 2 and 3 are held."""
+    ks = jax.random.split(jax.random.key(17), 2)
+    weights = jax.random.uniform(ks[0], (n, top_k), minval=0.2, maxval=0.8)
+    if case == "balanced":
+        _, experts = jax.lax.top_k(jax.random.normal(ks[1], (n, 8)), top_k)
+    else:
+        target = {"none_held": 6, "all_on_one_held_expert": 3}[case]
+        experts = jnp.stack([jnp.full((n,), target), jnp.full((n,), 7)], 1)
+    return weights, experts.astype(jnp.int32)
+
+
+def _dense_expert_sum(xn, weights, experts, wg, wu, wd, first):
+    """The same sum by plain ``jnp``: every held expert over every row."""
+    out = jnp.zeros_like(xn)
+    for e in range(wg.shape[0]):
+        w = jnp.sum(jnp.where(experts == first + e, weights, 0.0), axis=1)
+        out = out + w[:, None] * (
+            (jax.nn.silu(xn @ wg[e]) * (xn @ wu[e])) @ wd[e])
+    return out
+
+
+@pytest.mark.parametrize("expected", [1.0, 0.25],
+                         ids=["one_piece", "two_pieces"])
+@pytest.mark.parametrize("case", ["balanced", "none_held",
+                                  "all_on_one_held_expert"])
+def test_the_way_there_and_back_has_the_dense_gradients(case, expected):
+    """``dispatch_rows`` and ``combine`` with their hand-written backwards
+    against ``jax.grad`` of the dense sum: the output and the gradients of
+    the input, the ROUTING WEIGHTS and the three expert weights.  With
+    every first choice on one held expert the 64 held pairs pass the first
+    piece of 48, so the later piece runs."""
+    n, d, f = 64, 64, 32
+    ks = jax.random.split(jax.random.key(19), 5)
+    xn = jax.random.normal(ks[0], (n, d))
+    wg, wu = (jax.random.normal(k, (2, d, f)) * 0.1 for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (2, f, d)) * 0.1
+    cot = jax.random.normal(ks[4], (n, d))
+    weights, experts = _routed(case, n)
+
+    def ours(xn, weights, wg, wu, wd):
+        return moe.held_expert_sum(xn, weights, experts, wg, wu, wd, 2,
+                                   jnp.float32, expected)[0]
+
+    def dense(xn, weights, wg, wu, wd):
+        return _dense_expert_sum(xn, weights, experts, wg, wu, wd, 2)
+    args = (xn, weights, wg, wu, wd)
+    np.testing.assert_allclose(ours(*args), dense(*args), rtol=1e-4,
+                               atol=1e-6)
+    got = jax.grad(lambda *a: jnp.sum(ours(*a) * cot), range(5))(*args)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), range(5))(*args)
+    for name, a, b in zip(("xn", "weights", "wg", "wu", "wd"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6, err_msg=name)
+    if case == "none_held":
+        assert not any(np.asarray(a).any() for a in got)
+    else:
+        assert np.asarray(got[1]).any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["one_gather", "a_gather_a_slab"])
+def test_pair_rows_are_summed_live_and_in_order(dtype):
+    """Both forms of ``_sum_pair_rows`` against a plain loop: dead pairs
+    (row below 0, at or beyond ``live``) add nothing, whatever their row
+    holds."""
+    ks = jax.random.split(jax.random.key(31), 3)
+    src = jax.random.normal(ks[0], (24, 16)).astype(dtype)
+    src = src.at[10:].set(jnp.nan)                       # no one's rows
+    row = jax.random.randint(ks[1], (12, 4), -8, 24)
+    scale = jax.random.uniform(ks[2], (12, 4))
+    for given in (None, scale):
+        got = moe._sum_pair_rows(src, row, jnp.asarray(10), given)
+        want = np.zeros((12, 16), np.float32)
+        for t in range(12):
+            for k in range(4):
+                if 0 <= int(row[t, k]) < 10:
+                    want[t] += np.asarray(src[row[t, k]], np.float32) * (
+                        1.0 if given is None else float(scale[t, k]))
+        assert got.dtype == dtype and got.shape == (12, 16)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), want,
+            rtol=1e-6 if dtype == jnp.float32 else 1e-2, atol=1e-6)
+
+
+def _steered_block(collapsed: bool):
+    """A ``moe_block`` holding experts 2 and 3 of 8, 2 a token, whose router
+    reads two planted features: every token on (2, 5), or the even tokens
+    on (2, 5) and the odd ones on (6, 7): exactly a quarter of the pairs
+    held."""
+    _, mcfg = _moe_case((2, 2))
+    ks = jax.random.split(jax.random.key(23), 4)
+    x = jax.random.normal(ks[0], (2, 32, 64)) * 0.1
+    x = x.at[..., 0].set(5.0).at[..., 1].set(
+        jnp.where(jnp.arange(32) % 2 == 0, 5.0, -5.0))
+    wr = np.zeros((64, 8), np.float32)
+    if collapsed:
+        wr[0, 2], wr[0, 5] = 4.0, 3.0
+    else:
+        wr[:2, 2], wr[:2, 5] = 4.0, 3.0
+        wr[:2, 6], wr[:2, 7] = (4.0, -4.0), (3.0, -3.0)
+    wg, wu = (jax.random.normal(k, (2, 64, 32)) * 0.1 for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (2, 32, 64)) * 0.1
+    return (jnp.ones((64,)), jnp.asarray(wr), wg, wu, wd), x, mcfg
+
+
+@pytest.mark.parametrize("collapsed", [False, True],
+                         ids=["balanced", "collapsed"])
+def test_rows_moved_counts_the_pieces_that_ran(monkeypatch, collapsed):
+    """Two chunks of 32 tokens: 64 pairs each, a first piece of 24 rows
+    and a later one of 40 that runs only where more than 24 are held."""
+    monkeypatch.setattr(moe, "CHUNK_TOKENS", 32)
+    leaves, x, mcfg = _steered_block(collapsed)
+    assert moe.piece_rows(64, 0.25) == (24, 40)
+    _, counters = jax.jit(
+        lambda ls, x: moe.moe_block_fwd(ls, x, mcfg))(leaves, x)
+    assert int(counters["moe_assignments"]) == 128
+    assert int(counters["moe_assignments_held"]) == (64 if collapsed else 32)
+    assert int(counters["moe_rows_moved"]) == (2 * 64 if collapsed
+                                               else 2 * 24)
+    assert moe.COUNTER_FOLDS["moe_rows_moved"] == "sum"
+
+
+def _float_arrays(jaxpr):
+    """Every float array a jaxpr makes, those of its inner jaxprs too."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            if hasattr(v.aval, "shape") and jnp.issubdtype(
+                    v.aval.dtype, jnp.floating):
+                yield v.aval.shape
+        for param in eqn.params.values():
+            for inner in (param if isinstance(param, (tuple, list))
+                          else (param,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _float_arrays(inner)
+
+
+@pytest.mark.parametrize("collapsed", [False, True],
+                         ids=["balanced", "collapsed"])
+def test_no_pair_sized_float_array_there_or_back(collapsed):
+    """The block run and differentiated as the trainer does
+    (``block_vjp``): no float array of ``(tokens * top_k, d)`` anywhere,
+    and nothing float32 of that many rows of width d but what the one
+    gather of the way back makes (``(top_k, tokens, d)``: the taken rows,
+    weighted, masked); the pieces' own are 3/8 and 5/8 of that."""
+    leaves, x, mcfg = _steered_block(collapsed)
+    n, top_k, d = 64, 2, 64
+    jaxpr = jax.make_jaxpr(lambda ls, x, err: attention.block_vjp(
+        lambda ls, x: moe.moe_block_fwd(ls, x, mcfg), ls, x, err))(
+            leaves, x, jnp.ones_like(x))
+    shapes = set(_float_arrays(jaxpr.jaxpr))
+    assert (48, d) in shapes and (80, d) in shapes      # the walk sees them
+    wide = {s for s in shapes if len(s) >= 2 and s[-1] == d
+            and int(np.prod(s[:-1])) >= n * top_k}
+    assert wide <= {(top_k, n, d)}, wide
 
 
 # -- attention -------------------------------------------------------------------
@@ -254,11 +413,55 @@ def test_megablox_products_are_ragged_dot(interpreter):
         return out, grads
     out, grads = run(moe.pallas_grouped_matmul)
     want, want_grads = run(moe.xla_grouped_matmul)
-    assert not np.asarray(out[350:]).any() \
-        and not np.asarray(grads[0][350:]).any()
-    np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
-    for a, b in zip(grads, want_grads):
-        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2)
+    # the rows beyond the groups are no one's: the kernels leave them be
+    np.testing.assert_allclose(out[:350], want[:350], rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(grads[0][:350], want_grads[0][:350],
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(grads[1], want_grads[1], rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("collapsed", [False, True],
+                         ids=["first_piece", "both_pieces"])
+def test_rows_no_kernel_wrote_reach_nothing(interpreter, monkeypatch,
+                                            collapsed):
+    """NaN planted in the rows beyond ``sum(sizes)`` of every grouped
+    product's operands and output (the kernels leave those rows as they
+    find them): no output and no gradient of the expert sum sees it."""
+    def beyond(a, sizes):
+        return jnp.where((jnp.arange(a.shape[0]) < jnp.sum(sizes))[:, None],
+                         a, jnp.nan)
+    gmm, tgmm = moe._mosaic_gmm, moe._mosaic_tgmm
+    monkeypatch.setattr(moe, "_mosaic_gmm", lambda lhs, rhs, sizes, **kw: beyond(
+        gmm(beyond(lhs, sizes), rhs, sizes, **kw), sizes))
+    monkeypatch.setattr(moe, "_mosaic_tgmm", lambda lhs, g, sizes: tgmm(
+        beyond(lhs, sizes), beyond(g, sizes), sizes))
+    n, d, f, top_k = 128, 128, 128, 4
+    ks = jax.random.split(jax.random.key(29), 6)
+    xn = jax.random.normal(ks[0], (n, d))
+    wg, wu = (jax.random.normal(k, (2, d, f)) * 0.1 for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (2, f, d)) * 0.1
+    weights = jax.random.uniform(ks[4], (n, top_k), minval=0.1, maxval=0.4)
+    if collapsed:       # 3 of every 4 pairs held: 384 > the first 256 rows
+        experts = jnp.tile(jnp.asarray([2, 3, 2, 7]), (n, 1))
+    else:               # 1 of 4 held
+        experts = jnp.tile(jnp.asarray([5, 3, 6, 7]), (n, 1))
+    assert moe.piece_rows(n * top_k, 0.25) == (256, 256)
+    assert moe.kernel_route(256, d, f)
+
+    def ours(xn, weights, wg, wu, wd):
+        out, _, moved = moe.held_expert_sum(
+            xn, weights, experts, wg, wu, wd, 2, jnp.float32, 0.25)
+        return jnp.sum(jnp.sin(out)), (out, moved)
+    (_, (out, moved)), got = jax.value_and_grad(
+        ours, range(5), has_aux=True)(xn, weights, wg, wu, wd)
+    assert int(moved) == (512 if collapsed else 256)
+    want_out = _dense_expert_sum(xn, weights, experts, wg, wu, wd, 2)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(_dense_expert_sum(
+        a[0], a[1], experts, *a[2:], 2))), range(5))(xn, weights, wg, wu, wd)
+    np.testing.assert_allclose(out, want_out, rtol=2e-2, atol=2e-3)
+    for name, a, b in zip(("xn", "weights", "wg", "wu", "wd"), got, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-3, err_msg=name)
 
 
 # -- units, workflow, launcher ----------------------------------------------------
@@ -329,6 +532,8 @@ def test_the_sample_trains_through_the_launcher(sample):
     assert row["moe_assignments"] == row["moe_assignments_held"] \
         == 32 * 32 * 2 * 4
     assert 0 < row["moe_expert_load_max"] <= 4 * 32 * 2
+    # every expert held: one piece of all the pairs, every layer
+    assert row["moe_rows_moved"] == row["moe_assignments"]
 
 
 def test_a_model_without_the_kinds_has_no_counters():
@@ -338,5 +543,5 @@ def test_a_model_without_the_kinds_has_no_counters():
              epochs=1).run()
     row = _last_train_step(flightrecorder)
     assert not {"tokens", "moe_assignments", "moe_assignments_held",
-                "moe_expert_load_max"} & set(row)
+                "moe_expert_load_max", "moe_rows_moved"} & set(row)
     assert 0.0 <= row.get("examples", 1) and "wall_ms" in row

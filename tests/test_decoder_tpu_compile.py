@@ -3,13 +3,16 @@ shapes of ``mellum2-12b-a2.5b`` (benchmark/configs): splash attention,
 sliding and full, forward and backward, at 8,192 tokens with 32 query
 heads over 4 key/value heads of 128; and the grouped expert products of
 16 held experts of 2304 x 896 over the 32,768 assignment rows of half a
-sequence (``ops/moe.CHUNK_TOKENS``), forward and backward.  The TPU's own Mosaic and XLA compilers run here, with no
+sequence (``ops/moe.CHUNK_TOKENS``), forward and backward; and the rows'
+way there and back around them (``dispatch_rows``, ``combine``) over the
+first piece of those rows.  The TPU's own Mosaic and XLA compilers run here, with no
 chip; nothing runs, so this says nothing of results or times.
 
 The topology is described inside a fixture, never at import: only the
 worker that is given this file loads the TPU's library."""
 
 import os
+import re
 
 import pytest
 
@@ -113,3 +116,49 @@ def test_grouped_products_backward_compiles_for_a_v5e(one_chip, mosaic):
                           *_expert_shapes(one_chip))
     # two products forward, and an input and a weight gradient of each
     assert text.count("tpu_custom_call") >= 5
+
+
+def _way_shapes(one_chip):
+    n, pairs = moe.CHUNK_TOKENS, moe.CHUNK_TOKENS * TOP_K
+    rows = moe.piece_rows(pairs, HELD / 64)[0]
+    assert rows == 12288 and rows % moe.TILE_M == 0
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return (s((n, D), jnp.bfloat16), s((rows, D), jnp.float32),
+            s((n, TOP_K), jnp.float32), s((rows,), jnp.int32),
+            s((n, TOP_K), jnp.int32), s((), jnp.int32))
+
+
+def _there_and_back(x, ys, weights, taken, row, live):
+    """A piece without its products: ``ys`` stands for what they make of
+    the dispatched rows."""
+    xs = moe.dispatch_rows(x, taken, row, live)
+    return moe.combine(ys + xs.astype(jnp.float32), weights, taken, row,
+                       live)
+
+
+def _pair_sized_buffers(text: str) -> int:
+    """Arrays of ``tokens * top_k`` rows of width d that the program itself
+    (not a fused computation's inside) writes."""
+    pairs = moe.CHUNK_TOKENS * TOP_K
+    return len(re.findall(
+        rf"= (?:f32|bf16)\[(?:{pairs}|{TOP_K},{moe.CHUNK_TOKENS}),{D}\]",
+        text[text.index("ENTRY"):]))
+
+
+def test_rows_there_and_back_compile_for_a_v5e(one_chip, mosaic):
+    text = _compiled_text(_there_and_back, *_way_shapes(one_chip))
+    assert f"f32[{moe.CHUNK_TOKENS},{D}]" in text
+    # the rows taken back, once: gathered, then weighted and summed in place
+    assert _pair_sized_buffers(text) <= 2, "a pass over all the pairs more"
+
+
+def test_rows_there_and_back_backward_compiles_for_a_v5e(one_chip, mosaic):
+    def loss(x, ys, weights, taken, row, live):
+        return jnp.sum(_there_and_back(x, ys, weights, taken, row, live))
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_way_shapes(one_chip))
+    # the gradients of the rows, of the products' rows and of the weights
+    assert f"bf16[{moe.CHUNK_TOKENS},{D}]" in text
+    assert _pair_sized_buffers(text) == 0

@@ -1,0 +1,106 @@
+#!/usr/bin/env python3
+"""List what a compiled program runs under some scopes, with the bytes
+each instruction reads and writes: the fusions, kernels, gathers, sorts
+and copies of ``compiled.as_text()`` (``tools/compile_decoder_step.py
+--text-dir``) whose ``op_name`` holds one of the given scope names.
+Counted from shapes: what the compiler says the instruction touches, not
+what the chip moved, and no time.
+
+    python3 tools/hlo_scope_bytes.py train_epoch.hlo.txt \
+        [--in bwd/L02.moe_block] experts combine
+
+A gather's operand is counted whole, though it reads only the rows it
+takes: its bytes in overstate; bytes out are what it writes.  An
+instruction under ``cond/branch_1_fun`` (the later piece of the sorted
+pairs) is marked ``later``; ``T`` marks a transposed (backward)
+instruction."""
+
+import collections
+import re
+import sys
+
+ITEM = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+        "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+        "u64": 8}
+SHAPE = re.compile(r"\b(" + "|".join(ITEM) + r")\[([0-9,]*)\]")
+LINE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+SKIP = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
+        "while", "conditional", "call", "iota", "after-all", "partition-id",
+        "replica-id"}
+
+
+def nbytes(text: str) -> int:
+    total = 0
+    for dtype, dims in SHAPE.findall(text):
+        n = ITEM[dtype]
+        for dim in dims.split(","):
+            n *= int(dim) if dim else 1
+        total += n
+    return total
+
+
+def instructions(text: str):
+    """``(name, result text, opcode, rest of the line)`` of every
+    instruction of a compiled text outside the fused computations."""
+    fused = False
+    for line in text.splitlines():
+        if "fused_computation" in line.split("(")[0] and line.endswith("{"):
+            fused = True
+        elif line.startswith("}"):
+            fused = False
+        m = LINE.match(line)
+        if m and not fused:
+            yield m.groups()
+
+
+def op_name(rest: str) -> str:
+    found = re.search(r'op_name="([^"]*)"', rest)
+    return found.group(1) if found else ""
+
+
+def rows(text: str, scopes, within: str = ""):
+    """``(scope path, name, opcode, result text, bytes in, bytes out)`` of
+    every instruction outside the fused computations whose ``op_name``
+    holds one of ``scopes`` (and ``within``)."""
+    shapes, out = {}, []
+    for name, result, opcode, rest in instructions(text):
+        shapes[name] = result
+        path = op_name(rest)
+        if opcode in SKIP or within not in path or not any(
+                f"/{s}/" in path or path.endswith("/" + s) for s in scopes):
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split("),")[0])
+        out.append((path, name, opcode, result, sum(
+            nbytes(shapes.get(o, "")) for o in operands), nbytes(result)))
+    return out
+
+
+def main(argv) -> int:
+    args, within = list(argv[1:]), ""
+    if "--in" in args:
+        at = args.index("--in")
+        within = args[at + 1]
+        del args[at:at + 2]
+    path, scopes = args[0], args[1:] or ["experts", "combine"]
+    with open(path) as fh:
+        found = rows(fh.read(), scopes, within)
+    total = collections.Counter()
+    for op_path, name, opcode, result, b_in, b_out in found:
+        scope = [s for s in scopes if f"/{s}" in op_path][-1]
+        side = ("T" if "transpose(" in op_path else "-") + (
+            " later" if "cond/branch_1_fun" in op_path else " first")
+        total[scope, side, "instructions"] += 1
+        total[scope, side, "in_mb"] += b_in / 1e6
+        total[scope, side, "out_mb"] += b_out / 1e6
+        if b_in + b_out < 1e6:          # index arithmetic: in the totals only
+            continue
+        print(f"{scope:8s} {side:7s} {opcode:12s} {name:28s} "
+              f"in {b_in / 1e6:9.2f} MB out {b_out / 1e6:9.2f} MB  "
+              f"{result[:60]}")
+    for key in sorted(total):
+        print("total", *key, round(total[key], 1))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv))

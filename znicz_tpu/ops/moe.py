@@ -11,14 +11,22 @@ code stands in for the absent chips.
 
 The work follows the assignments held, whatever their spread: the
 token-expert pairs are sorted by held expert (the pairs of absent
-experts behind them), gathered once, multiplied in ONE grouped product a
-projection over the rows that are held, and gathered back.  No token is
-dropped and no expert has a capacity: shapes are static, so the sorted
-pairs are taken in pieces of at least one and a half times a balanced
-router's share (a long sequence goes ``CHUNK_TOKENS`` at a time); the
-first piece holds every held pair in the usual case, a later one runs
-only when the held pairs reach into it, and the grouped product visits
-only the row tiles that hold pairs.  Two tiers, dispatched as the repo's other ops:
+experts behind them) and multiplied in ONE grouped product a projection
+over the rows that are held.  No token is dropped and no expert has a
+capacity: shapes are static, so the sorted pairs are taken in two pieces
+(a long sequence goes ``CHUNK_TOKENS`` at a time): the first, one and a
+half times a balanced router's share (12,288 of a chunk's 32,768 pairs
+where a quarter of the experts is held), holds every held pair in the
+usual case; the later one takes all the rest and runs only when the held
+pairs reach into it.  What moves, each way once: a piece's rows of the
+normalised input, gathered into expert order in the operand dtype
+(``dispatch_rows``), and the products' float32 rows, gathered back and
+summed ``top_k`` a token with their routing weights (``combine``); the
+backward of each is the other's movement, written by hand in sorted
+space.  A pair that is not live in a piece reads nothing, so the rows no
+kernel wrote are never zeroed and never looked at.  ``moe_rows_moved``
+counts the rows of the pieces that ran.  Two tiers, dispatched as the
+repo's other ops:
 
 * TPU (and the Pallas interpreter): JAX's shipped megablox kernels
   (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` for the products
@@ -29,8 +37,6 @@ only the row tiles that hold pairs.  Two tiers, dispatched as the repo's other o
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -81,18 +87,26 @@ def _mosaic_gmm(lhs, rhs, sizes, transpose_rhs=False):
                        interpret=tuning.interpret_mode())
 
 
-def _valid_rows(rows: int, sizes):
-    return (jnp.arange(rows, dtype=jnp.int32) < jnp.sum(sizes))[:, None]
+def _mosaic_tgmm(lhs, g, sizes):
+    """``lhs[rows of group e].T @ g[rows of e]`` for every group: the
+    kernel selects the rows of a boundary tile that are its group's (a
+    select, not a product), so what the rows beyond ``sum(sizes)`` hold
+    reaches no group."""
+    return _megablox().tgmm(
+        lhs.swapaxes(0, 1), g, sizes, jnp.float32,
+        (min(TILE_M, lhs.shape[0]), _tile(lhs.shape[1]), _tile(g.shape[1])),
+        interpret=tuning.interpret_mode())
 
 
 @jax.custom_vjp
 def pallas_grouped_matmul(lhs, rhs, sizes):
     """``lhs[rows of group e] @ rhs[e]`` for every group: ``lhs (M, K)``,
     ``rhs (E, K, N)``, ``sizes (E,)`` int32, rows sorted by group; float32
-    out.  The kernels never visit the rows beyond ``sum(sizes)``: they
-    read back zero here, and so do their input gradients."""
-    return jnp.where(_valid_rows(lhs.shape[0], sizes),
-                     _mosaic_gmm(lhs, rhs, sizes), 0.0)
+    out.  The kernels never visit the rows beyond ``sum(sizes)``: what
+    they hold is NOT DEFINED, here and in the input gradient, and no one
+    may read them (the expert block's gathers mask them out; the weight
+    gradient's kernel selects its groups' rows)."""
+    return _mosaic_gmm(lhs, rhs, sizes)
 
 
 def _pgm_fwd(lhs, rhs, sizes):
@@ -100,15 +114,10 @@ def _pgm_fwd(lhs, rhs, sizes):
 
 
 def _pgm_bwd(res, g):
-    backend = _megablox()
     lhs, rhs, sizes = res
-    g = g.astype(lhs.dtype)         # rows beyond the groups: never read
-    d_lhs = jnp.where(_valid_rows(lhs.shape[0], sizes),
-                      _mosaic_gmm(g, rhs, sizes, transpose_rhs=True), 0.0)
-    d_rhs = backend.tgmm(
-        lhs.swapaxes(0, 1), g, sizes, jnp.float32,
-        (min(TILE_M, lhs.shape[0]), _tile(lhs.shape[1]), _tile(g.shape[1])),
-        interpret=tuning.interpret_mode())
+    g = g.astype(lhs.dtype)
+    d_lhs = _mosaic_gmm(g, rhs, sizes, transpose_rhs=True)
+    d_rhs = _mosaic_tgmm(lhs, g, sizes)
     return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
 
 
@@ -116,8 +125,8 @@ pallas_grouped_matmul.defvjp(_pgm_fwd, _pgm_bwd)
 
 
 def xla_grouped_matmul(lhs, rhs, sizes):
-    """The same function by ``jax.lax.ragged_dot`` (rows beyond the
-    groups are zero there too)."""
+    """The same function by ``jax.lax.ragged_dot`` (which writes zero to
+    the rows beyond the groups)."""
     return jax.lax.ragged_dot(lhs, rhs, sizes,
                               preferred_element_type=jnp.float32)
 
@@ -140,51 +149,88 @@ def grouped_matmul(lhs, rhs, sizes):
 # -- rows there and back: gathers in both directions ----------------------------
 # ``order`` sorts the ``tokens * top_k`` pairs by held expert (the pairs of
 # absent experts last); a piece takes ``rows`` of the sorted pairs
-# (``taken``), and ``inverse`` gives every pair its row in the piece, or
-# ``rows`` where the pair is not in it.
-def _from_sorted(a, inverse):
-    """Row ``inverse[p]`` of ``a (rows, d)`` for every pair ``p``; zero for
-    a pair that was not taken (``inverse[p] >= rows``)."""
-    padded = jnp.concatenate([a, jnp.zeros((1, a.shape[1]), a.dtype)])
-    return jnp.take(padded, jnp.minimum(inverse, a.shape[0]), axis=0)
+# (``taken``), of which the first ``live`` are on held experts, and ``row
+# (tokens, top_k)`` gives every pair its row in the piece.  A pair whose row
+# is not in ``0 .. live`` (it is in another piece, or its expert is absent)
+# reads nothing: its index is clipped and its term masked, so the rows from
+# ``live`` on, which no kernel writes, are never looked at.
+def _live(row, live):
+    return (row >= 0) & (row < live)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dispatch_rows(x, taken, inverse, top_k):
+def _sum_pair_rows(src, row, live, scale=None):
+    """``sum_k src[row[t, k]] (* scale[t, k])`` over the pairs of token
+    ``t`` whose row is live, in the order of ``k``: ``(tokens, d)``,
+    accumulated in float32 and rounded once to ``src``'s dtype.  The taken
+    rows land ``k`` outermost, as rows of ``(tokens, d)`` slabs (``k``
+    innermost would put a token's ``top_k`` rows into the sublanes of one
+    tile).  Float32 rows are taken by ONE gather; packed 16-bit rows a slab
+    at a time, because one gather of them into ``(top_k, tokens, d)`` runs
+    1.1 ms slower a chunk on a v5e (PERF.md section 6, PR 31)."""
+    ok = _live(row, live).T[..., None]
+    at = row.T
+    scale = None if scale is None else scale.T[..., None]
+
+    def slabs(at, ok, scale):
+        back = jnp.take(src, at, axis=0, mode="clip").astype(jnp.float32)
+        return jnp.where(ok, back if scale is None else back * scale, 0.0)
+    if src.dtype.itemsize >= 4:
+        out = jnp.sum(slabs(at, ok, scale), axis=0)
+    else:
+        out = sum(slabs(at[k], ok[k], None if scale is None else scale[k])
+                  for k in range(at.shape[0]))
+    return out.astype(src.dtype)
+
+
+@jax.custom_vjp
+def dispatch_rows(x, taken, row, live):
     """``x[taken // top_k]``: row ``i`` is the token of the ``i``-th sorted
-    pair (``taken = order[:rows]``).  The gradient is a gather and a sum
-    over ``top_k`` too, not a scatter."""
-    return jnp.take(x, taken // top_k, axis=0)
+    pair (``taken = order[lo:lo + rows]``).  The gradient is a gather and
+    a sum over ``top_k`` too, not a scatter."""
+    return jnp.take(x, taken // row.shape[1], axis=0, mode="clip")
 
 
-def _dispatch_fwd(x, taken, inverse, top_k):
-    return jnp.take(x, taken // top_k, axis=0), inverse
+def _dispatch_fwd(x, taken, row, live):
+    return dispatch_rows(x, taken, row, live), (row, live)
 
 
-def _dispatch_bwd(top_k, inverse, g):
-    back = _from_sorted(g, inverse)
-    return back.reshape(-1, top_k, g.shape[-1]).sum(axis=1), None, None
+def _dispatch_bwd(res, g):
+    return _sum_pair_rows(g, *res), None, None, None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def collect_rows(ys, taken, inverse):
-    """Every pair's row of ``ys (rows, d)``, zero for a pair not taken:
-    ``(tokens * top_k, d)``.  The gradient is ``g[taken]``."""
-    return _from_sorted(ys, inverse)
+def combine(ys, weights, taken, row, live):
+    """The way back: ``sum_k weights[t, k] * ys[row[t, k]]`` over the live
+    pairs of token ``t``: ``ys (rows, d)`` float32 -> ``(tokens, d)``.
+    The backward is written in sorted space: the gradient of ``ys`` is a
+    gather of ``rows`` rows of the ``(tokens, d)`` gradient times the
+    row's weight, and the weights' gradient the row-wise product of the
+    same gathered rows with ``ys``, carried to its pair by a gather of
+    scalars."""
+    return _sum_pair_rows(ys, row, live, weights)
 
 
-def _collect_fwd(ys, taken, inverse):
-    return _from_sorted(ys, inverse), taken
+def _combine_fwd(ys, weights, taken, row, live):
+    return combine(ys, weights, taken, row, live), (ys, weights, taken, row,
+                                                     live)
 
 
-def _collect_bwd(taken, g):
-    return jnp.take(g, taken, axis=0), None, None
+def _combine_bwd(res, d_out):
+    ys, weights, taken, row, live = res
+    valid = jnp.arange(ys.shape[0], dtype=jnp.int32) < live
+    g = jnp.take(d_out, taken // row.shape[1], axis=0, mode="clip")
+    w_row = jnp.take(weights.reshape(-1), taken, mode="clip")
+    d_ys = jnp.where(valid[:, None], g * w_row[:, None], 0.0)
+    d_w_row = jnp.where(valid, jnp.sum(ys * g, axis=1), 0.0)
+    d_weights = jnp.where(_live(row, live),
+                          jnp.take(d_w_row, row, mode="clip"), 0.0)
+    return d_ys, d_weights.astype(weights.dtype), None, None, None
 
 
-collect_rows.defvjp(_collect_fwd, _collect_bwd)
+combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # -- the layer -----------------------------------------------------------------
@@ -202,61 +248,82 @@ def route(xn, wr, top_k: int, norm_topk_prob: bool):
     return top_p, top_e
 
 
+def piece_rows(pairs: int, expected: float) -> tuple:
+    """``(first, rest)``: the lengths in which ``pairs`` sorted pairs are
+    taken when a balanced router sends the share ``expected`` of them
+    here.  The first piece is one and a half times that share, rounded up
+    to whole row tiles; the rest is ONE later piece (0: the first holds
+    every pair)."""
+    unit = TILE_M if pairs % TILE_M == 0 else 8
+    first = max(1, -(-int(1.5 * expected * pairs) // unit)) * unit
+    if pairs % 8 or first >= pairs:
+        return pairs, 0
+    return first, pairs - first
+
+
 def held_expert_sum(xn, weights, experts, wg, wu, wd, first: int, cdt,
                     expected: float = 1.0):
     """``sum_k [e_k held] w_k * (silu(x Wg_e) * x Wu_e) Wd_e`` over the
     rows of ``xn (N, d)``; ``wg``/``wu (E_held, d, f)``, ``wd (E_held, f,
     d)`` are experts ``first .. first + E_held``.  -> ``(sum (N, d)
-    float32, counts (E_held,) int32)``: the pairs on each held expert.
+    float32, counts (E_held,) int32, rows moved int32)``: the pairs on each
+    held expert, and the rows of the pieces that ran.
 
     ``expected``: the share of all pairs a balanced router sends here
-    (experts held over experts).  The ``N * top_k`` sorted pairs are taken
-    in pieces of at least one and a half times that share: the first
-    piece holds every held pair in the usual case, and a later piece is
-    skipped (``lax.cond``) unless the held pairs reach into it, so none
-    is dropped whatever the spread.  Each piece is rematerialised by
-    itself in the backward pass."""
+    (experts held over experts).  The ``N * top_k`` pairs are sorted by held
+    expert and taken in two pieces (``piece_rows``): the first, one
+    and a half times that share (12,288 of a chunk's 32,768 for a quarter),
+    holds every held pair in the usual case; the later one takes all the
+    rest and is skipped (``lax.cond``) unless the held pairs reach into it,
+    so none is dropped whatever the spread.  What moves, a piece: its rows
+    of ``xn`` gathered in the operand dtype; the products' float32 rows
+    gathered back and summed ``top_k`` a token with their weights, a pair
+    that is not live in the piece reading nothing; backward the same two
+    movements mirrored (``combine``, ``dispatch_rows``).  No row is zeroed:
+    nothing reads the rows no kernel wrote.  Each piece is rematerialised
+    by itself in the backward pass."""
     n, top_k = experts.shape
+    pairs = n * top_k
     e_held = wg.shape[0]
     local = experts - first
     held = (local >= 0) & (local < e_held)
     key = jnp.where(held, local, e_held).reshape(-1)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
+    # every pair's place among the sorted: a second sort inverts the first
+    # (19 us on a v5e against 150 us for a scatter of ``arange``: PERF.md)
+    place = jnp.argsort(order).astype(jnp.int32).reshape(n, top_k)
     counts = jnp.sum(key[:, None] == jnp.arange(e_held, dtype=key.dtype),
                      axis=0, dtype=jnp.int32)
     ends = jnp.cumsum(counts)
     xc, wgc, wuc, wdc = (a.astype(cdt) for a in (xn, wg, wu, wd))
-    pieces = max(1, int(1.0 / (1.5 * expected)))
-    while (n * top_k) % pieces or (n * top_k // pieces) % 8:
-        pieces -= 1
-    rows = n * top_k // pieces
+    first_rows, rest_rows = piece_rows(pairs, expected)
 
-    @jax.checkpoint
-    def piece(lo, xc, weights, wgc, wuc, wdc):
+    def piece(lo: int, rows: int):
         """The sorted pairs ``lo .. lo + rows``."""
-        taken = jax.lax.dynamic_slice_in_dim(order, lo, rows)
-        sizes = (jnp.clip(ends, lo, lo + rows)
-                 - jnp.clip(ends - counts, lo, lo + rows))
-        # a pair's row in this piece (``rows``: it is in another)
-        local_row = jnp.where((inverse >= lo) & (inverse < lo + rows),
-                              inverse - lo, rows)
-        with jax.named_scope("experts"):
-            xs = dispatch_rows(xc, taken, local_row, top_k)
-            hidden = (jax.nn.silu(grouped_matmul(xs, wgc, sizes))
-                      * grouped_matmul(xs, wuc, sizes)).astype(cdt)
-            ys = grouped_matmul(hidden, wdc, sizes)
-        with jax.named_scope("combine"):
-            back = collect_rows(ys, taken, local_row)
-            return jnp.sum(back.reshape(n, top_k, -1) * weights[..., None],
-                           axis=1)
-    out = piece(0, xc, weights, wgc, wuc, wdc)
-    for i in range(1, pieces):
-        out = out + jax.lax.cond(
-            ends[-1] > i * rows, piece,
-            lambda lo, xc, *_: jnp.zeros((n, xc.shape[1]), jnp.float32),
-            i * rows, xc, weights, wgc, wuc, wdc)
-    return out, counts
+        @jax.checkpoint
+        def run(xc, weights, wgc, wuc, wdc):
+            taken = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+            sizes = (jnp.clip(ends, lo, lo + rows)
+                     - jnp.clip(ends - counts, lo, lo + rows))
+            row, live = place - lo, jnp.sum(sizes)
+            with jax.named_scope("experts"):
+                xs = dispatch_rows(xc, taken, row, live)
+                hidden = (jax.nn.silu(grouped_matmul(xs, wgc, sizes))
+                          * grouped_matmul(xs, wuc, sizes)).astype(cdt)
+                ys = grouped_matmul(hidden, wdc, sizes)
+            with jax.named_scope("combine"):
+                return combine(ys, weights, taken, row, live)
+        return run
+    out = piece(0, first_rows)(xc, weights, wgc, wuc, wdc)
+    moved = jnp.asarray(first_rows, jnp.int32)
+    if rest_rows:
+        later = ends[-1] > first_rows
+        out = jax.lax.cond(
+            later,
+            lambda out, *args: out + piece(first_rows, rest_rows)(*args),
+            lambda out, *args: out, out, xc, weights, wgc, wuc, wdc)
+        moved = moved + jnp.where(later, rest_rows, 0).astype(jnp.int32)
+    return out, counts, moved
 
 
 def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
@@ -265,8 +332,11 @@ def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     ``wd (E_held, f, d)``; ``cfg``: ``experts``, ``experts_held`` as
     ``(first, count)``, ``top_k``, ``norm_topk_prob``, ``eps``.
     -> ``(y, counters)``; the counters are this layer's, this call's:
-    ``moe_assignments``, ``moe_assignments_held``, ``moe_expert_load_max``
-    (rows that pad a short last minibatch are routed and counted too)."""
+    ``moe_assignments``, ``moe_assignments_held``, ``moe_expert_load_max``,
+    ``moe_rows_moved`` (rows of the sorted pieces that ran, all chunks: over
+    ``moe_assignments_held`` it says how far the movement is from the pairs
+    held, and whether a later piece ran; rows that pad a short last
+    minibatch are routed and counted too)."""
     g2, wr, wg, wu, wd = leaves
     b, t, d = x.shape
     first, count = cfg["experts_held"]
@@ -282,21 +352,24 @@ def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     if n > chunk and n % chunk == 0:
         def some(args):
             return held_expert_sum(*args, wg, wu, wd, first, cdt, expected)
-        out, counts = jax.lax.map(jax.checkpoint(some), tuple(
+        out, counts, moved = jax.lax.map(jax.checkpoint(some), tuple(
             a.reshape(n // chunk, chunk, a.shape[-1])
             for a in (xn, weights, experts)))
-        out, counts = out.reshape(n, d), jnp.sum(counts, axis=0)
+        out, counts, moved = (out.reshape(n, d), jnp.sum(counts, axis=0),
+                              jnp.sum(moved))
     else:
-        out, counts = held_expert_sum(xn, weights, experts, wg, wu, wd,
-                                      first, cdt, expected)
+        out, counts, moved = held_expert_sum(xn, weights, experts, wg, wu,
+                                             wd, first, cdt, expected)
     counters = {
         "moe_assignments": jnp.asarray(b * t * cfg["top_k"], jnp.int32),
         "moe_assignments_held": jnp.sum(counts),
-        "moe_expert_load_max": jnp.max(counts)}
+        "moe_expert_load_max": jnp.max(counts),
+        "moe_rows_moved": moved}
     return x + out.reshape(b, t, d), counters
 
 
 #: how a layer's counters fold into a step's, and a step's into an
 #: epoch's
 COUNTER_FOLDS = {"moe_assignments": "sum", "moe_assignments_held": "sum",
-                 "moe_expert_load_max": "max", "tokens": "sum"}
+                 "moe_expert_load_max": "max", "moe_rows_moved": "sum",
+                 "tokens": "sum"}
