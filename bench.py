@@ -12,14 +12,13 @@ per unit per minibatch, Python between ops; SURVEY.md §3.1 hot-loop
 note), which is the only reference-equivalent baseline measurable here
 (the reference's own CUDA numbers are unrecoverable).
 
-Device contract: the training, ``--ablate`` and ``--kernels`` modes
-measure the TPU.  Every row names the device as JAX reports it
+Device contract: the training and ``--kernels`` modes measure the
+TPU.  Every row names the device as JAX reports it
 (``platform`` / ``device_kind`` / ``device_count``); where JAX finds no
 TPU the row carries an ``error`` and no value.  A row with an ``error``
 field — no chip, a kernel that does not compile, a failed measurement,
 a failed ``--kernels`` case — still prints where it can, and the
-process exits non-zero.  There is no CPU fallback and no in-process
-rerouting of kernel levers.
+process exits non-zero.  There is no CPU fallback.
 
 Extra modes (not used by the driver):
 
@@ -947,20 +946,14 @@ def _git_rev() -> str | None:
 
 
 def _record_run_config(args, result, mesh_applies: bool = False) -> None:
-    """Stamp the transcript row with what ran: the active routing
-    levers, the code revision, the sharding and the minibatch."""
+    """Stamp the transcript row with what ran: the ``ZNICZ_TPU_*``
+    environment, the code revision, the sharding and the minibatch."""
     levers = {k: v for k, v in sorted(os.environ.items())
               if k.startswith("ZNICZ_TPU_")}
     if levers:
         result["levers"] = levers
     else:
         result.pop("levers", None)
-    # the EFFECTIVE routing (env + defaults resolved): decide_levers.py
-    # compares configurations by this field, so transcript rows keep
-    # their meaning across default flips (round 5 flipped fused2 on,
-    # which silently re-aimed every pre-flip "no levers" row)
-    from znicz_tpu.ops import tuning
-    result["resolved"] = tuning.resolved_routing()
     rev = _git_rev()
     if rev:
         result["rev"] = rev
@@ -975,7 +968,7 @@ def _record_run_config(args, result, mesh_applies: bool = False) -> None:
     # row measure different programs, so decide_levers must only pair
     # like-for-like (its headline key includes this field).  Only the
     # training path actually lays work over the mesh (mesh_applies);
-    # the kernel/ablate/loader modes measure single-device regardless
+    # the kernel/loader modes measure single-device regardless
     # of the flag and must say so
     if mesh_applies and getattr(args, "mesh", None):
         from znicz_tpu.parallel.mesh import parse_mesh_arg
@@ -1089,9 +1082,7 @@ def bench_training(args) -> int:
 def _kernel_cases():
     """[(name, pallas_thunk, xla_thunk, compare)] on bench-scale shapes."""
     import jax.numpy as jnp
-    from znicz_tpu.ops import (activations, conv as conv_ops,
-                               deconv as deconv_ops,
-                               dropout as drop_ops,
+    from znicz_tpu.ops import (activations, dropout as drop_ops,
                                elementwise, kohonen as som_ops,
                                lrn_pool as lrn_pool_ops, matmul,
                                normalization as lrn_ops,
@@ -1103,7 +1094,6 @@ def _kernel_cases():
         return jnp.asarray(rng.standard_normal(s), jnp.float32)
 
     a, b = f32(512, 1024), f32(1024, 768)
-    a2 = f32(512, 768)                       # matmul_at_b rhs
     logits = f32(1024, 1000)
     labels = jnp.asarray(rng.integers(0, 1000, size=1024), jnp.int32)
     x4 = f32(32, 28, 28, 64)
@@ -1118,9 +1108,6 @@ def _kernel_cases():
     perr = f32(32 * 14 * 14, 64)
     poff = jnp.asarray(rng.integers(0, 9, size=(32 * 14 * 14, 64)),
                        jnp.int32)
-    ximg, cerr = f32(16, 28, 28, 64), f32(16, 28, 28, 64)
-    cw = f32(3, 3, 64, 64)
-    xdec, wdec = f32(16, 14, 14, 32), f32(4, 4, 16, 32)
     hypers = jnp.asarray([0.01, 1e-4, 0.0, 0.9], jnp.float32)
     _, d_lrn = lrn_ops.xla_lrn(x4)
     xlp = f32(32, 55, 55, 96)               # AlexNet L1 LRN+pool geometry
@@ -1131,13 +1118,6 @@ def _kernel_cases():
     cases = [
         ("matmul", lambda: matmul.pallas_matmul(a, b),
          lambda: matmul.xla_matmul(a, b), "close"),
-        # round-3 transposed-lhs weight-grad kernel: aᵀ@b without
-        # materializing aᵀ in HBM (conv grad_w contracts through it)
-        ("matmul_at_b", lambda: matmul.pallas_matmul_at_b(a, a2),
-         lambda: matmul.xla_matmul(a.T, a2), "close"),
-        ("conv",
-         lambda: conv_ops.pallas_conv2d(ximg, cw, 1, 1),
-         lambda: conv_ops.xla_conv2d(ximg, cw, 1, 1), "close"),
         ("softmax", lambda: softmax.pallas_softmax(logits),
          lambda: softmax.xla_softmax(logits), "close"),
         ("softmax_ce",
@@ -1172,19 +1152,6 @@ def _kernel_cases():
         ("pool_gather",
          lambda: elementwise.pallas_pool_gather(taps, poff),
          lambda: sum(taps[t] * (poff == t) for t in range(9)), "close"),
-        ("conv_grad_w",
-         lambda: conv_ops.pallas_conv2d_grad_weights(
-             ximg, cerr, (3, 3, 64, 64), 1, 1),
-         lambda: conv_ops.xla_conv2d_grad_weights(
-             ximg, cerr, (3, 3, 64, 64), 1, 1), "close"),
-        ("conv_grad_x",
-         lambda: conv_ops.pallas_conv2d_grad_input(
-             cerr, cw, ximg.shape, 1, 1),
-         lambda: conv_ops.xla_conv2d_grad_input(
-             cerr, cw, ximg.shape, 1, 1), "close"),
-        ("deconv",
-         lambda: deconv_ops.pallas_deconv2d(xdec, wdec, 2, 1),
-         lambda: deconv_ops.xla_deconv2d(xdec, wdec, 2, 1), "close"),
         ("kohonen_argmin",
          lambda: som_ops.pallas_distance_argmin(xsom, wsom)[0],
          lambda: som_ops.xla_forward(xsom, wsom)[0], "exact"),
@@ -1213,148 +1180,6 @@ def _kernel_cases():
             lambda act=act: activations.BY_NAME[act].fwd(xact, jnp),
             "close"))
     return cases
-
-
-def bench_ablate(args) -> int:
-    """Layer-kind ablation of the fused step (--ablate): times the
-    config's full net against variants with whole layer kinds removed,
-    plus the bf16-storage variant — the reproducible source of the
-    'where the time goes' table in docs/performance.md."""
-    import dataclasses
-
-    result = {"metric": f"{args.config}_ablation", "value": None,
-              "unit": "ms_per_step", "vs_baseline": None}
-    if args.config == "kohonen":
-        result["error"] = ("ablation needs a layer-chain config; the "
-                           "SOM has a dedicated epoch scan with no "
-                           "removable layer kinds")
-        return _emit(result)
-    if not _require_tpu(result):
-        return _emit(result)
-    # the table owns the routing levers END TO END: an ambient
-    # ZNICZ_TPU_LRN_POOL=fused2 or CONV1=s2d would otherwise leak into
-    # base_spec extraction and the baseline rows, flattening every A/B
-    # delta
-    saved_env = {v: os.environ.pop(v, None)
-                 for v in ("ZNICZ_TPU_LRN_POOL", "ZNICZ_TPU_CONV1")}
-    _record_run_config(args, result)
-    try:
-        from znicz_tpu.parallel import fused, FusedTrainer
-
-        wf = _build(args.config, args.minibatch, args.n_train)
-        base_spec, params, vels = fused.extract_model(wf)
-        ld = wf.loader
-        data = ld.original_data.devmem
-        target = (ld.original_targets.devmem
-                  if getattr(wf, "loss_function", "softmax") == "mse"
-                  else ld.original_labels.devmem)
-        n = ld.class_lengths[2]
-        idx = np.arange(ld.total_samples - n, ld.total_samples)
-        batch = ld.max_minibatch_size
-        import jax
-
-        def time_spec(spec, keep=None, ps=None, vs=None):
-            ps = params if ps is None else ps
-            vs = vels if vs is None else vs
-            if keep is not None:
-                keep_idx = [i for i, la in enumerate(spec.layers)
-                            if keep(la)]
-                remap = {old: new for new, old in enumerate(keep_idx)}
-                kept_layers = []
-                for old in keep_idx:
-                    la = spec.layers[old]
-                    cfg = la.cfg
-                    if "tie" in cfg:
-                        # deconv/depool cross-references are layer
-                        # INDICES — remap them past the removed layers
-                        if cfg["tie"] not in remap:
-                            raise RuntimeError(
-                                f"variant removes layer {cfg['tie']} "
-                                f"that layer {old} ties to")
-                        cfg["tie"] = remap[cfg["tie"]]
-                        la = dataclasses.replace(
-                            la, config=tuple(sorted(cfg.items())))
-                    kept_layers.append(la)
-                spec = dataclasses.replace(spec,
-                                           layers=tuple(kept_layers))
-                ps = [ps[i] for i in keep_idx]
-                vs = [vs[i] for i in keep_idx]
-            cp = jax.tree_util.tree_map(np.array, (ps, vs))
-            tr = FusedTrainer(spec=spec, params=cp[0], vels=cp[1])
-            for _ in range(getattr(args, "warm", 2)):
-                tr.train_epoch(data, target, idx, batch, sync=True)
-            t0 = time.perf_counter()
-            last = None
-            for _ in range(args.epochs):
-                last = tr.train_epoch(data, target, idx, batch,
-                                      sync=False)
-            np.asarray(last["loss"])
-            dt = time.perf_counter() - t0
-            return dt / max(1, args.epochs * (n // batch)) * 1e3
-
-        # the same model with the LRN+pool merge disabled (split layers)
-        # — the A/B for the fused-pair kernel (ops/lrn_pool.py); its own
-        # params/vels: the split spec has more layer rows.  The ambient
-        # default is fused2 since round 5, so "full" IS the fused2 row
-        # and the A/B variant is the phase-1 downgrade.
-        os.environ["ZNICZ_TPU_LRN_POOL"] = "split"
-        try:
-            split_spec, split_params, split_vels = fused.extract_model(wf)
-            os.environ["ZNICZ_TPU_LRN_POOL"] = "nofold"
-            nofold_spec = fused.extract_model(wf)[0]
-            os.environ["ZNICZ_TPU_LRN_POOL"] = "fused1"
-            fused1_spec = fused.extract_model(wf)[0]
-        finally:
-            os.environ.pop("ZNICZ_TPU_LRN_POOL", None)
-
-        # only shape-preserving kinds can be ablated (pooling changes
-        # every downstream activation shape, so it has no variant);
-        # no_lrn strips LRN from the SPLIT spec, where it is standalone
-        variants = [
-            ("full", None, base_spec, None, None, None),
-            ("lrn_pool_fused1", None, fused1_spec, None, None, None),
-            ("lrn_pool_nofold", None, nofold_spec, None, None, None),
-            ("lrn_pool_split", None, split_spec, split_params,
-             split_vels, None),
-            ("no_lrn", lambda la: la.kind != "lrn", split_spec,
-             split_params, split_vels, None),
-            ("no_dropout", lambda la: la.kind != "dropout", base_spec,
-             None, None, None),
-            ("storage_bf16", None,
-             dataclasses.replace(base_spec, storage_dtype="bfloat16"),
-             None, None, None),
-            # conv1 space-to-depth (round 4): same spec, env-routed in
-            # conv2d at trace time — each row's fresh FusedTrainer
-            # re-traces, so the env is honored per row
-            ("conv1_s2d", None, base_spec, None, None,
-             ("ZNICZ_TPU_CONV1", "s2d")),
-        ]
-        rows = {}
-        for name, keep, spec, ps, vs, env in variants:
-            if env is not None:
-                os.environ[env[0]] = env[1]
-            try:
-                rows[name] = round(time_spec(spec, keep, ps, vs), 2)
-            except Exception as e:   # the other variants still report
-                rows[name] = f"error: {e}"[:120]
-            finally:
-                if env is not None:
-                    os.environ.pop(env[0], None)
-            print(f"  {name:14s} {rows[name]} ms/step",
-                  file=sys.stderr)
-        result["value"] = rows.get("full")
-        result["rows"] = rows
-        failed = [name for name, row in rows.items()
-                  if isinstance(row, str)]
-        if failed:
-            result["error"] = "variant(s) failed: " + ", ".join(failed)
-    except Exception as e:
-        result["error"] = f"ablate failed: {e!r}"[:600]
-    finally:
-        for var, val in saved_env.items():
-            if val is not None:
-                os.environ[var] = val
-    return _emit(result)
 
 
 def _time_thunk(thunk, iters=20):
@@ -1448,9 +1273,6 @@ def main(argv=None) -> int:
                    help="disk→batch loader throughput, no device in "
                         "the loop (combine with --augment for the "
                         "decode→crop variant)")
-    p.add_argument("--ablate", action="store_true",
-                   help="time the fused step with layer kinds removed"
-                        " (the 'where the time goes' table)")
     p.add_argument("--stream", action="store_true",
                    help="also measure the disk-backed streaming path")
     p.add_argument("--augment", action="store_true",
@@ -1541,8 +1363,6 @@ def main(argv=None) -> int:
             return bench_kernels(args)
         if args.loader:
             return bench_loader(args)
-        if args.ablate:
-            return bench_ablate(args)
         return bench_training(args)
     except Exception as e:              # the row still prints; rc != 0
         return _emit({"metric": "bench_error", "value": None,

@@ -15,7 +15,8 @@ import pytest
 
 from znicz_tpu.analysis import (Analyzer, ConditionWaitPredicateRule,
                                 DeadlineDisciplineRule,
-                                DurationClockRule, HandlerSafetyRule,
+                                DurationClockRule, EnvRoutingRule,
+                                HandlerSafetyRule,
                                 JaxHygieneRule, LockDisciplineRule,
                                 LockLeakRule, LockOrderCycleRule,
                                 MetricDriftRule, RetryAfterRule,
@@ -1702,6 +1703,61 @@ class TestCli:
             zlint_cli.main(["znicz_tpu/dirty.py", "--changed",
                             "--root", str(tmp_path)])
         assert exc.value.code == 2
+
+
+# -- env-routing -------------------------------------------------------------
+
+ENV_ROUTING_BAD = """
+    import os
+    from os import environ, getenv
+
+    def pool_kernel():
+        if os.environ.get("ZNICZ_TPU_POOL", "taps") == "window":
+            return "window"
+        if "ZNICZ_TPU_TIER" in os.environ or getenv("ZNICZ_TPU_ROUTE"):
+            return environ["ZNICZ_TPU_TIER"]
+        return dict(os.environ)
+"""
+
+ENV_ROUTING_ALLOWED = """
+    import os
+
+    _INTERPRET = os.environ.get("ZNICZ_TPU_PALLAS_INTERPRET", "0") == "1"
+
+    def use_pallas():
+        if os.getenv("ZNICZ_TPU_NO_PALLAS", "0") == "1":
+            return False
+        return os.environ["ZNICZ_TPU_MXU"] != "f32" \
+            or "ZNICZ_TPU_MXU" in os.environ
+"""
+
+
+class TestEnvRouting:
+    def test_environment_read_under_ops_fires(self, tmp_path):
+        found = lint(tmp_path, ENV_ROUTING_BAD, [EnvRoutingRule()],
+                     rel="znicz_tpu/ops/mod.py")
+        assert rules_of(found) == ["env-routing"]
+        # get / in / getenv / subscript / the whole mapping
+        assert len(found) == 5
+        assert "'ZNICZ_TPU_POOL'" in found[0].message
+
+    def test_the_switches_that_stay_are_silent(self, tmp_path):
+        for rel in ("znicz_tpu/ops/tuning.py", "znicz_tpu/parallel/m.py"):
+            assert lint(tmp_path, ENV_ROUTING_ALLOWED, [EnvRoutingRule()],
+                        rel=rel) == []
+
+    def test_other_packages_are_not_patrolled(self, tmp_path):
+        for rel in ("znicz_tpu/serving/mod.py", "znicz_tpu/launcher.py",
+                    "pkg/ops/mod.py"):
+            assert lint(tmp_path, ENV_ROUTING_BAD, [EnvRoutingRule()],
+                        rel=rel) == []
+
+    def test_the_package_reads_only_the_switches_that_stay(self):
+        """ops/ and parallel/ as they are: no finding, with one inline
+        suppression (the coordinator's address in distributed.py)."""
+        from znicz_tpu.analysis.core import default_root
+        found = Analyzer([EnvRoutingRule()], root=default_root()).run()
+        assert found == [], "\n".join(f.render() for f in found)
 
 
 # -- the tier-1 gate -------------------------------------------------------

@@ -1,6 +1,5 @@
-"""bench.py's rev stamp, the resolved-routing stamp that keeps
-transcript rows meaningful across default flips, and its exit-code
-contract: a row with an ``error`` exits non-zero."""
+"""bench.py's rev stamp and its exit-code contract: a row with an
+``error`` exits non-zero."""
 
 import importlib.util
 import os
@@ -56,33 +55,3 @@ class TestRevStamp:
             raise OSError("no git")
         monkeypatch.setattr(subprocess, "run", boom)
         assert bench._git_rev() is None
-
-
-class TestResolvedRouting:
-    def test_default_is_fused2_since_round5(self, monkeypatch):
-        from znicz_tpu.ops import tuning
-        monkeypatch.delenv("ZNICZ_TPU_LRN_POOL", raising=False)
-        monkeypatch.delenv("ZNICZ_TPU_CONV1", raising=False)
-        res = tuning.resolved_routing()
-        assert res["LRN_POOL"] == "fused2"
-        assert res["CONV1"] == "direct"
-
-    @pytest.mark.parametrize("env,want", [
-        # explicit "fused" keeps its historical phase-1 meaning —
-        # recorded round-4 lever lines must reproduce their rows
-        ("fused1", "fused1"), ("fused2", "fused2"), ("fused", "fused1"),
-        ("split", "split"), ("nofold", "nofold")])
-    def test_lrn_pool_env_values(self, monkeypatch, env, want):
-        from znicz_tpu.ops import tuning
-        monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", env)
-        assert tuning.resolved_routing()["LRN_POOL"] == want
-
-    def test_split_conv_requires_merge_and_fold(self, monkeypatch):
-        """fused2 = merge + fold + parity convs; split/nofold disable
-        the prerequisite, so split_conv must be off there."""
-        from znicz_tpu.ops import tuning
-        for env in ("split", "nofold", "fused1"):
-            monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", env)
-            assert not tuning.lrn_pool_split_conv(), env
-        monkeypatch.delenv("ZNICZ_TPU_LRN_POOL")
-        assert tuning.lrn_pool_split_conv()

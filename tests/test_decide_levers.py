@@ -370,20 +370,6 @@ class TestVerdictRules:
         assert v["default"] == "keep-off"
 
 
-class TestShippedDefaultsSync:
-    def test_shipped_dict_mirrors_tuning_resolved_routing(self,
-                                                          monkeypatch):
-        """decide_levers imports nothing of the package, so it carries
-        its own copy of the shipped routing defaults — this pin is
-        what keeps the two in sync across future default flips."""
-        from znicz_tpu.ops import tuning
-        for var in ("ZNICZ_TPU_LRN_POOL", "ZNICZ_TPU_CONV1",
-                    "ZNICZ_TPU_CONV", "ZNICZ_TPU_NO_PALLAS",
-                    "ZNICZ_TPU_MXU"):
-            monkeypatch.delenv(var, raising=False)
-        assert dl._SHIPPED == tuning.resolved_routing()
-
-
 class TestMixedTranscripts:
     def test_legacy_and_new_rows_compare(self):
         """A round-4 default row (legacy, = fused1) pairs with a
@@ -397,3 +383,13 @@ class TestMixedTranscripts:
         pairs = dl.compare(hl, "LRN_POOL", "fused2", "fused1")
         assert len(pairs) == 2
         assert dl._win(pairs) is True
+
+    def test_row_without_stamp_but_with_rev_ran_the_shipped_routing(self):
+        """bench.py stamps no ``resolved`` since PR 32 (the program has
+        no routing lever left); such a row carries a ``rev`` and reads
+        as the shipped routing, a rev-less one as round 4's."""
+        assert dict(dl.canonical({"rev": "abc1234"})) == dl._SHIPPED
+        assert dict(dl.canonical({}))["LRN_POOL"] == "fused1"
+        off = dict(dl.canonical({"rev": "abc1234", "levers": {
+            "ZNICZ_TPU_NO_PALLAS": "1"}}))
+        assert off == {**dl._SHIPPED, "PALLAS": "off"}

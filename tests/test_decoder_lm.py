@@ -284,7 +284,7 @@ def test_rows_moved_counts_the_pieces_that_ran(monkeypatch, collapsed):
     assert int(counters["moe_assignments_held"]) == (64 if collapsed else 32)
     assert int(counters["moe_rows_moved"]) == (2 * 64 if collapsed
                                                else 2 * 24)
-    assert moe.COUNTER_FOLDS["moe_rows_moved"] == "sum"
+    assert fused.COUNTERS["moe_rows_moved"][0] == "sum"
 
 
 def _float_arrays(jaxpr):
@@ -534,6 +534,45 @@ def test_the_sample_trains_through_the_launcher(sample):
     assert 0 < row["moe_expert_load_max"] <= 4 * 32 * 2
     # every expert held: one piece of all the pairs, every layer
     assert row["moe_rows_moved"] == row["moe_assignments"]
+
+
+@pytest.fixture(scope="module")
+def counted_epoch():
+    """One epoch of the sample through the Launcher: its ``train_step``
+    row and the registry's families after it."""
+    from znicz_tpu.config import root
+    from znicz_tpu.launcher import Launcher
+    from znicz_tpu.telemetry import flightrecorder
+    from znicz_tpu.telemetry.registry import REGISTRY
+    saved = root.decoder_lm.to_dict()
+    try:
+        Launcher("znicz_tpu.models.decoder_lm", backend="xla", fused=True,
+                 epochs=1, seed=7).run()
+    finally:
+        root.decoder_lm.update(saved)
+    return _last_train_step(flightrecorder), REGISTRY
+
+
+@pytest.mark.parametrize("name", sorted(fused.COUNTERS))
+def test_every_declared_counter_reaches_row_gauge_and_docs(counted_epoch,
+                                                           name):
+    """``fused.COUNTERS`` is the one declaration: each name in it has a
+    fold the epoch loop knows, a field in the ``train_step`` row, a
+    ``train_<name>`` gauge with a help text holding the epoch's fold, and
+    its line in docs/observability.md."""
+    import os
+    row, registry = counted_epoch
+    fold, gauge = fused.COUNTERS[name]
+    assert fold in ("sum", "max") and hasattr(np, fold)
+    assert isinstance(row[name], int) and row[name] > 0
+    made = gauge()
+    assert made.name == "train_" + name and len(made.help) > 20
+    assert made is registry.gauge("train_" + name)
+    assert made.value() == row[name]
+    doc = open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "observability.md")).read()
+    assert f"| `train_{name}` | gauge |" in doc
+    assert name in doc.replace("train_" + name, "")    # the row's field
 
 
 def test_a_model_without_the_kinds_has_no_counters():

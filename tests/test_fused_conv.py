@@ -10,7 +10,10 @@ from znicz_tpu import prng
 from znicz_tpu.backends import Device
 from znicz_tpu.config import root
 from znicz_tpu.models import cifar
-from znicz_tpu.parallel import FusedTrainer, extract_model, make_mesh
+from znicz_tpu.parallel import (FusedTrainer, extract_model, fused,
+                                make_mesh)
+
+import helpers
 
 
 @pytest.fixture(autouse=True)
@@ -131,7 +134,6 @@ class TestFusedConvEquivalence:
         conv_tanh exercises the VALUE-dependent activation fold, whose
         derivative must also evaluate on the storage-dtype y."""
         import dataclasses
-        import os
         wf = _workflow(layers=[
             {"type": conv_type,
              "->": {"n_kernels": 8, "kx": 5, "sliding": 2},
@@ -141,15 +143,13 @@ class TestFusedConvEquivalence:
             {"type": "softmax", "->": {"output_sample_shape": 10},
              "<-": {"learning_rate": 0.1, "gradient_moment": 0.9}},
         ])
-        # fused1 pins phase-1 (bit-equality contract; fused2 is
-        # allclose-only), keeping the test default-independent
-        os.environ["ZNICZ_TPU_LRN_POOL"] = "fused1"
-        try:
-            spec_m, params, vels = extract_model(wf)
-            os.environ["ZNICZ_TPU_LRN_POOL"] = "split"
-            spec_s, params_s, vels_s = extract_model(wf)
-        finally:
-            os.environ.pop("ZNICZ_TPU_LRN_POOL", None)
+        # merge + fold (the bit-equality contract; the split convs of
+        # rewrite (iii) are allclose-only) against the unrewritten rows
+        spec_m, params, vels = helpers.routed(wf, fused.merge_lrn_pool,
+                                              fused.fold_pair_act)
+        assert spec_m.layers[1].cfg["fold_act"] == {
+            "conv_str": "strict_relu", "conv_tanh": "tanh"}[conv_type]
+        spec_s, params_s, vels_s = helpers.routed(wf)
         ld = wf.loader
         n0, n1, n2 = ld.class_lengths
         idx = np.arange(n0 + n1, n0 + n1 + n2)
@@ -193,60 +193,6 @@ class TestFusedConvEquivalence:
                        ld.max_minibatch_size, epoch=0)
         _drive_graph(wf, idx)
         _assert_params_match(wf, tr)
-
-    @pytest.mark.parametrize("mode", ["single", "mesh_dp", "mesh_tp"])
-    def test_conv1_s2d_full_model_matches_default(self, monkeypatch,
-                                                  mode):
-        """ZNICZ_TPU_CONV1=s2d (VERDICT r3 item 8 lever): a model whose
-        first conv qualifies (C=3, stride 2) must train to the same
-        params as the default single-device path to float tolerance —
-        including under data- and tensor-parallel meshes (the s2d
-        reshapes are batch-preserving, so sharding must pass through)."""
-        import jax
-        from znicz_tpu.parallel import make_mesh
-        layers = [
-            {"type": "conv_tanh",
-             "->": {"n_kernels": 8, "kx": 5, "sliding": 2},
-             "<-": {"learning_rate": 0.1, "gradient_moment": 0.9}},
-            {"type": "all2all_tanh", "->": {"output_sample_shape": 24},
-             "<-": {"learning_rate": 0.1, "gradient_moment": 0.9}},
-            {"type": "softmax", "->": {"output_sample_shape": 10},
-             "<-": {"learning_rate": 0.1, "gradient_moment": 0.9}},
-        ]
-
-        def train(env, mesh=None):
-            if env:
-                monkeypatch.setenv("ZNICZ_TPU_CONV1", env)
-            else:
-                monkeypatch.delenv("ZNICZ_TPU_CONV1", raising=False)
-            wf = _workflow(layers=layers)
-            spec, params, vels = extract_model(wf)
-            cp = jax.tree_util.tree_map(np.array, (params, vels))
-            tr = FusedTrainer(spec=spec, params=cp[0], vels=cp[1],
-                              mesh=mesh)
-            ld = wf.loader
-            idx = np.arange(ld.total_samples - ld.class_lengths[2],
-                            ld.total_samples)
-            tr.train_epoch(np.asarray(ld.original_data.mem),
-                           np.asarray(ld.original_labels.mem), idx,
-                           ld.max_minibatch_size, epoch=0)
-            return [(np.asarray(w), np.asarray(b))
-                    for w, b in tr.params]
-
-        mesh = {"single": None,
-                "mesh_dp": lambda: make_mesh(n_data=8, n_model=1),
-                "mesh_tp": lambda: make_mesh(n_data=4, n_model=2),
-                }[mode]
-        # the single-device baseline is byte-identical across modes —
-        # train it once and memoize on the test class
-        cls = type(self)
-        if not hasattr(cls, "_s2d_baseline"):
-            cls._s2d_baseline = train(None)
-        p_def = cls._s2d_baseline
-        p_s2d = train("s2d", mesh() if mesh else None)
-        for (w1, b1), (w2, b2) in zip(p_def, p_s2d):
-            np.testing.assert_allclose(w2, w1, rtol=1e-4, atol=1e-5)
-            np.testing.assert_allclose(b2, b1, rtol=1e-4, atol=1e-5)
 
     def test_run_fused_bfloat16_converges(self):
         """compute_dtype='bfloat16': MXU operands in bf16, params and

@@ -17,6 +17,7 @@ import pytest
 
 import jax.numpy as jnp
 
+import helpers
 from znicz_tpu import prng
 from znicz_tpu.ops import lrn_pool, normalization as lrn_math, \
     pooling as pool_ops, tuning
@@ -156,7 +157,7 @@ class TestSpecMerge:
         return mk
 
     def test_merge_and_tie_remap(self):
-        from znicz_tpu.parallel.fused import _merge_lrn_pool
+        from znicz_tpu.parallel import fused
         mk = self._mk_layers()
         layers = [
             mk("conv", stride=(1, 1), padding=0),            # 0
@@ -169,8 +170,10 @@ class TestSpecMerge:
             mk("deconv", stride=(1, 1), padding=0, tie=0),   # 5 tie → 0
         ]
         pv = [(None, None)] * len(layers)
-        out_l, out_p, out_v, src = _merge_lrn_pool(layers, list(pv),
-                                                   list(pv))
+        spec, out_p, out_v = fused.model_of_rows(
+            fused.split_pair_conv(fused.fold_pair_act(
+                fused.merge_lrn_pool(layers))), pv, pv, "mse")
+        out_l, src = spec.layers, spec.unit_index
         kinds = [la.kind for la in out_l]
         assert kinds == ["conv", "lrn_pool", "conv", "depooling",
                          "deconv"]
@@ -187,7 +190,8 @@ class TestSpecMerge:
         assert "act_folded" not in out_l[0].cfg
 
     def test_activation_fold_marks_both_layers(self):
-        from znicz_tpu.parallel.fused import LayerSpec, _merge_lrn_pool
+        from znicz_tpu.parallel.fused import (LayerSpec, fold_pair_act,
+                                              merge_lrn_pool)
         H = (0.01, 0.0, 0.0, 0.9)
         conv = LayerSpec(kind="conv", activation="strict_relu",
                          include_bias=True, hypers=H, hypers_bias=H,
@@ -197,44 +201,50 @@ class TestSpecMerge:
                   mk("lrn", n=5, alpha=1e-4, beta=0.75, k=2.0),
                   mk("max_pool", ksize=(3, 3), stride=(2, 2),
                      padding=0)]
-        pv = [(None, None)] * 3
-        out_l, _, _, _ = _merge_lrn_pool(layers, list(pv), list(pv))
+        merged = merge_lrn_pool(layers)
+        assert "fold_act" not in merged[1].cfg      # (i) alone folds nothing
+        out_l = fold_pair_act(merged)
         assert [la.kind for la in out_l] == ["conv", "lrn_pool"]
         assert out_l[1].cfg["fold_act"] == "strict_relu"
         assert out_l[0].cfg["act_folded"] is True
 
     def test_non_fusable_kept_split(self):
-        from znicz_tpu.parallel.fused import _merge_lrn_pool
+        from znicz_tpu.parallel import fused
         mk = self._mk_layers()
         layers = [
             mk("lrn", n=5, alpha=1e-4, beta=0.75, k=2.0),
             mk("max_pool", ksize=(3, 3), stride=(3, 3), padding=0),
         ]
         pv = [(None, None)] * 2
-        out_l, _, _, src = _merge_lrn_pool(layers, list(pv), list(pv))
-        assert [la.kind for la in out_l] == ["lrn", "max_pool"]
-        assert src == (0, 1)
+        spec, _, _ = fused.model_of_rows(fused.merge_lrn_pool(layers),
+                                         pv, pv, "mse")
+        assert [la.kind for la in spec.layers] == ["lrn", "max_pool"]
+        assert spec.unit_index == (0, 1)
 
-    def test_env_disables_merge(self, monkeypatch):
-        from znicz_tpu.parallel.fused import _merge_lrn_pool
-        monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", "split")
-        mk = self._mk_layers()
-        layers = [
-            mk("lrn", n=5, alpha=1e-4, beta=0.75, k=2.0),
-            mk("max_pool", ksize=(3, 3), stride=(2, 2), padding=0),
-        ]
-        pv = [(None, None)] * 2
-        out_l, _, _, _ = _merge_lrn_pool(layers, list(pv), list(pv))
-        assert [la.kind for la in out_l] == ["lrn", "max_pool"]
+    def test_unrewritten_rows_are_the_units(self):
+        """``workflow_rows`` (extract_model's first half) hands back one
+        row a forward unit, LRN and pool apart, nothing folded or split:
+        the reference every rewrite is compared against."""
+        from znicz_tpu.parallel import fused
+        wf = TestTrainEquivalence()._workflow()
+        rows, params, vels = fused.workflow_rows(wf)
+        assert len(rows) == len(params) == len(vels) == len(wf.forwards)
+        kinds = [la.kind for la in rows]
+        assert "lrn_pool" not in kinds and kinds.count("lrn") == 2
+        assert kinds[1:3] == ["lrn", "max_pool"]
+        assert not any({"act_folded", "split_out", "fold_act",
+                        "emit_split"} & set(la.cfg) for la in rows)
+        spec, _, _ = helpers.routed(wf)
+        assert spec.layers == tuple(rows)
+        assert spec.unit_index == tuple(range(len(rows)))
 
 
 class TestPhase2SplitConv:
-    def test_fused2_matches_default_merge(self, monkeypatch):
-        """ZNICZ_TPU_LRN_POOL=fused2: the conv feeding each folded pair
-        emits parity halves directly and consumes split gradients.
-        The parity convs are allclose (not bit-equal) to the plain
-        conv, so training must match the default merge to float
-        tolerance."""
+    def test_split_convs_match_merge_and_fold(self):
+        """Rewrite (iii): the conv feeding each folded pair emits parity
+        halves directly and consumes split gradients.  The parity convs
+        are allclose (not bit-equal) to the plain conv, so training must
+        match merge + fold to float tolerance."""
         from znicz_tpu.backends import Device
         from znicz_tpu.config import root
         from znicz_tpu.models import alexnet
@@ -254,13 +264,13 @@ class TestPhase2SplitConv:
         finally:
             root.alexnet.update(saved)
 
-        # pin BOTH sides so the contract survives a default flip:
-        # fused1 = phase-1 merge+fold, fused2 = parity-split convs
-        monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", "fused1")
-        spec0, params, vels = fused.extract_model(wf)
-        monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", "fused2")
+        # merge + fold against all three, which is what ships
+        spec0, params, vels = helpers.routed(wf, fused.merge_lrn_pool,
+                                             fused.fold_pair_act)
         spec2, params2, vels2 = fused.extract_model(wf)
-        monkeypatch.delenv("ZNICZ_TPU_LRN_POOL")
+        assert spec2 == helpers.routed(
+            wf, fused.merge_lrn_pool, fused.fold_pair_act,
+            fused.split_pair_conv)[0]
         split_convs = [la for la in spec2.layers
                        if la.kind == "conv" and la.cfg.get("split_out")]
         assert len(split_convs) == 2        # conv1 and conv2
@@ -298,8 +308,8 @@ class TestPhase2SplitConv:
 
     @pytest.mark.parametrize("mode", ["mesh_dp", "mesh_tp", "bf16",
                                       "accum"])
-    def test_fused2_under_training_modes(self, monkeypatch, mode):
-        """The phase-2 path must compile and train under every shipped
+    def test_split_convs_under_training_modes(self, mode):
+        """The shipped routing must compile and train under every shipped
         training mode: data/tensor-parallel meshes, bf16 activation
         storage, gradient accumulation."""
         import dataclasses
@@ -322,9 +332,7 @@ class TestPhase2SplitConv:
             wf.initialize(device=Device.create("xla"))
         finally:
             root.alexnet.update(saved)
-        monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", "fused2")
         spec, params, vels = fused.extract_model(wf)
-        monkeypatch.delenv("ZNICZ_TPU_LRN_POOL")
         assert any(la.cfg.get("split_out") for la in spec.layers)
 
         kw = {}
@@ -418,19 +426,18 @@ class TestTrainEquivalence:
             root.alexnet.update(saved)
         return wf
 
-    def test_merged_equals_split(self, monkeypatch):
+    def test_merged_equals_split(self):
         from znicz_tpu.parallel import FusedTrainer, fused
 
         prng.seed_all(77)
         wf = self._workflow()
-        # fused1 pins the phase-1 merge whose contract IS bit-equality
-        # (fused2's parity-split convs are allclose-only by design)
-        monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", "fused1")
-        spec_m, params_m, vels_m = fused.extract_model(wf)
+        # merge + fold, whose contract IS bit-equality (the parity-split
+        # convs of rewrite (iii) are allclose-only by design), against
+        # the unrewritten rows
+        spec_m, params_m, vels_m = helpers.routed(
+            wf, fused.merge_lrn_pool, fused.fold_pair_act)
         assert any(la.kind == "lrn_pool" for la in spec_m.layers)
-        monkeypatch.setenv("ZNICZ_TPU_LRN_POOL", "split")
-        spec_s, params_s, vels_s = fused.extract_model(wf)
-        monkeypatch.delenv("ZNICZ_TPU_LRN_POOL")
+        spec_s, params_s, vels_s = helpers.routed(wf)
         assert all(la.kind != "lrn_pool" for la in spec_s.layers)
 
         ld = wf.loader
@@ -454,6 +461,89 @@ class TestTrainEquivalence:
         assert len(flat_m) == len(flat_s)
         for a, b in zip(flat_m, flat_s):
             np.testing.assert_array_equal(a, b)
+
+
+class TestRewritesAlone:
+    """The paths that stay reachable from the layer list alone and that
+    AlexNet's does not take, each against the rows it rewrites, on the
+    XLA tier: to the bit where nothing is folded or the folded
+    derivative is a mask (strict_relu); a value-dependent derivative
+    (tanh: ``err * (a*y*y + b)``) is the same arithmetic traced inside
+    another fusion, which XLA may contract into an FMA differently: equal
+    to float32 rounding (found here: 1.5e-8 on weights of 0.2)."""
+
+    GD = {"learning_rate": 0.05, "gradient_moment": 0.9}
+    PAIR = [{"type": "norm", "->": {"n": 5}},
+            {"type": "max_pooling", "->": {"kx": 3, "sliding": 2}},
+            {"type": "softmax", "->": {"output_sample_shape": 10},
+             "<-": GD}]
+    #: name -> (layers, the rewrites of the one side, of the other; None
+    #: is extract_model), what the first side's pair and the row before
+    #: it hold, whether the two sides agree to the bit
+    CASES = {
+        # (ii) against (i): what ``nofold`` against ``fused1`` was
+        "fold_against_merge": (
+            [{"type": "conv_str",
+              "->": {"n_kernels": 8, "kx": 5, "sliding": 2}, "<-": GD}],
+            ("merge_lrn_pool", "fold_pair_act"), ("merge_lrn_pool",),
+            {"fold_act": "strict_relu"}, {"act_folded": True}, True),
+        # a deconv before the pair folds and stays whole
+        "deconv_before_pair": (
+            [{"type": "conv_tanh",
+              "->": {"n_kernels": 8, "kx": 3, "padding": 1}, "<-": GD},
+             {"type": "deconv_tanh",
+              "->": {"n_kernels": 8, "kx": 3, "padding": 1,
+                     "n_channels": 6}, "<-": GD}],
+            None, (), {"fold_act": "tanh"}, {"act_folded": True}, False),
+        # a pair after a linear conv merges and nothing more
+        "pair_after_linear_conv": (
+            [{"type": "conv",
+              "->": {"n_kernels": 8, "kx": 3, "padding": 1}, "<-": GD}],
+            None, (), {}, {}, True),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_bit_equal_to_the_other_rows(self, case):
+        from znicz_tpu.parallel import FusedTrainer, fused
+
+        head, one, other, pair_has, before_has, to_the_bit = \
+            self.CASES[case]
+        wf = helpers.tiny_workflow(head + self.PAIR, (16, 16, 3), 16)
+
+        def model(rewrites):
+            if rewrites is None:
+                return fused.extract_model(wf)
+            return helpers.routed(wf, *(getattr(fused, r)
+                                        for r in rewrites))
+        spec_a, params_a, vels_a = model(one)
+        spec_b, params_b, vels_b = model(other)
+        pair = next(i for i, la in enumerate(spec_a.layers)
+                    if la.kind == "lrn_pool")
+        marks = ("fold_act", "emit_split", "act_folded", "split_out")
+        assert {k: v for k, v in spec_a.layers[pair].cfg.items()
+                if k in marks} == pair_has
+        assert {k: v for k, v in spec_a.layers[pair - 1].cfg.items()
+                if k in marks} == before_has
+        assert spec_a != spec_b
+
+        ld = wf.loader
+        idx = np.arange(16, 48)
+
+        def run(spec, params, vels):
+            tr = FusedTrainer(spec=spec, params=params, vels=vels)
+            m = tr.train_epoch(ld.original_data.devmem,
+                               ld.original_labels.devmem, idx, 16)
+            return m, [np.asarray(a) for leaves in tr.params
+                       for a in leaves if a is not None]
+        m_a, p_a = run(spec_a, params_a, vels_a)
+        m_b, p_b = run(spec_b, params_b, vels_b)
+        tol = ({"rtol": 0, "atol": 0} if to_the_bit
+               else {"rtol": 2e-6, "atol": 1e-7})
+        np.testing.assert_allclose(np.asarray(m_a["loss"]),
+                                   np.asarray(m_b["loss"]), **tol)
+        assert len(p_a) == len(p_b)
+        for a, b in zip(p_a, p_b):
+            np.testing.assert_allclose(a, b, **tol)
 
 
 class TestBatchBlockVmem:

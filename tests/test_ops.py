@@ -37,19 +37,6 @@ class TestMatmul:
                                             jnp.asarray(w)))
         np.testing.assert_allclose(g, p, rtol=1e-4, atol=1e-4)
 
-    @pytest.mark.parametrize("shape", [(700, 72, 16), (128, 128, 128),
-                                       (9, 5, 3), (2000, 130, 260)])
-    def test_pallas_at_b_matches_numpy(self, pallas_interpret, shape):
-        """aᵀ@b without materializing aᵀ (the conv weight-grad shape:
-        M huge, K/N modest) — row blocks accumulate per output tile."""
-        m, k, n = shape
-        a = rng.standard_normal((m, k)).astype(np.float32)
-        b = rng.standard_normal((m, n)).astype(np.float32)
-        g = a.T @ b
-        p = np.asarray(matmul.pallas_matmul_at_b(jnp.asarray(a),
-                                                 jnp.asarray(b)))
-        np.testing.assert_allclose(g, p, rtol=1e-4, atol=1e-3)
-
 
 class TestMXUCastPath:
     """VERDICT r3 weak item 3: the bf16 MXU operand cast only activates
@@ -73,14 +60,6 @@ class TestMXUCastPath:
         # with sqrt(K) through cancellation
         np.testing.assert_allclose(g, p, rtol=2e-2, atol=1e-1)
         assert np.max(np.abs(g - p)) > 0.0   # the cast really happened
-
-    def test_cast_at_b_close_to_f32(self, forced_cast):
-        a = rng.standard_normal((300, 40)).astype(np.float32)
-        b = rng.standard_normal((300, 24)).astype(np.float32)
-        g = a.T @ b
-        p = np.asarray(matmul.pallas_matmul_at_b(jnp.asarray(a),
-                                                 jnp.asarray(b)))
-        np.testing.assert_allclose(g, p, rtol=2e-2, atol=2e-1)
 
     def test_f32_lever_wins_over_tpu(self, monkeypatch):
         monkeypatch.setenv("ZNICZ_TPU_MXU", "f32")

@@ -7,13 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from znicz_tpu.ops import (conv, dropout, normalization, pooling, rngbits,
-                           tuning)
-
-
-@pytest.fixture
-def pallas_interpret(monkeypatch):
-    monkeypatch.setattr(tuning, "_INTERPRET", True)
+from znicz_tpu.ops import conv, dropout, normalization, pooling, rngbits
 
 CONV_CASES = [
     # (h, w, c, oc, kh, kw, stride, pad)
@@ -26,7 +20,7 @@ CONV_CASES = [
 
 
 @pytest.mark.parametrize("case", CONV_CASES)
-def test_conv_forward_tiers_agree(case, pallas_interpret):
+def test_conv_forward_tiers_agree(case):
     h, w, c, oc, kh, kw, s, p = case
     rng = np.random.default_rng(7)
     x = rng.normal(size=(2, h, w, c)).astype(np.float32)
@@ -34,66 +28,6 @@ def test_conv_forward_tiers_agree(case, pallas_interpret):
     y_np = conv.np_conv2d(x, wt, s, p)
     y_x = np.asarray(conv.xla_conv2d(jnp.asarray(x), jnp.asarray(wt), s, p))
     np.testing.assert_allclose(y_np, y_x, atol=1e-4, rtol=1e-4)
-    y_p = np.asarray(conv.pallas_conv2d(jnp.asarray(x), jnp.asarray(wt),
-                                        s, p))
-    np.testing.assert_allclose(y_np, y_p, atol=1e-3, rtol=1e-3)
-
-
-S2D_CASES = [
-    # (h, w, c, oc, k, stride, pad) — square kernel/stride (the s2d
-    # algebra's precondition); AlexNet conv1 geometry scaled down, the
-    # k-multiple-of-s trim edge (h=11, k=2, s=2), padding, k < s
-    (59, 59, 3, 8, 11, 4, 0),                  # conv1 shape family
-    (11, 11, 3, 4, 2, 2, 0),                   # trailing-row trim
-    (12, 9, 2, 3, 3, 3, 2),                    # padding, s=3
-    (9, 9, 1, 2, 5, 2, 1),
-    (8, 8, 4, 4, 2, 4, 0),                     # k < s (khp = 1)
-    (227, 227, 3, 8, 11, 4, 0),                # the REAL conv1 geometry
-]
-
-
-@pytest.mark.parametrize("case", S2D_CASES)
-def test_conv_s2d_matches_plain(case):
-    """Space-to-depth conv1 formulation (VERDICT r3 item 8 lever):
-    forward and weight grad must reproduce the plain conv to f32
-    tolerance on every supported geometry."""
-    h, w, c, oc, k, s, p = case
-    rng = np.random.default_rng(13)
-    x = rng.normal(size=(2, h, w, c)).astype(np.float32)
-    wt = (rng.normal(size=(k, k, c, oc)) * 0.1).astype(np.float32)
-    assert conv.s2d_applicable(wt.shape, s, p)
-    y_ref = np.asarray(conv.xla_conv2d(jnp.asarray(x), jnp.asarray(wt),
-                                       s, p))
-    y_s2d = np.asarray(conv.xla_conv2d_s2d(jnp.asarray(x),
-                                           jnp.asarray(wt), s, p))
-    assert y_s2d.shape == y_ref.shape
-    np.testing.assert_allclose(y_s2d, y_ref, atol=1e-4, rtol=1e-4)
-    err = rng.normal(size=y_ref.shape).astype(np.float32)
-    dw_ref = np.asarray(conv.xla_conv2d_grad_weights(
-        jnp.asarray(x), jnp.asarray(err), wt.shape, s, p))
-    dw_s2d = np.asarray(conv.xla_conv2d_grad_weights_s2d(
-        jnp.asarray(x), jnp.asarray(err), wt.shape, s, p))
-    assert dw_s2d.shape == dw_ref.shape
-    np.testing.assert_allclose(dw_s2d, dw_ref, atol=2e-3, rtol=1e-3)
-
-
-def test_conv_s2d_dispatcher(monkeypatch):
-    """ZNICZ_TPU_CONV1=s2d routes qualifying convs (tiny C, square
-    stride ≥ 2) and leaves everything else on the plain path."""
-    rng = np.random.default_rng(17)
-    x = rng.normal(size=(2, 19, 19, 3)).astype(np.float32)
-    wt = (rng.normal(size=(5, 5, 3, 4)) * 0.1).astype(np.float32)
-    monkeypatch.delenv("ZNICZ_TPU_CONV1", raising=False)
-    plain = np.asarray(conv.conv2d(jnp.asarray(x), jnp.asarray(wt), 2,
-                                   0))
-    monkeypatch.setenv("ZNICZ_TPU_CONV1", "s2d")
-    routed = np.asarray(conv.conv2d(jnp.asarray(x), jnp.asarray(wt), 2,
-                                    0))
-    np.testing.assert_allclose(routed, plain, atol=1e-4, rtol=1e-4)
-    # non-qualifying: stride 1, big C — must stay the plain path
-    assert not conv.s2d_applicable((3, 3, 64, 64), 1, 1)
-    assert not conv.s2d_applicable((3, 3, 64, 64), 2, 0)   # C > 8
-    assert not conv.s2d_applicable((3, 3, 3, 8), (2, 1), 0)  # sh != sw
 
 
 @pytest.mark.parametrize("case", CONV_CASES)

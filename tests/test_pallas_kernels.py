@@ -96,55 +96,6 @@ class TestLRNKernel:
                                    atol=1e-6)
 
 
-class TestConvGradKernels:
-    """Implicit-GEMM Pallas tiers for conv gradients and the deconv
-    family (SURVEY.md §2.3 conv-grad + deconv rows)."""
-
-    @pytest.mark.parametrize("stride,padding", [(1, 0), (2, 1)])
-    def test_conv_grads_vs_golden(self, stride, padding):
-        from znicz_tpu.ops import conv as conv_ops
-        x = _x((2, 9, 9, 5))
-        w = _x((3, 3, 5, 7), "w")
-        y = conv_ops.np_conv2d(x, w, stride, padding)
-        err = _x(y.shape, "err")
-        dw_ref = conv_ops.np_conv2d_grad_weights(x, err, w.shape, stride,
-                                                 padding)
-        dw = conv_ops.pallas_conv2d_grad_weights(
-            jnp.asarray(x), jnp.asarray(err), w.shape, stride, padding)
-        np.testing.assert_allclose(np.asarray(dw), dw_ref, rtol=1e-4,
-                                   atol=1e-4)
-        dx_ref = conv_ops.np_conv2d_grad_input(err, w, x.shape, stride,
-                                               padding)
-        dx = conv_ops.pallas_conv2d_grad_input(
-            jnp.asarray(err), jnp.asarray(w), x.shape, stride, padding)
-        np.testing.assert_allclose(np.asarray(dx), dx_ref, rtol=1e-4,
-                                   atol=1e-4)
-
-    @pytest.mark.parametrize("stride,padding", [(1, 0), (2, 1)])
-    def test_deconv_all_directions_vs_golden(self, stride, padding):
-        from znicz_tpu.ops import deconv as deconv_ops
-        x = _x((2, 5, 5, 7))
-        w = _x((3, 3, 4, 7), "w")         # (KH, KW, C_out, C_in)
-        y_ref = deconv_ops.np_deconv2d(x, w, stride, padding)
-        y = deconv_ops.pallas_deconv2d(jnp.asarray(x), jnp.asarray(w),
-                                       stride, padding)
-        np.testing.assert_allclose(np.asarray(y), y_ref, rtol=1e-4,
-                                   atol=1e-4)
-        err = _x(y_ref.shape, "err")
-        dx_ref = deconv_ops.np_deconv2d_grad_input(err, w, stride,
-                                                   padding)
-        dx = deconv_ops.pallas_deconv2d_grad_input(
-            jnp.asarray(err), jnp.asarray(w), stride, padding)
-        np.testing.assert_allclose(np.asarray(dx), dx_ref, rtol=1e-4,
-                                   atol=1e-4)
-        dw_ref = deconv_ops.np_deconv2d_grad_weights(err, x, w.shape,
-                                                     stride, padding)
-        dw = deconv_ops.pallas_deconv2d_grad_weights(
-            jnp.asarray(err), jnp.asarray(x), w.shape, stride, padding)
-        np.testing.assert_allclose(np.asarray(dw), dw_ref, rtol=1e-4,
-                                   atol=1e-4)
-
-
 class TestKohonenKernel:
     def test_distance_argmin_vs_golden(self):
         from znicz_tpu.ops import kohonen as som_ops

@@ -20,8 +20,9 @@ r4 item 1, on the 1.78× on-chip b128 ablation):
   convs can too), because the verdict may differ.
 
 Rows are compared by their **resolved routing** (the ``resolved``
-field bench.py stamps since round 5 — env levers + defaults already
-applied) and their **code revision** (the ``rev`` sha stamped since
+field bench.py stamped from round 5 to PR 31 — env levers + defaults
+already applied; a later row ran the one routing the program has) and
+their **code revision** (the ``rev`` sha stamped since
 round 6): rows from different revisions neither average nor pair, so
 a keep/revert verdict never mixes measurements of different code.
 Pre-round-5 rows carry only explicit env levers; they are
@@ -76,7 +77,10 @@ def canonical(row):
     sorted-items tuple."""
     res = row.get("resolved")
     if not isinstance(res, dict):
-        res = dict(_LEGACY_DEFAULTS)
+        # unstamped: a pre-round-5 row, or (with the ``rev`` every row
+        # carries since round 6) a row of the program without routing
+        # levers, PR 32 on
+        res = dict(_SHIPPED if row.get("rev") else _LEGACY_DEFAULTS)
         lv = row.get("levers", {})
         if "ZNICZ_TPU_LRN_POOL" in lv:
             val = lv["ZNICZ_TPU_LRN_POOL"]
@@ -129,11 +133,9 @@ def headline(rows):
     return {k: round(sum(v) / len(v), 1) for k, v in acc.items()}
 
 
-#: today's SHIPPED routing defaults (fused2 since round 5) — the one
-#: copy in this module; must mirror znicz_tpu/ops/tuning.py
-#: resolved_routing()'s defaults (this tool reads transcripts and
-#: imports nothing of the package).  tests/test_decide_levers.py pins
-#: the two in sync.
+#: what an old row's unset levers stood for when it was written (fused2
+#: since round 5), the one copy in this module.  The program has no
+#: routing levers since PR 32: every row it writes now ran this routing.
 _SHIPPED = {"LRN_POOL": "fused2", "CONV1": "direct", "CONV": "xla",
             "PALLAS": "on", "MXU": "bf16"}
 
@@ -265,10 +267,9 @@ def lrn_pool_verdict(pairs, order=None):
     # the qualified (both-batch, newest-revision) pairs selected above
     losses = [p for p in pairs if p["gain_pct"] < 0]
     if losses:
-        # the shipped default's own risk note (tuning.py
-        # lrn_pool_split_conv) promises a revert on a loss at EITHER
-        # batch — symmetric with the no-loss-both-batches rule that
-        # would have gated the flip
+        # the flip's own risk note promised a revert on a loss at
+        # EITHER batch — symmetric with the no-loss-both-batches rule
+        # that would have gated the flip
         return "revert-to-fused1 (loss at " + ", ".join(
             f"b{p['minibatch']}: {p['gain_pct']}%" for p in losses) + ")"
     return "marginal-keep (within wobble)"

@@ -1,5 +1,5 @@
-"""Isolate the bf16-storage compile failure (the --ablate
-``storage_bf16`` variant failed to compile on chip on 2026-07-31 while
+"""Isolate the bf16-storage compile failure (the fused step with
+``storage_dtype="bfloat16"`` failed to compile on chip on 2026-07-31 while
 every f32 variant compiled; ROADMAP Speed 4).  Compiles each Pallas
 kernel family at the real AlexNet pair geometries with bf16 inputs, one
 at a time, printing PASS/FAIL per family so the first failing compile

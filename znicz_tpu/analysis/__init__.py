@@ -28,7 +28,10 @@ PRs grew (serving, resilience, telemetry, elastic):
   release, and ``cond.wait()`` outside a ``while`` predicate loop
   (:mod:`.concurrency`; runtime twin: :mod:`znicz_tpu.sanitizer`);
 * ``retry-after-discipline`` — 429/503/504 refusals in serving/ +
-  fleet/ without a ``Retry-After`` header (:mod:`.retry_after`).
+  fleet/ without a ``Retry-After`` header (:mod:`.retry_after`);
+* ``env-routing`` — ``os.environ`` touched under ops/ or parallel/,
+  where routes are picked from layer list, shapes and platform
+  (:mod:`.envrouting`).
 
 Run it: ``python -m znicz_tpu lint`` (or ``tools/lint.sh``); gate:
 ``pytest -m lint``.  Suppress: ``# zlint: disable=RULE`` inline, or a
@@ -43,6 +46,7 @@ from .core import (Analyzer, Finding, ModuleInfo, RepoRule, Rule,
                    load_baseline, write_baseline)
 from .cli import changed_paths, default_rules, main, run_repo
 from .deadlines import DeadlineDisciplineRule
+from .envrouting import EnvRoutingRule
 from .handlers import HandlerSafetyRule
 from .jaxrules import JaxHygieneRule, UnseededRandomRule
 from .locks import LockDisciplineRule
@@ -57,5 +61,5 @@ __all__ = [
     "UnseededRandomRule", "HandlerSafetyRule", "MetricDriftRule",
     "DurationClockRule", "DeadlineDisciplineRule",
     "SpanNameDriftRule", "LockOrderCycleRule", "LockLeakRule",
-    "ConditionWaitPredicateRule", "RetryAfterRule",
+    "ConditionWaitPredicateRule", "RetryAfterRule", "EnvRoutingRule",
 ]

@@ -20,6 +20,7 @@ from .concurrency import (ConditionWaitPredicateRule, LockLeakRule,
                           LockOrderCycleRule)
 from .core import Analyzer, default_root, iter_py_files, write_baseline
 from .deadlines import DeadlineDisciplineRule
+from .envrouting import EnvRoutingRule
 from .handlers import HandlerSafetyRule
 from .jaxrules import JaxHygieneRule, UnseededRandomRule
 from .locks import LockDisciplineRule
@@ -36,7 +37,8 @@ def default_rules() -> list:
             MetricDriftRule(), DurationClockRule(),
             DeadlineDisciplineRule(), SpanNameDriftRule(),
             LockOrderCycleRule(), LockLeakRule(),
-            ConditionWaitPredicateRule(), RetryAfterRule()]
+            ConditionWaitPredicateRule(), RetryAfterRule(),
+            EnvRoutingRule()]
 
 
 def changed_paths(root: str) -> list:

@@ -1,4 +1,4 @@
-"""2-D convolution: numpy golden, XLA, and Pallas implicit-GEMM tiers.
+"""2-D convolution: numpy golden and XLA tiers.
 
 Parity target: the reference's ``conv.cl``/``conv.cu`` + gradient variants
 (SURVEY.md §2.3 row 2: block-tiled, unpack-in-kernel im2col forward and the
@@ -17,11 +17,6 @@ TPU-native design decisions:
   by the numpy goldens below via explicit im2col/col2im; the XLA gradient
   tier expresses the same math as dilated/transposed convolutions.  Tests
   cross-check numpy vs XLA vs ``jax.grad``.
-* **Pallas tier**: implicit-GEMM — patch extraction stays in XLA (pure
-  data movement XLA pipelines well), the FLOPs run in the block-tiled
-  Pallas MXU matmul (``ops.matmul``).  This mirrors how the reference's
-  GPU kernel was "a matmul with unpack inside"; on TPU the unpack is
-  better left to the compiler and the GEMM to the hand-tiled kernel.
 """
 
 from __future__ import annotations
@@ -31,7 +26,6 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 
-from . import matmul, tuning
 from .geometry import norm2 as _norm2, out_size
 
 _DIMNUMS = ("NHWC", "HWIO", "NHWC")
@@ -140,91 +134,7 @@ def xla_conv2d_grad_weights(x, err, w_shape, stride=1, padding=0):
     return dw[:kh, :kw].astype(jnp.float32)
 
 
-# -- space-to-depth formulation for tiny-C strided convs (conv1) ----------
-# AlexNet's conv1 (11×11, stride 4, C=3) starves the MXU: 3 input
-# channels occupy 3 of 128 lanes in XLA's native lowering.  The
-# space-to-depth rewrite folds the stride into the channel axis —
-# x (H, W, C) → (⌈H/s⌉, ⌈W/s⌉, s²C), kernel (K, K, C) → (⌈K/s⌉, ⌈K/s⌉,
-# s²C) with structurally-zero taps — turning it into a stride-1 conv
-# with s²× the lane utilization (48 lanes for AlexNet).  The MLPerf-era
-# TPU trick, here as a pure-XLA rewrite (reshapes fuse).  Same math,
-# different contraction order → allclose, not bit-equal: opt-in via
-# ZNICZ_TPU_CONV1=s2d until the on-chip A/B (--ablate row conv1_s2d)
-# justifies a default flip.
-
-def _s2d_input(x, s: int, rows: int, cols: int):
-    """(B, H, W, C) → (B, rows, cols, s²C) phase stack, zero-padded (or
-    trimmed: trailing rows no window reaches) so every phase has
-    ``rows``×``cols`` positions."""
-    b, h, w, c = x.shape
-    hp, wp = rows * s, cols * s
-    if hp < h or wp < w:
-        x = x[:, :min(h, hp), :min(w, wp)]
-    if (hp, wp) != x.shape[1:3]:
-        x = jnp.pad(x, ((0, 0), (0, hp - x.shape[1]),
-                        (0, wp - x.shape[2]), (0, 0)))
-    x = x.reshape(b, rows, s, cols, s, c).transpose(0, 1, 3, 2, 4, 5)
-    return x.reshape(b, rows, cols, s * s * c)
-
-
-def _s2d_kernel(w, s: int):
-    """(KH, KW, C, F) → (⌈KH/s⌉, ⌈KW/s⌉, s²C, F); taps past the true
-    support are structurally zero."""
-    kh, kw, c, f = w.shape
-    khp, kwp = -(-kh // s), -(-kw // s)
-    wz = jnp.zeros((khp * s, kwp * s, c, f), w.dtype)
-    wz = wz.at[:kh, :kw].set(w)
-    wz = wz.reshape(khp, s, kwp, s, c, f).transpose(0, 2, 1, 3, 4, 5)
-    return wz.reshape(khp, kwp, s * s * c, f)
-
-
-def s2d_applicable(w_shape, stride, padding) -> bool:
-    """Worthwhile only where XLA's lowering starves the lanes: tiny C,
-    a real stride, equal in both dims (the phase algebra assumes it)."""
-    kh, kw, c, f = w_shape
-    (sh, sw), _ = _norm2(stride), _norm2(padding)
-    return sh == sw and sh >= 2 and c <= 8
-
-
-def _s2d_stack(x, w_shape, stride, padding):
-    """Shared preamble of the s2d forward/weight-grad: apply padding,
-    derive the phase geometry, build the input phase stack."""
-    kh, kw, c, f = w_shape
-    (sh, sw), (ph, pw) = _norm2(stride), _norm2(padding)
-    assert sh == sw and sh >= 2, (stride,)
-    s = sh
-    if (ph, pw) != (0, 0):
-        x = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
-    _, h, w_in, _ = x.shape
-    oh, ow = out_size(h, kh, s, 0), out_size(w_in, kw, s, 0)
-    khp, kwp = -(-kh // s), -(-kw // s)
-    xs = _s2d_input(x, s, oh + khp - 1, ow + kwp - 1)
-    return xs, s, khp, kwp
-
-
-def xla_conv2d_s2d(x, w, stride=1, padding=0, out_dtype=None):
-    """xla_conv2d, computed via space-to-depth (see section comment)."""
-    xs, s, _, _ = _s2d_stack(x, w.shape, stride, padding)
-    y = lax.conv_general_dilated(
-        xs, _s2d_kernel(w, s), window_strides=(1, 1),
-        padding=((0, 0), (0, 0)), dimension_numbers=_DIMNUMS,
-        preferred_element_type=jnp.float32)
-    return y.astype(out_dtype or x.dtype)
-
-
-def xla_conv2d_grad_weights_s2d(x, err, w_shape, stride=1, padding=0):
-    """Weight grad through the same phase algebra: grad of the s²C
-    kernel, rearranged back to (KH, KW, C, F) — taps beyond the true
-    support are structural zeros whose grads are simply dropped."""
-    kh, kw, c, f = w_shape
-    xs, s, khp, kwp = _s2d_stack(x, w_shape, stride, padding)
-    dwp = xla_conv2d_grad_weights(xs, err, (khp, kwp, s * s * c, f),
-                                  1, 0)
-    dwp = dwp.reshape(khp, kwp, s, s, c, f).transpose(0, 2, 1, 3, 4, 5)
-    return dwp.reshape(khp * s, kwp * s, c, f)[:kh, :kw]
-
-
-# -- column-parity variants (phase-2 of the fused LRN+pool pair) ----------
+# -- column-parity variants (rewrite (iii) of parallel/fused.py) -----------
 # A conv whose output feeds a merged LRN+max-pool pair can emit the
 # pair's column-parity halves DIRECTLY: the even/odd output columns of a
 # stride-s conv are themselves convs with W-stride 2s and a ±s·p input
@@ -315,93 +225,8 @@ def xla_conv2d_grad_input_split(err_e, err_o, w, x_shape, stride=1,
     return dx.astype(jnp.float32)
 
 
-# -- Pallas tier (implicit GEMM) ------------------------------------------
-def pallas_conv2d(x, w, stride=1, padding=0, out_dtype=None):
-    """Patch-extract (XLA) + block-tiled Pallas MXU matmul (FLOPs)."""
-    kh, kw, c, oc = w.shape
-    (sh, sw), (ph, pw) = _norm2(stride), _norm2(padding)
-    cols = lax.conv_general_dilated_patches(
-        x, (kh, kw), (sh, sw), ((ph, ph), (pw, pw)),
-        dimension_numbers=_DIMNUMS)          # (B, OH, OW, C*KH*KW)
-    b, oh, ow, k = cols.shape
-    # patches order is (C, KH, KW) minor-major per conv_general_dilated_
-    # patches docs (feature dim = flattened rhs spatial+input dims);
-    # reorder w to match: (C, KH, KW, OC)
-    w2 = jnp.transpose(w, (2, 0, 1, 3)).reshape(k, oc)
-    y = matmul.pallas_matmul(cols.reshape(-1, k), w2,
-                             out_dtype=out_dtype or x.dtype)
-    return y.reshape(b, oh, ow, oc)
-
-
-def pallas_conv2d_grad_input(err, w, x_shape, stride=1, padding=0):
-    """Implicit-GEMM transposed conv (SURVEY.md §2.3 conv-grad row): the
-    interior-dilate + edge-pad of err is pure data movement (XLA pad),
-    the FLOPs run in the Pallas MXU matmul against the spatially-flipped
-    IO-swapped kernel."""
-    kh, kw, c, oc = w.shape
-    (sh, sw), (ph, pw) = _norm2(stride), _norm2(padding)
-    _, h, w_in, _ = x_shape
-    _, oh, ow, _ = err.shape
-    w_flip = jnp.transpose(w[::-1, ::-1, :, :], (0, 1, 3, 2))
-    lo_h, lo_w = kh - 1 - ph, kw - 1 - pw
-    hi_h = h + ph - ((oh - 1) * sh + 1)
-    hi_w = w_in + pw - ((ow - 1) * sw + 1)
-    ed = lax.pad(err, jnp.zeros((), err.dtype),
-                 ((0, 0, 0), (lo_h, hi_h, sh - 1),
-                  (lo_w, hi_w, sw - 1), (0, 0, 0)))
-    cols = lax.conv_general_dilated_patches(
-        ed, (kh, kw), (1, 1), ((0, 0), (0, 0)),
-        dimension_numbers=_DIMNUMS)          # (B, H, W, OC*KH*KW)
-    b, hh, ww, k = cols.shape
-    w2 = jnp.transpose(w_flip, (2, 0, 1, 3)).reshape(k, c)
-    dx = matmul.pallas_matmul(cols.reshape(-1, k), w2,
-                              out_dtype=jnp.float32)
-    return dx.reshape(b, hh, ww, c)
-
-
-def pallas_conv2d_grad_weights(x, err, w_shape, stride=1, padding=0):
-    """Implicit-GEMM weight grad: colsᵀ·err on the MXU — cols is the
-    same patch matrix as the forward, so dw = (B·OH·OW, C·KH·KW)ᵀ @
-    (B·OH·OW, OC), reshaped to (KH, KW, C, OC).  The transposed-lhs
-    kernel streams cols in its natural row-major layout (round-3 retile:
-    the old ``cols.T`` materialized an extra HBM copy of the ~KH·KW×
-    activation-sized patch matrix before the matmul)."""
-    kh, kw, c, oc = w_shape
-    (sh, sw), (ph, pw) = _norm2(stride), _norm2(padding)
-    cols = lax.conv_general_dilated_patches(
-        x, (kh, kw), (sh, sw), ((ph, ph), (pw, pw)),
-        dimension_numbers=_DIMNUMS)          # (B, OH, OW, C*KH*KW)
-    k = cols.shape[-1]
-    dw = matmul.pallas_matmul_at_b(cols.reshape(-1, k),
-                                   err.reshape(-1, oc),
-                                   out_dtype=jnp.float32)
-    return jnp.transpose(dw.reshape(c, kh, kw, oc), (1, 2, 0, 3))
-
-
-def conv2d(x, w, stride=1, padding=0, out_dtype=None):
-    """Dispatcher: XLA conv is the default production path on TPU (the
-    compiler's conv→MXU lowering beats implicit GEMM for most shapes);
-    set ZNICZ_TPU_CONV=pallas to force the Pallas GEMM tier, or
-    ZNICZ_TPU_CONV1=s2d to route tiny-C strided convs (conv1) through
-    the space-to-depth formulation."""
-    if tuning.force_pallas_conv():
-        return pallas_conv2d(x, w, stride, padding, out_dtype)
-    if tuning.conv_s2d() and s2d_applicable(w.shape, stride, padding):
-        return xla_conv2d_s2d(x, w, stride, padding, out_dtype)
-    return xla_conv2d(x, w, stride, padding, out_dtype)
-
-
-def conv2d_grad_input(err, w, x_shape, stride=1, padding=0):
-    if tuning.force_pallas_conv():
-        return pallas_conv2d_grad_input(err, w, x_shape, stride, padding)
-    return xla_conv2d_grad_input(err, w, x_shape, stride, padding)
-
-
-def conv2d_grad_weights(x, err, w_shape, stride=1, padding=0):
-    if tuning.force_pallas_conv():
-        return pallas_conv2d_grad_weights(x, err, w_shape, stride,
-                                          padding)
-    if tuning.conv_s2d() and s2d_applicable(w_shape, stride, padding):
-        return xla_conv2d_grad_weights_s2d(x, err, w_shape, stride,
-                                           padding)
-    return xla_conv2d_grad_weights(x, err, w_shape, stride, padding)
+#: the one tier of each direction on an XLA device, under the names the
+#: fused step and ``nn/`` call
+conv2d = xla_conv2d
+conv2d_grad_input = xla_conv2d_grad_input
+conv2d_grad_weights = xla_conv2d_grad_weights

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import conv as conv_ops, tuning
+from . import conv as conv_ops
 from .geometry import norm2 as _norm2
 
 
@@ -88,45 +88,8 @@ def xla_deconv2d_grad_weights(err, x, w_shape, stride=1, padding=0):
                                             padding)
 
 
-# -- Pallas tier (SURVEY.md §2.3 "deconv/depooling kernels" row) -----------
-# Deconv inherits conv's implicit-GEMM Pallas kernels through the same
-# adjoint mapping as the numpy/XLA tiers: every tier of every deconv op
-# is one conv op with roles swapped, so the Pallas MXU matmul does the
-# FLOPs for all three directions.
-
-def pallas_deconv2d(x, w, stride=1, padding=0, out_dtype=None):
-    out_shape = deconv_out_shape(x.shape, w.shape, stride, padding)
-    y = conv_ops.pallas_conv2d_grad_input(x, w, out_shape, stride,
-                                          padding)
-    return y.astype(out_dtype or x.dtype)
-
-
-def pallas_deconv2d_grad_input(err, w, stride=1, padding=0):
-    return conv_ops.pallas_conv2d(err, w, stride, padding,
-                                  out_dtype=np.float32)
-
-
-def pallas_deconv2d_grad_weights(err, x, w_shape, stride=1, padding=0):
-    return conv_ops.pallas_conv2d_grad_weights(err, x, w_shape, stride,
-                                               padding)
-
-
-def deconv2d(x, w, stride=1, padding=0, out_dtype=None):
-    """Dispatcher mirroring ``conv_ops.conv2d`` (XLA default on TPU;
-    ZNICZ_TPU_CONV=pallas forces the implicit-GEMM tier)."""
-    if tuning.force_pallas_conv():
-        return pallas_deconv2d(x, w, stride, padding, out_dtype)
-    return xla_deconv2d(x, w, stride, padding, out_dtype)
-
-
-def deconv2d_grad_input(err, w, stride=1, padding=0):
-    if tuning.force_pallas_conv():
-        return pallas_deconv2d_grad_input(err, w, stride, padding)
-    return xla_deconv2d_grad_input(err, w, stride, padding)
-
-
-def deconv2d_grad_weights(err, x, w_shape, stride=1, padding=0):
-    if tuning.force_pallas_conv():
-        return pallas_deconv2d_grad_weights(err, x, w_shape, stride,
-                                            padding)
-    return xla_deconv2d_grad_weights(err, x, w_shape, stride, padding)
+#: the one tier of each direction on an XLA device, under the names the
+#: fused step and ``nn/`` call
+deconv2d = xla_deconv2d
+deconv2d_grad_input = xla_deconv2d_grad_input
+deconv2d_grad_weights = xla_deconv2d_grad_weights

@@ -119,72 +119,6 @@ def pallas_matmul(x, w, block_m: int = 128, block_n: int = 128,
     return out[:m, :n]
 
 
-def _matmul_at_b_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_m: int, cast):
-    mm = pl.program_id(2)
-
-    @pl.when(mm == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    a, b = a_ref[:], b_ref[:]
-    if cast is not None:
-        a, b = a.astype(cast), b.astype(cast)
-    # contract over the shared ROW dim of both operands (AᵀB) — the MXU
-    # takes the transposed-lhs dimension numbers directly; no HBM-side
-    # transpose of A ever exists
-    acc_ref[:] += jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(mm == n_m - 1)
-    def _flush():
-        o_ref[:] = acc_ref[:].astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_k", "block_n",
-                                             "block_m", "out_dtype"))
-def pallas_matmul_at_b(a, b, block_k: int = 128, block_n: int = 128,
-                       block_m: int = 512, out_dtype=None):
-    """``aᵀ @ b`` for row-major ``a (M, K)`` and ``b (M, N)`` → (K, N),
-    WITHOUT materializing ``aᵀ`` in HBM.
-
-    This is the conv weight-gradient shape: ``a`` is the implicit-GEMM
-    patch matrix (B·OH·OW rows — huge), and transposing it before a
-    plain matmul costs a full extra HBM pass over ~KH·KW× the activation
-    bytes.  Here the M rows are the innermost (sequential) grid axis:
-    each (K, N) output tile accumulates over row blocks streamed in
-    their natural layout."""
-    m, k = a.shape
-    m2, n = b.shape
-    assert m == m2, (a.shape, b.shape)
-    out_dtype = out_dtype or a.dtype
-    bk = min(block_k, tuning.round_up(k, 128))
-    bn = min(block_n, tuning.round_up(n, 128))
-    bm = min(block_m, tuning.round_up(m, 128))
-    kp, np_, mp = (tuning.round_up(k, bk), tuning.round_up(n, bn),
-                   tuning.round_up(m, bm))
-    if (mp, kp) != (m, k):
-        a = jnp.pad(a, ((0, mp - m), (0, kp - k)))
-    if (mp, np_) != (m, n):
-        b = jnp.pad(b, ((0, mp - m), (0, np_ - n)))
-    grid = (kp // bk, np_ // bn, mp // bm)
-    out = pl.pallas_call(
-        functools.partial(_matmul_at_b_kernel, n_m=grid[2],
-                          cast=_mxu_cast(a.dtype)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, mm: (mm, i)),
-            pl.BlockSpec((bm, bn), lambda i, j, mm: (mm, j)),
-        ],
-        out_specs=pl.BlockSpec((bk, bn), lambda i, j, mm: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
-        out_shape=jax.ShapeDtypeStruct((kp, np_), out_dtype),
-        name="pallas_matmul_at_b",
-        interpret=tuning.interpret_mode(),
-    )(a, b)
-    return out[:k, :n]
-
-
 def matmul(x, w, out_dtype=None):
     """Dispatching matmul for jax arrays: Pallas on TPU, XLA otherwise."""
     if tuning.use_pallas() and x.ndim == 2 and w.ndim == 2:

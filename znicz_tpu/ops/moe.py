@@ -366,10 +366,3 @@ def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
         "moe_expert_load_max": jnp.max(counts),
         "moe_rows_moved": moved}
     return x + out.reshape(b, t, d), counters
-
-
-#: how a layer's counters fold into a step's, and a step's into an
-#: epoch's
-COUNTER_FOLDS = {"moe_assignments": "sum", "moe_assignments_held": "sum",
-                 "moe_expert_load_max": "max", "moe_rows_moved": "sum",
-                 "tokens": "sum"}

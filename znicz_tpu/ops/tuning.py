@@ -83,80 +83,6 @@ def batch_sharded(fn, *arrays):
                          check_vma=False)(*arrays)
 
 
-def lrn_pool_merge() -> bool:
-    """Whether extract_model merges adjacent LRN + max-pool layers into
-    the fused pair op (ops/lrn_pool.py).  ZNICZ_TPU_LRN_POOL=split
-    disables the merge (A/B lever; read per call so bench can toggle)."""
-    return os.environ.get("ZNICZ_TPU_LRN_POOL", "fused") != "split"
-
-
-def lrn_pool_split_conv() -> bool:
-    """Phase-2 (the default; ZNICZ_TPU_LRN_POOL=fused2): the conv
-    feeding a folded pair emits the column-parity halves DIRECTLY (two
-    stride-doubled convs) and consumes the pair's split gradient halves
-    — removing the pair forward's split pass and the backward's
-    interleave.
-
-    The default rests on one on-chip b128 ablation from 2026-07-31,
-    taken on an earlier JAX; it has not been re-measured on this code
-    (ROADMAP Speed 2 owns the fused1-vs-fused2 ruling).  The parity
-    convs are allclose (atol 1e-5), not bit-equal, to the plain conv.
-    ``fused1`` names phase-1 explicitly (merge + fold, plain convs);
-    the bit-equality tests stay pinned to it.  An EXPLICIT ``fused``
-    keeps its historical phase-1 meaning so a recorded lever line
-    reproduces the routing its row claims — only the UNSET default is
-    fused2."""
-    v = os.environ.get("ZNICZ_TPU_LRN_POOL")
-    return v is None or v == "fused2"
-
-
-def resolved_routing() -> dict:
-    """The EFFECTIVE kernel-routing configuration, independent of which
-    values came from env levers and which from defaults.  bench.py
-    stamps this into every transcript row so tools/decide_levers.py can
-    compare configurations across default flips — a row tagged only
-    with explicit env levers silently changes meaning when a default
-    changes (exactly what round 5's fused2 flip did to "default" rows).
-    """
-    return {
-        "LRN_POOL": ("split" if not lrn_pool_merge() else
-                     "nofold" if not lrn_pool_act_fold() else
-                     "fused2" if lrn_pool_split_conv() else "fused1"),
-        "CONV1": "s2d" if conv_s2d() else "direct",
-        "CONV": "pallas" if force_pallas_conv() else "xla",
-        "PALLAS": ("off" if os.environ.get("ZNICZ_TPU_NO_PALLAS", "0")
-                   == "1" else "on"),
-        "MXU": os.environ.get("ZNICZ_TPU_MXU", "").lower() or "bf16",
-    }
-
-
-def lrn_pool_act_fold() -> bool:
-    """Whether the merge also folds the preceding conv's activation
-    derivative into the pair backward.  ZNICZ_TPU_LRN_POOL=nofold keeps
-    the merge but skips the fold AND, with it, the split-halves cache
-    (which is only correct when nothing downstream needs the unsplit
-    x — i.e. when the fold is on), so the --ablate row measures the two
-    together against the plain merge."""
-    return os.environ.get("ZNICZ_TPU_LRN_POOL", "fused") != "nofold"
-
-
-def conv_s2d() -> bool:
-    """ZNICZ_TPU_CONV1=s2d routes tiny-C strided convs (AlexNet's
-    conv1) through the space-to-depth formulation (ops/conv.py
-    xla_conv2d_s2d): the stride folds into the channel axis, lifting
-    MXU lane utilization s²× on a layer whose C=3 occupies 3/128 lanes
-    natively.  Opt-in (allclose, not bit-equal, to the plain conv);
-    the --ablate row ``conv1_s2d`` measures it on-chip."""
-    return os.environ.get("ZNICZ_TPU_CONV1") == "s2d"
-
-
-def force_pallas_conv() -> bool:
-    """Whether ZNICZ_TPU_CONV=pallas routes the conv/deconv family to
-    the implicit-GEMM Pallas tier (default: XLA's native conv lowering;
-    ROADMAP D2 owns the re-measurement)."""
-    return os.environ.get("ZNICZ_TPU_CONV") == "pallas" and use_pallas()
-
-
 # dtype → (sublane, lane) minimum tile (pallas_guide.md tiling table)
 _MIN_TILE = {
     jnp.float32: (8, 128),
@@ -173,12 +99,12 @@ def round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
-#: Per-operand VMEM budget for elementwise block sizing (bytes).
-#: Default measured on v5e (2026-07-30 A/B, AlexNet batch 256): 256-row
-#: blocks (128 KiB) beat 2048-row blocks by ~14% — the short-block
-#: pipeline hides HBM latency better than big transfers, so the budget
-#: floor is the sweet spot.  Raise via env to re-run the experiment.
-_VMEM_BUDGET = int(os.environ.get("ZNICZ_TPU_VMEM_BUDGET", 768 * 1024))
+#: Per-operand VMEM budget for elementwise block sizing (bytes).  Measured
+#: on v5e (2026-07-30 A/B, AlexNet batch 256): 256-row blocks (128 KiB)
+#: beat 2048-row blocks by ~14% — the short-block pipeline hides HBM
+#: latency better than big transfers, so the budget floor is the sweet
+#: spot.
+_VMEM_BUDGET = 768 * 1024
 
 
 def block_rows(n_operands: int, lanes: int = 128, dtype_bytes: int = 4,

@@ -47,6 +47,8 @@ def initialize(coordinator: str | None = None,
     worker the ElasticRunner would only restart anyway."""
     from ..resilience import faults
     from ..resilience.retry import RetryPolicy
+    # a deployment address, not a route of the step
+    # zlint: disable=env-routing
     coordinator = coordinator or os.environ.get("JAX_COORDINATOR_ADDRESS")
     if coordinator is None:
         return   # single-process: nothing to negotiate

@@ -35,6 +35,7 @@ from ..ops import (activations, attention as attn_ops, conv as conv_ops,
                    normalization as lrn_ops, pooling as pool_ops,
                    softmax as softmax_ops, tuning)
 from ..telemetry import compilestats, tracing
+from ..telemetry.registry import REGISTRY
 from . import mesh as mesh_lib
 
 #: The znicz kinds with trainable parameters: a ``(w, b)`` pair and a
@@ -136,10 +137,11 @@ def sequence_layer(unit, hypers: tuple) -> LayerSpec:
                      config=tuple(sorted(unit.fused_config().items())))
 
 
-def extract_model(workflow) -> tuple[ModelSpec, list, list]:
-    """Read (spec, params, velocities) out of an initialized
-    StandardWorkflow.  params/velocities: one tuple of numpy leaves a
-    layer: ``(w, b)`` for the znicz kinds (``(None, None)`` for
+def workflow_rows(workflow) -> tuple[list, list, list]:
+    """(rows, params, velocities) of an initialized StandardWorkflow: one
+    ``LayerSpec`` a forward unit, as the unit graph has them, before any
+    rewrite (``extract_model``).  params/velocities: one tuple of numpy
+    leaves a layer: ``(w, b)`` for the znicz kinds (``(None, None)`` for
     parameter-less layers), the unit's ``LEAVES`` for a sequence kind
     (device arrays where the unit is on an XLA device)."""
     from ..nn import activation as act_units
@@ -281,91 +283,111 @@ def extract_model(workflow) -> tuple[ModelSpec, list, list]:
         else:
             params.append((None, None))
             vels.append((None, None))
-    loss = workflow.loss_function
-    layers, params, vels, unit_index = _merge_lrn_pool(layers, params,
-                                                       vels)
-    return (ModelSpec(tuple(layers), loss, unit_index=unit_index),
-            params, vels)
+    return layers, params, vels
 
 
-def _merge_lrn_pool(layers, params, vels):
-    """Collapse adjacent (lrn, max_pool|maxabs_pool) pairs into the fused
-    ``lrn_pool`` kind (ops/lrn_pool.py: one HBM pass per direction, the
-    round-2 ablation's ~39%-of-step lever).  Bit-identical to the split
-    layers by construction (same window math, same flat tap order), so
-    the merge is on by default; ZNICZ_TPU_LRN_POOL=split keeps the split
-    layers (A/B lever).  ``tie`` indices (weight-tied deconv, depooling)
-    are remapped; a depooling tied to a merged pool keeps working — the
+def extract_model(workflow) -> tuple[ModelSpec, list, list]:
+    """Read (spec, params, velocities) out of an initialized
+    StandardWorkflow: its rows (``workflow_rows``) through the three
+    rewrites below, in this order.  Which rows merge, fold and split
+    follows from the layer list alone."""
+    layers, params, vels = workflow_rows(workflow)
+    return model_of_rows(
+        split_pair_conv(fold_pair_act(merge_lrn_pool(layers))),
+        params, vels, workflow.loss_function)
+
+
+def model_of_rows(rows, params, vels, loss: str
+                  ) -> tuple[ModelSpec, list, list]:
+    """(spec, params, velocities) of ``workflow_rows``' rows after any of
+    the rewrites, with that call's params and velocities.  A merged
+    ``lrn_pool`` row stands for two forward units and every other row for
+    one: ``unit_index`` names each row's first (a merged row's LRN; both
+    of its units are parameter-less)."""
+    index, unit = [], 0
+    for la in rows:
+        index.append(unit)
+        unit += 2 if la.kind == "lrn_pool" else 1
+    return (ModelSpec(tuple(rows), loss, unit_index=tuple(index)),
+            [params[i] for i in index], [vels[i] for i in index])
+
+
+def _with_config(layer: LayerSpec, **items) -> LayerSpec:
+    return dataclasses.replace(
+        layer, config=tuple(sorted({**layer.cfg, **items}.items())))
+
+
+def merge_lrn_pool(layers) -> list:
+    """Rewrite (i): adjacent (lrn, max_pool|maxabs_pool) rows that
+    ``lrn_pool_ops.fusable`` admits become one ``lrn_pool`` row
+    (ops/lrn_pool.py: one HBM pass per direction).  Bit-identical to the
+    split layers by construction (same window math, same flat tap
+    order).  ``tie`` indices (weight-tied deconv, depooling) are
+    remapped; a depooling tied to a merged pool keeps working — the
     merged layer's aux IS the pool's winner-offset tensor."""
-    from ..ops import tuning
-    identity = tuple(range(len(layers)))
-    if not tuning.lrn_pool_merge():
-        return layers, params, vels, identity
-    out_l, out_p, out_v = [], [], []
-    src = []          # spec row → ORIGINAL forwards index (write_back)
-    idx_map = {}
+    out, idx_map = [], {}
     i = 0
     while i < len(layers):
         la = layers[i]
+        idx_map[i] = len(out)
         if (i + 1 < len(layers) and la.kind == "lrn"
                 and layers[i + 1].kind in ("max_pool", "maxabs_pool")
                 and lrn_pool_ops.fusable(layers[i + 1].cfg["ksize"],
                                          layers[i + 1].cfg["stride"],
                                          layers[i + 1].cfg["padding"])):
             pool = layers[i + 1]
-            cfg = dict(la.config)
-            cfg.update(pool.config)
-            cfg["use_abs"] = pool.kind == "maxabs_pool"
-            merged = LayerSpec(
-                kind="lrn_pool", activation="linear", include_bias=False,
-                hypers=la.hypers, hypers_bias=la.hypers_bias,
-                config=tuple(sorted(cfg.items())))
-            # fold the PRECEDING conv's activation derivative into the
-            # pair backward when its bwd needs only y (y is the pair's
-            # input, already in the kernel's VMEM) — kills the separate
-            # elementwise sweep over the net's biggest dx tensor
-            if out_l and out_l[-1].kind in ("conv", "deconv") \
-                    and tuning.lrn_pool_act_fold():
-                act = activations.BY_NAME[out_l[-1].activation]
-                if out_l[-1].activation != "linear" \
-                        and not act.needs_input:
-                    cfg["fold_act"] = out_l[-1].activation
-                    prev_cfg = dict(out_l[-1].config, act_folded=True)
-                    # phase-2 (opt-in): the conv emits the parity
-                    # halves directly and takes split gradients back
-                    if out_l[-1].kind == "conv" \
-                            and tuning.lrn_pool_split_conv():
-                        prev_cfg["split_out"] = True
-                        cfg["emit_split"] = True
-                    out_l[-1] = dataclasses.replace(
-                        out_l[-1],
-                        config=tuple(sorted(prev_cfg.items())))
-                    merged = dataclasses.replace(
-                        merged, config=tuple(sorted(cfg.items())))
-            idx_map[i] = len(out_l)
-            idx_map[i + 1] = len(out_l)   # ties to the pool → merged
-            out_l.append(merged)
-            out_p.append((None, None))
-            out_v.append((None, None))
-            src.append(i)                 # paramless: index is nominal
+            idx_map[i + 1] = len(out)     # ties to the pool → merged
+            out.append(_with_config(
+                LayerSpec(kind="lrn_pool", activation="linear",
+                          include_bias=False, hypers=la.hypers,
+                          hypers_bias=la.hypers_bias, config=la.config),
+                **pool.cfg, use_abs=pool.kind == "maxabs_pool"))
             i += 2
         else:
-            idx_map[i] = len(out_l)
-            out_l.append(la)
-            out_p.append(params[i])
-            out_v.append(vels[i])
-            src.append(i)
+            out.append(la)
             i += 1
-    if len(out_l) == len(layers):
-        return layers, params, vels, identity
-    remapped = []
-    for la in out_l:
-        cfg = la.cfg
-        if "tie" in cfg:
-            cfg["tie"] = idx_map[cfg["tie"]]
-            la = dataclasses.replace(la, config=tuple(sorted(cfg.items())))
-        remapped.append(la)
-    return remapped, out_p, out_v, tuple(src)
+    return [_with_config(la, tie=idx_map[la.cfg["tie"]])
+            if "tie" in la.cfg else la for la in out]
+
+
+def fold_pair_act(layers) -> list:
+    """Rewrite (ii): a conv or deconv row directly before a merged pair
+    hands its activation's derivative to the pair's backward (``fold_act``
+    on the pair, ``act_folded`` on the row) when that derivative needs
+    only y — y is the pair's input, already in the kernel's VMEM, so the
+    separate elementwise sweep over the net's biggest dx tensor goes.
+    Bit-identical where the derivative is a mask (strict_relu); a
+    value-dependent one (tanh) is the same arithmetic inside another
+    fusion and equal to float32 rounding.  A pair after a linear row, or
+    after no conv, keeps the unfolded kernels."""
+    out = list(layers)
+    for i in range(1, len(out)):
+        prev = out[i - 1]
+        if out[i].kind != "lrn_pool" or prev.kind not in ("conv", "deconv"):
+            continue
+        if prev.activation == "linear" \
+                or activations.BY_NAME[prev.activation].needs_input:
+            continue
+        out[i - 1] = _with_config(prev, act_folded=True)
+        out[i] = _with_config(out[i], fold_act=prev.activation)
+    return out
+
+
+def split_pair_conv(layers) -> list:
+    """Rewrite (iii): a conv row that (ii) folded emits the pair's
+    column-parity halves directly (two stride-doubled convs,
+    ``split_out``) and takes the pair's split gradient halves back
+    (``emit_split`` on the pair): the pair forward's split pass and the
+    backward's interleave go.  The parity convs are allclose (atol 1e-5),
+    not bit-equal, to the plain conv.  A folded deconv stays whole."""
+    out = list(layers)
+    for i in range(1, len(out)):
+        prev = out[i - 1]
+        if prev.kind == "conv" and prev.cfg.get("act_folded") \
+                and "fold_act" in out[i].cfg:
+            out[i - 1] = _with_config(prev, split_out=True)
+            out[i] = _with_config(out[i], emit_split=True)
+    return out
 
 
 # -- names in the device trace ----------------------------------------------
@@ -373,7 +395,7 @@ def layer_label(spec: ModelSpec, i: int) -> str:
     """``L<unit>.<kind>`` of spec row ``i``: ``unit`` is the index of the
     first workflow forward unit the row stands for (a merged ``lrn_pool``
     row names its LRN), so a trace joins to the configuration's layer
-    list whatever ``_merge_lrn_pool`` did to the rows."""
+    list whatever ``merge_lrn_pool`` did to the rows."""
     unit = spec.unit_index[i] if spec.unit_index else i
     return f"L{unit:02d}.{spec.layers[i].kind}"
 
@@ -438,12 +460,42 @@ def _sequence_call(spec: ModelSpec, layer: LayerSpec):
                              cdt=jnp.dtype(spec.compute_dtype))
 
 
+#: The step's device counters, the one table of them: name -> (how a
+#: layer's value folds into a step's and a step's into an epoch's, the
+#: gauge that holds the last epoch's fold).  The name is the field of the
+#: ``train_step`` row.  A sequence kind's forward hands back its own
+#: beside its output (``ops/moe.moe_block_fwd``); ``tokens`` is counted in
+#: ``_step_metrics``.  A gauge is made when a model first counts, so that
+#: no other model shows it at 0.
+COUNTERS = {
+    "tokens": ("sum", lambda: REGISTRY.gauge(
+        "train_tokens",
+        "targets trained in the last epoch (token-sequence models)")),
+    "moe_assignments": ("sum", lambda: REGISTRY.gauge(
+        "train_moe_assignments",
+        "token-expert pairs the routers chose in the last epoch, all "
+        "expert layers")),
+    "moe_assignments_held": ("sum", lambda: REGISTRY.gauge(
+        "train_moe_assignments_held",
+        "those of train_moe_assignments whose expert this chip holds")),
+    "moe_expert_load_max": ("max", lambda: REGISTRY.gauge(
+        "train_moe_expert_load_max",
+        "most pairs on one held expert in one layer of one step of the "
+        "last epoch")),
+    "moe_rows_moved": ("sum", lambda: REGISTRY.gauge(
+        "train_moe_rows_moved",
+        "rows of the sorted pieces the expert layers moved in the last "
+        "epoch; over train_moe_assignments_held: 1.5 with a quarter held "
+        "and no later piece run")),
+}
+
+
 def _fold_counters(into: dict, new: dict) -> None:
-    """One layer's counters into the step's (``ops/moe.COUNTER_FOLDS``)."""
+    """One layer's counters into the step's."""
     for name, value in new.items():
         if name not in into:
             into[name] = value
-        elif moe_ops.COUNTER_FOLDS[name] == "max":
+        elif COUNTERS[name][0] == "max":
             into[name] = jnp.maximum(into[name], value)
         else:
             into[name] = into[name] + value

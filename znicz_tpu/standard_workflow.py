@@ -354,7 +354,6 @@ class StandardWorkflowBase(AcceleratedWorkflow):
         from .config import root
 
         from .loader.base import TEST, TRAIN, VALID
-        from .ops.moe import COUNTER_FOLDS
         from .parallel import FusedTrainer, fused
 
         assert self.initialized, "initialize() first"
@@ -593,7 +592,7 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             # the step's device counters (a model with the sequence
             # kinds only), folded over the epoch's training rows
             counted = {
-                name: int(getattr(np, COUNTER_FOLDS[name])(
+                name: int(getattr(np, fused.COUNTERS[name][0])(
                     np.concatenate([np.ravel(tm.get(name, ())),
                                     np.ravel(em_tail[name])])))
                 for name in em_tail if name not in ("loss", "n_err")}
@@ -639,10 +638,8 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                         "examples_per_sec": (round(n_train / epoch_s, 1)
                                              if epoch_s > 0 else None),
                         **parts, "prev_tail_ms": prev_tail_ms, **counted}
-            if counted:
-                gauges = _counter_gauges()
-                for name, value in counted.items():
-                    gauges[name].set(value)
+            for name, value in counted.items():
+                fused.COUNTERS[name][1]().set(value)
             _flightrecorder.RECORDER.record(
                 "train_step", duration_ms=epoch_s * 1e3, **step_row)
             if timeline is not None:
@@ -724,33 +721,6 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             else:
                 ckpt.wait()
         return trainer
-
-
-def _counter_gauges() -> dict:
-    """The fused step's device counters as gauges, an epoch's fold each
-    (``ops/moe.COUNTER_FOLDS``); made when a model with the
-    token-sequence kinds first counts, so that no other model shows
-    them at 0."""
-    return {
-        "tokens": REGISTRY.gauge(
-            "train_tokens",
-            "targets trained in the last epoch (token-sequence models)"),
-        "moe_assignments": REGISTRY.gauge(
-            "train_moe_assignments",
-            "token-expert pairs the routers chose in the last epoch, all "
-            "expert layers"),
-        "moe_assignments_held": REGISTRY.gauge(
-            "train_moe_assignments_held",
-            "those of train_moe_assignments whose expert this chip holds"),
-        "moe_expert_load_max": REGISTRY.gauge(
-            "train_moe_expert_load_max",
-            "most pairs on one held expert in one layer of one step of "
-            "the last epoch"),
-        "moe_rows_moved": REGISTRY.gauge(
-            "train_moe_rows_moved",
-            "rows of the sorted pieces the expert layers moved in the last "
-            "epoch; over train_moe_assignments_held: 1.5 with a quarter "
-            "held and no later piece run")}
 
 
 def sample_snapshotter_config(tree, explicit):
