@@ -109,12 +109,11 @@ def measure_fused(wf, epochs: int, warm: int = 2, dtype: str | None = None,
     from znicz_tpu.parallel import fused, FusedTrainer
     from znicz_tpu.parallel.mesh import mesh_shape_of, resolve_mesh
 
-    spec, params, vels = fused.extract_model(wf)
+    mesh = resolve_mesh(mesh)
+    spec, params, vels = fused.extract_model(wf, mesh,
+                                             storage or "float32")
     if dtype and dtype != spec.compute_dtype:
         spec = dataclasses.replace(spec, compute_dtype=dtype)
-    if storage and storage != spec.storage_dtype:
-        spec = dataclasses.replace(spec, storage_dtype=storage)
-    mesh = resolve_mesh(mesh)
     dp, tp = mesh_shape_of(mesh)
     n_devices = dp * tp
     tr = FusedTrainer(spec=spec, params=params, vels=vels, mesh=mesh)
@@ -155,11 +154,10 @@ def measure_stream(wf, epochs: int, warm: int = 2,
     from znicz_tpu.parallel.stream import StreamTrainer
     from znicz_tpu.workflow import Workflow
 
-    spec, params, vels = fused.extract_model(wf)
+    spec, params, vels = fused.extract_model(
+        wf, storage_dtype=storage or "float32")
     if dtype and dtype != spec.compute_dtype:
         spec = dataclasses.replace(spec, compute_dtype=dtype)
-    if storage and storage != spec.storage_dtype:
-        spec = dataclasses.replace(spec, storage_dtype=storage)
     ld = wf.loader
     n = ld.class_lengths[2]
     data = np.asarray(ld.original_data.mem)
@@ -1164,6 +1162,19 @@ def _kernel_cases():
              xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0)[0], "close"),
         ("gd_lrn_maxpool",
          lambda: lrn_pool_ops.pallas_gd_lrn_maxpool(
+             elp, olp, xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0),
+         lambda: lrn_pool_ops.xla_gd_lrn_maxpool(
+             elp, olp, xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0),
+         "close"),
+        # the same pair on the convolutions' own layout (PR 33): what a
+        # float32 batch that is a multiple of 8 runs
+        ("lrn_maxpool_window",
+         lambda: lrn_pool_ops.pallas_lrn_maxpool_window(
+             xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0)[0],
+         lambda: lrn_pool_ops.xla_lrn_maxpool(
+             xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0)[0], "close"),
+        ("gd_lrn_maxpool_window",
+         lambda: lrn_pool_ops.pallas_gd_lrn_maxpool_window(
              elp, olp, xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0),
          lambda: lrn_pool_ops.xla_gd_lrn_maxpool(
              elp, olp, xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0),

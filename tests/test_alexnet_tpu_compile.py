@@ -1,17 +1,22 @@
-"""The four longest operations of ``alexnet-b128-train`` and pool5's two
-kernels, compiled for a described v5e at the cell's shapes: the merged
-LRN+pool pair's forward and backward over the column-parity halves the
-split convs hand it (128x55x55x96 and 128x27x27x256), and pool5's
-select and scatter (128x13x13x256, 3x3/2, the tap stack).  The TPU's own
-Mosaic and XLA compilers run here, with no chip, and refuse what the
-chip would refuse (an access Mosaic cannot lower, a block over the
-scoped VMEM): what ``bench.py --kernels`` showed only on a chip.
-Nothing runs, so this says nothing of results or times.
+"""The longest Pallas operations of ``alexnet-b128-train`` and pool5's
+two kernels, compiled for a described v5e at the cell's shapes: the
+merged LRN+pool pair's forward and backward on the convolutions' own
+layout (the window kernels the cell runs since PR 33, 128x55x55x96 and
+128x27x27x256), the same over the column-parity halves (what a batch
+that is no multiple of 8 still runs), pool5's select and scatter
+(128x13x13x256, 3x3/2, the tap stack), and a conv -> pair -> conv ->
+pair step whose text must show no layout copy beside the window
+kernels.  The TPU's own Mosaic and XLA compilers run here, with no
+chip, and refuse what the chip would refuse (an access Mosaic cannot
+lower, a block over the scoped VMEM): what ``bench.py --kernels`` showed
+only on a chip.  Nothing runs, so this says nothing of results or
+times.
 
 The topology is described inside a fixture, never at import: only the
 worker that is given this file loads the TPU's library."""
 
 import os
+import re
 
 import pytest
 
@@ -92,6 +97,90 @@ def test_pair_backward_compiles_for_a_v5e(one_chip, mosaic, layer):
             return_split=True), err, idx, *_halves(shape, one_chip))
     assert "tpu_custom_call" in text
     assert "pallas_gd_lrn_maxpool_split" in text
+
+
+@pytest.mark.parametrize("layer", sorted(PAIRS))
+def test_window_forward_compiles_for_a_v5e(one_chip, mosaic, layer):
+    shape = PAIRS[layer]
+    assert lrn_pool.windowed(shape, *POOL)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _compiled_text(lambda x: lrn_pool.lrn_maxpool(x, *LRN, *POOL), x)
+    assert "tpu_custom_call" in text
+    assert "pallas_lrn_maxpool_window" in text
+    assert "pallas_lrn_maxpool_split" not in text
+
+
+@pytest.mark.parametrize("layer", sorted(PAIRS))
+def test_window_backward_compiles_for_a_v5e(one_chip, mosaic, layer):
+    """As the step calls it: the conv's strict ReLU folded in."""
+    shape = PAIRS[layer]
+    pooled = pooling.pool_out_shape(shape, *POOL)
+    err = jax.ShapeDtypeStruct(pooled, jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct(pooled, jnp.int32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _compiled_text(
+        lambda e, i, x: lrn_pool.gd_lrn_maxpool(
+            e, i, x, *LRN, *POOL, fold_act="strict_relu"), err, idx, x)
+    assert "tpu_custom_call" in text
+    assert "pallas_gd_lrn_maxpool_window" in text
+    assert "pallas_gd_lrn_maxpool_split" not in text
+
+
+def test_no_layout_copy_stands_beside_the_window_kernels(one_chip, mosaic):
+    """The window kernels work on (H, W, B, C), the layout XLA's TPU
+    convolutions emit and take: in a compiled conv -> pair -> conv ->
+    pair -> conv step (AlexNet's head at its widths, the convs whole as
+    ``extract_model`` leaves them at this batch) the kernels must take
+    the convs' own fusions, with no float32 copy, transpose or
+    ``dynamic-update-slice`` of a pair's input, output or gradient —
+    PR 32 measured what such passes cost when XLA makes them (13 ms a
+    step, PERF.md section 6)."""
+    from znicz_tpu.parallel import fused
+    hyp = (0.01, 0.0005, 0.0, 0.9)
+
+    def conv(stride, padding, folded=True):
+        return fused.LayerSpec("conv", "strict_relu", True, hyp, hyp, tuple(
+            sorted({"stride": stride, "padding": padding,
+                    **({"act_folded": True} if folded else {})}.items())))
+    pair = fused.LayerSpec("lrn_pool", "linear", False, hyp, hyp, tuple(
+        sorted(dict(zip(("n", "alpha", "beta", "k"), LRN),
+                    ksize=POOL[0], stride=POOL[1], padding=(0, 0),
+                    use_abs=False, fold_act="strict_relu").items())))
+    spec = fused.ModelSpec(
+        (conv((4, 4), (0, 0)), pair, conv((1, 1), (2, 2)), pair,
+         conv((1, 1), (1, 1), folded=False),
+         fused.LayerSpec("fc", "linear", True, hyp, hyp)), "softmax")
+    b = 128
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = [(shaped(11, 11, 3, 96), shaped(96)), (None, None),
+              (shaped(5, 5, 96, 256), shaped(256)), (None, None),
+              (shaped(3, 3, 256, 32), shaped(32)),
+              (shaped(13 * 13 * 32, 16), shaped(16))]
+
+    def steps(params, vels, data, labels):
+        def body(carry, batch):
+            p, v, _ = fused.train_minibatch(
+                spec, *carry, batch[0].astype(jnp.float32), batch[1])
+            return (p, v), None
+        return jax.lax.scan(body, (params, vels), (data, labels))[0]
+    text = _compiled_text(steps, params, params,
+                          shaped(2, b, 227, 227, 3, dtype=jnp.bfloat16),
+                          shaped(2, b, dtype=jnp.int32))
+    assert text.count("pallas_lrn_maxpool_window") >= 2
+    assert text.count("pallas_gd_lrn_maxpool_window") >= 2
+    assert "maxpool_split" not in text
+    # the pairs' inputs, outputs and gradients, in either dim order
+    paired = {f"f32[{dims}]" for h, c in ((55, 96), (27, 96), (27, 256),
+                                          (13, 256))
+              for dims in (f"{b},{h},{h},{c}", f"{h},{h},{b},{c}")}
+    for op in ("copy", "transpose", "dynamic-update-slice"):
+        moved = re.findall(r"= (f32\[[\d,]*\])\{[^}]*\} " + op + r"\(",
+                           text)
+        assert not paired & set(moved), (op, sorted(paired & set(moved)))
+    assert re.findall(r"= (f32\[[\d,]*\])\{[^}]*\} copy\(", text), \
+        "no copy at all: the pattern no longer matches"
 
 
 def test_pool5_select_compiles_for_a_v5e(one_chip, mosaic):

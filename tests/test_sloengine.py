@@ -461,19 +461,30 @@ class TestHttpSurfaces:
 
     def test_flightrecorder_model_filter_and_device_stage(
             self, served_zoo):
+        import time
         server, zoo = served_zoo
+        t0 = time.time()
         for _ in range(2):
             assert _post(server.url, {"inputs": X["mnist"].tolist()},
                          {"X-Model": "mnist"})[0] == 200
         assert _post(server.url,
                      {"inputs": X["wine"].tolist()})[0] == 200
-        snap = _get(server.url, "debug/flightrecorder?model=mnist")
+        # a request's record is written after its response is sent, and
+        # the process-wide recorder also holds what earlier tests' servers
+        # of the same worker left there: wait for THESE requests' records
+        for _ in range(100):
+            snap = _get(server.url, "debug/flightrecorder?model=mnist")
+            ok = [r for r in snap["recent"]
+                  if r["code"] == 200 and r["at"] >= t0]
+            if len(ok) >= 2 and RECORDER.stage_breakdown(
+                    model="wine")["requests"] >= 1:
+                break
+            time.sleep(0.02)
         assert snap["model"] == "mnist"
         assert snap["recent"], "model-scoped view lost the records"
         assert all(r["model"] == "mnist" for r in snap["recent"])
         # the per-request device-time share landed in the stages
-        ok = [r for r in snap["recent"] if r["code"] == 200]
-        assert ok and all(
+        assert len(ok) >= 2 and all(
             r["stages"].get("device_ms", 0) > 0 for r in ok)
         # recorder-level aggregation scopes to the tenant too
         agg = RECORDER.stage_breakdown(model="mnist")

@@ -10,6 +10,10 @@ whose instructions (name and result shape) it shares; an event found in
 none is ``<not joined>``.  Times come from the trace, so from a chip.
 
     python3 tools/trace_scopes.py trace_planes.json.gz TEXT... [--rows N]
+        [--units]
+
+``--units`` keeps a layer's unit number (``L03.conv``, not ``conv``):
+one line a layer of a model whose layers are few.
 """
 
 import collections
@@ -32,11 +36,11 @@ def instructions(text: str) -> dict:
             for name, result, _, rest in hlo_scope_bytes.instructions(text)}
 
 
-def scope_of(name: str, path: str) -> tuple:
+def scope_of(name: str, path: str, units: bool = False) -> tuple:
     phase = next((p for p in ("fwd", "bwd", "upd", "input", "loss", "accum")
                   if f"/{p}/" in path or path.endswith("/" + p)),
                  "<no scope>")
-    layer = re.search(r"/L\d+\.(\w+)", path)
+    layer = re.search(r"/(L\d+\.\w+)" if units else r"/L\d+\.(\w+)", path)
     inner = [s for s in INNER if f"/{s}/" in path or path.endswith("/" + s)]
     kernel = next((k for k in ("gmm", "splash") if k in name), "")
     return (phase, layer.group(1) if layer else "-",
@@ -45,6 +49,9 @@ def scope_of(name: str, path: str) -> tuple:
 
 def main(argv) -> int:
     args, rows = list(argv[1:]), 1.0
+    units = "--units" in args
+    if units:
+        args.remove("--units")
     if "--rows" in args:
         at = args.index("--rows")
         rows = float(args[at + 1])
@@ -73,7 +80,7 @@ def main(argv) -> int:
             text = texts[shared.index(max(shared))] if texts else {}
             for n, shape, d in events:
                 shape_there, path = text.get(n, ("", ""))
-                key = (scope_of(n, path) if shape_there == shape
+                key = (scope_of(n, path, units) if shape_there == shape
                        else ("<not joined>", module.split("(")[0], "-", "-"))
                 total[key] += d / 1e6
     whole = sum(total.values())

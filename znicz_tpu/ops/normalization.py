@@ -60,8 +60,11 @@ def _dpow_nbeta(d, beta, xp):
     return d ** (-beta)
 
 
-def _fwd(x, n, alpha, beta, k, xp):
-    s = _window_sum(x * x, n, xp)
+def _fwd(x, n, alpha, beta, k, xp, window_sum=_window_sum):
+    """``window_sum``: another lowering of :func:`_window_sum` with the
+    same terms in the same order (``ops/lrn_pool.py`` has one for its
+    Mosaic kernels), so the result is the same bits."""
+    s = window_sum(x * x, n, xp)
     d = k + alpha * s
     return x * _dpow_nbeta(d, beta, xp), d
 
@@ -75,10 +78,10 @@ def xla_lrn(x, n=5, alpha=1e-4, beta=0.75, k=2.0):
     return _fwd(x, n, alpha, beta, k, jnp)
 
 
-def _bwd(err, x, d, n, alpha, beta, xp):
+def _bwd(err, x, d, n, alpha, beta, xp, window_sum=_window_sum):
     p = _dpow_nbeta(d, beta, xp)
     q = err * x * (p / d)
-    return err * p - 2.0 * alpha * beta * x * _window_sum(q, n, xp)
+    return err * p - 2.0 * alpha * beta * x * window_sum(q, n, xp)
 
 
 def np_gd_lrn(err, x, d, n=5, alpha=1e-4, beta=0.75, k=2.0):
@@ -98,9 +101,9 @@ def xla_gd_lrn(err, x, d, n=5, alpha=1e-4, beta=0.75, k=2.0):
 # keeps the (y, denom) contract for parity with the reference's
 # LRNormalizerForward; the fused trainer uses these.
 
-def _bwd_recompute(err, x, n, alpha, beta, k, xp):
-    d = k + alpha * _window_sum(x * x, n, xp)
-    return _bwd(err, x, d, n, alpha, beta, xp)
+def _bwd_recompute(err, x, n, alpha, beta, k, xp, window_sum=_window_sum):
+    d = k + alpha * window_sum(x * x, n, xp)
+    return _bwd(err, x, d, n, alpha, beta, xp, window_sum)
 
 
 def np_gd_lrn_x(err, x, n=5, alpha=1e-4, beta=0.75, k=2.0):

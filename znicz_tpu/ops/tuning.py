@@ -83,6 +83,13 @@ def batch_sharded(fn, *arrays):
                          check_vma=False)(*arrays)
 
 
+def device_rows(b: int) -> int:
+    """Rows of a batch of ``b`` on one device inside
+    :func:`batch_sharded`, under the mesh being traced."""
+    mesh = _KERNEL_MESH.get()
+    return b if mesh is None else b // mesh.shape["data"]
+
+
 # dtype → (sublane, lane) minimum tile (pallas_guide.md tiling table)
 _MIN_TILE = {
     jnp.float32: (8, 128),

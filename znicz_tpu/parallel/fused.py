@@ -286,29 +286,42 @@ def workflow_rows(workflow) -> tuple[list, list, list]:
     return layers, params, vels
 
 
-def extract_model(workflow) -> tuple[ModelSpec, list, list]:
+def extract_model(workflow, mesh=None, storage_dtype: str = "float32"
+                  ) -> tuple[ModelSpec, list, list]:
     """Read (spec, params, velocities) out of an initialized
     StandardWorkflow: its rows (``workflow_rows``) through the three
-    rewrites below, in this order.  Which rows merge, fold and split
-    follows from the layer list alone."""
+    rewrites below, in this order.  Which rows merge and fold follows
+    from the layer list alone; a pair that takes the window kernels
+    (``windowed_pairs``: from the shapes one device of ``mesh`` holds,
+    ``storage_dtype`` and the kernel tier) leaves its conv whole."""
     layers, params, vels = workflow_rows(workflow)
-    return model_of_rows(
-        split_pair_conv(fold_pair_act(merge_lrn_pool(layers))),
-        params, vels, workflow.loss_function)
+    rows = fold_pair_act(merge_lrn_pool(layers))
+    rows = split_pair_conv(rows, whole=windowed_pairs(
+        rows, workflow.forwards, mesh, storage_dtype))
+    spec, params, vels = model_of_rows(rows, params, vels,
+                                       workflow.loss_function)
+    return (dataclasses.replace(spec, storage_dtype=storage_dtype),
+            params, vels)
+
+
+def row_units(rows) -> tuple:
+    """Per row, the index of the first workflow forward unit it stands
+    for: a merged ``lrn_pool`` row stands for two units (it names its
+    LRN; both are parameter-less) and every other row for one."""
+    index, unit = [], 0
+    for la in rows:
+        index.append(unit)
+        unit += 2 if la.kind == "lrn_pool" else 1
+    return tuple(index)
 
 
 def model_of_rows(rows, params, vels, loss: str
                   ) -> tuple[ModelSpec, list, list]:
     """(spec, params, velocities) of ``workflow_rows``' rows after any of
-    the rewrites, with that call's params and velocities.  A merged
-    ``lrn_pool`` row stands for two forward units and every other row for
-    one: ``unit_index`` names each row's first (a merged row's LRN; both
-    of its units are parameter-less)."""
-    index, unit = [], 0
-    for la in rows:
-        index.append(unit)
-        unit += 2 if la.kind == "lrn_pool" else 1
-    return (ModelSpec(tuple(rows), loss, unit_index=tuple(index)),
+    the rewrites, with that call's params and velocities, picked by
+    ``row_units`` (the spec's ``unit_index``)."""
+    index = row_units(rows)
+    return (ModelSpec(tuple(rows), loss, unit_index=index),
             [params[i] for i in index], [vels[i] for i in index])
 
 
@@ -373,18 +386,20 @@ def fold_pair_act(layers) -> list:
     return out
 
 
-def split_pair_conv(layers) -> list:
+def split_pair_conv(layers, whole=()) -> list:
     """Rewrite (iii): a conv row that (ii) folded emits the pair's
     column-parity halves directly (two stride-doubled convs,
     ``split_out``) and takes the pair's split gradient halves back
     (``emit_split`` on the pair): the pair forward's split pass and the
     backward's interleave go.  The parity convs are allclose (atol 1e-5),
-    not bit-equal, to the plain conv.  A folded deconv stays whole."""
+    not bit-equal, to the plain conv.  A folded deconv stays whole, and
+    so does the conv of a pair row in ``whole`` (``windowed_pairs``:
+    its kernels take x unsplit, so there is nothing to save)."""
     out = list(layers)
     for i in range(1, len(out)):
         prev = out[i - 1]
         if prev.kind == "conv" and prev.cfg.get("act_folded") \
-                and "fold_act" in out[i].cfg:
+                and "fold_act" in out[i].cfg and i not in whole:
             out[i - 1] = _with_config(prev, split_out=True)
             out[i] = _with_config(out[i], emit_split=True)
     return out
@@ -442,6 +457,42 @@ def pool_routes(spec: ModelSpec, forwards, mesh=None) -> str:
     return " ".join(f"{k}:{v}" for k, v in counts.items())
 
 
+def windowed_pairs(rows, forwards, mesh=None,
+                   storage_dtype: str = "float32") -> tuple:
+    """The ``lrn_pool`` rows of ``rows`` whose kernels run on the
+    (H, W, B, C) view of an unsplit x (``ops/lrn_pool.py`` header), by
+    index: on the Pallas tier, where ``lrn_pool_ops.windowed`` admits
+    the pair's input as one device of ``mesh`` holds it — the rule the
+    step applies again to its operands as it is traced."""
+    if not tuning.use_pallas():
+        return ()
+    dp = mesh_lib.mesh_shape_of(mesh)[0]
+    units = row_units(rows)
+    out = []
+    for i, layer in enumerate(rows):
+        if layer.kind != "lrn_pool":
+            continue
+        b, *rest = forwards[units[i]].input.shape
+        cfg = layer.cfg
+        if lrn_pool_ops.windowed((b // dp, *rest), cfg["ksize"],
+                                 cfg["stride"], cfg["padding"],
+                                 storage_dtype):
+            out.append(i)
+    return tuple(out)
+
+
+def pair_routes(spec: ModelSpec, forwards, mesh=None) -> str:
+    """``window:<n> split:<m>``: how many merged LRN+pool rows of
+    ``spec`` take the window kernels and how many the column-parity
+    ones (``windowed_pairs``).  Both read 0 off the Pallas tier, where
+    a pair is the composed XLA ops."""
+    pairs = sum(la.kind == "lrn_pool" for la in spec.layers) \
+        if tuning.use_pallas() else 0
+    window = len(windowed_pairs(spec.layers, forwards, mesh,
+                                spec.storage_dtype))
+    return f"window:{window} split:{pairs - window}"
+
+
 def attn_routes(spec: ModelSpec) -> str:
     """``window:<n> full:<m>``: the attention rows of ``spec`` over a
     sliding window, and over everything before."""
@@ -453,6 +504,15 @@ def attn_routes(spec: ModelSpec) -> str:
 
 
 # -- pure math (all traced; spec is static) --------------------------------
+def _takes_window(x, cfg) -> bool:
+    """``windowed_pairs``' rule on a pair's traced input: the batch as
+    one device of the mesh being traced under holds it."""
+    b, *rest = x.shape
+    return tuning.use_pallas() and lrn_pool_ops.windowed(
+        (tuning.device_rows(b), *rest), cfg["ksize"], cfg["stride"],
+        cfg["padding"], x.dtype)
+
+
 def _sequence_call(spec: ModelSpec, layer: LayerSpec):
     """``(leaves, x) -> (y, counters)`` of a sequence kind: the ``ops``
     function with the layer's static config and the compute dtype."""
@@ -612,8 +672,10 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
                 # With the activation folded, NOTHING downstream needs the
                 # unsplit x (the conv below skips its activation backward),
                 # so the cache keeps the column-parity halves the kernel
-                # consumed — the backward never re-splits x
-                if "fold_act" in cfg:
+                # consumed — the backward never re-splits x.  A pair on
+                # the window kernels has no halves: it caches x whole
+                if isinstance(h, tuple) or (
+                        "fold_act" in cfg and not _takes_window(h, cfg)):
                     xe, xo = (h if isinstance(h, tuple)   # split-out conv
                               else lrn_pool_ops.split_cols(h))
                     x_in = (xe, xo)

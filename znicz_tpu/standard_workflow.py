@@ -357,11 +357,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
         from .parallel import FusedTrainer, fused
 
         assert self.initialized, "initialize() first"
-        spec, params, vels = fused.extract_model(self)
+        spec, params, vels = fused.extract_model(
+            self, mesh, storage_dtype or "float32")
         if compute_dtype is not None:
             spec = dataclasses.replace(spec, compute_dtype=compute_dtype)
-        if storage_dtype is not None:
-            spec = dataclasses.replace(spec, storage_dtype=storage_dtype)
         from .loader.streaming import StreamingLoader
         if isinstance(self.loader, StreamingLoader):
             # disk-backed dataset: stream minibatches through the
@@ -419,6 +418,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                  # pooling rows on the one-pass windowed kernels, and on
                  # the tap stack (ops/pooling.py)
                  "pool_routes": fused.pool_routes(spec, self.forwards,
+                                                  mesh),
+                 # merged LRN+pool rows on the window kernels, and on
+                 # the column-parity ones (ops/lrn_pool.py)
+                 "pair_routes": fused.pair_routes(spec, self.forwards,
                                                   mesh)}
         if any(la.kind == "attn_block" for la in spec.layers):
             # attention rows over a sliding window, and over everything
