@@ -1,13 +1,14 @@
 #!/usr/bin/env python3
 """Compile the fused trainer's whole training and evaluation programs of
-a decoder configuration of the benchmark for a DESCRIBED v5e (no chip:
+a token-sequence configuration of the benchmark (its model file is the one
+the configuration names) for a DESCRIBED v5e (no chip:
 ``on-chip-measurement`` guide, section 2) and print each program's
 ``memory_analysis``.  Nothing runs; this says what the TPU's compiler
 accepts and how many bytes the program needs beside its arguments.
 
     JAX_PLATFORMS=cpu python3 tools/compile_decoder_step.py \\
         [--config benchmark/configs/mellum2-12b-a2.5b.json] \\
-        [--traffic benchmark/traffic/resident-s8192-b1.json]
+        [--traffic benchmark/traffic/resident-s8192-b1.json] [--steps 1]
 """
 
 import argparse
@@ -35,6 +36,14 @@ def main(argv=None) -> int:
                     help="stop before the compile and print a hash of each "
                          "program's lowered text (two processes that print "
                          "the same hash share a compile-cache entry)")
+    ap.add_argument("--steps", type=int, default=None,
+                    help="minibatches of the training program (default: "
+                         "an epoch's head call; 1: the program the "
+                         "benchmark's probe follows three steps with)")
+    ap.add_argument("--crowded", action="store_true",
+                    help="the programs of a trainer whose state crowds "
+                         "the device (fused.state_crowds_device): give it "
+                         "--steps 1 too, it runs one minibatch a launch")
     ap.add_argument("--reference", action="store_true",
                     help="compile the plain reference's training step "
                          "instead (benchmark/lib/decoder_reference.py)")
@@ -44,7 +53,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from benchmark.lib import decoder_model as model
+    import importlib
     from znicz_tpu.nn import decoder
     from znicz_tpu.ops import tuning
     from znicz_tpu.parallel import fused
@@ -53,22 +62,21 @@ def main(argv=None) -> int:
         cfg = json.load(fh)
     with open(args.traffic) as fh:
         traffic = json.load(fh)
+    model = importlib.import_module(
+        os.path.splitext(cfg["model"])[0].replace("/", "."))
     tuning.on_tpu = lambda: True        # the dispatch a TPU process takes
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    units = {cls.MAPPING[0]: cls for cls in (
-        decoder.Embedding, decoder.AttentionBlock, decoder.MoEBlock,
-        decoder.LMHead)}
-    layers = []
-    for la in model.layer_list(cfg):
-        unit = units[la["type"]](None, **la["->"])
+    layers, listed = [], model.layer_list(cfg)
+    for la, unit in zip(listed, decoder.units_of(listed)):
         h = la["<-"]
         layers.append(fused.sequence_layer(unit, (
             h["learning_rate"], h["weights_decay"], 0.0,
             h["gradient_moment"])))
     spec = fused.ModelSpec(tuple(layers), "softmax",
-                           compute_dtype=cfg["precision"]["matmul_operands"])
+                           compute_dtype=cfg["precision"]["matmul_operands"],
+                           fresh_backward=args.crowded)
 
     def shaped(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
@@ -96,8 +104,9 @@ def main(argv=None) -> int:
     trainer = fused.FusedTrainer.__new__(fused.FusedTrainer)
     trainer.spec, trainer.mesh, trainer.accum_steps = spec, None, 1
     trainer.augment = trainer._batch_sharding = None
+    trainer.crowded, trainer.params = args.crowded, params
     trainer._build()
-    steps = (int(traffic["n_train"]) - 1) // b      # an epoch's head call
+    steps = args.steps or (int(traffic["n_train"]) - 1) // b   # the head
     for name, fn, call in (
             ("train_epoch", trainer._train_epoch_fn.fn, (
                 params, params, rows, rows, shaped((steps, b), jnp.int32),

@@ -26,7 +26,8 @@ import hlo_scope_bytes
 
 HEAD = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+)")
 CONTAINERS = ("while", "conditional", "call")
-INNER = ("rope", "scores", "route", "experts", "combine")
+INNER = ("rope", "scores", "route", "experts", "combine", "shared_expert",
+         "mamba_block", "ssd_scan")
 
 
 def instructions(text: str) -> dict:

@@ -6,7 +6,10 @@ The layer kinds are the token-sequence kinds of ``nn/decoder.py``; the
 sizes here are test widths (d 64, 4 query heads over 2 key/value heads of
 16, window 8, 8 experts of width 32 with 2 a token, vocabulary 128), so
 that ``python -m znicz_tpu znicz_tpu.models.decoder_lm --fused`` trains a
-decoder through the ``Launcher`` in seconds on a CPU.  Rows are drawn
+decoder through the ``Launcher`` in seconds on a CPU.  A ``"mamba"`` in
+``root.decoder_lm.layer_types`` puts a Mamba-2 mixer (``mamba_block``) in
+an attention block's place; ``shared_width``, ``tied`` and the four
+multipliers make the hybrid's other parts (all off by default).  Rows are drawn
 from a seeded first-order Markov chain over the vocabulary, so the loss
 can fall below ``log(vocab)``; the target of a position is the next
 token.  ``root.decoder_lm.experts_held`` (``[first, count]``) makes every
@@ -36,6 +39,11 @@ root.decoder_lm.setdefaults({
         "full": {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
                  "original_max_position_embeddings": 16, "beta_fast": 32,
                  "beta_slow": 1, "attention_factor": 1.1386}},
+    "mamba": {"heads": 8, "heads_held": 8, "head_dim": 8, "state": 16,
+              "conv": 4, "chunk": 8},
+    "shared_width": 0, "tied": False, "positional": "rope",
+    "embedding_scale": None, "residual_scale": None, "score_scale": None,
+    "logits_scale": None,
     "learning_rate": 0.05, "gradient_moment": 0.9, "weights_decay": 0.0,
     "decision": {"max_epochs": 5, "fail_iterations": 5},
     "synthetic": {"n_train": 32, "n_valid": 8, "n_test": 0,
@@ -53,20 +61,34 @@ def decoder_layers(cfg) -> list[dict]:
     back = {"learning_rate": get("learning_rate"),
             "gradient_moment": get("gradient_moment"),
             "weights_decay": get("weights_decay")}
+    mamba = get("mamba")
+    mamba = mamba.to_dict() if hasattr(mamba, "to_dict") else dict(mamba)
+    block = {"scale": get("residual_scale")}
     layers = [{"type": "embedding", "<-": back,
-               "->": {"vocab": get("vocab"), "hidden": get("hidden")}}]
+               "->": {"vocab": get("vocab"), "hidden": get("hidden"),
+                      "scale": get("embedding_scale")}}]
     for kind in get("layer_types"):
-        layers.append({"type": "attn_block", "<-": back, "->": {
-            "heads": get("heads"), "kv_heads": get("kv_heads"),
-            "head_dim": get("head_dim"),
-            "window": get("window") if kind == "sliding" else None,
-            "rope": dict(rope[kind])}})
+        if kind == "mamba":
+            layers.append({"type": "mamba_block", "<-": back,
+                           "->": {**block, **mamba}})
+        else:
+            layers.append({"type": "attn_block", "<-": back, "->": {
+                **block, "heads": get("heads"),
+                "kv_heads": get("kv_heads"), "head_dim": get("head_dim"),
+                "window": get("window") if kind == "sliding" else None,
+                "positional": get("positional"),
+                "score_scale": get("score_scale"),
+                "rope": (None if get("positional") == "nope"
+                         else dict(rope[kind]))}})
         layers.append({"type": "moe_block", "<-": back, "->": {
-            "experts": get("experts"),
+            **block, "experts": get("experts"),
             "experts_held": list(get("experts_held")),
-            "expert_width": get("expert_width"), "top_k": get("top_k")}})
-    layers.append({"type": "lm_head", "<-": back,
-                   "->": {"vocab": get("vocab")}})
+            "expert_width": get("expert_width"), "top_k": get("top_k"),
+            "shared_width": get("shared_width")}})
+    head = {"vocab": get("vocab"), "scale": get("logits_scale")}
+    if get("tied"):
+        head["tie"] = 0
+    layers.append({"type": "lm_head", "<-": back, "->": head})
     return layers
 
 

@@ -216,18 +216,26 @@ def attention(q, k, v, window: int | None):
 # -- the layer kinds ---------------------------------------------------------
 def embed_fwd(leaves, ids, cfg: dict, cdt=jnp.float32):
     """``embed``: leaf ``table (V_held, d)``; ids ``(B, T)`` int in,
-    ``(B, T, d)`` float32 out."""
+    ``(B, T, d)`` float32 out, times ``cfg["scale"]`` where the model
+    has an embedding multiplier."""
     (table,) = leaves
-    return jnp.take(table, ids, axis=0).astype(jnp.float32), {}
+    out = jnp.take(table, ids, axis=0).astype(jnp.float32)
+    if cfg.get("scale") is not None:
+        out = out * cfg["scale"]
+    return out, {}
 
 
 def attn_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
-    """``attn_block``: ``x + Attn(RMSNorm(x; g1))``.  Leaves ``g1 (d,)``,
-    ``wq (d, H*D)``, ``wk (d, KV*D)``, ``wv (d, KV*D)``, ``wo (H*D, d)``;
-    ``cfg``: ``heads``, ``kv_heads``, ``head_dim``, ``window`` (None: full
-    causal), ``rope`` (sorted items of the layer type's rope entry),
-    ``eps``.  Matmul operands in ``cdt``, accumulation, the residual, the
-    norm and the softmax in float32."""
+    """``attn_block``: ``x + scale * Attn(RMSNorm(x; g1))``.  Leaves ``g1
+    (d,)``, ``wq (d, H*D)``, ``wk (d, KV*D)``, ``wv (d, KV*D)``, ``wo (H*D,
+    d)`` for the ``H`` query and ``KV`` key/value heads held; ``cfg``:
+    ``heads``, ``kv_heads``, ``head_dim``, ``window`` (None: full causal),
+    ``rope`` (sorted items of the layer type's rope entry; None: no
+    positional embedding), ``eps`` and, where the model states them,
+    ``score_scale`` (on ``q k^T``; else ``1 / sqrt(head_dim)``) and
+    ``scale`` (the block's scale on what it adds to the stream).  Matmul
+    operands in ``cdt``, accumulation, the residual, the norm and the
+    softmax in float32."""
     g1, wq, wk, wv, wo = leaves
     b, t, _ = x.shape
     nh, nkv, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
@@ -238,31 +246,56 @@ def attn_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
                        preferred_element_type=jnp.float32
                        ).reshape(b, t, heads, hd)
     q, k, v = proj(wq, nh), proj(wk, nkv), proj(wv, nkv)
-    with jax.named_scope("rope"):
-        cos, sin = rope_tables(t, hd, tuple(cfg["rope"]))
-        q = apply_rope(q, cos, sin) * (1.0 / math.sqrt(hd))
-        k = apply_rope(k, cos, sin)
+    score_scale = cfg.get("score_scale")
+    if score_scale is None:
+        score_scale = 1.0 / math.sqrt(hd)
+    if cfg["rope"] is None:
+        q = q * score_scale
+    else:
+        with jax.named_scope("rope"):
+            cos, sin = rope_tables(t, hd, tuple(cfg["rope"]))
+            q = apply_rope(q, cos, sin) * score_scale
+            k = apply_rope(k, cos, sin)
     with jax.named_scope("scores"):
         o = attention(q.astype(cdt), k.astype(cdt), v.astype(cdt),
                       cfg["window"])
-    return x + jnp.dot(o.reshape(b, t, nh * hd).astype(cdt), wo.astype(cdt),
-                       preferred_element_type=jnp.float32), {}
+    out = jnp.dot(o.reshape(b, t, nh * hd).astype(cdt), wo.astype(cdt),
+                  preferred_element_type=jnp.float32)
+    if cfg.get("scale") is not None:
+        out = out * cfg["scale"]
+    return x + out, {}
 
 
 def lm_head_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     """``lm_head``: ``RMSNorm(x; gf) @ W``; leaves ``gf (d,)``, ``W (d,
-    V_held)``; ``(B, T, V_held)`` float32 logits out."""
+    V_held)``; ``(B, T, V_held)`` float32 logits out.  A tied head
+    (``cfg["tied_to"]``: the layer whose table it shares) is handed that
+    layer's ``table (V_held, d)`` as its second leaf and multiplies by its
+    transpose; ``cfg["scale"]``, where the model has a logits scaling, is
+    on the logits."""
     gf, w = leaves
-    return jnp.dot(rms_norm(x, gf, cfg["eps"]).astype(cdt), w.astype(cdt),
-                   preferred_element_type=jnp.float32), {}
+    xn = rms_norm(x, gf, cfg["eps"]).astype(cdt)
+    if cfg.get("tied_to") is None:
+        out = jnp.dot(xn, w.astype(cdt), preferred_element_type=jnp.float32)
+    else:
+        out = jax.lax.dot_general(
+            xn, w.astype(cdt), (((xn.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    if cfg.get("scale") is not None:
+        out = out * cfg["scale"]
+    return out, {}
 
 
-def block_vjp(call, leaves, x, err):
+def block_vjp(call, leaves, x, err, fresh: bool = False):
     """``(leaf gradients, input gradient)`` of a layer kind's ``call(leaves,
     x) -> (y, counters)`` at ``err``, the gradient with respect to ``y``:
     the block is run again from its input and differentiated by JAX (a
     kernel brings its ``custom_vjp``).  Integer ids have no gradient:
-    None."""
+    None.  ``fresh``: the leaves pass an optimization barrier first, so
+    that nothing the forward pass made of them (their casts to the operand
+    dtype) is shared with this recomputation and kept alive for it."""
+    if fresh:
+        leaves = jax.lax.optimization_barrier(leaves)
     if jnp.issubdtype(x.dtype, jnp.integer):
         _, vjp, _ = jax.vjp(lambda ls: call(ls, x), leaves, has_aux=True)
         return vjp(err)[0], None

@@ -1,6 +1,8 @@
 """The sparse expert layer of a decoder, for the experts one chip holds.
 
-``moe_block`` is ``h + MoE(RMSNorm(h; g2))`` with a router over ALL the
+``moe_block`` is ``h + MoE(RMSNorm(h; g2))`` (with the columns held of a
+shared expert beside the sparse ones, and a scale on the sum, where the
+model has them) with a router over ALL the
 model's experts and the products of the experts HELD here: the layer is
 told ``experts`` (the router's width), ``experts_held = (first, count)``
 and ``top_k``; it routes every token over all experts, normalises the
@@ -326,23 +328,40 @@ def held_expert_sum(xn, weights, experts, wg, wu, wd, first: int, cdt,
     return out, counts, moved
 
 
+def shared_expert(xn, sg, su, sd, cdt):
+    """``(silu(x Sg) * x Su) Sd`` for every row of ``xn (N, d)``: the
+    columns held of an expert every token takes, ungated: ``sg``/``su (d,
+    f_held)``, ``sd (f_held, d)``."""
+    xc = xn.astype(cdt)
+
+    def dot(a, w):
+        return jnp.dot(a, w.astype(cdt), preferred_element_type=jnp.float32)
+    return dot((jax.nn.silu(dot(xc, sg)) * dot(xc, su)).astype(cdt), sd)
+
+
 def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
-    """``moe_block``: ``x + MoE(RMSNorm(x; g2))`` for the experts held.
-    Leaves ``g2 (d,)``, ``wr (d, experts)``, ``wg``, ``wu (E_held, d, f)``,
-    ``wd (E_held, f, d)``; ``cfg``: ``experts``, ``experts_held`` as
-    ``(first, count)``, ``top_k``, ``norm_topk_prob``, ``eps``.
+    """``moe_block``: ``x + scale * (MoE(n) + Shared(n))``, ``n =
+    RMSNorm(x; g2)``, for the experts held.  Leaves ``g2 (d,)``, ``wr (d,
+    experts)``, ``wg``, ``wu (E_held, d, f)``, ``wd (E_held, f, d)`` and,
+    where the model has a shared expert (``cfg["shared"]``), the columns
+    held of it: ``sg``, ``su (d, f_held)``, ``sd (f_held, d)``; ``cfg``:
+    ``experts``, ``experts_held`` as ``(first, count)``, ``top_k``,
+    ``norm_topk_prob``, ``eps``, ``scale`` (the block's scale on what it
+    adds to the stream; None: 1).
     -> ``(y, counters)``; the counters are this layer's, this call's:
     ``moe_assignments``, ``moe_assignments_held``, ``moe_expert_load_max``,
     ``moe_rows_moved`` (rows of the sorted pieces that ran, all chunks: over
     ``moe_assignments_held`` it says how far the movement is from the pairs
     held, and whether a later piece ran; rows that pad a short last
     minibatch are routed and counted too)."""
-    g2, wr, wg, wu, wd = leaves
+    g2, wr, wg, wu, wd, *shared = leaves
     b, t, d = x.shape
     first, count = cfg["experts_held"]
-    if wr.shape[1] != cfg["experts"] or wg.shape[0] != count:
+    if (wr.shape[1] != cfg["experts"] or wg.shape[0] != count
+            or bool(shared) != bool(cfg.get("shared"))):
         raise ValueError(f"moe_block of {cfg['experts']} experts holding "
-                         f"{count}: router {wr.shape}, experts {wg.shape}")
+                         f"{count}: router {wr.shape}, experts {wg.shape}, "
+                         f"{len(shared)} leaves of a shared expert")
     xn = rms_norm(x, g2, cfg["eps"]).reshape(b * t, d)
     with jax.named_scope("route"):
         weights, experts = route(xn, wr, cfg["top_k"],
@@ -365,4 +384,9 @@ def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
         "moe_assignments_held": jnp.sum(counts),
         "moe_expert_load_max": jnp.max(counts),
         "moe_rows_moved": moved}
+    if shared:
+        with jax.named_scope("shared_expert"):
+            out = out + shared_expert(xn, *shared, cdt)
+    if cfg.get("scale") is not None:
+        out = out * cfg["scale"]
     return x + out.reshape(b, t, d), counters

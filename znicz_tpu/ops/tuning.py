@@ -38,6 +38,13 @@ def interpret_mode() -> bool:
     return _INTERPRET and not on_tpu()
 
 
+def device_memory_bytes() -> int | None:
+    """Bytes of memory the default device gives a program, or None where
+    it does not say (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
 def kernel_tier() -> str:
     """The tier the ``ops`` dispatchers take in this process, as the
     trainer states it at start: ``pallas`` (Mosaic on a TPU),
