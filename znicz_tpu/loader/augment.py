@@ -30,6 +30,11 @@ class RandomCropFlip:
     Works on (B, H, W, ...) minibatches — channels-last like every image
     loader here; label/target blocks are untouched."""
 
+    #: every output element is an input element, moved: a consumer that
+    #: rounds the rows may round them before the augmentation instead
+    #: (``FusedTrainer`` holds such a set in bfloat16)
+    selects_only = True
+
     def __init__(self, out_hw: tuple[int, int], mirror: bool = True,
                  seed: int = 1234):
         self.out_hw = (int(out_hw[0]), int(out_hw[1]))

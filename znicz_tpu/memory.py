@@ -74,8 +74,11 @@ class Vector:
 
     @devmem.setter
     def devmem(self, value):
-        """Direct device-side store (used by xla_run bodies)."""
+        """Direct device-side store (used by xla_run bodies).  The host
+        copy, if any, is another value's from here on: dropped, so that
+        ``_mem`` is never stale."""
         self._devmem = value
+        self._mem = None
         self._host_owned = False
 
     @property
@@ -138,6 +141,21 @@ class Vector:
                     and self._device.is_xla):
                 self._devmem = self._device.put(self._mem)
             self._host_owned = self._devmem is None
+        return self
+
+    def release_device(self) -> "Vector":
+        """Free the device buffer NOW, whoever else refers to the array:
+        for a caller that holds the values in another form and knows
+        that nobody reads this one meanwhile (the fused trainer's held
+        set).  Where the host has the values they are the host's again
+        and the next ``devmem`` uploads them anew; values that lived on
+        the device alone are gone, and a later read of them raises
+        JAX's "Array has been deleted"."""
+        if self._devmem is not None:
+            self._devmem.delete()
+            if self._mem is not None:
+                self._devmem = None
+                self._host_owned = True
         return self
 
     # -- conveniences ------------------------------------------------------

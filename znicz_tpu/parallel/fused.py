@@ -22,12 +22,14 @@ the reference's ``apply_data_from_slave`` fold [baseline]."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from ..ops import (activations, attention as attn_ops, conv as conv_ops,
                    deconv as deconv_ops, dropout as drop_ops,
@@ -1051,6 +1053,104 @@ def eval_minibatch(spec: ModelSpec, params, x, target, mask=None):
     return _step_metrics(spec, loss, n_err, counters, mask, target)
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("rows",), meta_fields=("row_shape",))
+@dataclasses.dataclass(frozen=True)
+class HeldSet:
+    """A resident set as ``FusedTrainer.hold`` keeps it: ``rows`` in
+    bfloat16, each dim the asked layout tiles rounded up to its tile, so
+    that the array's DEFAULT device layout is the one the epoch programs
+    gather from; ``row_shape`` (static) is a row before the padding."""
+    rows: jax.Array
+    row_shape: tuple
+
+    def take(self, step_idx):
+        """The rows ``step_idx``, the padding cut off in the pass that
+        gathers them.  The barrier keeps the cut there: left free, XLA
+        moves it into every reader of the minibatch, which then read
+        the padded rows (conv1's forward and weight gradient a third
+        slower; PERF.md section 6, PR 35)."""
+        x = jnp.take(self.rows, step_idx, axis=0)
+        return jax.lax.optimization_barrier(
+            x[(slice(None), *(slice(n) for n in self.row_shape))])
+
+
+def tiled_shape(shape: tuple, layout: Layout) -> tuple:
+    """``shape`` with the dims ``layout`` tiles (its minor-most ones)
+    rounded up to the tile: the shape whose bytes ``layout`` holds
+    without padding of its own."""
+    out = list(shape)
+    for tile in (layout.tiling or ())[:1]:
+        for dim, n in zip(layout.major_to_minor[-len(tile):], tile):
+            out[dim] = tuning.round_up(out[dim], n)
+    return tuple(out)
+
+
+#: ``padded_bfloat16`` works through a set in this many pieces of rows
+PREPARE_PIECES = 8
+
+
+@contextlib.contextmanager
+def _build_counted():
+    """The accounting of a build beside the epoch calls' ``build_timed``
+    jits (the program that is asked for its layout, the prepare pass's
+    two small ones together): a ``compile`` span and a count."""
+    with tracing.span("compile", site="train.fused", cause="cold"), \
+            compilestats.timed("train.fused", "cold"):
+        yield
+
+
+def padded_bfloat16(data, shape: tuple):
+    """``data`` in bfloat16, zero-padded to ``shape``, on the device that
+    has it.  By pieces of rows, each cut out by one executable and
+    narrowed, padded and written in place by another: in one pass the
+    compiler narrows the WHOLE set before it re-lays it out (3.1 GB of
+    temporaries for 9,216 images, more than a chip has left beside the
+    set, the result and the unit graph's buffers at minibatch 512), and
+    inside one program it does so whatever the loop (PERF.md section 6,
+    PR 35).  The two executables are built ahead, as a counted build;
+    the pass over the set is a ``trainer.prepare_set`` span."""
+    n = data.shape[0]
+    piece = min(n, tuning.round_up(-(-n // PREPARE_PIECES), 128))
+    widths = [(0, 0)] + [(0, to - size)
+                         for size, to in zip(data.shape[1:], shape[1:])]
+
+    def cut_rows(d, at):
+        return jax.lax.dynamic_slice_in_dim(d, at, piece, 0)
+
+    def put_rows(rows, part, at):
+        return jax.lax.dynamic_update_slice_in_dim(
+            rows, jnp.pad(part.astype(jnp.bfloat16), widths), at, 0)
+    at = np.int32(0)
+    with _build_counted():
+        cut_rows = jax.jit(cut_rows).lower(data, at).compile()
+        put_rows = jax.jit(put_rows, donate_argnums=0).lower(
+            jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                 sharding=data.sharding),
+            jax.ShapeDtypeStruct((piece, *data.shape[1:]), data.dtype,
+                                 sharding=data.sharding), at).compile()
+    with tracing.span("trainer.prepare_set"):
+        rows = jnp.zeros(shape, jnp.bfloat16, device=data.sharding)
+        for at in range(0, n, piece):
+            # the last piece laps the one before
+            at = np.int32(min(at, n - piece))
+            # one piece alive at a time: queued ahead, every cut is
+            # allocated at once, the float32 set a second time
+            rows = jax.block_until_ready(
+                put_rows(rows, cut_rows(data, at), at))
+    return rows
+
+
+def _set_prepares():
+    """The gauge of prepare passes (``FusedTrainer.hold``), made when a
+    trainer first prepares a set, so that no other run shows it at 0."""
+    return REGISTRY.gauge(
+        "train_set_prepares",
+        "passes that put a resident set into the format the epoch "
+        "programs gather from, in this process: one a set; more means "
+        "the trainer's memo missed")
+
+
 def state_crowds_device(spec: ModelSpec, params) -> bool:
     """Whether the training state with one more copy of the parameters
     beside it (three times the leaves' bytes: parameters, velocities, and
@@ -1162,8 +1262,15 @@ class FusedTrainer:
         self._train_epoch_fn = None
         self._eval_epoch_fn = None
         self._auto_epoch = 0
-        #: _mesh_place memo: id(source) -> (source, placed-on-mesh)
+        #: memo of _mesh_place and hold: (id(source), held) -> (source,
+        #: placed on the mesh | held in the programs' format)
         self._placed: dict = {}
+        #: the layout the compiler asked for a held set (``_ask_layout``),
+        #: once a set was held
+        self._set_layout = None
+        #: how the trainer holds the resident set, for its start line
+        #: and its rows: as given, or dtype, layout and padded row
+        self.set_form = "as-given"
 
     def _mesh_scoped(self, fn):
         """``fn`` with its trace scoped to this trainer's mesh, so the
@@ -1179,17 +1286,24 @@ class FusedTrainer:
         return scoped
 
     # -- epoch-granular compiled drivers ----------------------------------
-    @jax.named_scope("input")
-    def _gather(self, data, target, step_idx, epoch, train: bool):
-        """One minibatch out of the resident set (traced): the rows by
-        index, laid over the mesh's ``data`` axis, through the on-device
-        augmentation (``train=False``: its centre crop)."""
-        x = jnp.take(data, step_idx, axis=0)
+    def _rows(self, data, step_idx, epoch, train: bool):
+        """The rows ``step_idx`` of the resident set (traced), laid over
+        the mesh's ``data`` axis, through the on-device augmentation
+        (``train=False``: its centre crop)."""
+        x = (data.take(step_idx) if isinstance(data, HeldSet)
+             else jnp.take(data, step_idx, axis=0))
         if self._batch_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, self._batch_sharding)
         if self.augment is not None:
             x = self.augment.device_apply(x, step_idx, epoch, train=train)
-        return x, jnp.take(target, step_idx, axis=0)
+        return x
+
+    @jax.named_scope("input")
+    def _gather(self, data, target, step_idx, epoch, train: bool):
+        """One minibatch out of the resident set (traced): its rows and
+        their targets."""
+        return (self._rows(data, step_idx, epoch, train),
+                jnp.take(target, step_idx, axis=0))
 
     def _build(self):
         spec = self.spec
@@ -1296,6 +1410,22 @@ class FusedTrainer:
             jax.jit(self._mesh_scoped(eval_epoch), **ejit_kw),
             site="train.fused", cause="cold")
 
+    def _memo(self, a, held: bool, make):
+        """``make(a)``, made once for the SOURCE array object: the fused
+        loop hands the same devmem to train/eval several times per
+        epoch.  The memo holds the source too, so an id() can never
+        alias a collected array — callers must not mutate a memoized
+        source in place (loader devmem and the epoch tensors never
+        are)."""
+        hit = self._placed.get((id(a), held))
+        if hit is not None and hit[0] is a:
+            return hit[1]
+        made = make(a)
+        while len(self._placed) >= 8:     # a handful of epoch tensors
+            self._placed.pop(next(iter(self._placed)))
+        self._placed[id(a), held] = (a, made)
+        return made
+
     def _mesh_place(self, a):
         """Re-place a whole-epoch tensor onto the mesh (replicated:
         every step gathers its global batch from it by index, then the
@@ -1304,25 +1434,118 @@ class FusedTrainer:
         jit rejects as incompatible — host arrays and already-placed
         mesh arrays pass through at no cost.  Meshless: identity.
 
-        The placement memoizes on the SOURCE array object: the fused
-        loop hands the same devmem to train/eval several times per
-        epoch, and re-replicating the whole dataset each call would
-        put O(dataset × devices) transfer traffic on the hot path.
-        The memo holds the source too, so an id() can never alias a
-        collected array — callers must not mutate a placed source in
-        place (loader devmem and the epoch tensors never are)."""
+        Memoized on the source (``_memo``): re-replicating the whole
+        dataset each call would put O(dataset × devices) transfer
+        traffic on the hot path."""
         if self._batch_sharding is None or a is None:
             return a
         if getattr(a, "sharding", None) == self._repl:
             return a
-        hit = self._placed.get(id(a))
-        if hit is not None and hit[0] is a:
-            return hit[1]
-        placed = jax.device_put(a, self._repl)
-        while len(self._placed) >= 8:     # a handful of epoch tensors
-            self._placed.pop(next(iter(self._placed)))
-        self._placed[id(a)] = (a, placed)
-        return placed
+        return self._memo(a, False,
+                          lambda a: jax.device_put(a, self._repl))
+
+    # -- the resident set, in the form the programs gather from ------------
+    def _holds_bfloat16(self, data) -> bool:
+        """Whether resident set ``data`` is held once as bfloat16 in the
+        layout the epoch programs ask for, in place of being handed to
+        every program as given.  The default device layout of
+        ``f32[rows, H, W, C]`` puts the rows minor-most and a gather of
+        rows wants them major-most, so every program that is handed
+        such a set re-lays all of it out before its first step (and
+        narrows it on the way: its one reader rounds to bfloat16).
+        Held where bfloat16 is what the step reads already, and where
+        the compiler's answer was compiled and measured: on a TPU,
+        float32 ``[rows, H, W, C]`` on the device, the default matmul
+        precision, a first layer that is a convolution and takes the
+        rows as an operand, an augmentation that only selects.  The
+        same rounding once in place of once a launch: no number of the
+        step changes.  Elsewhere (integer rows, the CPU, a first layer
+        that computes in float32, or one whose sets no compile test
+        covers: ``fc``, ``deconv``) the set is passed as given."""
+        return (tuning.on_tpu()
+                and isinstance(data, jax.Array)
+                and data.dtype == jnp.float32 and data.ndim == 4
+                and jax.config.jax_default_matmul_precision is None
+                and self.spec.compute_dtype in ("float32", "bfloat16")
+                and bool(self.spec.layers)
+                and self.spec.layers[0].kind == "conv"
+                and (self.augment is None
+                     or getattr(self.augment, "selects_only", False)))
+
+    def _ask_layout(self, data, batch: int) -> Layout:
+        """The layout the compiler gives a bfloat16 set of ``data``'s
+        shape when the choice is left to it (``Layout.AUTO``): asked of
+        the rows' first use, one minibatch gathered and put through the
+        first layer's product (what decides it in the epoch programs,
+        and a fraction of their trace and compile), once a trainer.
+        Compiled only, never run: an executable whose parameter has a
+        layout of its own does not come back whole from the persistent
+        compile cache (libtpu 0.0.34: the loaded one expects the
+        default layout's bytes), so ``hold`` gives the set a SHAPE whose
+        default layout is the asked one."""
+        first = dataclasses.replace(self.spec, layers=self.spec.layers[:1],
+                                    loss="mse")
+
+        def first_use(leaves, data, step_idx):
+            return forward(first, [leaves],
+                           self._rows(data, step_idx, 0, False),
+                           want_caches=False)[0]
+        sharding = (data.sharding if self._batch_sharding is None
+                    else self._repl)
+        with _build_counted():
+            asked = jax.jit(
+                self._mesh_scoped(first_use),
+                in_shardings=(None, Format(Layout.AUTO, sharding),
+                              None)).lower(
+                self.params[0],
+                jax.ShapeDtypeStruct(data.shape, jnp.bfloat16,
+                                     sharding=sharding),
+                np.arange(batch, dtype=np.int32)).compile()
+        return asked.input_formats[0][1].layout
+
+    @staticmethod
+    def _laid_as(rows, asked: Layout) -> bool:
+        """Whether ``rows`` lies on the device as ``asked``, its rows
+        major-most: what a ``HeldSet`` stands on, and two heuristics of
+        the compiler have to agree for it (its answer to
+        ``Layout.AUTO``, and the default layout of the padded
+        shape)."""
+        has = rows.format.layout
+        return (has.major_to_minor == asked.major_to_minor
+                and has.major_to_minor[0] == 0
+                and (has.tiling or ()) == (asked.tiling or ()))
+
+    def hold(self, data, batch: int):
+        """Resident set ``data`` as the epoch programs read it: a
+        ``HeldSet`` where ``_holds_bfloat16`` says so (one pass on the
+        device that has the set, then laid over the mesh), else placed
+        as given.  Memoized on the source, so ``train_epoch``,
+        ``eval_epoch`` and whoever cuts their calls share one held
+        form."""
+        if self._train_epoch_fn is None:
+            self._build()
+        if not self._holds_bfloat16(data):
+            return self._mesh_place(data)
+
+        def prepare(data):
+            if self._set_layout is None:
+                self._set_layout = self._ask_layout(data, batch)
+            rows = padded_bfloat16(
+                data, tiled_shape(data.shape, self._set_layout))
+            _set_prepares().inc()
+            if not self._laid_as(rows, self._set_layout):
+                # another shape or another compiler: held so, every
+                # program would re-lay the set out as before, the
+                # padding on top.  The memo keeps the set as given.
+                rows.delete()
+                return self._mesh_place(data)
+            if self._batch_sharding is not None:
+                rows = jax.device_put(rows, self._repl)
+            order = ",".join(map(str, self._set_layout.major_to_minor))
+            self.set_form = (f"bfloat16 major_to_minor=({order}) rows="
+                             + "x".join(map(str, rows.shape[1:])))
+            return HeldSet(rows, tuple(data.shape[1:]))
+        return self._memo(data, True, prepare)
 
     @staticmethod
     def _step_scales(lr_scale, lr_scale_bias, n_steps: int):
@@ -1383,10 +1606,9 @@ class FusedTrainer:
         if epoch is None:
             epoch = self._auto_epoch
         self._auto_epoch = epoch + 1
-        if self._train_epoch_fn is None:
-            self._build()
         with tracing.span("trainer.prep"):
-            data, target = self._mesh_place(data), self._mesh_place(target)
+            data = self.hold(data, batch)
+            target = self._mesh_place(target)
             idx, mask, ctrs = self._idx_matrix(np.asarray(indices), batch,
                                                ctr_base)
             scales, scales_b = self._step_scales(lr_scale, lr_scale_bias,
@@ -1414,10 +1636,9 @@ class FusedTrainer:
 
     def eval_epoch(self, data, target, indices, batch: int,
                    sync: bool = True) -> dict:
-        if self._eval_epoch_fn is None:
-            self._build()
         with tracing.span("trainer.prep"):
-            data, target = self._mesh_place(data), self._mesh_place(target)
+            data = self.hold(data, batch)
+            target = self._mesh_place(target)
             idx, mask, _ = self._idx_matrix(np.asarray(indices), batch)
         with tracing.span("trainer.dispatch"):
             ms = self._eval_epoch_fn(self.params, data, target, idx, mask)

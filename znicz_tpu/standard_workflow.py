@@ -401,6 +401,25 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                                        root.common.get("accum_steps")
                                        or 1))
         trainer.workflow = self
+        loader = self.loader
+        batch = loader.max_minibatch_size
+        if isinstance(loader, StreamingLoader):
+            data = target = None       # StreamTrainer reads the loader
+        else:
+            data = loader.original_data.devmem
+            target = (loader.original_targets.devmem
+                      if self.loss_function == "mse"
+                      else loader.original_labels.devmem)
+            # the resident set in the form every epoch program reads it
+            # in, made here so that the start line can say which.  Where
+            # that is a form of the trainer's own, no program of this
+            # run reads the loader's rows again (the unit graph does not
+            # run under run_fused; the trainer's memo answers for the
+            # source by its identity): their device buffer, the larger
+            # of the two, goes now and not at the run's end
+            if isinstance(trainer.hold(data, batch), fused.HeldSet) \
+                    and target is not data:
+                loader.original_data.release_device()
         # where this run executes, stated by the program itself: once
         # here and in every timeline row, so a run that came up on the
         # wrong platform, mesh or kernel tier cannot pass for another
@@ -422,7 +441,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                  # merged LRN+pool rows on the window kernels, and on
                  # the column-parity ones (ops/lrn_pool.py)
                  "pair_routes": fused.pair_routes(spec, self.forwards,
-                                                  mesh)}
+                                                  mesh),
+                 # how the trainer holds the resident set: as given, or
+                 # once in the dtype and layout its programs gather from
+                 "set_form": trainer.set_form}
         if any(la.kind == "attn_block" for la in spec.layers):
             # attention rows over a sliding window, and over everything
             # before (ops/attention.py)
@@ -457,18 +479,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             ckpt = TrainerCheckpointer(checkpoint_dir)
             own_ckpt = True
         ckpt_every = max(1, int(checkpoint_every or 1))
-        loader, decision = self.loader, self.decision
-        if isinstance(loader, StreamingLoader):
-            data = target = None       # StreamTrainer reads the loader
-        else:
-            data = loader.original_data.devmem
-            target = (loader.original_targets.devmem
-                      if self.loss_function == "mse"
-                      else loader.original_labels.devmem)
+        decision = self.decision
         bounds = np.cumsum([0] + list(loader.class_lengths))
         cls_idx = {k: np.arange(bounds[k], bounds[k + 1])
                    for k in (TEST, VALID, TRAIN)}
-        batch = loader.max_minibatch_size
         # the targets of one row: 1 for a label a row, T for a token
         # sequence (n_err counts targets, so *_err_pct divides by them)
         row_targets = (int(np.prod(target.shape[1:]))
