@@ -539,15 +539,15 @@ def test_the_sample_trains_through_the_launcher(sample):
 @pytest.fixture(scope="module")
 def counted_epoch():
     """One epoch of the sample through the Launcher, a Mamba layer in
-    its first attention layer's place so that every declared counter
-    counts: its ``train_step`` row and the registry's families after
-    it."""
+    its first attention layer's place and a gated-delta-rule layer in its
+    second's, so that every declared counter counts: its ``train_step``
+    row and the registry's families after it."""
     from znicz_tpu.config import root
     from znicz_tpu.launcher import Launcher
     from znicz_tpu.telemetry import flightrecorder
     from znicz_tpu.telemetry.registry import REGISTRY
     saved = root.decoder_lm.to_dict()
-    root.decoder_lm.layer_types = ["mamba", "sliding", "sliding", "full"]
+    root.decoder_lm.layer_types = ["mamba", "linear", "sliding", "full"]
     try:
         Launcher("znicz_tpu.models.decoder_lm", backend="xla", fused=True,
                  epochs=1, seed=7).run()

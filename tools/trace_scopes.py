@@ -26,8 +26,9 @@ import hlo_scope_bytes
 
 HEAD = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+)")
 CONTAINERS = ("while", "conditional", "call")
-INNER = ("rope", "scores", "route", "experts", "combine", "shared_expert",
-         "mamba_block", "ssd_scan")
+INNER = ("rope", "scores", "qk_norm", "route", "experts", "combine",
+         "shared_expert", "mamba_block", "ssd_scan", "mlp_block",
+         "gdn_block", "short_conv", "delta_rule")
 
 
 def instructions(text: str) -> dict:

@@ -8,8 +8,12 @@ sizes here are test widths (d 64, 4 query heads over 2 key/value heads of
 that ``python -m znicz_tpu znicz_tpu.models.decoder_lm --fused`` trains a
 decoder through the ``Launcher`` in seconds on a CPU.  A ``"mamba"`` in
 ``root.decoder_lm.layer_types`` puts a Mamba-2 mixer (``mamba_block``) in
-an attention block's place; ``shared_width``, ``tied`` and the four
-multipliers make the hybrid's other parts (all off by default).  Rows are drawn
+an attention block's place and a ``"linear"`` a gated-delta-rule one
+(``gdn_block``); ``shared_width``, ``tied`` and the four multipliers make
+the hybrid's other parts, ``mlp_width`` a dense gated feed-forward
+(``mlp_block``) in every expert block's place, ``qk_norm`` and
+``norm="post"`` the norms of a block that norms its sublayers' outputs
+(all off by default).  Rows are drawn
 from a seeded first-order Markov chain over the vocabulary, so the loss
 can fall below ``log(vocab)``; the target of a position is the next
 token.  ``root.decoder_lm.experts_held`` (``[first, count]``) makes every
@@ -41,6 +45,9 @@ root.decoder_lm.setdefaults({
                  "beta_slow": 1, "attention_factor": 1.1386}},
     "mamba": {"heads": 8, "heads_held": 8, "head_dim": 8, "state": 16,
               "conv": 4, "chunk": 8},
+    "gdn": {"heads": 4, "key_dim": 8, "value_dim": 16, "conv": 4,
+            "chunk": 8},
+    "mlp_width": 0, "qk_norm": False, "norm": "pre",
     "shared_width": 0, "tied": False, "positional": "rope",
     "embedding_scale": None, "residual_scale": None, "score_scale": None,
     "logits_scale": None,
@@ -56,14 +63,16 @@ def decoder_layers(cfg) -> list[dict]:
     config subtree or dict with the keys of ``root.decoder_lm``)
     describes."""
     get = cfg.get if hasattr(cfg, "get") else cfg.__getitem__
-    rope = get("rope")
-    rope = rope.to_dict() if hasattr(rope, "to_dict") else rope
+
+    def group(key) -> dict:
+        sub = get(key)
+        return sub.to_dict() if hasattr(sub, "to_dict") else dict(sub)
+    rope, mamba, gdn = group("rope"), group("mamba"), group("gdn")
     back = {"learning_rate": get("learning_rate"),
             "gradient_moment": get("gradient_moment"),
             "weights_decay": get("weights_decay")}
-    mamba = get("mamba")
-    mamba = mamba.to_dict() if hasattr(mamba, "to_dict") else dict(mamba)
     block = {"scale": get("residual_scale")}
+    normed = {**block, "norm": get("norm")}
     layers = [{"type": "embedding", "<-": back,
                "->": {"vocab": get("vocab"), "hidden": get("hidden"),
                       "scale": get("embedding_scale")}}]
@@ -71,15 +80,22 @@ def decoder_layers(cfg) -> list[dict]:
         if kind == "mamba":
             layers.append({"type": "mamba_block", "<-": back,
                            "->": {**block, **mamba}})
+        elif kind == "linear":
+            layers.append({"type": "gdn_block", "<-": back,
+                           "->": {**normed, **gdn}})
         else:
             layers.append({"type": "attn_block", "<-": back, "->": {
-                **block, "heads": get("heads"),
+                **normed, "qk_norm": get("qk_norm"), "heads": get("heads"),
                 "kv_heads": get("kv_heads"), "head_dim": get("head_dim"),
                 "window": get("window") if kind == "sliding" else None,
                 "positional": get("positional"),
                 "score_scale": get("score_scale"),
                 "rope": (None if get("positional") == "nope"
                          else dict(rope[kind]))}})
+        if get("mlp_width"):
+            layers.append({"type": "mlp_block", "<-": back, "->": {
+                **normed, "width": get("mlp_width")}})
+            continue
         layers.append({"type": "moe_block", "<-": back, "->": {
             **block, "experts": get("experts"),
             "experts_held": list(get("experts_held")),

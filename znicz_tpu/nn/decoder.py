@@ -1,12 +1,12 @@
 """The token-sequence layer kinds of a decoder language model as units:
-``embedding``, ``attn_block``, ``mamba_block``, ``moe_block`` and
-``lm_head``, each with its gradient unit.
+``embedding``, ``attn_block``, ``mamba_block``, ``gdn_block``,
+``moe_block``, ``mlp_block`` and ``lm_head``, each with its gradient unit.
 
 A unit here holds any number of parameter ``Vector``s, named by its
 ``LEAVES`` (the gradient unit holds ``velocity_<leaf>`` for each), where
 the znicz kinds hold ``weights`` and ``bias``.  The math is one pure
-function a kind in ``ops/attention.py`` / ``ops/ssm.py`` / ``ops/moe.py``;
-the fused
+function a kind in ``ops/attention.py`` / ``ops/ssm.py`` / ``ops/gdn.py`` /
+``ops/moe.py``; the fused
 trainer (``parallel/fused.py``) calls it directly, and the tick path
 (``wf.run()``) calls the same function here and ``jax.vjp`` of it in the
 gradient unit, followed by the one momentum-SGD update of ``ops/update``.
@@ -21,8 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..memory import Vector
-from ..ops import (attention as attn_ops, moe as moe_ops, softmax,
-                   ssm as ssm_ops, update)
+from ..ops import (attention as attn_ops, gdn as gdn_ops, moe as moe_ops,
+                   softmax, ssm as ssm_ops, update)
 from .nn_units import Forward, GradientDescentBase
 
 
@@ -58,6 +58,14 @@ class SequenceForward(Forward):
     def fused_config(self) -> dict:
         """The kind's static config (hashable values)."""
         return {"eps": self.rms_norm_eps, "scale": self.scale}
+
+    def _norm_at(self, norm: str) -> str:
+        """Where a block's norm sits: on the sublayer's input ("pre") or
+        on its output ("post")."""
+        if norm not in ("pre", "post"):
+            raise ValueError(f"{self.name}: norm {norm!r} is neither 'pre' "
+                             "nor 'post'")
+        return norm
 
     def leaf_shapes(self, in_shape: tuple) -> dict:
         raise NotImplementedError
@@ -134,17 +142,26 @@ class AttentionBlock(SequenceForward):
     causal, over a sliding ``window`` or (None) everything before, for
     the ``heads`` and ``kv_heads`` held here; rotary embeddings, or none
     (``positional="nope"``); ``score_scale`` on the scores where the
-    model states one (else ``1 / sqrt(head_dim)``)."""
+    model states one (else ``1 / sqrt(head_dim)``).  ``qk_norm``: RMS
+    norms on the query and key projections (leaves ``gq``, ``gk``);
+    ``norm="post"``: ``g1``'s norm on the mixer's output instead,
+    ``x + scale * RMSNorm(Attn(x); g1)``."""
 
     MAPPING = ("attn_block",)
     KIND = "attn_block"
     FWD = staticmethod(attn_ops.attn_block_fwd)
     LEAVES = ("g1", "wq", "wk", "wv", "wo")
+    QK_LEAVES = ("gq", "gk")
 
     def __init__(self, workflow=None, name=None, heads=None, kv_heads=None,
                  head_dim=None, window=None, rope=None, positional="rope",
-                 score_scale=None, **kwargs):
+                 score_scale=None, qk_norm=False, norm="pre", **kwargs):
         super().__init__(workflow, name, **kwargs)
+        self.qk_norm, self.norm = bool(qk_norm), self._norm_at(norm)
+        if self.qk_norm:
+            self.LEAVES = type(self).LEAVES + self.QK_LEAVES
+            for leaf in self.QK_LEAVES:
+                setattr(self, leaf, Vector())
         self.heads, self.kv_heads = int(heads), int(kv_heads)
         self.head_dim = int(head_dim)
         self.window = None if window is None else int(window)
@@ -160,18 +177,46 @@ class AttentionBlock(SequenceForward):
         return {**super().fused_config(), "heads": self.heads,
                 "kv_heads": self.kv_heads, "head_dim": self.head_dim,
                 "window": self.window, "score_scale": self.score_scale,
+                "qk_norm": self.qk_norm, "norm": self.norm,
                 "rope": (None if self.rope is None
                          else tuple(sorted(self.rope.items())))}
 
     def leaf_shapes(self, in_shape):
         d, hd = in_shape[-1], self.head_dim
-        return {"g1": (d,), "wq": (d, self.heads * hd),
-                "wk": (d, self.kv_heads * hd),
-                "wv": (d, self.kv_heads * hd),
-                "wo": (self.heads * hd, d)}
+        shapes = {"g1": (d,), "wq": (d, self.heads * hd),
+                  "wk": (d, self.kv_heads * hd),
+                  "wv": (d, self.kv_heads * hd),
+                  "wo": (self.heads * hd, d)}
+        if self.qk_norm:
+            shapes.update(gq=(self.heads * hd,), gk=(self.kv_heads * hd,))
+        return shapes
 
 
-class MambaBlock(SequenceForward):
+class Mamba2Start:
+    """The Mamba-2 starting point of a decay's leaves, which the gated
+    delta rule's layer shares: ``a = -exp(a_log)`` uniform in ``[-16,
+    -1]``, a step ``softplus(dt_bias)`` log-uniform in ``[0.001, 0.1]``,
+    conv taps (``conv_*``: ``(taps, channels)``) uniform within ``1 /
+    sqrt(taps)`` and no bias; every other leaf as the base fills it."""
+
+    def _fill_leaf(self, leaf, shape):
+        gen = self.prng
+        if leaf == "a_log":
+            return np.log(np.asarray(gen.uniform(1.0, 16.0, shape),
+                                     np.float32))
+        if leaf == "dt_bias":
+            dt = np.exp(np.asarray(gen.uniform(np.log(1e-3), np.log(0.1),
+                                               shape), np.float64))
+            return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+        if leaf == "conv_b":
+            return np.zeros(shape, np.float32)
+        if leaf.startswith("conv_"):
+            lim = 1.0 / np.sqrt(shape[0])
+            return np.asarray(gen.uniform(-lim, lim, shape), np.float32)
+        return super()._fill_leaf(leaf, shape)
+
+
+class MambaBlock(Mamba2Start, SequenceForward):
     """``x + scale * Mamba2(RMSNorm(x; g1))`` for the ``heads_held`` first
     of the model's ``heads`` (``ops/ssm.py``): a chunked scan over the
     sequence, whose length has to be a multiple of ``chunk``."""
@@ -208,25 +253,39 @@ class MambaBlock(SequenceForward):
         return ssm_ops.leaf_shapes(in_shape[-1], self.heads_held,
                                    self.head_dim, self.state, self.conv)
 
-    def _fill_leaf(self, leaf, shape):
-        """The Mamba-2 starting point: ``a = -exp(a_log)`` uniform in
-        ``[-16, -1]``, a step ``softplus(dt_bias)`` log-uniform in
-        ``[0.001, 0.1]``, a skip of 1, taps uniform within ``1 /
-        sqrt(conv)`` and no bias."""
-        gen = self.prng
-        if leaf == "a_log":
-            return np.log(np.asarray(gen.uniform(1.0, 16.0, shape),
-                                     np.float32))
-        if leaf == "dt_bias":
-            dt = np.exp(np.asarray(gen.uniform(np.log(1e-3), np.log(0.1),
-                                               shape), np.float64))
-            return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
-        if leaf == "conv_w":
-            lim = 1.0 / np.sqrt(self.conv)
-            return np.asarray(gen.uniform(-lim, lim, shape), np.float32)
-        if leaf == "conv_b":
-            return np.zeros(shape, np.float32)
-        return super()._fill_leaf(leaf, shape)
+
+class GatedDeltaBlock(Mamba2Start, SequenceForward):
+    """``x + scale * RMSNorm(GatedDeltaNet(x); g1)`` (``norm="post"``; or
+    the norm on the mixer's input): the gated-delta-rule linear-attention
+    mixer (``ops/gdn.py``), ``heads`` heads with keys of ``key_dim`` and
+    values of ``value_dim``, in chunks of ``chunk`` tokens, of which the
+    sequence length has to be a multiple."""
+
+    MAPPING = ("gdn_block",)
+    KIND = "gdn_block"
+    FWD = staticmethod(gdn_ops.gdn_block_fwd)
+    LEAVES = gdn_ops.LEAVES
+
+    def __init__(self, workflow=None, name=None, heads=None, key_dim=None,
+                 value_dim=None, conv=4, chunk=64, norm="pre", **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        self.heads, self.key_dim = int(heads), int(key_dim)
+        self.value_dim = int(value_dim)
+        self.conv, self.chunk = int(conv), int(chunk)
+        self.norm = self._norm_at(norm)
+
+    def fused_config(self):
+        return {**super().fused_config(), "heads": self.heads,
+                "key_dim": self.key_dim, "value_dim": self.value_dim,
+                "conv": self.conv, "chunk": self.chunk, "norm": self.norm}
+
+    def leaf_shapes(self, in_shape):
+        if in_shape[-2] % self.chunk:
+            raise ValueError(
+                f"{self.name}: sequence length {in_shape[-2]} is no "
+                f"multiple of the delta rule's chunk {self.chunk}")
+        return gdn_ops.leaf_shapes(in_shape[-1], self.heads, self.key_dim,
+                                   self.value_dim, self.conv)
 
 
 class MoEBlock(SequenceForward):
@@ -274,6 +333,29 @@ class MoEBlock(SequenceForward):
             s = self.shared_width
             shapes.update(sg=(d, s), su=(d, s), sd=(s, d))
         return shapes
+
+
+class MLPBlock(SequenceForward):
+    """``x + scale * MLP(RMSNorm(x; g2))`` (or, ``norm="post"``, the norm
+    on the output): the dense gated feed-forward of ``width`` columns,
+    ``MLP(n) = (silu(n Wg) * n Wu) Wd`` (``ops/moe.mlp_block_fwd``)."""
+
+    MAPPING = ("mlp_block",)
+    KIND = "mlp_block"
+    FWD = staticmethod(moe_ops.mlp_block_fwd)
+    LEAVES = ("g2", "wg", "wu", "wd")
+
+    def __init__(self, workflow=None, name=None, width=None, norm="pre",
+                 **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        self.width, self.norm = int(width), self._norm_at(norm)
+
+    def fused_config(self):
+        return {**super().fused_config(), "norm": self.norm}
+
+    def leaf_shapes(self, in_shape):
+        d, f = in_shape[-1], self.width
+        return {"g2": (d,), "wg": (d, f), "wu": (d, f), "wd": (f, d)}
 
 
 class LMHead(SequenceForward):
@@ -346,7 +428,8 @@ def units_of(layers: list, workflow=None) -> list:
     are not initialized.  For code that wants a model's spec or static
     configs without a workflow (``fused.sequence_layer``)."""
     kinds = {cls.MAPPING[0]: cls for cls in (
-        Embedding, AttentionBlock, MambaBlock, MoEBlock, LMHead)}
+        Embedding, AttentionBlock, MambaBlock, GatedDeltaBlock, MoEBlock,
+        MLPBlock, LMHead)}
     made = []
     for la in layers:
         forward = dict(la["->"])
@@ -363,8 +446,8 @@ class SequenceGD(GradientDescentBase):
     unit's function at its input, then momentum SGD on every leaf with
     the layer's one learning rate, decay and moment."""
 
-    MAPPING = ("embedding", "attn_block", "mamba_block", "moe_block",
-               "lm_head")
+    MAPPING = ("embedding", "attn_block", "mamba_block", "gdn_block",
+               "moe_block", "mlp_block", "lm_head")
 
     def setup_from_forward(self, fwd):
         super().setup_from_forward(fwd)

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from . import tuning
-from .attention import rms_norm
+from .attention import residual_block, rms_norm
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -390,3 +390,16 @@ def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     if cfg.get("scale") is not None:
         out = out * cfg["scale"]
     return x + out.reshape(b, t, d), counters
+
+
+def mlp_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
+    """``mlp_block``: the dense gated feed-forward, ``x + scale *
+    MLP(RMSNorm(x; g2))`` or, with ``cfg["norm"]`` "post", ``x + scale *
+    RMSNorm(MLP(x); g2)``; ``MLP(n) = (silu(n Wg) * n Wu) Wd``, the
+    shared expert's form over the whole width.  Leaves ``g2 (d,)``, ``wg``,
+    ``wu (d, f)``, ``wd (f, d)``; ``cfg``: ``eps``, ``norm``, ``scale``."""
+    g2, wg, wu, wd = leaves
+    b, t, d = x.shape
+    with jax.named_scope("mlp_block"):
+        return residual_block(x, g2, cfg, lambda xn: shared_expert(
+            xn.reshape(b * t, d), wg, wu, wd, cdt).reshape(b, t, d)), {}

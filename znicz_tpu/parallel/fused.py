@@ -32,7 +32,7 @@ import numpy as np
 from jax.experimental.layout import Format, Layout
 
 from ..ops import (activations, attention as attn_ops, conv as conv_ops,
-                   deconv as deconv_ops, dropout as drop_ops,
+                   deconv as deconv_ops, dropout as drop_ops, gdn as gdn_ops,
                    lrn_pool as lrn_pool_ops, moe as moe_ops,
                    normalization as lrn_ops, pooling as pool_ops,
                    softmax as softmax_ops, ssm as ssm_ops, tuning)
@@ -50,7 +50,9 @@ PAIR_KINDS = ("fc", "conv", "deconv")
 SEQUENCE_FWD = {"embed": attn_ops.embed_fwd,
                 "attn_block": attn_ops.attn_block_fwd,
                 "mamba_block": ssm_ops.mamba_block_fwd,
+                "gdn_block": gdn_ops.gdn_block_fwd,
                 "moe_block": moe_ops.moe_block_fwd,
+                "mlp_block": moe_ops.mlp_block_fwd,
                 "lm_head": attn_ops.lm_head_fwd}
 
 #: Layer kinds with trainable parameters.
@@ -63,8 +65,8 @@ class LayerSpec:
     #                               avg_pool | stochastic_pool |
     #                               stochastic_abs_pool | lrn | lrn_pool |
     #                               dropout | activation | embed |
-    #                               attn_block | mamba_block | moe_block |
-    #                               lm_head
+    #                               attn_block | mamba_block | gdn_block |
+    #                               moe_block | mlp_block | lm_head
     activation: str               # activations.BY_NAME key; last fc layer
     include_bias: bool            # of a softmax model keeps "linear"
     hypers: tuple                 # (lr, weights_decay, l1_vs_l2, momentum)
@@ -515,6 +517,25 @@ def attn_routes(spec: ModelSpec) -> str:
     return " ".join(f"{k}:{v}" for k, v in counts.items())
 
 
+#: the mixer kinds of a decoder's hidden layers, by the name the start
+#: record counts them under
+MIXERS = {"gdn_block": "linear", "mamba_block": "ssm"}
+
+
+def mixer_routes(spec: ModelSpec) -> str:
+    """``linear:<n> full:<m>`` (and ``ssm:``, ``window:`` where the model
+    has such layers): the hidden layers of ``spec`` by the mixer each
+    runs, attention rows as :func:`attn_routes` names them."""
+    counts: dict = {}
+    for layer in spec.layers:
+        name = (attn_ops.attn_route(layer.cfg) if layer.kind == "attn_block"
+                else MIXERS.get(layer.kind))
+        if name is not None:
+            counts[name] = counts.get(name, 0) + 1
+    order = ("linear", "ssm", "window", "full")
+    return " ".join(f"{k}:{counts[k]}" for k in order if k in counts)
+
+
 # -- pure math (all traced; spec is static) --------------------------------
 def _takes_window(x, cfg) -> bool:
     """``windowed_pairs``' rule on a pair's traced input: the batch as
@@ -583,6 +604,10 @@ COUNTERS = {
         "train_ssm_tokens",
         "token-layer pairs the state-space layers scanned in the last "
         "epoch (tokens a step times such layers)")),
+    "gdn_tokens": ("sum", lambda: REGISTRY.gauge(
+        "train_gdn_tokens",
+        "token-layer pairs the linear-attention (gated delta rule) layers "
+        "scanned in the last epoch (tokens a step times such layers)")),
 }
 
 
@@ -1154,8 +1179,11 @@ def _set_prepares():
 def state_crowds_device(spec: ModelSpec, params) -> bool:
     """Whether the training state with one more copy of the parameters
     beside it (three times the leaves' bytes: parameters, velocities, and
-    a snapshot in flight or a caller's copy) takes more than two thirds of
-    the device's memory.  The trainer of such a model gives the step what
+    a snapshot in flight or a caller's copy) takes more than five eighths
+    of the device's memory (a v5e's 15.75 GiB: over 10.57 GB; 12.67 and
+    11.15 GB in the two cells that are, 7.14 GB in the decoder cell that is
+    not: ``PERF.md`` section 6, PR 36 has what the chip refused at two
+    thirds).  The trainer of such a model gives the step what
     room it can: it keeps the backward's recomputation apart from the
     forward (``ModelSpec.fresh_backward``), runs one minibatch a launch
     (a ``lax.scan`` over the steps holds copies of part of the state it
@@ -1165,7 +1193,7 @@ def state_crowds_device(spec: ModelSpec, params) -> bool:
     room = tuning.device_memory_bytes()
     if room is None or any(la.kind in PAIR_KINDS for la in spec.layers):
         return False
-    return 3 * _leaf_bytes(params) > 2 * room // 3
+    return 3 * _leaf_bytes(params) > 5 * room // 8
 
 
 def _leaf_bytes(params) -> int:
