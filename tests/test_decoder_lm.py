@@ -224,6 +224,80 @@ def test_the_way_there_and_back_has_the_dense_gradients(case, expected):
         assert np.asarray(got[1]).any()
 
 
+def _expert_sum_case(n=64, d=64, f=32):
+    """``(xn, wg, wu, wd, cot)``: rows, experts 2 and 3 of 8, and a
+    cotangent of the sum."""
+    ks = jax.random.split(jax.random.key(37), 5)
+    wg, wu = (jax.random.normal(k, (2, d, f)) * 0.1 for k in ks[1:3])
+    return (jax.random.normal(ks[0], (n, d)), wg, wu,
+            jax.random.normal(ks[3], (2, f, d)) * 0.1,
+            jax.random.normal(ks[4], (n, d)))
+
+
+def _made(jaxpr, later_piece=True):
+    """``(primitive, aval)`` of every array a jaxpr makes, those of its
+    inner jaxprs too; ``later_piece`` False: but for the branch of a
+    ``cond`` that runs (index 1: the later piece of the sorted pairs); the
+    branch that does nothing is walked."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            if hasattr(v.aval, "shape"):
+                yield eqn.primitive.name, v.aval
+        for key, param in eqn.params.items():
+            inner = param if isinstance(param, (tuple, list)) else (param,)
+            if (not later_piece and eqn.primitive.name == "cond"
+                    and key == "branches"):
+                inner = inner[:1]
+            for sub in inner:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _made(sub, later_piece)
+
+
+def test_a_skipped_piece_makes_no_gradient():
+    """The gradient of the expert sum with a later piece: no array of an
+    expert leaf's shape is made of nothing (``broadcast_in_dim``) and none
+    is added, but in the branch that runs the later piece; there the
+    piece's gradients are added into the first piece's."""
+    xn, wg, wu, wd, cot = _expert_sum_case()
+    weights, experts = _routed("balanced", 64)
+    assert moe.piece_rows(128, 0.25) == (48, 80)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(moe.held_expert_sum(
+        a[0], a[1], experts, *a[2:], 2, jnp.float32, 0.25)[0] * cot),
+        range(5)))(xn, weights, wg, wu, wd).jaxpr
+    leaf = {wg.shape, wd.shape}
+    made = [(name, aval.shape) for name, aval in _made(jaxpr, False)
+            if aval.shape in leaf]
+    assert made, "the walk sees the leaves' gradients"
+    assert not [m for m in made if m[0] in (
+        "broadcast_in_dim", "add", "add_any")], made
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    later = [e.primitive.name for e in conds[-1].params["branches"][1].eqns
+             if e.outvars[0].aval.shape in leaf]
+    assert later.count("add") == 3, later
+
+
+@pytest.mark.parametrize("case", ["balanced", "none_held"])
+def test_a_skipped_piece_leaves_the_first_pieces_gradients(case):
+    """Two pieces of which the later one is skipped against ONE piece over
+    the same pairs: output and all five gradients bit for bit."""
+    xn, wg, wu, wd, cot = _expert_sum_case()
+    weights, experts = _routed(case, 64)
+
+    def run(expected):
+        def loss(xn, weights, wg, wu, wd):
+            out, _, moved = moe.held_expert_sum(
+                xn, weights, experts, wg, wu, wd, 2, jnp.float32, expected)
+            return jnp.sum(out * cot), (out, moved)
+        return jax.value_and_grad(loss, range(5), has_aux=True)(
+            xn, weights, wg, wu, wd)
+    (_, (out, moved)), got = run(0.25)
+    (_, (one, every)), want = run(1.0)
+    assert (int(moved), int(every)) == (48, 128)
+    for a, b in zip((out,) + got, (one,) + want):
+        np.testing.assert_array_equal(a, b)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["one_gather", "a_gather_a_slab"])
 def test_pair_rows_are_summed_live_and_in_order(dtype):
@@ -289,17 +363,8 @@ def test_rows_moved_counts_the_pieces_that_ran(monkeypatch, collapsed):
 
 def _float_arrays(jaxpr):
     """Every float array a jaxpr makes, those of its inner jaxprs too."""
-    for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            if hasattr(v.aval, "shape") and jnp.issubdtype(
-                    v.aval.dtype, jnp.floating):
-                yield v.aval.shape
-        for param in eqn.params.values():
-            for inner in (param if isinstance(param, (tuple, list))
-                          else (param,)):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _float_arrays(inner)
+    return (aval.shape for _, aval in _made(jaxpr)
+            if jnp.issubdtype(aval.dtype, jnp.floating))
 
 
 @pytest.mark.parametrize("collapsed", [False, True],

@@ -162,3 +162,46 @@ def test_rows_there_and_back_backward_compiles_for_a_v5e(one_chip, mosaic):
     # the gradients of the rows, of the products' rows and of the weights
     assert f"bf16[{moe.CHUNK_TOKENS},{D}]" in text
     assert _pair_sized_buffers(text) == 0
+
+
+# -- a later piece that does not run: granite-4.0-h-small's expert layer ----
+G_N, G_D, G_F, G_HELD, G_EXPERTS, G_TOP_K = 2048, 4096, 768, 9, 72, 10
+
+
+def _hlo_scope_bytes():
+    """``tools/hlo_scope_bytes.py``, which is no package's."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "hlo_scope_bytes", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "hlo_scope_bytes.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_a_skipped_later_piece_costs_no_leaf_sized_pass(one_chip, mosaic):
+    """One expert layer's backward at ``granite-4.0-h-small``'s widths, 9
+    held experts of 4096 x 768 over 2,048 tokens at 10 a token (pieces of
+    3,840 and 16,640 rows): beside the conditional, and in its branch that
+    does nothing, no ``broadcast`` and no ``copy`` makes an array of an
+    expert leaf's shape (56.6 MB each in bfloat16)."""
+    assert moe.piece_rows(G_N * G_TOP_K, G_HELD / G_EXPERTS) == (3840, 16640)
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grads(xn, weights, experts, wg, wu, wd, d_out):
+        return jax.vjp(lambda *a: moe.held_expert_sum(
+            a[0], a[1], experts, *a[2:], 0, jnp.bfloat16,
+            G_HELD / G_EXPERTS)[0], xn, weights, wg, wu, wd)[1](d_out)
+    text = _compiled_text(
+        grads, s((G_N, G_D)), s((G_N, G_TOP_K)),
+        s((G_N, G_TOP_K), jnp.int32), s((G_HELD, G_D, G_F)),
+        s((G_HELD, G_D, G_F)), s((G_HELD, G_F, G_D)), s((G_N, G_D)))
+    made = _hlo_scope_bytes().made_of_shape(
+        text, [f"{G_HELD},{G_D},{G_F}", f"{G_HELD},{G_F},{G_D}"])
+    assert sum(n for (where, opcode, _), n in made.items()
+               if where == "later" and opcode.endswith("tgmm")) == 3, made
+    passes = {key: n for key, n in made.items() if key[0] in ("step", "idle")
+              and key[1] in ("broadcast", "copy")}
+    assert not passes, passes

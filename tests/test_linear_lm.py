@@ -346,19 +346,27 @@ def test_the_vocabulary_shares_stand_side_by_side():
 #: the output) and ``decoder.py`` gained two units: with none of that asked
 #: for, ``mellum2``'s and ``granite``'s layer lists have to trace to what
 #: they did.  A change that means to alter those programs replaces the
-#: digest and says so.
+#: digest and says so.  PR 37 meant to, where a list has a later piece of
+#: the sorted pairs (a quarter and a half of the experts held: the sum over
+#: the pieces is a ``custom_vjp`` whose backward stands under the forward's
+#: condition, ``ops/moe.sum_of_pieces``), and replaced those two; a list
+#: that holds every expert has one piece and no conditional, and traces to
+#: what it did before.
 STEPS_BEFORE = {
     "tiny-decoder":
-    "d8b0c96e381086fbd224b265fb6a9c9540891d892ce3ea3230cf769e7cb7c171",
+    "e818025f7d20ad50392e2e71b32d9b506dff8125c0f3cd24fab3e0f1fc62f109",
+    "tiny-decoder-all-held":
+    "d46cc8282b279da6d6793c71774b30ddf3dd3685a871c4fed74b1f747ed15dcd",
     "tiny-hybrid":
-    "18e1f1d7fbe017e0536f6c2ebe381df652f7c744455b79aecc8e12187e91f4a4"}
+    "fce75a833f1367f66dc98e360f4e14eadf93bbd5e8d92e45dceaefffd70efe06"}
 
 
 @pytest.mark.parametrize("name", sorted(STEPS_BEFORE))
 def test_the_other_decoders_build_the_programs_they_did(name):
-    if name == "tiny-decoder":
+    if name.startswith("tiny-decoder"):
         import test_decoder_lm as before
-        _, spec, weights, x, y = before.setup((2, 2))
+        _, spec, weights, x, y = before.setup(
+            (0, 8) if name.endswith("all-held") else (2, 2))
     else:
         import test_hybrid_lm as before
         _, spec, weights, x, y = before.setup()

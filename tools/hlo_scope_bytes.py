@@ -9,6 +9,15 @@ what the chip moved, and no time.
     python3 tools/hlo_scope_bytes.py train_epoch.hlo.txt \
         [--in bwd/L02.moe_block] experts combine
 
+    python3 tools/hlo_scope_bytes.py train_epoch.hlo.txt \
+        --shape 9,4096,768 --shape 9,768,4096
+
+lists instead what MAKES an array of those shapes, whatever its scope
+(the zeros a conditional's idle branch hands back carry none): by
+computation (``step``: the program's own; ``idle``: a conditional's
+branch 0; ``later``: its branch 1), opcode and result, with the bytes
+written.
+
 A gather's operand is counted whole, though it reads only the rows it
 takes: its bytes in overstate; bytes out are what it writes.  An
 instruction under ``cond/branch_1_fun`` (the later piece of the sorted
@@ -75,8 +84,46 @@ def rows(text: str, scopes, within: str = ""):
     return out
 
 
+def made_of_shape(text: str, dims) -> collections.Counter:
+    """``{(where, opcode, result): arrays made}`` over the instructions
+    outside the fused computations whose result is one array of a shape in
+    ``dims`` (``"9,4096,768"``; ``result``: ``bf16[9,4096,768]``); views,
+    buffer markers and the half of an asynchronous copy that writes nothing
+    are left out."""
+    branches = dict(
+        pair for found in re.findall(
+            r"branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}", text)
+        for pair in zip(found, ("idle", "later")))
+    silent = SKIP | {"copy-start", "slice-start"}
+    made, where = collections.Counter(), "step"
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[0].lstrip("%")
+            where = ("step" if line.startswith("ENTRY") else branches.get(
+                name, "fused" if "fused_computation" in name else name))
+        m = LINE.match(line)
+        shape = m and SHAPE.match(m.group(2))
+        if (shape and shape.group(2) in dims and where != "fused"
+                and m.group(3) not in silent
+                and (m.group(3) != "custom-call"        # a kernel, not a
+                     or "tpu_custom_call" in line)):    # buffer's marker
+            opcode = m.group(3)
+            if opcode in ("fusion", "custom-call"):
+                opcode += ":" + re.sub(r"[.\d]+$", "", m.group(1))
+            made[where, opcode, shape.group(0)] += 1
+    return made
+
+
 def main(argv) -> int:
     args, within = list(argv[1:]), ""
+    if "--shape" in args:
+        dims = [args[i + 1] for i, a in enumerate(args) if a == "--shape"]
+        with open(args[0]) as fh:
+            made = made_of_shape(fh.read(), dims)
+        for (where, opcode, result), n in sorted(made.items()):
+            print(f"{where:24s} {opcode:24s} {result:20s} x{n:3d} "
+                  f"{n * nbytes(result) / 1e6:9.1f} MB written")
+        return 0
     if "--in" in args:
         at = args.index("--in")
         within = args[at + 1]

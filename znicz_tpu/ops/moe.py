@@ -20,7 +20,12 @@ capacity: shapes are static, so the sorted pairs are taken in two pieces
 half times a balanced router's share (12,288 of a chunk's 32,768 pairs
 where a quarter of the experts is held), holds every held pair in the
 usual case; the later one takes all the rest and runs only when the held
-pairs reach into it.  What moves, each way once: a piece's rows of the
+pairs reach into it, forward AND backward: the sum over the pieces has a
+``custom_vjp`` (``sum_of_pieces``) whose backward stands under the same
+condition and adds the later piece's gradients into the first's, so a
+piece that did not run makes no gradient of zeros for the experts' weights
+and none is added (170 MB a layer at 9 x 4096 x 768).  What moves, each
+way once: a piece's rows of the
 normalised input, gathered into expert order in the operand dtype
 (``dispatch_rows``), and the products' float32 rows, gathered back and
 summed ``top_k`` a token with their routing weights (``combine``); the
@@ -39,6 +44,8 @@ repo's other ops:
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -93,9 +100,11 @@ def _mosaic_tgmm(lhs, g, sizes):
     """``lhs[rows of group e].T @ g[rows of e]`` for every group: the
     kernel selects the rows of a boundary tile that are its group's (a
     select, not a product), so what the rows beyond ``sum(sizes)`` hold
-    reaches no group."""
+    reaches no group.  Accumulated in float32 and written in the operands'
+    dtype, rounded once as the kernel stores a group: the rounding a
+    ``convert`` of a float32 result would do in a pass of its own."""
     return _megablox().tgmm(
-        lhs.swapaxes(0, 1), g, sizes, jnp.float32,
+        lhs.swapaxes(0, 1), g, sizes, lhs.dtype,
         (min(TILE_M, lhs.shape[0]), _tile(lhs.shape[1]), _tile(g.shape[1])),
         interpret=tuning.interpret_mode())
 
@@ -263,6 +272,71 @@ def piece_rows(pairs: int, expected: float) -> tuple:
     return first, pairs - first
 
 
+def _piece(lo: int, rows: int, sort):
+    """The sorted pairs ``lo .. lo + rows`` of ``sort = (order, place,
+    counts, ends)`` as a function of the operands, rematerialised by
+    itself in a backward pass."""
+    order, place, counts, ends = sort
+
+    @jax.checkpoint
+    def run(xc, weights, wgc, wuc, wdc):
+        taken = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+        sizes = (jnp.clip(ends, lo, lo + rows)
+                 - jnp.clip(ends - counts, lo, lo + rows))
+        row, live = place - lo, jnp.sum(sizes)
+        with jax.named_scope("experts"):
+            xs = dispatch_rows(xc, taken, row, live)
+            hidden = (jax.nn.silu(grouped_matmul(xs, wgc, sizes))
+                      * grouped_matmul(xs, wuc, sizes)).astype(xc.dtype)
+            ys = grouped_matmul(hidden, wdc, sizes)
+        with jax.named_scope("combine"):
+            return combine(ys, weights, taken, row, live)
+    return run
+
+
+def _reaches_later(lengths, sort):
+    """Whether the held pairs reach beyond the first piece."""
+    return sort[3][-1] > lengths[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def sum_of_pieces(lengths, operands, sort):
+    """The first piece of the sorted pairs plus, where the held pairs reach
+    into it, the later one (``lengths = piece_rows(...)``, both nonzero).
+    The backward stands under the forward's condition and adds the later
+    piece's gradients into the first's: a piece that did not run makes no
+    gradient, not one of zeros."""
+    first_rows, rest_rows = lengths
+    return jax.lax.cond(
+        _reaches_later(lengths, sort),
+        lambda out: out + _piece(first_rows, rest_rows, sort)(*operands),
+        lambda out: out, _piece(0, first_rows, sort)(*operands))
+
+
+def _sum_fwd(lengths, operands, sort):
+    return sum_of_pieces(lengths, operands, sort), (operands, sort)
+
+
+def _sum_bwd(lengths, res, d_out):
+    operands, sort = res
+    first_rows, rest_rows = lengths
+
+    def grads(lo, rows):
+        return jax.vjp(_piece(lo, rows, sort), *operands)[1](d_out)
+    got = jax.lax.cond(
+        _reaches_later(lengths, sort),
+        lambda got: jax.tree.map(jnp.add, got, grads(first_rows, rest_rows)),
+        lambda got: got, grads(0, first_rows))
+    # the weights' gradients leave the conditional's tuple by themselves:
+    # beside the rows' gradients, which the backward pass goes on with at
+    # once, XLA's scheduler holds them all to the end of the step, a
+    # layer's three leaves of gradients each (PERF.md section 6, PR 37)
+    return got[:2] + jax.lax.optimization_barrier(got[2:]), None
+
+
+sum_of_pieces.defvjp(_sum_fwd, _sum_bwd)
+
+
 def held_expert_sum(xn, weights, experts, wg, wu, wd, first: int, cdt,
                     expected: float = 1.0):
     """``sum_k [e_k held] w_k * (silu(x Wg_e) * x Wu_e) Wd_e`` over the
@@ -276,8 +350,10 @@ def held_expert_sum(xn, weights, experts, wg, wu, wd, first: int, cdt,
     expert and taken in two pieces (``piece_rows``): the first, one
     and a half times that share (12,288 of a chunk's 32,768 for a quarter),
     holds every held pair in the usual case; the later one takes all the
-    rest and is skipped (``lax.cond``) unless the held pairs reach into it,
-    so none is dropped whatever the spread.  What moves, a piece: its rows
+    rest and is skipped unless the held pairs reach into it, so none is
+    dropped whatever the spread; its backward is skipped with it
+    (``sum_of_pieces``: the first piece's gradients go on as they are, no
+    zeros are made or added).  What moves, a piece: its rows
     of ``xn`` gathered in the operand dtype; the products' float32 rows
     gathered back and summed ``top_k`` a token with their weights, a pair
     that is not live in the piece reading nothing; backward the same two
@@ -298,33 +374,14 @@ def held_expert_sum(xn, weights, experts, wg, wu, wd, first: int, cdt,
                      axis=0, dtype=jnp.int32)
     ends = jnp.cumsum(counts)
     xc, wgc, wuc, wdc = (a.astype(cdt) for a in (xn, wg, wu, wd))
-    first_rows, rest_rows = piece_rows(pairs, expected)
-
-    def piece(lo: int, rows: int):
-        """The sorted pairs ``lo .. lo + rows``."""
-        @jax.checkpoint
-        def run(xc, weights, wgc, wuc, wdc):
-            taken = jax.lax.dynamic_slice_in_dim(order, lo, rows)
-            sizes = (jnp.clip(ends, lo, lo + rows)
-                     - jnp.clip(ends - counts, lo, lo + rows))
-            row, live = place - lo, jnp.sum(sizes)
-            with jax.named_scope("experts"):
-                xs = dispatch_rows(xc, taken, row, live)
-                hidden = (jax.nn.silu(grouped_matmul(xs, wgc, sizes))
-                          * grouped_matmul(xs, wuc, sizes)).astype(cdt)
-                ys = grouped_matmul(hidden, wdc, sizes)
-            with jax.named_scope("combine"):
-                return combine(ys, weights, taken, row, live)
-        return run
-    out = piece(0, first_rows)(xc, weights, wgc, wuc, wdc)
+    operands, sort = (xc, weights, wgc, wuc, wdc), (order, place, counts, ends)
+    lengths = first_rows, rest_rows = piece_rows(pairs, expected)
     moved = jnp.asarray(first_rows, jnp.int32)
-    if rest_rows:
-        later = ends[-1] > first_rows
-        out = jax.lax.cond(
-            later,
-            lambda out, *args: out + piece(first_rows, rest_rows)(*args),
-            lambda out, *args: out, out, xc, weights, wgc, wuc, wdc)
-        moved = moved + jnp.where(later, rest_rows, 0).astype(jnp.int32)
+    if not rest_rows:
+        return _piece(0, first_rows, sort)(*operands), counts, moved
+    out = sum_of_pieces(lengths, operands, sort)
+    moved = moved + jnp.where(_reaches_later(lengths, sort), rest_rows,
+                              0).astype(jnp.int32)
     return out, counts, moved
 
 
