@@ -277,8 +277,8 @@ def test_a_state_that_crowds_the_device_trains_to_the_same_numbers(
         launches = []
         trainer._build()
         step = trainer._train_epoch_fn
-        trainer._train_epoch_fn = lambda *a: (launches.append(
-            a[4].shape[0]), step(*a))[1]
+        trainer._train_epoch_fn = lambda *a, **kw: (launches.append(
+            a[4].shape[0]), step(*a, **kw))[1]
         ms = trainer.train_epoch(*rows, np.arange(6), 2)
         return trainer, ms, launches
     roomy, ms_roomy, launches = three_steps(5 * held)

@@ -287,7 +287,15 @@ class TestIntrospection:
         status, _b, _h = _post(server.url, {"inputs": X["kohonen"],
                                             "model": "kohonen"})
         assert status == 200
-        after = REGISTRY.as_dict()["model_requests_total"]
+        # the handler counts the request after it has sent the answer:
+        # the client may read the registry first (1 run in 3 did)
+        deadline = time.monotonic() + 5.0
+        while True:
+            after = REGISTRY.as_dict()["model_requests_total"]
+            if (after.get("code=200,model=kohonen", 0) > n200
+                    or time.monotonic() > deadline):
+                break
+            time.sleep(0.01)
         assert after.get("code=200,model=kohonen", 0) == n200 + 1
 
 

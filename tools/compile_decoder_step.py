@@ -3,8 +3,12 @@
 a token-sequence configuration of the benchmark (its model file is the one
 the configuration names) for a DESCRIBED v5e (no chip:
 ``on-chip-measurement`` guide, section 2) and print each program's
-``memory_analysis``.  Nothing runs; this says what the TPU's compiler
-accepts and how many bytes the program needs beside its arguments.
+``memory_analysis``, as gigabytes and as the ``plan`` the trainer's
+register of executables keeps (``plan_temp_bytes``: the figure a
+``train_step`` row's ``plan_temp_bytes_max`` and the benchmark's
+``step_plan_temp_gb`` read on the chip).  Nothing runs; this says what
+the TPU's compiler accepts and how many bytes the program needs beside
+its arguments.
 
     JAX_PLATFORMS=cpu python3 tools/compile_decoder_step.py \\
         [--config benchmark/configs/mellum2-12b-a2.5b.json] \\
@@ -57,6 +61,7 @@ def main(argv=None) -> int:
     from znicz_tpu.nn import decoder
     from znicz_tpu.ops import tuning
     from znicz_tpu.parallel import fused
+    from znicz_tpu.telemetry import programs
 
     with open(args.config) as fh:
         cfg = json.load(fh)
@@ -94,6 +99,7 @@ def main(argv=None) -> int:
         m = compiled.memory_analysis()
         print(json.dumps({
             "program": "reference step",
+            "plan": programs.plan_of(compiled),
             "argument_gb": m.argument_size_in_bytes / 1e9,
             "temp_gb": m.temp_size_in_bytes / 1e9,
             "arguments_plus_temporaries_gb": (
@@ -140,8 +146,16 @@ def main(argv=None) -> int:
                       "w") as fh:
                 fh.write(compiled.as_text())
         m = compiled.memory_analysis()
+        # the plan under the names the trainer's register gives it
+        # (telemetry/programs.py): `plan_temp_bytes` is the word in a
+        # `trainer.dispatch` span and, as its epoch's largest, in a
+        # `train_step` row (`plan_temp_bytes_max`)
+        plan = programs.plan_of(compiled)
         print(json.dumps({
             "program": name,
+            "role": ("eval" if name == "eval_epoch" else
+                     "train.step" if steps == 1 else "train.head"),
+            "plan": plan, "plan_temp_bytes": plan.get("temp"),
             "argument_gb": m.argument_size_in_bytes / 1e9,
             "output_gb": m.output_size_in_bytes / 1e9,
             "alias_gb": m.alias_size_in_bytes / 1e9,

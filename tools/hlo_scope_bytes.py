@@ -4,7 +4,8 @@ each instruction reads and writes: the fusions, kernels, gathers, sorts
 and copies of ``compiled.as_text()`` (``tools/compile_decoder_step.py
 --text-dir``) whose ``op_name`` holds one of the given scope names.
 Counted from shapes: what the compiler says the instruction touches, not
-what the chip moved, and no time.
+what the chip moved, and no time.  The texts are read with the
+program's own parser (``znicz_tpu/telemetry/scopes.py``).
 
     python3 tools/hlo_scope_bytes.py train_epoch.hlo.txt \
         [--in bwd/L02.moe_block] experts combine
@@ -25,14 +26,19 @@ pairs) is marked ``later``; ``T`` marks a transposed (backward)
 instruction."""
 
 import collections
+import os
 import re
 import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from znicz_tpu.telemetry.scopes import (LINE, instructions,  # noqa: E402
+                                        op_name)
 
 ITEM = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
         "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
         "u64": 8}
 SHAPE = re.compile(r"\b(" + "|".join(ITEM) + r")\[([0-9,]*)\]")
-LINE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
 SKIP = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
         "while", "conditional", "call", "iota", "after-all", "partition-id",
         "replica-id"}
@@ -46,25 +52,6 @@ def nbytes(text: str) -> int:
             n *= int(dim) if dim else 1
         total += n
     return total
-
-
-def instructions(text: str):
-    """``(name, result text, opcode, rest of the line)`` of every
-    instruction of a compiled text outside the fused computations."""
-    fused = False
-    for line in text.splitlines():
-        if "fused_computation" in line.split("(")[0] and line.endswith("{"):
-            fused = True
-        elif line.startswith("}"):
-            fused = False
-        m = LINE.match(line)
-        if m and not fused:
-            yield m.groups()
-
-
-def op_name(rest: str) -> str:
-    found = re.search(r'op_name="([^"]*)"', rest)
-    return found.group(1) if found else ""
 
 
 def rows(text: str, scopes, within: str = ""):

@@ -36,7 +36,8 @@ from ..ops import (activations, attention as attn_ops, conv as conv_ops,
                    lrn_pool as lrn_pool_ops, moe as moe_ops,
                    normalization as lrn_ops, pooling as pool_ops,
                    softmax as softmax_ops, ssm as ssm_ops, tuning)
-from ..telemetry import compilestats, tracing
+from ..telemetry import (compilestats, flightrecorder, programs,
+                         tracing)
 from ..telemetry.registry import REGISTRY
 from . import mesh as mesh_lib
 
@@ -1119,10 +1120,14 @@ PREPARE_PIECES = 8
 def _build_counted():
     """The accounting of a build beside the epoch calls' ``build_timed``
     jits (the program that is asked for its layout, the prepare pass's
-    two small ones together): a ``compile`` span and a count."""
-    with tracing.span("compile", site="train.fused", cause="cold"), \
+    two small ones together): a ``compile`` span and a count.  Yields
+    the way into the register of executables for what is built inside:
+    ``enter(name, compiled, args)``, role ``prepare_set``."""
+    with tracing.span("compile", site="train.fused", cause="cold",
+                      role="prepare_set"), \
             compilestats.timed("train.fused", "cold"):
-        yield
+        yield functools.partial(programs.register, "train.fused",
+                                "prepare_set")
 
 
 def padded_bfloat16(data, shape: tuple):
@@ -1147,13 +1152,16 @@ def padded_bfloat16(data, shape: tuple):
         return jax.lax.dynamic_update_slice_in_dim(
             rows, jnp.pad(part.astype(jnp.bfloat16), widths), at, 0)
     at = np.int32(0)
-    with _build_counted():
+    put_args = (jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                     sharding=data.sharding),
+                jax.ShapeDtypeStruct((piece, *data.shape[1:]), data.dtype,
+                                     sharding=data.sharding), at)
+    with _build_counted() as enter:
         cut_rows = jax.jit(cut_rows).lower(data, at).compile()
+        enter("jit_cut_rows", cut_rows, (data, at))
         put_rows = jax.jit(put_rows, donate_argnums=0).lower(
-            jax.ShapeDtypeStruct(shape, jnp.bfloat16,
-                                 sharding=data.sharding),
-            jax.ShapeDtypeStruct((piece, *data.shape[1:]), data.dtype,
-                                 sharding=data.sharding), at).compile()
+            *put_args).compile()
+        enter("jit_put_rows", put_rows, put_args)
     with tracing.span("trainer.prepare_set"):
         rows = jnp.zeros(shape, jnp.bfloat16, device=data.sharding)
         for at in range(0, n, piece):
@@ -1176,24 +1184,39 @@ def _set_prepares():
         "the trainer's memo missed")
 
 
+def crowding(spec: ModelSpec, params) -> dict:
+    """How ``state_crowds_device`` decides, with the numbers it compares:
+    ``state_bytes``, the training state with one more copy of the
+    parameters beside it (three times the leaves' bytes: parameters,
+    velocities, and a snapshot in flight or a caller's copy), against
+    ``crowd_limit_bytes``, five eighths of the device's memory as the
+    device states it (``bytes_limit``: 16,909,336,064 on a v5e, so
+    10.57 GB; absent where the device does not say, the CPU), and
+    ``crowded``.  Sequence kinds only, the only ones of that size."""
+    out = {"crowded": False, "state_bytes": 3 * _leaf_bytes(params)}
+    room = tuning.device_memory_bytes()
+    if room is not None:
+        out["crowd_limit_bytes"] = 5 * room // 8
+        out["crowded"] = (
+            not any(la.kind in PAIR_KINDS for la in spec.layers)
+            and out["state_bytes"] > out["crowd_limit_bytes"])
+    return out
+
+
 def state_crowds_device(spec: ModelSpec, params) -> bool:
     """Whether the training state with one more copy of the parameters
-    beside it (three times the leaves' bytes: parameters, velocities, and
-    a snapshot in flight or a caller's copy) takes more than five eighths
-    of the device's memory (a v5e's 15.75 GiB: over 10.57 GB; 12.67 and
-    11.15 GB in the two cells that are, 7.14 GB in the decoder cell that is
-    not: ``PERF.md`` section 6, PR 36 has what the chip refused at two
-    thirds).  The trainer of such a model gives the step what
+    beside it takes more than five eighths of the device's memory
+    (``crowding`` has the numbers: 12.67 and 11.15 GB in the two cells
+    that are, 7.14 GB in the decoder cell that is not: ``PERF.md``
+    section 6, PR 36 has what the chip refused at two thirds).  The
+    trainer of such a model gives the step what
     room it can: it keeps the backward's recomputation apart from the
     forward (``ModelSpec.fresh_backward``), runs one minibatch a launch
     (a ``lax.scan`` over the steps holds copies of part of the state it
     carries) and tells the compiler that a copy of the parameters shares
-    the device.  Sequence kinds only, the only ones of that size; False
-    where the device does not say what it holds (the CPU)."""
-    room = tuning.device_memory_bytes()
-    if room is None or any(la.kind in PAIR_KINDS for la in spec.layers):
-        return False
-    return 3 * _leaf_bytes(params) > 5 * room // 8
+    the device.  False where the device does not say what it holds (the
+    CPU)."""
+    return crowding(spec, params)["crowded"]
 
 
 def _leaf_bytes(params) -> int:
@@ -1214,9 +1237,14 @@ class FusedTrainer:
                  augment=None):
         if workflow is not None:
             spec, params, vels = extract_model(workflow)
-        #: the state crowds the device: see ``state_crowds_device``
-        self.crowded = (mesh is None and accum_steps == 1
-                        and state_crowds_device(spec, params))
+        #: the state crowds the device: see ``state_crowds_device``; how
+        #: the rule went and with what numbers, for the start record
+        #: (a mesh or an accumulation keeps the scan and asks no device)
+        self.crowding = (crowding(spec, params)
+                         if mesh is None and accum_steps == 1 else
+                         {"crowded": False,
+                          "state_bytes": 3 * _leaf_bytes(params)})
+        self.crowded = self.crowding["crowded"]
         if self.crowded:
             spec = dataclasses.replace(spec, fresh_backward=True)
         self.spec = spec
@@ -1290,6 +1318,13 @@ class FusedTrainer:
         self._train_epoch_fn = None
         self._eval_epoch_fn = None
         self._auto_epoch = 0
+        #: this process's devices, whose memory a launch reads (another
+        #: process's do not say what they hold), and the roles read in
+        #: the epoch (one request id) that is running: ``_dispatch``
+        self._devices = (jax.local_devices()[:1] if mesh is None else
+                         [d for d in mesh.devices.flat
+                          if d.process_index == jax.process_index()])
+        self._epoch_read = (None, set())
         #: memo of _mesh_place and hold: (id(source), held) -> (source,
         #: placed on the mesh | held in the programs' format)
         self._placed: dict = {}
@@ -1425,11 +1460,13 @@ class FusedTrainer:
             psh = [tuple(s) for s in self._param_shardings]
             jit_kw["out_shardings"] = (psh, psh, self._repl)
             ejit_kw["out_shardings"] = self._repl
-        # compile accounting (telemetry.compilestats): jit compiles
-        # lazily, once a shape — the head's (k, b), the deferred tail's
-        # (1, b), each evaluated set's — so every call that builds an
-        # executable is timed into compile_time_ms{site="train.fused"}
-        # and the MFU work can subtract compile from measured step time
+        # compile accounting (telemetry.compilestats): one executable
+        # a shape — the head's (k, b), the deferred tail's (1, b), each
+        # evaluated set's — compiled ahead by the call that first needs
+        # it, timed into compile_time_ms{site="train.fused"} (the MFU
+        # work can subtract compile from measured step time) and entered
+        # in the register of executables with its memory plan
+        # (telemetry.programs), which the launches' spans read
         self._train_epoch_fn = compilestats.build_timed(
             jax.jit(self._mesh_scoped(train_epoch),
                     donate_argnums=(0, 1), **jit_kw),
@@ -1520,15 +1557,16 @@ class FusedTrainer:
                            want_caches=False)[0]
         sharding = (data.sharding if self._batch_sharding is None
                     else self._repl)
-        with _build_counted():
+        args = (self.params[0],
+                jax.ShapeDtypeStruct(data.shape, jnp.bfloat16,
+                                     sharding=sharding),
+                np.arange(batch, dtype=np.int32))
+        with _build_counted() as enter:
             asked = jax.jit(
                 self._mesh_scoped(first_use),
                 in_shardings=(None, Format(Layout.AUTO, sharding),
-                              None)).lower(
-                self.params[0],
-                jax.ShapeDtypeStruct(data.shape, jnp.bfloat16,
-                                     sharding=sharding),
-                np.arange(batch, dtype=np.int32)).compile()
+                              None)).lower(*args).compile()
+            enter("jit_first_use", asked, args)
         return asked.input_formats[0][1].layout
 
     @staticmethod
@@ -1648,29 +1686,88 @@ class FusedTrainer:
             # one minibatch (an epoch's deferred tail is one already)
             parts = []
             for s in range(idx.shape[0]):
-                with tracing.span("trainer.dispatch"):
+                with self._dispatch(self._train_epoch_fn, "train.step"):
                     self.params, self.vels, ms = self._train_epoch_fn(
                         self.params, self.vels, data, target,
                         *(a if a.ndim == 0 else a[s:s + 1]
-                          for a in step_args))
+                          for a in step_args), role="train.step")
                 parts.append(ms)
             ms = {k: jnp.concatenate([p[k] for p in parts])
                   for k in parts[0]}
             return self._readback(ms, sync)
-        with tracing.span("trainer.dispatch"):
+        role = "train.head" if idx.shape[0] > 1 else "train.step"
+        with self._dispatch(self._train_epoch_fn, role):
             self.params, self.vels, ms = self._train_epoch_fn(
-                self.params, self.vels, data, target, *step_args)
+                self.params, self.vels, data, target, *step_args,
+                role=role)
         return self._readback(ms, sync)
 
     def eval_epoch(self, data, target, indices, batch: int,
-                   sync: bool = True) -> dict:
+                   sync: bool = True, role: str = "eval") -> dict:
+        """``role``: what the register of executables and the launch's
+        span call this evaluation (``eval.validation``, ...)."""
         with tracing.span("trainer.prep"):
             data = self.hold(data, batch)
             target = self._mesh_place(target)
             idx, mask, _ = self._idx_matrix(np.asarray(indices), batch)
-        with tracing.span("trainer.dispatch"):
-            ms = self._eval_epoch_fn(self.params, data, target, idx, mask)
+        with self._dispatch(self._eval_epoch_fn, role):
+            ms = self._eval_epoch_fn(self.params, data, target, idx, mask,
+                                     role=role)
         return self._readback(ms, sync)
+
+    # -- a launch, with what it plans and what the device holds -----------
+    def _memory_now(self) -> dict:
+        """``bytes_in_use`` and ``bytes_limit`` of the fullest of the
+        trainer's devices; nothing where the device does not say (the
+        CPU)."""
+        stats = [s for s in (d.memory_stats() for d in self._devices)
+                 if s and "bytes_in_use" in s]
+        if not stats:
+            return {}
+        fullest = max(stats, key=lambda s: s["bytes_in_use"])
+        return {k: int(fullest[k]) for k in ("bytes_in_use", "bytes_limit")
+                if k in fullest}
+
+    def _memory_before(self, role: str) -> dict:
+        """``_memory_now`` once an epoch (one request id) and role, not
+        once a launch: nothing on the role's later launches."""
+        epoch = tracing.current_request_id()
+        if self._epoch_read[0] != epoch:
+            self._epoch_read = (epoch, set())
+        if role in self._epoch_read[1]:
+            return {}
+        self._epoch_read[1].add(role)
+        return self._memory_now()
+
+    @contextlib.contextmanager
+    def _dispatch(self, fn, role: str):
+        """The ``trainer.dispatch`` span around one call of epoch program
+        ``fn`` (the call and whatever makes its arguments stand inside,
+        as ever: a slice of a device array waits behind a full queue
+        like the launch itself).  The span carries the launch's
+        ``role``, what its executable plans for temporaries
+        (``plan_temp_bytes``) and, once an epoch and role, what the
+        device held just before.  A launch the runtime refuses for
+        memory leaves an ``error`` record in the flight recorder with
+        the role, the plan and what the device holds, and the exception
+        goes on unchanged."""
+        def plan() -> dict:
+            """Of the executable the call took (``BuildTimed.last``)."""
+            return getattr(getattr(fn, "last", None), "plan", None) or {}
+        with tracing.span("trainer.dispatch", role=role) as sp:
+            sp.attrs.update(self._memory_before(role))
+            try:
+                yield
+            except Exception as e:
+                if "RESOURCE_EXHAUSTED" in str(e):
+                    flightrecorder.RECORDER.record(
+                        "error", outcome="error", error=e, role=role,
+                        plan=dict(plan()), **self._memory_now())
+                raise
+            finally:
+                temp = plan().get("temp")
+                if temp is not None:
+                    sp.attrs["plan_temp_bytes"] = temp
 
     # -- sync back into the unit graph ------------------------------------
     def write_back(self) -> None:

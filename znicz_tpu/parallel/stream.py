@@ -218,7 +218,8 @@ class StreamTrainer(FusedTrainer):
                 self.params, self.vels, m = self._step_fn(
                     self.params, self.vels, x, t,
                     jnp.asarray(mask[step_i]), ep,
-                    jnp.uint32(ctrs[step_i]), ls, lsb, rows)
+                    jnp.uint32(ctrs[step_i]), ls, lsb, rows,
+                    role="train.step")
             else:
                 grads, m = self._grad_fn(self.params, x, t,
                                          jnp.asarray(mask[step_i]), ep,
@@ -239,7 +240,7 @@ class StreamTrainer(FusedTrainer):
         return {k: np.asarray(v) for k, v in ms.items()} if sync else ms
 
     def eval_epoch(self, data, target, indices, batch: int,
-                   sync: bool = True) -> dict:
+                   sync: bool = True, role: str = "eval") -> dict:
         if self._eval_fn is None:
             self._build_steps()
         idx, mask, _ = self._idx_matrix(np.asarray(indices), batch)
@@ -251,7 +252,8 @@ class StreamTrainer(FusedTrainer):
         for step_i, (x, t) in enumerate(pf):
             m = self._eval_fn(self.params, x, t,
                               jnp.asarray(mask[step_i]),
-                              jnp.asarray(idx[step_i], jnp.int32))
+                              jnp.asarray(idx[step_i], jnp.int32),
+                              role=role)
             losses.append(m["loss"])
             n_errs.append(m["n_err"])
         ms = {"loss": jnp.stack(losses), "n_err": jnp.stack(n_errs)}

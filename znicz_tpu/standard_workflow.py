@@ -444,7 +444,12 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                                                   mesh),
                  # how the trainer holds the resident set: as given, or
                  # once in the dtype and layout its programs gather from
-                 "set_form": trainer.set_form}
+                 "set_form": trainer.set_form,
+                 # how the trainer's rule on a state that crowds the
+                 # device went, and the numbers it compared: crowded,
+                 # state_bytes, crowd_limit_bytes (fused.crowding; a
+                 # streamed trainer has no such rule)
+                 **getattr(trainer, "crowding", {})}
         if any(la.kind == "attn_block" for la in spec.layers):
             # attention rows over a sliding window, and over everything
             # before (ops/attention.py)
@@ -601,7 +606,8 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             # differ slightly from the unit graph's dropout-active ones;
             # weights stay exactly equal either way
             with _tracing.span("train.eval_tail"):
-                em_tail = trainer.eval_epoch(data, target, tail, batch)
+                em_tail = trainer.eval_epoch(data, target, tail, batch,
+                                             role="eval.train")
             pending = (tail, epoch, tail_scale, split, tail_scale_b)
             metrics["train_loss"] = float(
                 np.concatenate([tm["loss"], em_tail["loss"]]).mean())
@@ -622,7 +628,7 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                 name = CLASS_NAMES[k]
                 with _tracing.span(eval_spans[k]):
                     em = trainer.eval_epoch(data, target, cls_idx[k],
-                                            batch)
+                                            batch, role=f"eval.{name}")
                 metrics[f"{name}_loss"] = float(em["loss"].mean())
                 metrics[f"{name}_n_err"] = int(em["n_err"].sum())
                 metrics[f"{name}_err_pct"] = (

@@ -21,6 +21,10 @@ later perf/robustness PR reports through:
   site (``compile_time_ms{site}``, ``compiles_total{site,cause}``,
   executable-cache hit/miss counters): "zero request-path compiles in
   steady state" as a testable metric.
+* :mod:`programs` — the register of the executables a process built
+  (role, argument shapes, memory plan, a weak handle to the compiled
+  text); :mod:`scopes` — device time by the step's scopes and idle
+  time by the host's open span, out of a capture and those texts.
 * :mod:`flightrecorder` — bounded ring of recent request / train-step
   records with threshold-retained slow outliers and last-N errors;
   serves ``GET /debug/flightrecorder``.

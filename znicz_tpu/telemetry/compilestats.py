@@ -17,7 +17,10 @@ layer every executable-creation site reports through:
   (:func:`first_call_timed`); the trainers call one jitted function
   with several shapes (the head ``(k, b)``, the deferred tail
   ``(1, b)``, one evaluation shape a set) and account every one of them
-  (:func:`build_timed`).  Coarse-bucketed up to minutes — cold compiles
+  (:func:`build_timed`, which compiles ahead once a signature of the
+  arguments and enters what it built, with its memory plan, in the
+  register of executables: :mod:`~znicz_tpu.telemetry.programs`).
+  Coarse-bucketed up to minutes — cold compiles
   of big models are multi-second events.
 * ``compiles_total{site, cause}`` — why the executable had to be
   built: ``cold`` (explicit warmup / first engine construction, off
@@ -46,7 +49,7 @@ from __future__ import annotations
 import threading
 import time
 
-from . import tracing
+from . import programs, tracing
 from .registry import REGISTRY
 
 #: the causes `compiles_total` is allowed to carry (docs/observability.md)
@@ -164,27 +167,48 @@ def first_call_timed(fn, site: str, cause: str,
 
 class BuildTimed:
     """Wrap a ``jax.jit`` function so EVERY call that builds an
-    executable is recorded, not only the first: a build shows as the
-    function's own executable cache growing across the call (a new
-    shape, dtype or sharding; loaded from the persistent cache or
-    compiled).  Two reads of a counter a call."""
+    executable is recorded, not only the first, and so that what was
+    built is kept: the wrapper compiles ahead, once a signature of the
+    arguments (``programs.signature``: a new shape, dtype or sharding;
+    loaded from the persistent cache or compiled), enters the
+    executable in the register (:mod:`~znicz_tpu.telemetry.programs`)
+    under the ``role`` the caller states, and calls it from then on.
+    The ``compile`` span covers trace, compile or load and the first
+    run, as before, and carries the role and the plan's parts
+    (``plan_temp_bytes``, ...).  ``last`` is the entry of the newest
+    call, set before the launch: what a caller whose launch was refused
+    reports."""
 
-    __slots__ = ("fn", "site", "cause")
+    __slots__ = ("fn", "site", "cause", "last", "_built")
 
     def __init__(self, fn, site: str, cause: str):
         self.fn = fn
         self.site = site
         self.cause = cause
+        self.last = None
+        #: signature -> (the executable, its entry)
+        self._built: dict = {}
 
-    def __call__(self, *args, **kwargs):
-        size = self.fn._cache_size
-        before = size()
+    def __call__(self, *args, role: str | None = None):
+        key = programs.signature(args)
+        hit = self._built.get(key)
+        if hit is not None:
+            self.last = hit[1]
+            return hit[0](*args)
+        self.last = None        # a build that fails names no other's plan
         sp = tracing.Span("compile", {"site": self.site,
                                       "cause": self.cause})
-        out = self.fn(*args, **kwargs)
-        if size() > before:
-            tracing.record(sp.finish())
-            record_compile(self.site, self.cause, sp.duration_ms)
+        compiled = self.fn.lower(*args).compile()
+        self.last = entry = programs.register(
+            self.site, role, programs.module_name(self.fn), compiled, args)
+        self._built[key] = compiled, entry
+        out = compiled(*args)
+        if role is not None:
+            sp.attrs["role"] = role
+        sp.attrs.update((f"plan_{part}_bytes", value)
+                        for part, value in entry.plan.items())
+        tracing.record(sp.finish())
+        record_compile(self.site, self.cause, sp.duration_ms)
         return out
 
 

@@ -186,9 +186,25 @@ def train_breakdown(spans: list) -> dict:
     is launch cost the device may idle behind.  ``compiles``:
     executables built or loaded (``compile`` spans).  A trainer that
     opens no ``trainer.*`` spans (the streamed one) reads None for the
-    four."""
+    four.
+
+    Out of the ``trainer.dispatch`` spans' attributes, each ABSENT,
+    never 0, where no span carries what it reads (a runtime without a
+    memory plan, a device that does not say what it holds, the streamed
+    trainer): ``plan_temp_bytes_max`` (the largest ``plan_temp_bytes``
+    among the executables launched in the epoch), ``hbm_in_use_bytes``
+    (the most the device held just before a launch, read once an epoch
+    and role), ``launch_need_bytes`` (the most that ``bytes_in_use``
+    before a launch plus that launch's ``plan_temp_bytes`` came to:
+    what the device has to have for the epoch's launches to load) and
+    ``hbm_limit_bytes`` (the device's own ``bytes_limit``)."""
     device = prep = dispatch = readback = 0.0
     launches = compiles = 0
+    memory: dict = {}
+
+    def most(field: str, value) -> None:
+        if value is not None:
+            memory[field] = max(memory.get(field, 0), int(value))
     for s in spans:
         ms = s.duration_ms or 0.0
         if s.name in _TRAIN_CALL_SPANS:
@@ -196,6 +212,13 @@ def train_breakdown(spans: list) -> dict:
         elif s.name == "trainer.dispatch":
             dispatch += ms
             launches += 1
+            plan, held = (s.attrs.get("plan_temp_bytes"),
+                          s.attrs.get("bytes_in_use"))
+            most("plan_temp_bytes_max", plan)
+            most("hbm_in_use_bytes", held)
+            most("hbm_limit_bytes", s.attrs.get("bytes_limit"))
+            if plan is not None and held is not None:
+                most("launch_need_bytes", plan + held)
         elif s.name == "trainer.prep":
             prep += ms
         elif s.name == "trainer.readback":
@@ -204,7 +227,7 @@ def train_breakdown(spans: list) -> dict:
             compiles += 1
     out = {"device_ms": round(device, 3), "launches": None,
            "prep_ms": None, "dispatch_ms": None, "readback_ms": None,
-           "compiles": compiles}
+           "compiles": compiles, **memory}
     if launches:
         out.update(launches=launches, prep_ms=round(prep, 3),
                    dispatch_ms=round(dispatch, 3),

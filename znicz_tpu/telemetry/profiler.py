@@ -33,6 +33,8 @@ import os
 import signal as _signal
 import threading
 
+from . import scopes
+
 _log = logging.getLogger(__name__)
 
 
@@ -159,21 +161,31 @@ class StepTraceHook:
 
     Drive it with :meth:`on_step` once per step and :meth:`close` when
     the loop ends (closing mid-window stops the capture cleanly).
-    ``start``/``stop`` are injectable for tests.
+    Each capture it closes it also READS (``read``:
+    :func:`~znicz_tpu.telemetry.scopes.profile_record`): device time by
+    the step's scopes through the texts of the executables the trainer
+    registered, idle time by the host's open span, as one
+    ``train_profile`` flight-recorder record (``records``), so that a
+    windowed capture of a long run says which layer's time grew without
+    anyone opening a file.  ``start``/``stop``/``read`` are injectable
+    for tests; ``read=None`` leaves the files unread.
     """
 
     def __init__(self, profile_dir: str, every: int = 100,
-                 duration: int = 1, start=start_trace, stop=stop_trace):
+                 duration: int = 1, start=start_trace, stop=stop_trace,
+                 read=scopes.profile_record):
         if every < 1 or duration < 1:
             raise ValueError(f"every/duration must be >= 1, got "
                              f"{every}/{duration}")
         self.profile_dir = profile_dir
         self.every = int(every)
         self.duration = int(duration)
-        self._start, self._stop = start, stop
+        self._start, self._stop, self._read = start, stop, read
         self._capturing_until: int | None = None
-        #: directories of completed captures, for tests/logs
+        #: directories of completed captures, for tests/logs, and what
+        #: was read from each (None: a capture that could not be read)
         self.captured: list[str] = []
+        self.records: list = []
         self._current: str | None = None
 
     def on_step(self, step: int) -> None:
@@ -192,6 +204,8 @@ class StepTraceHook:
         self._stop()
         if self._current is not None:
             self.captured.append(self._current)
+            if self._read is not None:
+                self.records.append(self._read(self._current))
         self._current = None
         self._capturing_until = None
 
