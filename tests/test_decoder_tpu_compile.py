@@ -5,8 +5,11 @@ heads over 4 key/value heads of 128; and the grouped expert products of
 16 held experts of 2304 x 896 over the 32,768 assignment rows of half a
 sequence (``ops/moe.CHUNK_TOKENS``), forward and backward; and the rows'
 way there and back around them (``dispatch_rows``, ``combine``) over the
-first piece of those rows.  The TPU's own Mosaic and XLA compilers run here, with no
-chip; nothing runs, so this says nothing of results or times.
+first piece of those rows; and the gated delta rule's kernels at the
+shapes of one of ``olmo-hybrid-7b``'s linear layers (4,096 tokens, 30
+heads, keys of 96, values of 192, chunks of 64), forward and backward.
+The TPU's own Mosaic and XLA compilers run here, with no chip; nothing
+runs, so this says nothing of results or times.
 
 The topology is described inside a fixture, never at import: only the
 worker that is given this file loads the TPU's library."""
@@ -20,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from znicz_tpu.ops import attention, moe, tuning
+from znicz_tpu.ops import attention, gdn, moe, tuning
 
 T, HEADS, KV_HEADS, HEAD_DIM, WINDOW = 8192, 32, 4, 128, 1024
 D, F, HELD, TOP_K = 2304, 896, 16, 8
@@ -205,3 +208,25 @@ def test_a_skipped_later_piece_costs_no_leaf_sized_pass(one_chip, mosaic):
     passes = {key: n for key, n in made.items() if key[0] in ("step", "idle")
               and key[1] in ("broadcast", "copy")}
     assert not passes, passes
+
+
+def _rule_shapes(one_chip):
+    t, h, dk, dv = 4096, 30, 96, 192
+    return tuple(jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+                 for shape in ((1, t, h, dk), (1, t, h, dk), (1, t, h, dv),
+                               (1, t, h), (1, t, h)))
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_delta_rule_kernels_compile_for_a_v5e(one_chip, mosaic, backward):
+    def rule(*a):
+        return gdn.delta_rule(*a, 64, jnp.bfloat16)
+
+    def loss(*a):
+        return jnp.sum(rule(*a))
+    assert gdn.rule_route() == "kernel"
+    text = _compiled_text(jax.grad(loss, argnums=tuple(range(5)))
+                          if backward else rule, *_rule_shapes(one_chip))
+    assert ("pallas_delta_rule_bwd" in text) == backward
+    assert "pallas_delta_rule_fwd" in text

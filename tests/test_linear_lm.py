@@ -78,13 +78,16 @@ def kind_cfg(cfg: dict, kind: str, nth: int = 0) -> dict:
 
 
 # -- the chunked delta rule against the recurrence itself ---------------------
-def _rule_case(decay: str, beta: str, t: int = 256):
+def _rule_case(decay: str, beta: str, t: int = 256, widths=None):
     """``decay``: a token's ``exp(g)`` near 0 (every token forgets the
     state), near 1 (the state carries over many chunks) or mixed;
     ``beta``: the write strength under 1, over 1 (a negative eigenvalue
     along ``k``) or on both sides.  256 tokens are two groups of eight
-    chunks of 16 (``gdn.GROUP``) and one group of four chunks of 64."""
-    b, h, dk, dv = 2, 3, 8, 16
+    chunks of 16 (``gdn.GROUP``) and one group of four chunks of 64.
+    ``widths``: keys and values of the cell's sizes (96, 192), one row of
+    two heads and 128 tokens."""
+    b, h, dk, dv = (1, 2, *widths) if widths else (2, 3, 8, 16)
+    t = 128 if widths else t
     k = jax.random.split(jax.random.key(13), 5)
     lo, hi = {"near_0": (3.0, 9.0), "near_1": (1e-5, 1e-3),
               "mixed": (1e-3, 1.0)}[decay]
@@ -103,12 +106,25 @@ def _recur(q, k, v, g, beta):
         q, k, v, jnp.exp(g), beta))(q, k, v, g, beta)
 
 
+@pytest.fixture(params=["chunked", "kernel"])
+def form(request, monkeypatch):
+    """The delta rule's two forms: the ``jax.numpy`` chunked one, and the
+    Pallas kernels in interpret mode (set as ``test_pallas_kernels.py``
+    sets it)."""
+    from znicz_tpu.ops import tuning
+    monkeypatch.setattr(tuning, "_INTERPRET", request.param == "kernel")
+    assert gdn.rule_route() == request.param
+    return request.param
+
+
 @pytest.mark.parametrize("chunk", [16, 64])
-@pytest.mark.parametrize("decay,beta", [
-    ("near_0", "both"), ("near_1", "both"), ("mixed", "under_1"),
-    ("mixed", "over_1"), ("mixed", "both")])
-def test_the_chunked_delta_rule_is_the_recurrence(decay, beta, chunk):
-    args = _rule_case(decay, beta)
+@pytest.mark.parametrize("decay,beta,widths", [
+    ("near_0", "both", None), ("near_1", "both", None),
+    ("mixed", "under_1", None), ("mixed", "over_1", None),
+    ("mixed", "both", None), ("mixed", "both", (96, 192))])
+def test_the_chunked_delta_rule_is_the_recurrence(decay, beta, widths, chunk,
+                                                  form):
+    args = _rule_case(decay, beta, widths=widths)
 
     def chunked(*a):
         return gdn.delta_rule(*a, chunk)
@@ -126,6 +142,39 @@ def test_the_chunked_delta_rule_is_the_recurrence(decay, beta, chunk):
             np.testing.assert_allclose(
                 got_g, want_g, rtol=2e-4,
                 atol=2e-5 * float(jnp.max(jnp.abs(want_g))))
+
+
+def test_the_kernel_saves_the_states_the_chunked_form_carries():
+    """The forward kernel's state a chunk starts from, chunk by chunk,
+    against ``chunk_group`` run a chunk at a time from the state the one
+    before it closed with."""
+    from znicz_tpu.ops import tuning
+    q, k, v, g, beta = _rule_case("mixed", "both", t=128)
+    b, t, h, _ = q.shape
+    chunk, nc = 16, 8
+    with jax.default_matmul_precision("highest"):
+        states = [jnp.zeros((b, h, v.shape[-1], k.shape[-1]))]
+        for n in range(nc - 1):
+            part = tuple(a[:, n * chunk:(n + 1) * chunk].reshape(
+                1, b, chunk, h, -1).transpose(0, 1, 3, 2, 4)
+                for a in (q, k, v, g, beta))
+            states.append(gdn.chunk_group(states[-1], *part,
+                                          jnp.float32)[0])
+        want = jnp.stack(states, axis=2)             # (B, H, nc, V, K)
+        c = jnp.cumsum(g.reshape(b, nc, chunk, h).transpose(0, 3, 1, 2),
+                       axis=-1)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(tuning, "_INTERPRET", True)
+            o, got = gdn._rule_fwd_call(
+                *(a.transpose(0, 2, 1, 3)
+                  for a in (q, k, v * beta[..., None])), c,
+                beta.reshape(b, nc, chunk, h).transpose(0, 3, 1, 2),
+                jnp.float32, save=True)
+    assert got.shape == (b, h, nc, 16, 8)
+    assert float(jnp.max(jnp.abs(want[:, :, -1]))) > 0.1
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(o.transpose(0, 2, 1, 3), _recur(
+        q, k, v, g, beta), rtol=1e-4, atol=1e-5)
 
 
 def test_a_length_that_is_no_multiple_of_the_chunk_is_refused():
@@ -221,6 +270,7 @@ def test_the_counter_and_the_routes_read_what_they_should():
     assert "ssm_tokens" not in got and "moe_assignments" not in got
     assert fused.mixer_routes(spec) == "linear:3 full:1"
     assert fused.attn_routes(spec) == "window:0 full:1"
+    assert fused.rule_routes(spec) == "kernel:0 chunked:3"
     assert fused.COUNTERS["gdn_tokens"][0] == "sum"
     # absent, never 0, without the kind
     import test_hybrid_lm as hybrid
@@ -229,6 +279,20 @@ def test_the_counter_and_the_routes_read_what_they_should():
         other, p, a, b))(w, hx[0], hy[0])
     assert "gdn_tokens" not in counted
     assert fused.mixer_routes(other) == "ssm:2 full:1"
+
+
+def test_the_rule_routes_name_the_form_the_step_traces(form):
+    """``rule_routes`` counts the linear rows by the form of their delta
+    rule: the kernels where Pallas runs (here the interpreter), else the
+    chunked form; and the traced step holds that form."""
+    cfg, spec, weights, x, y = setup()
+    kernel = 3 if form == "kernel" else 0
+    assert fused.rule_routes(spec) == f"kernel:{kernel} chunked:{3 - kernel}"
+    text = str(jax.make_jaxpr(lambda p, a, b: fused.grad_minibatch(
+        spec, p, a, b))(weights, x[0], y[0]))
+    # a custom_vjp's call holds its forward's jaxpr beside the primal's
+    assert text.count("name=pallas_delta_rule_fwd") == 2 * kernel
+    assert text.count("name=pallas_delta_rule_bwd") == kernel
 
 
 def test_the_start_record_names_the_mixers(tmp_path):
@@ -254,6 +318,7 @@ def test_the_start_record_names_the_mixers(tmp_path):
         root.decoder_lm.update(saved)
     rows = [json.loads(line) for line in open(path)]
     assert rows and all(r["mixer_routes"] == "linear:3 full:1"
+                        and r["rule_routes"] == "kernel:0 chunked:3"
                         and r["gdn_tokens"] == 3 * r["tokens"] > 0
                         for r in rows)
     step = [r for r in flightrecorder.RECORDER.snapshot()["recent"]

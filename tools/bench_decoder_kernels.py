@@ -8,9 +8,13 @@ one at two other row tiles against ``jax.lax.ragged_dot``), forward and forward 
 expert layer of one chunk alone (``ops/moe.held_expert_sum``: sort, rows
 there, three products, rows back) with its three products' time beside
 it, so that what the layer spends outside its kernels can be read
-without the step.  One JSON line a case; exits 3 off-TPU.
+without the step; and the gated delta rule at the shapes of one of
+``olmo-hybrid-7b``'s linear layers (``ops/gdn.py``: the kernels against
+the ``jax.numpy`` chunked form).
+One JSON line a case; exits 3 off-TPU.
 
     chiprun -- python3 tools/bench_decoder_kernels.py [splash] [gmm] [layer]
+        [delta_rule]
 """
 
 import json
@@ -42,7 +46,7 @@ def main(argv) -> int:
         return 3
     ks = jax.random.split(jax.random.key(0), 8)
     cases = {"splash": splash, "gmm": grouped_products,
-             "layer": expert_layer}
+             "layer": expert_layer, "delta_rule": delta_rule}
     for name in argv or list(cases):
         cases[name](ks)
     return 0
@@ -169,6 +173,50 @@ def expert_layer(keys) -> None:
         "outside_products_fwd_bwd_ms": (times["layer"][1]
                                         - times["products"][1])}),
         flush=True)
+
+
+def delta_rule(keys) -> None:
+    """One row of ``olmo-hybrid-7b``'s linear layer (4,096 tokens, 30 heads,
+    keys of 96, values of 192, chunks of 64, bfloat16 operands): forward,
+    and forward + backward (the gradient of all five inputs), through the
+    kernels and through the chunked form; the largest gap of the kernels'
+    output and gradients to the chunked form's, against their largest
+    value; and the share of the rule's roofline
+    (``benchmark/metrics/olmo_delta_rule_roofline.py``'s count)."""
+    import jax
+    import jax.numpy as jnp
+    from znicz_tpu.ops import gdn
+    t, h, dk, dv, chunk = 4096, 30, 96, 192, 64
+    ks = jax.random.split(keys[6], 6)
+    args = (gdn.l2_norm(jax.random.normal(ks[0], (1, t, h, dk))) * dk ** -0.5,
+            gdn.l2_norm(jax.random.normal(ks[1], (1, t, h, dk))),
+            jax.random.normal(ks[2], (1, t, h, dv)),
+            -jnp.exp(jax.random.uniform(ks[3], (1, t, h), minval=-7.0,
+                                        maxval=0.0)),
+            jax.random.uniform(ks[4], (1, t, h), minval=0.05, maxval=1.95))
+    do = jax.random.normal(ks[5], (1, t, h, dv))
+    ops = 7.0 * t * h * dk * dv
+    least = (max(ops / 197e12, 4.0 * t * h * (2 * dk + 2 * dv) / 819e9)
+             + max(2 * ops / 197e12, 4.0 * t * h * (4 * dk + 3 * dv) / 819e9))
+
+    def measured(form):
+        def fwd(*a):
+            return form(*a, chunk, jnp.bfloat16)
+        grad = jax.grad(lambda *a: jnp.sum(fwd(*a) * do), tuple(range(5)))
+        fwd, grad = jax.jit(fwd), jax.jit(grad)
+        return (fwd(*args), grad(*args), 1e3 * timed(fwd, *args, reps=10),
+                1e3 * timed(grad, *args, reps=10))
+    want_o, want_g, t_f, t_b = measured(gdn.delta_rule_chunked)
+    print(json.dumps({"kernel": "delta_rule_chunked", "fwd_ms": t_f,
+                      "fwd_bwd_ms": t_b,
+                      "roofline_share": least / (t_b * 1e-3)}), flush=True)
+    o, g, t_f, t_b = measured(gdn.delta_rule_kernel)
+    gaps = [float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            for a, b in zip((o, *g), (want_o, *want_g))]
+    print(json.dumps({"kernel": "delta_rule_kernel", "fwd_ms": t_f,
+                      "fwd_bwd_ms": t_b,
+                      "roofline_share": least / (t_b * 1e-3),
+                      "gap_o_dq_dk_dv_dg_dbeta": gaps}), flush=True)
 
 
 if __name__ == "__main__":

@@ -17,11 +17,11 @@ The 2 of ``beta`` is the model's ``allow_neg_eigval``: the transition's
 eigenvalue along ``k`` is ``1 - beta``, in (-1, 1).  The gated norm norms
 first and gates after (the other order from ``mamba_block``).
 
-The delta rule runs in its chunked form, ``chunk`` tokens at a time
-(:func:`delta_rule`).  A token's rank-one correction acts on the state the
-tokens before it left, so inside a chunk the corrections compose into a
-unit-lower-triangular system, which is SOLVED (forward substitution) before
-the products a state-space scan has.  With ``c`` the running sum of ``g``
+The delta rule runs ``chunk`` tokens at a time (:func:`delta_rule`).  A
+token's rank-one correction acts on the state the tokens before it left,
+so inside a chunk the corrections compose into a unit-lower-triangular
+system, which is SOLVED (forward substitution) before the products a
+state-space scan has.  With ``c`` the running sum of ``g``
 inside a chunk and ``S`` the state the chunk starts from:
 
     A  = strict_lower(beta_i (k_i . k_j) exp(c_i - c_j))
@@ -32,22 +32,47 @@ inside a chunk and ``S`` the state the chunk starts from:
 
 Decays, running sums, every ``exp`` (of a difference of running sums,
 never a ratio), ``softplus``, ``sigmoid``, the L2 norms, the convs, the
-Gram matrix of the system and its solution are float32; the operands of
-the other products are in ``cdt``, accumulated in float32.  The backward
-is ``jax.vjp`` of this function under the trainer's block
-rematerialisation; inside it the projections with their convs, and each
-group of ``GROUP`` chunks of the delta rule, are rematerialised by
-themselves, so that the wide float32 intermediates of the one are never
-alive beside the other's (the step of one such layer and its feed-forward
-at 4,096 tokens plans 1.70 GB of temporaries where it planned 3.34:
-compiles for a described v5e, PR 36).
+Gram matrix of the system, its inverse and the inverse's products are
+float32 (``highest``); the operands of the other products are in ``cdt``,
+accumulated in float32.  The backward is ``jax.vjp`` of this function
+under the trainer's block rematerialisation; inside it the projections
+with their convs are rematerialised by themselves, so that their wide
+float32 intermediates are never alive beside the delta rule's.
+
+The rule takes one of two forms, by ``tuning.use_pallas()`` (counted in
+the start record's ``rule_routes``):
+
+* TPU (and the Pallas interpreter): two Pallas kernels under a
+  ``custom_vjp`` (:func:`delta_rule_kernel`), a grid step a (row, head,
+  chunk).  The forward carries the state over the chunks in VMEM and sets
+  up, inverts and applies each chunk's system there too; HBM holds ``q``,
+  ``k``, ``v`` (made head-major), the running sums, beta and ``o`` --
+  and, under the ``custom_vjp``'s forward alone, the state each chunk
+  starts from (float32, ``(B, H, chunks, V, K)``).  The backward walks the
+  chunks from the last, carries the state's gradient in VMEM, sets each
+  chunk's system up again from its inputs and saved state and writes
+  ``dq``, ``dk``, ``dv``, ``dbeta`` and the gradient of the running sums,
+  whose reverse sums inside a chunk JAX makes ``dg``.  The backward's
+  products are the forward's transposes at the forward's precision.
+* elsewhere: the ``jax.numpy`` chunked form (:func:`delta_rule_chunked`),
+  ``GROUP`` chunks set up and solved together, a ``lax.scan`` over them,
+  each group rematerialised by itself: HBM holds the group's float32
+  ``(GROUP, B, H, L, L)`` and ``(GROUP, B, H, L, K + V)`` arrays and the
+  state a group starts from (the step of one such layer and its
+  feed-forward at 4,096 tokens plans 1.70 GB of temporaries where the
+  whole chunked form planned 3.34: compiles for a described v5e, PR 36).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from . import tuning
 from .attention import residual_block, rms_norm
 from .ssm import causal_conv
 
@@ -82,9 +107,11 @@ def l2_norm(x):
 
 
 def carried(state, whole):
-    """What a chunk hands on of the state it started from: ``state (B, H,
-    V, K)`` times the chunk's whole decay ``whole (B, H)``."""
-    return whole[..., None, None] * state
+    """What a chunk hands on of the state it started from: ``state (...,
+    V, K)`` times the chunk's whole decay ``whole``, shaped to broadcast
+    against it (the kernels hand the transpose of the same product, the
+    gradient carried back over a chunk, through here too)."""
+    return whole * state
 
 
 def unit_lower_inverse(m):
@@ -154,26 +181,317 @@ def chunk_group(state, q, k, v, g, beta, cdt):
                        preferred_element_type=f32) \
             + jnp.einsum("bhij,bhjv->bhiv", qk.astype(cdt), new.astype(cdt),
                          preferred_element_type=f32)
-        state = carried(state, whole) + jnp.einsum(
+        state = carried(state, whole[..., None, None]) + jnp.einsum(
             "bhiv,bhik->bhvk", new.astype(cdt), k_out.astype(cdt),
             preferred_element_type=f32)
         return state, o
     return jax.lax.scan(step, state, (w, u, qk, q_in, k_out, whole))
 
 
+# -- the delta rule: the kernel tier ----------------------------------------
+#: bytes of VMEM the kernels may take (their blocks, double-buffered)
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _mm(a, b, ta=False, tb=False, cdt=None):
+    """``a @ b`` (``ta`` / ``tb``: of the transpose), float32 out: the
+    operands in ``cdt``, or float32 at ``highest`` where ``cdt`` is None."""
+    dims = (((0 if ta else 1,), (1 if tb else 0,)), ((), ()))
+    if cdt is None:
+        return jax.lax.dot_general(a, b, dims, precision=HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(a.astype(cdt), b.astype(cdt), dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _col(row, eye):
+    """A ``(1, L)`` row as an ``(L, 1)`` column (exact: a sum of zeros)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _row(col, eye):
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _inverse(a, ri, ci):
+    """``(I + a)^-1`` of a strictly lower ``a (L, L)`` by halves, as
+    :func:`unit_lower_inverse` on whole matrices: ``x`` holds the
+    inverses of the diagonal blocks of ``size``; the lower-left quarter
+    of each block of twice that, ``-Q C P``, is ``-x C x``."""
+    x = (ri == ci).astype(jnp.float32)
+    size = 1
+    while size < a.shape[0]:
+        quarter = (((ri ^ ci) < 2 * size) & ((ri & size) != 0)
+                   & ((ci & size) == 0))
+        x = x - _mm(_mm(x, jnp.where(quarter, a, 0.0)), x)
+        size *= 2
+    return x
+
+
+def _system(q, k, vb, c_row, b_row, cdt):
+    """What a chunk computes before its state: ``q``, ``k (L, K)``, ``vb =
+    beta v (L, V)``, the running sums ``c_row`` and ``b_row`` (beta) ``(1,
+    L)`` -> a dict of the chunk's float32 arrays (``chunk_group``'s)."""
+    n = q.shape[0]
+    ri = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    eye, lower, strict = ri == ci, ci <= ri, ci < ri
+    c_col, b_col = _col(c_row, eye), _col(b_row, eye)
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, c_col - c_row, 0.0)),
+                      0.0)
+    kb = k * b_col
+    gram = _mm(kb, k, tb=True)
+    t = _inverse(jnp.where(strict, decay * gram, 0.0), ri, ci)
+    e_col = jnp.exp(c_col)
+    last = c_row[:, n - 1:]                              # (1, 1)
+    qk = _mm(q, k, tb=True, cdt=cdt)
+    f_col = jnp.exp(last - c_col)
+    return {"eye": eye, "lower": lower, "strict": strict, "b_col": b_col,
+            "decay": decay, "kb": kb, "gram": gram, "t": t, "e_col": e_col,
+            "w": _mm(t, kb * e_col), "u": _mm(t, vb), "qk_raw": qk,
+            "qk": decay * qk, "q_in": q * e_col, "f_col": f_col,
+            "k_out": k * f_col, "whole": jnp.exp(last),
+            # the same as a row of K (a sum over the rows: Mosaic broadcasts
+            # a row over a state, not one number)
+            "whole_row": jnp.exp(jnp.sum(jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, k.shape, 0) == n - 1,
+                jnp.broadcast_to(c_col, k.shape), 0.0), axis=0,
+                keepdims=True))}
+
+
+def _chunk(c_ref, b_ref, q_ref, k_ref, v_ref, n):
+    """Chunk ``n``'s blocks: (q, k, vb, c_row, b_row)."""
+    return (q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
+            c_ref[0, 0, pl.ds(n, 1), :], b_ref[0, 0, pl.ds(n, 1), :])
+
+
+def _corrected(s, sy, cdt):
+    """A chunk's corrected values ``u' = U - W S^T`` from the state ``s``
+    it starts from."""
+    return sy["u"] - _mm(sy["w"], s, tb=True, cdt=cdt)
+
+
+def _fwd_kernel(*refs, cdt, save):
+    """Chunk ``n`` of one (row, head): the state in ``s_scr``, zeroed at
+    the row's first chunk; ``st_ref`` (``save``) takes the state the chunk
+    starts from."""
+    n = pl.program_id(2)
+    o_ref, s_scr = refs[5], refs[-1]
+
+    @pl.when(n == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    sy = _system(*_chunk(*refs[:5], n), cdt)
+    s = s_scr[...]
+    if save:
+        refs[6][0, 0, 0] = s
+    new = _corrected(s, sy, cdt)
+    o_ref[0, 0] = (_mm(sy["q_in"], s, tb=True, cdt=cdt)
+                   + _mm(sy["qk"], new, cdt=cdt))
+    s_scr[...] = carried(s, sy["whole_row"]) + _mm(new, sy["k_out"],
+                                                   ta=True, cdt=cdt)
+
+
+def _bwd_kernel(*refs, cdt):
+    """Chunk ``n`` of one (row, head), the row's chunks walked from its
+    last: the chunk's system is set up again from its inputs and the saved
+    state it started from; ``ds_scr`` carries the gradient of the state
+    the chunk hands on."""
+    st_ref, do_ref = refs[5], refs[6]
+    dq_ref, dk_ref, dv_ref, dc_ref, db_ref, ds_scr = refs[7:]
+    n = pl.num_programs(2) - 1 - pl.program_id(2)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    q, k, vb, c_row, b_row = _chunk(*refs[:5], n)
+    sy = _system(q, k, vb, c_row, b_row, cdt)
+    s, do, ds = st_ref[0, 0, 0], do_ref[0, 0], ds_scr[...]
+    w, u = sy["w"], sy["u"]
+    new = _corrected(s, sy, cdt)
+    # o = q_in s^T + qk new;  s' = whole s + new^T k_out;  new = u - w s^T
+    dnew = (_mm(sy["qk"], do, ta=True, cdt=cdt)
+            + _mm(sy["k_out"], ds, tb=True, cdt=cdt))
+    dqk = _mm(do, new, tb=True, cdt=cdt)
+    dk_out = _mm(new, ds, cdt=cdt)
+    dq_in = _mm(do, s, cdt=cdt)
+    dw = -_mm(dnew, s, cdt=cdt)
+    d_whole = jnp.sum(jnp.sum(ds * s, axis=1, keepdims=True), axis=0,
+                      keepdims=True)
+    ds_scr[...] = (carried(ds, sy["whole_row"])
+                   + _mm(do, sy["q_in"], ta=True, cdt=cdt)
+                   - _mm(dnew, w, ta=True, cdt=cdt))
+    # [w | u] = t [kb exp(c) | beta v],  t = (I + a)^-1
+    drw, dru = _mm(sy["t"], dw, ta=True), _mm(sy["t"], dnew, ta=True)
+    da = jnp.where(sy["strict"], -(_mm(drw, w, tb=True)
+                                   + _mm(dru, u, tb=True)), 0.0)
+    # a = strict(decay * kb k^T),  qk = decay * (q k^T)
+    dgram = da * sy["decay"]
+    dqk_raw = dqk * sy["decay"]
+    ddecay = da * sy["gram"] + jnp.where(sy["lower"], dqk * sy["qk_raw"],
+                                         0.0)
+    e_col, b_col, kb = sy["e_col"], sy["b_col"], sy["kb"]
+    dkb = _mm(dgram, k) + drw * e_col
+    dq_ref[0, 0] = _mm(dqk_raw, k, cdt=cdt) + dq_in * e_col
+    dk_ref[0, 0] = (_mm(dqk_raw, q, ta=True, cdt=cdt) + _mm(dgram, kb, ta=True)
+                    + dkb * b_col + dk_out * sy["f_col"])
+    dv_ref[0, 0] = dru
+    # the running sums: exp(c), exp(c_L - c), exp(c_L), the decays
+    z = jnp.sum(dk_out * sy["k_out"], axis=1, keepdims=True)
+    y = ddecay * sy["decay"]
+    dc_col = ((jnp.sum(drw * kb, axis=1, keepdims=True)
+               + jnp.sum(dq_in * q, axis=1, keepdims=True)) * e_col
+              - z + jnp.sum(y, axis=1, keepdims=True))
+    chunk = q.shape[0]
+    at_last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    d_last = jnp.sum(z, axis=0, keepdims=True) + d_whole * sy["whole"]
+    dc_ref[0, 0, pl.ds(n, 1), :] = (
+        _row(dc_col, sy["eye"]) - jnp.sum(y, axis=0, keepdims=True)
+        + jnp.where(at_last, d_last, 0.0))
+    db_ref[0, 0, pl.ds(n, 1), :] = _row(
+        jnp.sum(dkb * k, axis=1, keepdims=True), sy["eye"])
+
+
+def _specs(nc, chunk, widths, reverse=False):
+    """Blocks of one grid step (row, head, chunk): the running sums and
+    beta whole for the row and head, the chunk of each of ``widths``."""
+    def at(i):
+        return nc - 1 - i if reverse else i
+    whole = pl.BlockSpec((1, 1, nc, chunk), lambda r, hh, i: (r, hh, 0, 0))
+    return whole, [pl.BlockSpec((1, 1, chunk, wd),
+                                lambda r, hh, i: (r, hh, at(i), 0))
+                   for wd in widths]
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _states_spec(nc, dv, dk, reverse=False):
+    return pl.BlockSpec((1, 1, 1, dv, dk), lambda r, hh, i: (
+        r, hh, nc - 1 - i if reverse else i, 0, 0))
+
+
+def _rule_fwd_call(q, k, vb, c, beta, cdt, save):
+    """The forward kernel on head-major ``q``, ``k (B, H, T, K)``, ``vb =
+    beta v (B, H, T, V)`` and the chunks' running sums ``c`` and ``beta (B,
+    H, nc, L)``: ``o (B, H, T, V)`` (and, ``save``, the state each chunk
+    starts from, ``(B, H, nc, V, K)``)."""
+    b, h, t, dk = q.shape
+    dv = vb.shape[-1]
+    nc, chunk = c.shape[2:]
+    whole, (qs, ks, vs) = _specs(nc, chunk, (dk, dk, dv))
+    out_specs = [vs]
+    out_shape = [jax.ShapeDtypeStruct((b, h, t, dv), jnp.float32)]
+    if save:
+        out_specs.append(_states_spec(nc, dv, dk))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, nc, dv, dk),
+                                              jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, cdt=cdt, save=save),
+        grid=(b, h, nc),
+        in_specs=[whole, whole, qs, ks, vs],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+        name="pallas_delta_rule_fwd",
+        interpret=tuning.interpret_mode(),
+    )(c, beta, q, k, vb)
+    return out if save else out[0]
+
+
+def _rule_bwd_call(q, k, vb, c, beta, states, do, cdt):
+    """The backward kernel: ``(dq, dk, dvb, dc, dbeta)``, shaped as ``q``,
+    ``k``, ``vb``, ``c``, ``beta``."""
+    b, h, t, dk = q.shape
+    dv = vb.shape[-1]
+    nc, chunk = c.shape[2:]
+    whole, (qs, ks, vs) = _specs(nc, chunk, (dk, dk, dv), reverse=True)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, cdt=cdt),
+        grid=(b, h, nc),
+        in_specs=[whole, whole, qs, ks, vs,
+                  _states_spec(nc, dv, dk, reverse=True), vs],
+        out_specs=[qs, ks, vs, whole, whole],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, jnp.float32)
+                   for a in (q, k, vb, c, beta)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+        name="pallas_delta_rule_bwd",
+        interpret=tuning.interpret_mode(),
+    )(c, beta, q, k, vb, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule_kernel(q, k, vb, c, beta, cdt):
+    return tuning.batch_sharded(
+        lambda *a: _rule_fwd_call(*a, cdt, save=False), q, k, vb, c, beta)
+
+
+def _rule_kernel_fwd(q, k, vb, c, beta, cdt):
+    o, states = tuning.batch_sharded(
+        lambda *a: tuple(_rule_fwd_call(*a, cdt, save=True)),
+        q, k, vb, c, beta)
+    return o, (q, k, vb, c, beta, states)
+
+
+def _rule_kernel_bwd(cdt, res, do):
+    return tuning.batch_sharded(
+        lambda *a: tuple(_rule_bwd_call(*a, cdt)), *res, do)
+
+
+_rule_kernel.defvjp(_rule_kernel_fwd, _rule_kernel_bwd)
+
+
+def rule_route() -> str:
+    """``kernel`` where the delta rule runs as the Pallas kernels, else
+    ``chunked`` (the ``jax.numpy`` form)."""
+    return "kernel" if tuning.use_pallas() else "chunked"
+
+
+def delta_rule_kernel(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
+    """:func:`delta_rule` through the kernels: the same arguments and
+    result.  Inputs go head-major (``q``'s and ``k``'s relayouts fuse
+    into their L2 norms' pass, ``v``'s into the product ``beta v`` after
+    one copy of 94 MB a layer at 4,096 tokens), ``v`` as ``beta v`` and
+    ``g`` as its running sums inside each chunk (JAX hands their
+    gradients back to ``v``, ``beta`` and ``g``)."""
+    bsz, t, h, _ = q.shape
+    nc = t // chunk
+
+    def by_chunk(a):        # (B, T, H) -> (B, H, nc, L)
+        return a.reshape(bsz, nc, chunk, h).transpose(0, 3, 1, 2)
+    o = _rule_kernel(*(a.transpose(0, 2, 1, 3)
+                       for a in (q, k, v * beta[..., None])),
+                     jnp.cumsum(by_chunk(g), axis=-1), by_chunk(beta), cdt)
+    return o.transpose(0, 2, 1, 3)
+
+
 def delta_rule(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
     """``o_t = S_t q_t`` of ``S_t = exp(g_t) S_{t-1} + beta_t (v_t -
     exp(g_t) S_{t-1} k_t) k_t^T`` in chunks of ``chunk`` tokens.  ``q``,
     ``k (B, T, H, K)``, ``v (B, T, H, V)``, ``g`` (not positive) and
-    ``beta (B, T, H)``; float32 in and out.  The chunks go ``GROUP`` at a
+    ``beta (B, T, H)``; float32 in and out.  The kernels where Pallas runs
+    (:func:`delta_rule_kernel`), else the ``jax.numpy`` chunked form
+    (:func:`delta_rule_chunked`)."""
+    if q.shape[1] % chunk:
+        raise ValueError(f"sequence length {q.shape[1]} is no multiple of "
+                         f"the delta rule's chunk {chunk}")
+    form = delta_rule_kernel if tuning.use_pallas() else delta_rule_chunked
+    return form(q, k, v, g, beta, chunk, cdt)
+
+
+def delta_rule_chunked(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
+    """:func:`delta_rule` in ``jax.numpy``: the chunks go ``GROUP`` at a
     time (:func:`chunk_group`), each group rematerialised by itself in the
     backward: what is kept a group is the state it starts from, and the
     float32 ``(chunks, B, H, L, L)`` and ``(chunks, B, H, L, K + V)``
     arrays of the chunked form are never whole."""
     bsz, t, h, _ = q.shape
-    if t % chunk:
-        raise ValueError(f"sequence length {t} is no multiple of the "
-                         f"delta rule's chunk {chunk}")
     nc = t // chunk
     group = GROUP if nc % GROUP == 0 else nc
 

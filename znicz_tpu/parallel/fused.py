@@ -537,6 +537,16 @@ def mixer_routes(spec: ModelSpec) -> str:
     return " ".join(f"{k}:{counts[k]}" for k in order if k in counts)
 
 
+def rule_routes(spec: ModelSpec) -> str:
+    """``kernel:<n> chunked:<m>``: the linear-attention rows of ``spec``
+    whose delta rule runs as the Pallas kernels, and as the ``jax.numpy``
+    chunked form (``ops/gdn.py``; the same rule the step applies as it is
+    traced)."""
+    rows = sum(la.kind == "gdn_block" for la in spec.layers)
+    kernel = rows if gdn_ops.rule_route() == "kernel" else 0
+    return f"kernel:{kernel} chunked:{rows - kernel}"
+
+
 # -- pure math (all traced; spec is static) --------------------------------
 def _takes_window(x, cfg) -> bool:
     """``windowed_pairs``' rule on a pair's traced input: the batch as

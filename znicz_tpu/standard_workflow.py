@@ -455,8 +455,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             # before (ops/attention.py)
             where["attn_routes"] = fused.attn_routes(spec)
         if any(la.kind == "gdn_block" for la in spec.layers):
-            # the hidden layers by the mixer each runs (ops/gdn.py)
+            # the hidden layers by the mixer each runs, and the linear
+            # ones by the form their delta rule takes (ops/gdn.py)
             where["mixer_routes"] = fused.mixer_routes(spec)
+            where["rule_routes"] = fused.rule_routes(spec)
         self.info("fused trainer on %s",
                   " ".join(f"{k}={v!r}" for k, v in where.items()))
         # host-vs-device time split (telemetry): every call of
