@@ -69,6 +69,21 @@ def test_confusion_matrix_resets_each_epoch():
     assert total <= wf.loader.total_samples
 
 
+def test_confusion_matrix_stays_on_the_host(xla_device):
+    """Counted and read on the host: an XLA workflow's evaluator puts no
+    copy of it on the device (a language model's is vocabulary squared),
+    and the tick path still counts every sample into it."""
+    prng.seed_all(1234)
+    wf = mnist.MnistWorkflow()
+    wf.decision.max_epochs = 1
+    wf.initialize(device=xla_device)
+    cm = wf.evaluator.confusion_matrix
+    assert cm and cm._devmem is None
+    wf.run()
+    assert cm._devmem is None
+    assert 0 < cm.mem.sum() <= wf.loader.total_samples
+
+
 def test_sgd_update_dispatcher_used_by_gd(xla_device):
     """gd xla path goes through ops.update.sgd_update_h."""
     from znicz_tpu import Vector

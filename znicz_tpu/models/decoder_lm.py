@@ -8,8 +8,9 @@ sizes here are test widths (d 64, 4 query heads over 2 key/value heads of
 that ``python -m znicz_tpu znicz_tpu.models.decoder_lm --fused`` trains a
 decoder through the ``Launcher`` in seconds on a CPU.  A ``"mamba"`` in
 ``root.decoder_lm.layer_types`` puts a Mamba-2 mixer (``mamba_block``) in
-an attention block's place and a ``"linear"`` a gated-delta-rule one
-(``gdn_block``); ``shared_width``, ``tied`` and the four multipliers make
+an attention block's place, a ``"linear"`` a gated-delta-rule one
+(``gdn_block``) and a ``"parallel"`` attention and a Mamba-2 mixer side by
+side (``parallel_block``); ``shared_width``, ``tied`` and the four multipliers make
 the hybrid's other parts, ``mlp_width`` a dense gated feed-forward
 (``mlp_block``) in every expert block's place, ``qk_norm`` and
 ``norm="post"`` the norms of a block that norms its sublayers' outputs
@@ -83,6 +84,16 @@ def decoder_layers(cfg) -> list[dict]:
         elif kind == "linear":
             layers.append({"type": "gdn_block", "<-": back,
                            "->": {**normed, **gdn}})
+        elif kind == "parallel":
+            scale = get("residual_scale")     # on each mixer's output
+            layers.append({"type": "parallel_block", "<-": back, "->": {
+                "attn_scale": scale, "ssm_scale": scale,
+                "heads": get("heads"), "kv_heads": get("kv_heads"),
+                "head_dim": get("head_dim"), "rope": dict(rope["full"]),
+                "ssm_heads": mamba["heads"],
+                "ssm_heads_held": mamba["heads_held"],
+                "ssm_head_dim": mamba["head_dim"], "state": mamba["state"],
+                "conv": mamba["conv"], "chunk": mamba["chunk"]}})
         else:
             layers.append({"type": "attn_block", "<-": back, "->": {
                 **normed, "qk_norm": get("qk_norm"), "heads": get("heads"),

@@ -1,12 +1,13 @@
 """The token-sequence layer kinds of a decoder language model as units:
 ``embedding``, ``attn_block``, ``mamba_block``, ``gdn_block``,
-``moe_block``, ``mlp_block`` and ``lm_head``, each with its gradient unit.
+``parallel_block``, ``moe_block``, ``mlp_block`` and ``lm_head``, each with
+its gradient unit.
 
 A unit here holds any number of parameter ``Vector``s, named by its
 ``LEAVES`` (the gradient unit holds ``velocity_<leaf>`` for each), where
 the znicz kinds hold ``weights`` and ``bias``.  The math is one pure
 function a kind in ``ops/attention.py`` / ``ops/ssm.py`` / ``ops/gdn.py`` /
-``ops/moe.py``; the fused
+``ops/hybrid.py`` / ``ops/moe.py``; the fused
 trainer (``parallel/fused.py``) calls it directly, and the tick path
 (``wf.run()``) calls the same function here and ``jax.vjp`` of it in the
 gradient unit, followed by the one momentum-SGD update of ``ops/update``.
@@ -21,8 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..memory import Vector
-from ..ops import (attention as attn_ops, gdn as gdn_ops, moe as moe_ops,
-                   softmax, ssm as ssm_ops, update)
+from ..ops import (attention as attn_ops, gdn as gdn_ops,
+                   hybrid as hybrid_ops, moe as moe_ops, softmax,
+                   ssm as ssm_ops, update)
 from .nn_units import Forward, GradientDescentBase
 
 
@@ -137,6 +139,34 @@ class Embedding(SequenceForward):
         return (*in_shape, self.hidden)
 
 
+def attn_mixer_config(name: str, heads, kv_heads, head_dim, window=None,
+                      rope=None, positional="rope",
+                      score_scale=None) -> dict:
+    """The static config of ``ops/attention.attn_mixer`` from a unit's
+    arguments, which ``AttentionBlock`` and ``ParallelBlock`` share."""
+    heads, kv_heads = int(heads), int(kv_heads)
+    if heads % kv_heads:
+        raise ValueError(f"{name}: {heads} query heads over {kv_heads} "
+                         "key/value heads")
+    if positional not in ("rope", "nope"):
+        raise ValueError(f"{name}: positional {positional!r} is neither "
+                         "'rope' nor 'nope'")
+    rope = dict(rope or {"rope_type": "default", "rope_theta": 10000.0})
+    return {"heads": heads, "kv_heads": kv_heads, "head_dim": int(head_dim),
+            "window": None if window is None else int(window),
+            "score_scale": None if score_scale is None else float(score_scale),
+            "rope": (None if positional == "nope"
+                     else tuple(sorted(rope.items())))}
+
+
+def attn_leaf_shapes(d: int, mixer: dict) -> dict:
+    """``wq``, ``wk``, ``wv`` and ``wo`` of an attention mixer's config
+    over a stream of width ``d``."""
+    q, kv = (mixer["heads"] * mixer["head_dim"],
+             mixer["kv_heads"] * mixer["head_dim"])
+    return {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
+
+
 class AttentionBlock(SequenceForward):
     """``x + scale * Attn(RMSNorm(x; g1))``: grouped-query attention,
     causal, over a sliding ``window`` or (None) everything before, for
@@ -162,33 +192,18 @@ class AttentionBlock(SequenceForward):
             self.LEAVES = type(self).LEAVES + self.QK_LEAVES
             for leaf in self.QK_LEAVES:
                 setattr(self, leaf, Vector())
-        self.heads, self.kv_heads = int(heads), int(kv_heads)
-        self.head_dim = int(head_dim)
-        self.window = None if window is None else int(window)
-        if positional not in ("rope", "nope"):
-            raise ValueError(f"{self.name}: positional {positional!r} is "
-                             "neither 'rope' nor 'nope'")
-        self.rope = None if positional == "nope" else dict(
-            rope or {"rope_type": "default", "rope_theta": 10000.0})
-        self.score_scale = (None if score_scale is None
-                            else float(score_scale))
+        self.mixer = attn_mixer_config(self.name, heads, kv_heads, head_dim,
+                                       window, rope, positional, score_scale)
 
     def fused_config(self):
-        return {**super().fused_config(), "heads": self.heads,
-                "kv_heads": self.kv_heads, "head_dim": self.head_dim,
-                "window": self.window, "score_scale": self.score_scale,
-                "qk_norm": self.qk_norm, "norm": self.norm,
-                "rope": (None if self.rope is None
-                         else tuple(sorted(self.rope.items())))}
+        return {**super().fused_config(), **self.mixer,
+                "qk_norm": self.qk_norm, "norm": self.norm}
 
     def leaf_shapes(self, in_shape):
-        d, hd = in_shape[-1], self.head_dim
-        shapes = {"g1": (d,), "wq": (d, self.heads * hd),
-                  "wk": (d, self.kv_heads * hd),
-                  "wv": (d, self.kv_heads * hd),
-                  "wo": (self.heads * hd, d)}
+        shapes = {"g1": (in_shape[-1],),
+                  **attn_leaf_shapes(in_shape[-1], self.mixer)}
         if self.qk_norm:
-            shapes.update(gq=(self.heads * hd,), gk=(self.kv_heads * hd,))
+            shapes.update(gq=shapes["wq"][1:], gk=shapes["wk"][1:])
         return shapes
 
 
@@ -216,10 +231,46 @@ class Mamba2Start:
         return super()._fill_leaf(leaf, shape)
 
 
+def ssm_mixer_config(name: str, heads, heads_held=None, head_dim=None,
+                     state=None, conv=4, chunk=256, groups=1,
+                     in_scales=None) -> dict:
+    """The static config of ``ops/ssm.mamba_mixer`` from a unit's
+    arguments, which ``MambaBlock`` and ``ParallelBlock`` share: the first
+    ``heads_held`` of the model's ``heads`` heads, whose B and C come in
+    ``groups`` groups (a layout :func:`~..ops.ssm.groups_held` refuses is
+    refused); ``in_scales``: the model's five multipliers on ``z | x | B |
+    C | dt`` of the input projection, or None."""
+    heads = int(heads)
+    held = heads if heads_held is None else int(heads_held)
+    if not 0 < held <= heads:
+        raise ValueError(f"{name}: heads_held {heads_held} of {heads} heads")
+    if in_scales is not None and len(in_scales) != 5:
+        raise ValueError(f"{name}: in_scales {in_scales} are not five: z, "
+                         "x, B, C, dt")
+    return {"heads": heads, "heads_held": held, "head_dim": int(head_dim),
+            "state": int(state), "conv": int(conv), "chunk": int(chunk),
+            "groups_held": ssm_ops.groups_held(heads, held, int(groups)),
+            "in_scales": (None if in_scales is None
+                          else tuple(float(v) for v in in_scales))}
+
+
+def ssm_leaf_shapes(name: str, in_shape: tuple, mixer: dict) -> dict:
+    """``ops/ssm.LEAVES``' shapes (``g1`` among them) of a Mamba mixer's
+    config over an input ``(..., T, d)``; ``T`` has to be a multiple of
+    the scan's ``chunk``."""
+    if in_shape[-2] % mixer["chunk"]:
+        raise ValueError(f"{name}: sequence length {in_shape[-2]} is no "
+                         f"multiple of the scan's chunk {mixer['chunk']}")
+    return ssm_ops.leaf_shapes(in_shape[-1], mixer["heads_held"],
+                               mixer["head_dim"], mixer["state"],
+                               mixer["conv"], mixer["groups_held"])
+
+
 class MambaBlock(Mamba2Start, SequenceForward):
     """``x + scale * Mamba2(RMSNorm(x; g1))`` for the ``heads_held`` first
-    of the model's ``heads`` (``ops/ssm.py``): a chunked scan over the
-    sequence, whose length has to be a multiple of ``chunk``."""
+    of the model's ``heads`` (``ops/ssm.py``; :func:`ssm_mixer_config`): a
+    chunked scan over the sequence, whose length has to be a multiple of
+    ``chunk``."""
 
     MAPPING = ("mamba_block",)
     KIND = "mamba_block"
@@ -230,28 +281,58 @@ class MambaBlock(Mamba2Start, SequenceForward):
                  heads_held=None, head_dim=None, state=None, conv=4,
                  chunk=256, **kwargs):
         super().__init__(workflow, name, **kwargs)
-        self.heads = int(heads)
-        self.heads_held = self.heads if heads_held is None \
-            else int(heads_held)
-        if not 0 < self.heads_held <= self.heads:
-            raise ValueError(f"{self.name}: heads_held {heads_held} of "
-                             f"{self.heads} heads")
-        self.head_dim, self.state = int(head_dim), int(state)
-        self.conv, self.chunk = int(conv), int(chunk)
+        self.mixer = ssm_mixer_config(self.name, heads, heads_held, head_dim,
+                                      state, conv, chunk)
 
     def fused_config(self):
-        return {**super().fused_config(), "heads": self.heads,
-                "heads_held": self.heads_held, "head_dim": self.head_dim,
-                "state": self.state, "conv": self.conv,
-                "chunk": self.chunk}
+        return {**super().fused_config(), **self.mixer}
 
     def leaf_shapes(self, in_shape):
-        if in_shape[-2] % self.chunk:
-            raise ValueError(
-                f"{self.name}: sequence length {in_shape[-2]} is no "
-                f"multiple of the scan's chunk {self.chunk}")
-        return ssm_ops.leaf_shapes(in_shape[-1], self.heads_held,
-                                   self.head_dim, self.state, self.conv)
+        return ssm_leaf_shapes(self.name, in_shape, self.mixer)
+
+
+class ParallelBlock(Mamba2Start, SequenceForward):
+    """``x + attn_scale * Attn(n) + ssm_scale * Mamba2(n)``, ``n =
+    RMSNorm(x; g1)`` (``ops/hybrid.py``): the attention mixer of
+    :func:`attn_mixer_config` (causal over everything before, rotary
+    tables of ``rope``) beside the Mamba-2 mixer of
+    :func:`ssm_mixer_config` (``ssm_heads``, ``ssm_heads_held``,
+    ``ssm_head_dim``, ``state``, ``conv``, ``chunk``, ``groups``,
+    ``in_scales``).  Each mixer has its own multiplier, so the block takes
+    no ``scale``."""
+
+    MAPPING = ("parallel_block",)
+    KIND = "parallel_block"
+    FWD = staticmethod(hybrid_ops.parallel_block_fwd)
+    LEAVES = hybrid_ops.LEAVES
+
+    def __init__(self, workflow=None, name=None, heads=None, kv_heads=None,
+                 head_dim=None, rope=None, score_scale=None, ssm_heads=None,
+                 ssm_heads_held=None, ssm_head_dim=None, state=None, conv=4,
+                 chunk=256, groups=1, in_scales=None, attn_scale=None,
+                 ssm_scale=None, **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        if self.scale is not None:
+            raise ValueError(f"{self.name}: a parallel block scales each "
+                             "mixer (attn_scale, ssm_scale), not their sum")
+        self.attn = attn_mixer_config(self.name, heads, kv_heads, head_dim,
+                                      rope=rope, score_scale=score_scale)
+        self.ssm = ssm_mixer_config(self.name, ssm_heads, ssm_heads_held,
+                                    ssm_head_dim, state, conv, chunk, groups,
+                                    in_scales)
+        self.attn_scale = None if attn_scale is None else float(attn_scale)
+        self.ssm_scale = None if ssm_scale is None else float(ssm_scale)
+
+    def fused_config(self):
+        return {"eps": self.rms_norm_eps,
+                "attn": tuple(sorted(self.attn.items())),
+                "ssm": tuple(sorted(self.ssm.items())),
+                "attn_scale": self.attn_scale, "ssm_scale": self.ssm_scale}
+
+    def leaf_shapes(self, in_shape):
+        ssm = ssm_leaf_shapes(self.name, in_shape, self.ssm)
+        return {"g1": ssm.pop("g1"),
+                **attn_leaf_shapes(in_shape[-1], self.attn), **ssm}
 
 
 class GatedDeltaBlock(Mamba2Start, SequenceForward):
@@ -338,7 +419,8 @@ class MoEBlock(SequenceForward):
 class MLPBlock(SequenceForward):
     """``x + scale * MLP(RMSNorm(x; g2))`` (or, ``norm="post"``, the norm
     on the output): the dense gated feed-forward of ``width`` columns,
-    ``MLP(n) = (silu(n Wg) * n Wu) Wd`` (``ops/moe.mlp_block_fwd``)."""
+    ``MLP(n) = (silu(gate_scale * n Wg) * n Wu) Wd``
+    (``ops/moe.mlp_block_fwd``; ``gate_scale`` None: 1)."""
 
     MAPPING = ("mlp_block",)
     KIND = "mlp_block"
@@ -346,12 +428,17 @@ class MLPBlock(SequenceForward):
     LEAVES = ("g2", "wg", "wu", "wd")
 
     def __init__(self, workflow=None, name=None, width=None, norm="pre",
-                 **kwargs):
+                 gate_scale=None, **kwargs):
         super().__init__(workflow, name, **kwargs)
         self.width, self.norm = int(width), self._norm_at(norm)
+        #: a model's multiplier on ``n Wg`` inside the activation
+        self.gate_scale = None if gate_scale is None else float(gate_scale)
 
     def fused_config(self):
-        return {**super().fused_config(), "norm": self.norm}
+        config = {**super().fused_config(), "norm": self.norm}
+        if self.gate_scale is not None:
+            config["gate_scale"] = self.gate_scale
+        return config
 
     def leaf_shapes(self, in_shape):
         d, f = in_shape[-1], self.width
@@ -428,8 +515,8 @@ def units_of(layers: list, workflow=None) -> list:
     are not initialized.  For code that wants a model's spec or static
     configs without a workflow (``fused.sequence_layer``)."""
     kinds = {cls.MAPPING[0]: cls for cls in (
-        Embedding, AttentionBlock, MambaBlock, GatedDeltaBlock, MoEBlock,
-        MLPBlock, LMHead)}
+        Embedding, AttentionBlock, MambaBlock, GatedDeltaBlock,
+        ParallelBlock, MoEBlock, MLPBlock, LMHead)}
     made = []
     for la in layers:
         forward = dict(la["->"])
@@ -447,7 +534,7 @@ class SequenceGD(GradientDescentBase):
     the layer's one learning rate, decay and moment."""
 
     MAPPING = ("embedding", "attn_block", "mamba_block", "gdn_block",
-               "moe_block", "mlp_block", "lm_head")
+               "parallel_block", "moe_block", "mlp_block", "lm_head")
 
     def setup_from_forward(self, fwd):
         super().setup_from_forward(fwd)

@@ -56,7 +56,11 @@ class EvaluatorSoftmax(EvaluatorBase):
         if self.compute_confusion and not self.confusion_matrix:
             self.confusion_matrix.mem = np.zeros((n_classes, n_classes),
                                                  np.int64)
-        self.init_vectors(self.err_output, self.confusion_matrix)
+        # the confusion matrix is counted on the host (numpy_run) and read
+        # there: it is never put on the device, where a language model's
+        # (vocabulary x vocabulary: 4.3 GB at 32,640 rows) would take the
+        # room its training program needs
+        self.init_vectors(self.err_output)
         self._confusion_epoch = -1
 
     def numpy_run(self) -> None:

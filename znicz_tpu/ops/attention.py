@@ -239,56 +239,61 @@ def residual_block(x, g, cfg: dict, inner):
     return x + out
 
 
+def attn_mixer(leaves, xn, cfg: dict, cdt=jnp.float32):
+    """``Attn(xn)`` of the block's input ``xn (B, T, d)`` (normalised, or
+    not with the norm on the output), float32.  Leaves ``wq (d, H*D)``,
+    ``wk (d, KV*D)``, ``wv (d, KV*D)``, ``wo (H*D, d)`` for the ``H`` query
+    and ``KV`` key/value heads held and, with ``cfg["qk_norm"]``, ``gq
+    (H*D,)``, ``gk (KV*D,)``: the gains of RMS norms on the query and key
+    projections, over all the channels held and before the split into
+    heads.  ``cfg``: ``heads``, ``kv_heads``, ``head_dim``, ``window``
+    (None: full causal), ``rope`` (sorted items of the layer type's rope
+    entry; None: no positional embedding), ``eps`` and, where the model
+    states one, ``score_scale`` (on ``q k^T``; else ``1 /
+    sqrt(head_dim)``).  Matmul operands in ``cdt``, accumulation, the
+    norms and the softmax in float32."""
+    wq, wk, wv, wo, *qk_gains = leaves
+    b, t, _ = xn.shape
+    nh, nkv, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
+    xn = xn.astype(cdt)
+
+    def proj(w, heads, gain=None):
+        y = jnp.dot(xn, w.astype(cdt), preferred_element_type=jnp.float32)
+        if gain is not None:
+            with jax.named_scope("qk_norm"):
+                y = rms_norm(y, gain, cfg["eps"])
+        return y.reshape(b, t, heads, hd)
+    q, k, v = (proj(wq, nh, *qk_gains[:1]), proj(wk, nkv, *qk_gains[1:]),
+               proj(wv, nkv))
+    score_scale = cfg.get("score_scale")
+    if score_scale is None:
+        score_scale = 1.0 / math.sqrt(hd)
+    if cfg["rope"] is None:
+        q = q * score_scale
+    else:
+        with jax.named_scope("rope"):
+            cos, sin = rope_tables(t, hd, tuple(cfg["rope"]))
+            q = apply_rope(q, cos, sin) * score_scale
+            k = apply_rope(k, cos, sin)
+    with jax.named_scope("scores"):
+        o = attention(q.astype(cdt), k.astype(cdt), v.astype(cdt),
+                      cfg["window"])
+    return jnp.dot(o.reshape(b, t, nh * hd).astype(cdt), wo.astype(cdt),
+                   preferred_element_type=jnp.float32)
+
+
 def attn_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     """``attn_block``: ``x + scale * Attn(RMSNorm(x; g1))``, or with
     ``cfg["norm"]`` "post" ``x + scale * RMSNorm(Attn(x); g1)``.  Leaves
-    ``g1 (d,)``, ``wq (d, H*D)``, ``wk (d, KV*D)``, ``wv (d, KV*D)``, ``wo
-    (H*D, d)`` for the ``H`` query and ``KV`` key/value heads held and,
-    with ``cfg["qk_norm"]``, ``gq (H*D,)``, ``gk (KV*D,)``: the gains of
-    RMS norms on the query and key projections, over all the channels held
-    and before the split into heads.  ``cfg``:
-    ``heads``, ``kv_heads``, ``head_dim``, ``window`` (None: full causal),
-    ``rope`` (sorted items of the layer type's rope entry; None: no
-    positional embedding), ``eps`` and, where the model states them,
-    ``score_scale`` (on ``q k^T``; else ``1 / sqrt(head_dim)``) and
-    ``scale`` (the block's scale on what it adds to the stream).  Matmul
-    operands in ``cdt``, accumulation, the residual, the norms and the
-    softmax in float32."""
-    g1, wq, wk, wv, wo, *qk_gains = leaves
-    b, t, _ = x.shape
-    nh, nkv, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
-    if bool(qk_gains) != bool(cfg.get("qk_norm")):
+    ``g1 (d,)`` and :func:`attn_mixer`'s; ``cfg``: the mixer's and, where
+    the model states it, ``scale`` (the block's scale on what it adds to
+    the stream)."""
+    g1, *mixer_leaves = leaves
+    if (len(mixer_leaves) > 4) != bool(cfg.get("qk_norm")):
         raise ValueError(f"attn_block with qk_norm {cfg.get('qk_norm')!r} "
                          f"and {len(leaves)} leaves")
-
-    def mixer(xn):
-        xn = xn.astype(cdt)
-
-        def proj(w, heads, gain=None):
-            y = jnp.dot(xn, w.astype(cdt),
-                        preferred_element_type=jnp.float32)
-            if gain is not None:
-                with jax.named_scope("qk_norm"):
-                    y = rms_norm(y, gain, cfg["eps"])
-            return y.reshape(b, t, heads, hd)
-        q, k, v = (proj(wq, nh, *qk_gains[:1]), proj(wk, nkv, *qk_gains[1:]),
-                   proj(wv, nkv))
-        score_scale = cfg.get("score_scale")
-        if score_scale is None:
-            score_scale = 1.0 / math.sqrt(hd)
-        if cfg["rope"] is None:
-            q = q * score_scale
-        else:
-            with jax.named_scope("rope"):
-                cos, sin = rope_tables(t, hd, tuple(cfg["rope"]))
-                q = apply_rope(q, cos, sin) * score_scale
-                k = apply_rope(k, cos, sin)
-        with jax.named_scope("scores"):
-            o = attention(q.astype(cdt), k.astype(cdt), v.astype(cdt),
-                          cfg["window"])
-        return jnp.dot(o.reshape(b, t, nh * hd).astype(cdt), wo.astype(cdt),
-                       preferred_element_type=jnp.float32)
-    return residual_block(x, g1, cfg, mixer), {}
+    return residual_block(x, g1, cfg, lambda xn: attn_mixer(
+        mixer_leaves, xn, cfg, cdt)), {}
 
 
 def lm_head_fwd(leaves, x, cfg: dict, cdt=jnp.float32):

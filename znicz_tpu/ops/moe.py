@@ -385,15 +385,19 @@ def held_expert_sum(xn, weights, experts, wg, wu, wd, first: int, cdt,
     return out, counts, moved
 
 
-def shared_expert(xn, sg, su, sd, cdt):
+def shared_expert(xn, sg, su, sd, cdt, gate_scale=None):
     """``(silu(x Sg) * x Su) Sd`` for every row of ``xn (N, d)``: the
     columns held of an expert every token takes, ungated: ``sg``/``su (d,
-    f_held)``, ``sd (f_held, d)``."""
+    f_held)``, ``sd (f_held, d)``; ``gate_scale``, where the model has
+    one, multiplies ``x Sg`` inside the activation."""
     xc = xn.astype(cdt)
 
     def dot(a, w):
         return jnp.dot(a, w.astype(cdt), preferred_element_type=jnp.float32)
-    return dot((jax.nn.silu(dot(xc, sg)) * dot(xc, su)).astype(cdt), sd)
+    gate = dot(xc, sg)
+    if gate_scale is not None:
+        gate = gate * gate_scale
+    return dot((jax.nn.silu(gate) * dot(xc, su)).astype(cdt), sd)
 
 
 def moe_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
@@ -454,9 +458,12 @@ def mlp_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     MLP(RMSNorm(x; g2))`` or, with ``cfg["norm"]`` "post", ``x + scale *
     RMSNorm(MLP(x); g2)``; ``MLP(n) = (silu(n Wg) * n Wu) Wd``, the
     shared expert's form over the whole width.  Leaves ``g2 (d,)``, ``wg``,
-    ``wu (d, f)``, ``wd (f, d)``; ``cfg``: ``eps``, ``norm``, ``scale``."""
+    ``wu (d, f)``, ``wd (f, d)``; ``cfg``: ``eps``, ``norm``, ``scale`` and,
+    where the model has one, ``gate_scale`` (``silu(gate_scale * n Wg)``;
+    None: 1)."""
     g2, wg, wu, wd = leaves
     b, t, d = x.shape
     with jax.named_scope("mlp_block"):
         return residual_block(x, g2, cfg, lambda xn: shared_expert(
-            xn.reshape(b * t, d), wg, wu, wd, cdt).reshape(b, t, d)), {}
+            xn.reshape(b * t, d), wg, wu, wd, cdt,
+            cfg.get("gate_scale")).reshape(b, t, d)), {}

@@ -33,7 +33,8 @@ from jax.experimental.layout import Format, Layout
 
 from ..ops import (activations, attention as attn_ops, conv as conv_ops,
                    deconv as deconv_ops, dropout as drop_ops, gdn as gdn_ops,
-                   lrn_pool as lrn_pool_ops, moe as moe_ops,
+                   hybrid as hybrid_ops, lrn_pool as lrn_pool_ops,
+                   moe as moe_ops,
                    normalization as lrn_ops, pooling as pool_ops,
                    softmax as softmax_ops, ssm as ssm_ops, tuning)
 from ..telemetry import (compilestats, flightrecorder, programs,
@@ -52,6 +53,7 @@ SEQUENCE_FWD = {"embed": attn_ops.embed_fwd,
                 "attn_block": attn_ops.attn_block_fwd,
                 "mamba_block": ssm_ops.mamba_block_fwd,
                 "gdn_block": gdn_ops.gdn_block_fwd,
+                "parallel_block": hybrid_ops.parallel_block_fwd,
                 "moe_block": moe_ops.moe_block_fwd,
                 "mlp_block": moe_ops.mlp_block_fwd,
                 "lm_head": attn_ops.lm_head_fwd}
@@ -67,7 +69,8 @@ class LayerSpec:
     #                               stochastic_abs_pool | lrn | lrn_pool |
     #                               dropout | activation | embed |
     #                               attn_block | mamba_block | gdn_block |
-    #                               moe_block | mlp_block | lm_head
+    #                               parallel_block | moe_block |
+    #                               mlp_block | lm_head
     activation: str               # activations.BY_NAME key; last fc layer
     include_bias: bool            # of a softmax model keeps "linear"
     hypers: tuple                 # (lr, weights_decay, l1_vs_l2, momentum)
@@ -520,20 +523,22 @@ def attn_routes(spec: ModelSpec) -> str:
 
 #: the mixer kinds of a decoder's hidden layers, by the name the start
 #: record counts them under
-MIXERS = {"gdn_block": "linear", "mamba_block": "ssm"}
+MIXERS = {"gdn_block": "linear", "mamba_block": "ssm",
+          "parallel_block": "parallel"}
 
 
 def mixer_routes(spec: ModelSpec) -> str:
-    """``linear:<n> full:<m>`` (and ``ssm:``, ``window:`` where the model
-    has such layers): the hidden layers of ``spec`` by the mixer each
-    runs, attention rows as :func:`attn_routes` names them."""
+    """``linear:<n> full:<m>`` (and ``ssm:``, ``parallel:``, ``window:``
+    where the model has such layers): the hidden layers of ``spec`` by the
+    mixer each runs (``parallel``: attention and a Mamba-2 mixer side by
+    side), attention rows as :func:`attn_routes` names them."""
     counts: dict = {}
     for layer in spec.layers:
         name = (attn_ops.attn_route(layer.cfg) if layer.kind == "attn_block"
                 else MIXERS.get(layer.kind))
         if name is not None:
             counts[name] = counts.get(name, 0) + 1
-    order = ("linear", "ssm", "window", "full")
+    order = ("linear", "ssm", "parallel", "window", "full")
     return " ".join(f"{k}:{counts[k]}" for k in order if k in counts)
 
 
@@ -619,6 +624,11 @@ COUNTERS = {
         "train_gdn_tokens",
         "token-layer pairs the linear-attention (gated delta rule) layers "
         "scanned in the last epoch (tokens a step times such layers)")),
+    "parallel_tokens": ("sum", lambda: REGISTRY.gauge(
+        "train_parallel_tokens",
+        "token-layer pairs the parallel hybrid layers ran through both "
+        "their attention and their Mamba-2 mixer in the last epoch (tokens "
+        "a step times such layers)")),
 }
 
 

@@ -454,10 +454,12 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             # attention rows over a sliding window, and over everything
             # before (ops/attention.py)
             where["attn_routes"] = fused.attn_routes(spec)
-        if any(la.kind == "gdn_block" for la in spec.layers):
-            # the hidden layers by the mixer each runs, and the linear
-            # ones by the form their delta rule takes (ops/gdn.py)
+        if any(la.kind in fused.MIXERS for la in spec.layers):
+            # the hidden layers by the mixer each runs
             where["mixer_routes"] = fused.mixer_routes(spec)
+        if any(la.kind == "gdn_block" for la in spec.layers):
+            # the linear ones by the form their delta rule takes
+            # (ops/gdn.py)
             where["rule_routes"] = fused.rule_routes(spec)
         self.info("fused trainer on %s",
                   " ".join(f"{k}={v!r}" for k, v in where.items()))

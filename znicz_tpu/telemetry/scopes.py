@@ -54,7 +54,8 @@ HEAD = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+)")
 CONTAINERS = ("while", "conditional", "call")
 PHASES = ("fwd", "bwd", "upd", "input", "loss", "accum")
 #: scopes inside a layer's row, outermost first
-INNER = ("rope", "scores", "qk_norm", "route", "experts", "combine",
+INNER = ("parallel_block", "attn_mixer", "mamba_mixer",
+         "rope", "scores", "qk_norm", "route", "experts", "combine",
          "shared_expert", "mamba_block", "ssd_scan", "mlp_block",
          "gdn_block", "short_conv", "delta_rule")
 KERNELS = ("gmm", "splash")
