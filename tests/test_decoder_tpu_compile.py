@@ -230,3 +230,27 @@ def test_delta_rule_kernels_compile_for_a_v5e(one_chip, mosaic, backward):
                           if backward else rule, *_rule_shapes(one_chip))
     assert ("pallas_delta_rule_bwd" in text) == backward
     assert "pallas_delta_rule_fwd" in text
+
+
+#: the four decoder cells' tables and tokens a step: rows, width, tokens
+EMBED_SHAPES = {"falcon-h1-34b-instruct": (32640, 5120, 4096),
+                "olmo-hybrid-7b": (12544, 3840, 4096),
+                "mellum2-12b-a2.5b": (24576, 2304, 8192),
+                "granite-4.0-h-small": (12544, 4096, 2048)}
+
+
+@pytest.mark.parametrize("config", sorted(EMBED_SHAPES))
+def test_the_embedding_gradient_compiles_for_a_v5e(one_chip, mosaic, config):
+    """The table's gradient is the kernel: no scatter is left in the
+    compiled program."""
+    rows, width, tokens = EMBED_SHAPES[config]
+
+    def loss(table, ids):
+        out, _ = attention.embed_fwd((table,), ids, {"width": width})
+        return jnp.sum(out)
+    assert attention.embed_route(width) == "kernel"
+    text = _compiled_text(jax.grad(loss), jax.ShapeDtypeStruct(
+        (rows, width), jnp.float32, sharding=one_chip), jax.ShapeDtypeStruct(
+        (1, tokens), jnp.int32, sharding=one_chip))
+    assert "pallas_embed_grad" in text
+    assert not re.search(r"\bscatter\(", text)

@@ -319,6 +319,7 @@ def test_the_start_record_names_the_mixers(tmp_path):
     rows = [json.loads(line) for line in open(path)]
     assert rows and all(r["mixer_routes"] == "linear:3 full:1"
                         and r["rule_routes"] == "kernel:0 chunked:3"
+                        and r["embed_routes"] == "kernel:0 scatter:1"
                         and r["gdn_tokens"] == 3 * r["tokens"] > 0
                         for r in rows)
     step = [r for r in flightrecorder.RECORDER.snapshot()["recent"]

@@ -10,11 +10,13 @@ there, three products, rows back) with its three products' time beside
 it, so that what the layer spends outside its kernels can be read
 without the step; and the gated delta rule at the shapes of one of
 ``olmo-hybrid-7b``'s linear layers (``ops/gdn.py``: the kernels against
-the ``jax.numpy`` chunked form).
+the ``jax.numpy`` chunked form); and the embedding's table gradient at
+the four decoder cells' shapes (XLA's scatter-add, alone and into the
+update's decay term, against ``ops/attention.embed_grad``'s kernel).
 One JSON line a case; exits 3 off-TPU.
 
     chiprun -- python3 tools/bench_decoder_kernels.py [splash] [gmm] [layer]
-        [delta_rule]
+        [delta_rule] [embed]
 """
 
 import json
@@ -46,7 +48,8 @@ def main(argv) -> int:
         return 3
     ks = jax.random.split(jax.random.key(0), 8)
     cases = {"splash": splash, "gmm": grouped_products,
-             "layer": expert_layer, "delta_rule": delta_rule}
+             "layer": expert_layer, "delta_rule": delta_rule,
+             "embed": embed}
     for name in argv or list(cases):
         cases[name](ks)
     return 0
@@ -217,6 +220,56 @@ def delta_rule(keys) -> None:
                       "fwd_bwd_ms": t_b,
                       "roofline_share": least / (t_b * 1e-3),
                       "gap_o_dq_dk_dv_dg_dbeta": gaps}), flush=True)
+
+
+#: the decoder cells' tables and tokens a step: rows, width, tokens
+EMBED_SHAPES = {"falcon-h1-34b-instruct": (32640, 5120, 4096),
+                "olmo-hybrid-7b": (12544, 3840, 4096),
+                "mellum2-12b-a2.5b": (24576, 2304, 8192),
+                "granite-4.0-h-small": (12544, 4096, 2048)}
+
+
+def embed(keys) -> None:
+    """The embedding's table gradient alone at each decoder cell's shape,
+    ids of its Markov rows (``benchmark/lib/decoder_model.py``): XLA's
+    scatter-add (``jnp.take``'s transpose) against the kernel
+    (``attention.embed_grad``), the largest gap of the two against the
+    largest value, and the share of the HBM bound: the float32 table
+    written once and the rows read once, over 819 GB/s."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark.lib import decoder_model
+    from znicz_tpu.ops import attention
+    for name, (rows, width, tokens) in EMBED_SHAPES.items():
+        cfg = {"vocab_size": rows,
+               "assumed": {"branching": 4, "chain_states": 1024}}
+        ids, _ = decoder_model.make_rows(2147483647, np.arange(1), cfg,
+                                         {"seq_len": tokens})
+        ids = ids.reshape(-1)
+        dy = jax.random.normal(keys[7], (tokens, width))
+        table = jax.random.normal(keys[6], (rows, width))
+        wd = jnp.zeros((), jnp.float32)
+        scatter = jax.jit(lambda dy, w, wd: jnp.zeros(
+            (rows, width), jnp.float32).at[ids].add(dy))
+        # as the step has it: added in place into the update's decay term
+        into_decay = jax.jit(lambda dy, w, wd: (wd * w).at[ids].add(dy))
+        kernel = jax.jit(lambda dy, w, wd: attention.embed_grad(ids, dy,
+                                                                rows))
+        want, got = scatter(dy, table, wd), kernel(dy, table, wd)
+        least = 4.0 * (rows + tokens) * width / 819e9
+        for form, fn in (("scatter", scatter), ("scatter_into_decay",
+                                                into_decay),
+                         ("kernel", kernel)):
+            t = timed(fn, dy, table, wd, reps=20)
+            print(json.dumps({
+                "kernel": "embed_grad", "config": name, "form": form,
+                "rows": rows, "width": width, "tokens": tokens,
+                "distinct_ids": int(jnp.unique(ids).size),
+                "block_rows": attention.embed_block_rows(rows, width),
+                "ms": 1e3 * t, "hbm_bound_share": least / t,
+                "gap": float(jnp.max(jnp.abs(got - want))
+                             / jnp.max(jnp.abs(want)))}), flush=True)
 
 
 if __name__ == "__main__":

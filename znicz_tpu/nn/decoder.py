@@ -132,6 +132,11 @@ class Embedding(SequenceForward):
         super().__init__(workflow, name, **kwargs)
         self.vocab, self.hidden = int(vocab), int(hidden)
 
+    def fused_config(self):
+        # the width decides how the table's gradient is made
+        # (``ops/attention.embed_route``)
+        return {**super().fused_config(), "width": self.hidden}
+
     def leaf_shapes(self, in_shape):
         return {"table": (self.vocab, self.hidden)}
 

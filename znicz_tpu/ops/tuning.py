@@ -74,6 +74,12 @@ def kernel_mesh(mesh):
         _KERNEL_MESH.reset(token)
 
 
+def traced_mesh():
+    """The mesh :func:`kernel_mesh` names for the jit being traced, or
+    None."""
+    return _KERNEL_MESH.get()
+
+
 def batch_sharded(fn, *arrays):
     """``fn(*arrays)`` for a Pallas-tier call whose operands and
     results all lead with the batch dim.  Under :func:`kernel_mesh` it

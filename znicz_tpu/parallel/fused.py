@@ -552,6 +552,17 @@ def rule_routes(spec: ModelSpec) -> str:
     return f"kernel:{kernel} chunked:{rows - kernel}"
 
 
+def embed_routes(spec: ModelSpec, mesh=None) -> str:
+    """``kernel:<n> scatter:<m>``: the embedding rows of ``spec`` whose
+    table's gradient is the Pallas kernel's sorted segment sum, and XLA's
+    scatter-add (``attn_ops.embed_route``: the rule the step applies as it
+    is traced; on a ``mesh`` it takes the scatter)."""
+    widths = [la.cfg["width"] for la in spec.layers if la.kind == "embed"]
+    kernel = 0 if mesh is not None else sum(
+        attn_ops.embed_route(w) == "kernel" for w in widths)
+    return f"kernel:{kernel} scatter:{len(widths) - kernel}"
+
+
 # -- pure math (all traced; spec is static) --------------------------------
 def _takes_window(x, cfg) -> bool:
     """``windowed_pairs``' rule on a pair's traced input: the batch as

@@ -461,6 +461,9 @@ class StandardWorkflowBase(AcceleratedWorkflow):
             # the linear ones by the form their delta rule takes
             # (ops/gdn.py)
             where["rule_routes"] = fused.rule_routes(spec)
+        if any(la.kind == "embed" for la in spec.layers):
+            # how the embedding's table gradient is made (ops/attention.py)
+            where["embed_routes"] = fused.embed_routes(spec, mesh)
         self.info("fused trainer on %s",
                   " ".join(f"{k}={v!r}" for k, v in where.items()))
         # host-vs-device time split (telemetry): every call of
