@@ -605,7 +605,8 @@ def test_the_sample_trains_through_the_launcher(sample):
 def counted_epoch():
     """One epoch of the sample through the Launcher, a Mamba layer in
     its first attention layer's place, a gated-delta-rule layer in its
-    second's and a parallel hybrid layer in its third's, so that every
+    second's, a parallel hybrid layer in its third's and a
+    Kimi-delta-attention layer before the full one, so that every
     declared counter counts: its ``train_step`` row and the registry's
     families after it."""
     from znicz_tpu.config import root
@@ -613,7 +614,8 @@ def counted_epoch():
     from znicz_tpu.telemetry import flightrecorder
     from znicz_tpu.telemetry.registry import REGISTRY
     saved = root.decoder_lm.to_dict()
-    root.decoder_lm.layer_types = ["mamba", "linear", "parallel", "full"]
+    root.decoder_lm.layer_types = ["mamba", "linear", "parallel", "kda",
+                                   "full"]
     try:
         Launcher("znicz_tpu.models.decoder_lm", backend="xla", fused=True,
                  epochs=1, seed=7).run()

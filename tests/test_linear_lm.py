@@ -422,6 +422,11 @@ def test_the_vocabulary_shares_stand_side_by_side():
 #: and the Mamba mixer were factored out of their blocks, the scan gained
 #: groups and multipliers, the feed-forward a gate multiplier, and with
 #: none of that asked for, all four lists trace to what they did.
+#: ``tiny-falcon-h1`` is ``test_parallel_hybrid_lm.py``'s list as the commit
+#: before the per-channel rule traced it: the attention mixer gained an
+#: output gate and the chunked rule's loop over a group's chunks became a
+#: function of its own, and with none of that asked for, all five lists
+#: trace to what they did.
 STEPS_BEFORE = {
     "tiny-decoder":
     "e818025f7d20ad50392e2e71b32d9b506dff8125c0f3cd24fab3e0f1fc62f109",
@@ -430,7 +435,9 @@ STEPS_BEFORE = {
     "tiny-hybrid":
     "fce75a833f1367f66dc98e360f4e14eadf93bbd5e8d92e45dceaefffd70efe06",
     "tiny-olmo-hybrid":
-    "0c569f2634e57a4686933ada2a85897924716e4b804a61bde8208770af2a5991"}
+    "0c569f2634e57a4686933ada2a85897924716e4b804a61bde8208770af2a5991",
+    "tiny-falcon-h1":
+    "441435dcfe799ba2fb64c6731642ab343f6f5a72cde5d05893717b8233168dfc"}
 
 
 @pytest.mark.parametrize("name", sorted(STEPS_BEFORE))
@@ -441,6 +448,9 @@ def test_the_other_decoders_build_the_programs_they_did(name):
             (0, 8) if name.endswith("all-held") else (2, 2))
     elif name == "tiny-hybrid":
         import test_hybrid_lm as before
+        _, spec, weights, x, y = before.setup()
+    elif name == "tiny-falcon-h1":
+        import test_parallel_hybrid_lm as before
         _, spec, weights, x, y = before.setup()
     else:
         _, spec, weights, x, y = setup()

@@ -9,8 +9,10 @@ that ``python -m znicz_tpu znicz_tpu.models.decoder_lm --fused`` trains a
 decoder through the ``Launcher`` in seconds on a CPU.  A ``"mamba"`` in
 ``root.decoder_lm.layer_types`` puts a Mamba-2 mixer (``mamba_block``) in
 an attention block's place, a ``"linear"`` a gated-delta-rule one
-(``gdn_block``) and a ``"parallel"`` attention and a Mamba-2 mixer side by
-side (``parallel_block``); ``shared_width``, ``tied`` and the four multipliers make
+(``gdn_block``), a ``"kda"`` one with a decay a key channel (``kda_block``)
+and a ``"parallel"`` attention and a Mamba-2 mixer side by side
+(``parallel_block``); ``attn_gate`` gives the attention blocks an output
+gate; ``shared_width``, ``tied`` and the four multipliers make
 the hybrid's other parts, ``mlp_width`` a dense gated feed-forward
 (``mlp_block``) in every expert block's place, ``qk_norm`` and
 ``norm="post"`` the norms of a block that norms its sublayers' outputs
@@ -48,7 +50,9 @@ root.decoder_lm.setdefaults({
               "conv": 4, "chunk": 8},
     "gdn": {"heads": 4, "key_dim": 8, "value_dim": 16, "conv": 4,
             "chunk": 8},
-    "mlp_width": 0, "qk_norm": False, "norm": "pre",
+    "kda": {"heads": 4, "key_dim": 16, "value_dim": 16, "rank": 16,
+            "conv": 4, "chunk": 16},
+    "mlp_width": 0, "qk_norm": False, "norm": "pre", "attn_gate": False,
     "shared_width": 0, "tied": False, "positional": "rope",
     "embedding_scale": None, "residual_scale": None, "score_scale": None,
     "logits_scale": None,
@@ -68,7 +72,8 @@ def decoder_layers(cfg) -> list[dict]:
     def group(key) -> dict:
         sub = get(key)
         return sub.to_dict() if hasattr(sub, "to_dict") else dict(sub)
-    rope, mamba, gdn = group("rope"), group("mamba"), group("gdn")
+    rope, mamba, gdn, kda = (group(key) for key in
+                             ("rope", "mamba", "gdn", "kda"))
     back = {"learning_rate": get("learning_rate"),
             "gradient_moment": get("gradient_moment"),
             "weights_decay": get("weights_decay")}
@@ -84,6 +89,9 @@ def decoder_layers(cfg) -> list[dict]:
         elif kind == "linear":
             layers.append({"type": "gdn_block", "<-": back,
                            "->": {**normed, **gdn}})
+        elif kind == "kda":
+            layers.append({"type": "kda_block", "<-": back,
+                           "->": {**normed, **kda}})
         elif kind == "parallel":
             scale = get("residual_scale")     # on each mixer's output
             layers.append({"type": "parallel_block", "<-": back, "->": {
@@ -101,6 +109,7 @@ def decoder_layers(cfg) -> list[dict]:
                 "window": get("window") if kind == "sliding" else None,
                 "positional": get("positional"),
                 "score_scale": get("score_scale"),
+                **({"output_gate": True} if get("attn_gate") else {}),
                 "rope": (None if get("positional") == "nope"
                          else dict(rope[kind]))}})
         if get("mlp_width"):

@@ -1,7 +1,7 @@
 """The token-sequence layer kinds of a decoder language model as units:
 ``embedding``, ``attn_block``, ``mamba_block``, ``gdn_block``,
-``parallel_block``, ``moe_block``, ``mlp_block`` and ``lm_head``, each with
-its gradient unit.
+``kda_block``, ``parallel_block``, ``moe_block``, ``mlp_block`` and
+``lm_head``, each with its gradient unit.
 
 A unit here holds any number of parameter ``Vector``s, named by its
 ``LEAVES`` (the gradient unit holds ``velocity_<leaf>`` for each), where
@@ -180,35 +180,46 @@ class AttentionBlock(SequenceForward):
     model states one (else ``1 / sqrt(head_dim)``).  ``qk_norm``: RMS
     norms on the query and key projections (leaves ``gq``, ``gk``);
     ``norm="post"``: ``g1``'s norm on the mixer's output instead,
-    ``x + scale * RMSNorm(Attn(x); g1)``."""
+    ``x + scale * RMSNorm(Attn(x); g1)``.  ``output_gate``: the mixer's
+    heads times ``sigmoid(n Wgate)`` before ``Wo`` (leaf ``wgate``)."""
 
     MAPPING = ("attn_block",)
     KIND = "attn_block"
     FWD = staticmethod(attn_ops.attn_block_fwd)
     LEAVES = ("g1", "wq", "wk", "wv", "wo")
     QK_LEAVES = ("gq", "gk")
+    GATE_LEAVES = ("wgate",)
 
     def __init__(self, workflow=None, name=None, heads=None, kv_heads=None,
                  head_dim=None, window=None, rope=None, positional="rope",
-                 score_scale=None, qk_norm=False, norm="pre", **kwargs):
+                 score_scale=None, qk_norm=False, norm="pre",
+                 output_gate=False, **kwargs):
         super().__init__(workflow, name, **kwargs)
         self.qk_norm, self.norm = bool(qk_norm), self._norm_at(norm)
-        if self.qk_norm:
-            self.LEAVES = type(self).LEAVES + self.QK_LEAVES
-            for leaf in self.QK_LEAVES:
-                setattr(self, leaf, Vector())
+        self.output_gate = bool(output_gate)
+        for on, extra in ((self.qk_norm, self.QK_LEAVES),
+                          (self.output_gate, self.GATE_LEAVES)):
+            if on:
+                self.LEAVES = self.LEAVES + extra
+                for leaf in extra:
+                    setattr(self, leaf, Vector())
         self.mixer = attn_mixer_config(self.name, heads, kv_heads, head_dim,
                                        window, rope, positional, score_scale)
 
     def fused_config(self):
-        return {**super().fused_config(), **self.mixer,
-                "qk_norm": self.qk_norm, "norm": self.norm}
+        config = {**super().fused_config(), **self.mixer,
+                  "qk_norm": self.qk_norm, "norm": self.norm}
+        if self.output_gate:
+            config["gate"] = True
+        return config
 
     def leaf_shapes(self, in_shape):
         shapes = {"g1": (in_shape[-1],),
                   **attn_leaf_shapes(in_shape[-1], self.mixer)}
         if self.qk_norm:
             shapes.update(gq=shapes["wq"][1:], gk=shapes["wk"][1:])
+        if self.output_gate:
+            shapes["wgate"] = shapes["wq"]
         return shapes
 
 
@@ -374,6 +385,49 @@ class GatedDeltaBlock(Mamba2Start, SequenceForward):
                                    self.value_dim, self.conv)
 
 
+class KDABlock(Mamba2Start, SequenceForward):
+    """``x + scale * KDA(RMSNorm(x; g1))`` (or ``norm="post"``): Kimi Delta
+    Attention (``ops/gdn.kda_mixer``), the delta rule with a decay a key
+    channel, ``heads`` heads with keys of ``key_dim`` and values of
+    ``value_dim``, the decay's and the output gate's low-rank products of
+    ``rank`` columns, in chunks of ``chunk`` tokens, of which the sequence
+    length has to be a multiple.  ``a_log`` a head and ``dt_bias`` a key
+    channel start as Mamba-2's; the output gate's bias ``bg`` at 0."""
+
+    MAPPING = ("kda_block",)
+    KIND = "kda_block"
+    FWD = staticmethod(gdn_ops.kda_block_fwd)
+    LEAVES = gdn_ops.KDA_LEAVES
+
+    def __init__(self, workflow=None, name=None, heads=None, key_dim=None,
+                 value_dim=None, rank=None, conv=4, chunk=64, norm="pre",
+                 **kwargs):
+        super().__init__(workflow, name, **kwargs)
+        self.heads, self.key_dim = int(heads), int(key_dim)
+        self.value_dim, self.rank = int(value_dim), int(rank)
+        self.conv, self.chunk = int(conv), int(chunk)
+        self.norm = self._norm_at(norm)
+
+    def fused_config(self):
+        return {**super().fused_config(), "heads": self.heads,
+                "key_dim": self.key_dim, "value_dim": self.value_dim,
+                "conv": self.conv, "chunk": self.chunk, "norm": self.norm}
+
+    def _fill_leaf(self, leaf, shape):
+        if leaf == "bg":
+            return np.zeros(shape, np.float32)
+        return super()._fill_leaf(leaf, shape)
+
+    def leaf_shapes(self, in_shape):
+        if in_shape[-2] % self.chunk:
+            raise ValueError(
+                f"{self.name}: sequence length {in_shape[-2]} is no "
+                f"multiple of the delta rule's chunk {self.chunk}")
+        return gdn_ops.kda_leaf_shapes(in_shape[-1], self.heads,
+                                       self.key_dim, self.value_dim,
+                                       self.conv, self.rank)
+
+
 class MoEBlock(SequenceForward):
     """``x + scale * (MoE(n) + Shared(n))``, ``n = RMSNorm(x; g2)``, for
     the ``experts_held = [first, count]`` of the model's ``experts``:
@@ -520,7 +574,7 @@ def units_of(layers: list, workflow=None) -> list:
     are not initialized.  For code that wants a model's spec or static
     configs without a workflow (``fused.sequence_layer``)."""
     kinds = {cls.MAPPING[0]: cls for cls in (
-        Embedding, AttentionBlock, MambaBlock, GatedDeltaBlock,
+        Embedding, AttentionBlock, MambaBlock, GatedDeltaBlock, KDABlock,
         ParallelBlock, MoEBlock, MLPBlock, LMHead)}
     made = []
     for la in layers:
@@ -539,7 +593,8 @@ class SequenceGD(GradientDescentBase):
     the layer's one learning rate, decay and moment."""
 
     MAPPING = ("embedding", "attn_block", "mamba_block", "gdn_block",
-               "parallel_block", "moe_block", "mlp_block", "lm_head")
+               "kda_block", "parallel_block", "moe_block", "mlp_block",
+               "lm_head")
 
     def setup_from_forward(self, fwd):
         super().setup_from_forward(fwd)

@@ -380,13 +380,16 @@ def attn_mixer(leaves, xn, cfg: dict, cdt=jnp.float32):
     and ``KV`` key/value heads held and, with ``cfg["qk_norm"]``, ``gq
     (H*D,)``, ``gk (KV*D,)``: the gains of RMS norms on the query and key
     projections, over all the channels held and before the split into
-    heads.  ``cfg``: ``heads``, ``kv_heads``, ``head_dim``, ``window``
-    (None: full causal), ``rope`` (sorted items of the layer type's rope
-    entry; None: no positional embedding), ``eps`` and, where the model
-    states one, ``score_scale`` (on ``q k^T``; else ``1 /
-    sqrt(head_dim)``).  Matmul operands in ``cdt``, accumulation, the
-    norms and the softmax in float32."""
+    heads; with ``cfg["gate"]`` last ``wgate (d, H*D)``: an output gate,
+    ``o * sigmoid(xn Wgate)`` elementwise before ``Wo``.  ``cfg``:
+    ``heads``, ``kv_heads``, ``head_dim``, ``window`` (None: full causal),
+    ``rope`` (sorted items of the layer type's rope entry; None: no
+    positional embedding), ``eps`` and, where the model states one,
+    ``score_scale`` (on ``q k^T``; else ``1 / sqrt(head_dim)``).  Matmul
+    operands in ``cdt``, accumulation, the norms, the gate and the softmax
+    in float32."""
     wq, wk, wv, wo, *qk_gains = leaves
+    wgate = qk_gains.pop() if cfg.get("gate") else None
     b, t, _ = xn.shape
     nh, nkv, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
     xn = xn.astype(cdt)
@@ -412,20 +415,28 @@ def attn_mixer(leaves, xn, cfg: dict, cdt=jnp.float32):
     with jax.named_scope("scores"):
         o = attention(q.astype(cdt), k.astype(cdt), v.astype(cdt),
                       cfg["window"])
-    return jnp.dot(o.reshape(b, t, nh * hd).astype(cdt), wo.astype(cdt),
+    o = o.reshape(b, t, nh * hd)
+    if wgate is not None:
+        with jax.named_scope("attn_gate"):
+            o = o * jax.nn.sigmoid(jnp.dot(
+                xn, wgate.astype(cdt), preferred_element_type=jnp.float32))
+    return jnp.dot(o.astype(cdt), wo.astype(cdt),
                    preferred_element_type=jnp.float32)
 
 
 def attn_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     """``attn_block``: ``x + scale * Attn(RMSNorm(x; g1))``, or with
     ``cfg["norm"]`` "post" ``x + scale * RMSNorm(Attn(x); g1)``.  Leaves
-    ``g1 (d,)`` and :func:`attn_mixer`'s; ``cfg``: the mixer's and, where
+    ``g1 (d,)`` and :func:`attn_mixer`'s (``wgate`` last where the mixer is
+    gated); ``cfg``: the mixer's and, where
     the model states it, ``scale`` (the block's scale on what it adds to
     the stream)."""
     g1, *mixer_leaves = leaves
-    if (len(mixer_leaves) > 4) != bool(cfg.get("qk_norm")):
-        raise ValueError(f"attn_block with qk_norm {cfg.get('qk_norm')!r} "
-                         f"and {len(leaves)} leaves")
+    if len(mixer_leaves) != 4 + 2 * bool(cfg.get("qk_norm")) + bool(
+            cfg.get("gate")):
+        raise ValueError(f"attn_block with qk_norm {cfg.get('qk_norm')!r}, "
+                         f"gate {cfg.get('gate')!r} and {len(leaves)} "
+                         "leaves")
     return residual_block(x, g1, cfg, lambda xn: attn_mixer(
         mixer_leaves, xn, cfg, cdt)), {}
 

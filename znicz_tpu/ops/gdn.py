@@ -61,6 +61,27 @@ the start record's ``rule_routes``):
   state a group starts from (the step of one such layer and its
   feed-forward at 4,096 tokens plans 1.70 GB of temporaries where the
   whole chunked form planned 3.34: compiles for a described v5e, PR 36).
+
+``kda_block`` is the sibling kind of Kimi Delta Attention (Kimi Linear,
+arXiv:2510.26692, section 3): the same rule with a decay a KEY CHANNEL,
+``S_t = S_{t-1} Diag(a_t) + beta_t (v_t - S_{t-1} Diag(a_t) k_t) k_t^T``
+with ``a_t = exp(g_t)`` in ``R^K``, low-rank gates and a sigmoid output
+gate (:func:`kda_mixer`).  Its chunked form (:func:`kda_group`) is the one
+above term for term, with ``c`` the running sum of ``g`` a channel:
+
+    A  = strict_lower(beta_i sum_k k_ik k_jk exp(c_ik - c_jk))
+    W  = (I + A)^-1 (beta k exp(c)),   U = (I + A)^-1 (beta v)
+    o  = (q exp(c)) S^T + tril(sum_k q_ik k_jk exp(c_ik - c_jk)) (U - W S^T)
+    S <- S Diag(exp(c_L)) + (U - W S^T)^T (k exp(c_L - c))
+
+The decay between two tokens no longer factors out of the sum over ``k``,
+and ``exp(-c)`` overflows once a chunk's decays add up below -88, so no
+``exp`` of a positive sum is taken: across the ``SUB``-token sub-chunks of a
+chunk the factor is split at the start ``b`` of the later one, ``exp(c_i -
+c_b) exp(c_b - c_j)``, both at most 1, and the sums are products of
+matrices; inside a sub-chunk the ``(SUB, SUB, K)`` decays are formed whole.
+It runs as the ``jax.numpy`` chunked form on every tier (``rule_routes``
+``kda:chunked``), each group of chunks rematerialised by itself.
 """
 
 from __future__ import annotations
@@ -89,6 +110,15 @@ L2_EPS = 1e-6
 #: together
 GROUP = 8
 
+#: leaves of a ``kda_block``, in the order of ``kda_block_fwd``'s ``leaves``:
+#: the two low-rank gates are ``n Wfa Wfb`` (the decay's) and ``n Wga Wgb +
+#: bg`` (the output's)
+KDA_LEAVES = ("g1", "wq", "wk", "wv", "wb", "wfa", "wfb", "wga", "wgb", "bg",
+              "conv_q", "conv_k", "conv_v", "a_log", "dt_bias", "g_o", "wo")
+
+#: tokens a sub-chunk of the per-channel rule (:func:`kda_group`)
+SUB = 16
+
 
 def leaf_shapes(d: int, heads: int, key_dim: int, value_dim: int,
                 conv: int) -> dict:
@@ -97,6 +127,17 @@ def leaf_shapes(d: int, heads: int, key_dim: int, value_dim: int,
             "wa": (d, heads), "wb": (d, heads), "wg": (d, vv),
             "conv_q": (conv, qk), "conv_k": (conv, qk),
             "conv_v": (conv, vv), "a_log": (heads,), "dt_bias": (heads,),
+            "g_o": (value_dim,), "wo": (vv, d)}
+
+
+def kda_leaf_shapes(d: int, heads: int, key_dim: int, value_dim: int,
+                    conv: int, rank: int) -> dict:
+    qk, vv = heads * key_dim, heads * value_dim
+    return {"g1": (d,), "wq": (d, qk), "wk": (d, qk), "wv": (d, vv),
+            "wb": (d, heads), "wfa": (d, rank), "wfb": (rank, qk),
+            "wga": (d, rank), "wgb": (rank, vv), "bg": (vv,),
+            "conv_q": (conv, qk), "conv_k": (conv, qk),
+            "conv_v": (conv, vv), "a_log": (heads,), "dt_bias": (qk,),
             "g_o": (value_dim,), "wo": (vv, d)}
 
 
@@ -171,6 +212,16 @@ def chunk_group(state, q, k, v, g, beta, cdt):
     q_in = q * jnp.exp(cum)[..., None]
     k_out = k * jnp.exp(cum[..., -1:] - cum)[..., None]
     whole = jnp.exp(cum[..., -1])                        # (G, B, H)
+    return _chunks_in_turn(state, (w, u, qk, q_in, k_out, whole),
+                           lambda whole: whole[..., None, None], cdt)
+
+
+def _chunks_in_turn(state, parts, widen, cdt):
+    """The loop over a group's chunks that carries the state ``(B, H, V,
+    K)``: ``parts`` are the chunks' ``(w, u, qk, q_in, k_out, whole)``,
+    ``widen`` shapes a chunk's ``whole`` decay to broadcast against the
+    state.  -> (the closing state, ``o (G, B, H, L, V)``)."""
+    f32 = jnp.float32
 
     def step(state, chunk_of):
         w, u, qk, q_in, k_out, whole = chunk_of
@@ -181,11 +232,76 @@ def chunk_group(state, q, k, v, g, beta, cdt):
                        preferred_element_type=f32) \
             + jnp.einsum("bhij,bhjv->bhiv", qk.astype(cdt), new.astype(cdt),
                          preferred_element_type=f32)
-        state = carried(state, whole[..., None, None]) + jnp.einsum(
+        state = carried(state, widen(whole)) + jnp.einsum(
             "bhiv,bhik->bhvk", new.astype(cdt), k_out.astype(cdt),
             preferred_element_type=f32)
         return state, o
-    return jax.lax.scan(step, state, (w, u, qk, q_in, k_out, whole))
+    return jax.lax.scan(step, state, parts)
+
+
+def decayed_products(left, right, cum, dot):
+    """``P_ij = sum_k left_ik right_jk exp(c_ik - c_jk)`` where ``j <= i``,
+    0 elsewhere: ``left``, ``right``, and the running sums ``cum`` of decays
+    that are not positive, ``(..., L, K)`` -> ``(..., L, L)`` float32.
+    Across the ``SUB``-token sub-chunks the decay goes through the later
+    sub-chunk's start ``b``, ``exp(c_i - c_b) exp(c_b - c_j)`` (both at
+    most 1), and the sum is ``dot(a (..., m, SUB, K), b (..., m, L, K)) ->
+    (..., m, SUB, L)``, a product of matrices a sub-chunk; inside one the
+    ``(SUB, SUB, K)`` decays are formed whole, in float32."""
+    *lead, n, width = left.shape
+    sub = min(SUB, n)
+    m = n // sub
+    # the running sum just before each sub-chunk's first token
+    start = jnp.concatenate([jnp.zeros_like(cum[..., :1, :]),
+                             cum[..., sub - 1:-1:sub, :]], axis=-2)
+    left_s, cum_s = (a.reshape(*lead, m, sub, width) for a in (left, cum))
+    # token j lies in a sub-chunk before sub-chunk s
+    before = (jnp.arange(n) // sub)[None, :] < jnp.arange(m)[:, None]
+    gap = start[..., :, None, :] - cum[..., None, :, :]      # (.., m, L, K)
+    cross = dot(left_s * jnp.exp(cum_s - start[..., :, None, :]),
+                right[..., None, :, :] * jnp.exp(
+                    jnp.where(before[..., None], gap, 0.0)))
+    cross = jnp.where(before[:, None, :], cross, 0.0)
+    keep = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    seg = cum_s[..., :, None, :] - cum_s[..., None, :, :]    # (.., m, i, j, K)
+    decay = jnp.where(keep, jnp.exp(jnp.where(keep, seg, 0.0)), 0.0)
+    inner = jnp.sum(left_s[..., :, None, :]
+                    * right.reshape(*lead, m, 1, sub, width) * decay, axis=-1)
+    on_diagonal = inner[..., :, :, None, :] * jnp.eye(m)[:, None, :, None]
+    return (cross + on_diagonal.reshape(*lead, m, sub, n)).reshape(
+        *lead, n, n)
+
+
+def kda_group(state, q, k, v, g, beta, cdt):
+    """``GROUP`` chunks of the per-channel rule from the state they start
+    from: ``q``, ``k``, ``g (G, B, H, L, K)``, ``v (G, B, H, L, V)``, ``beta
+    (G, B, H, L, 1)``, ``state (B, H, V, K)`` -> (the closing state, ``o
+    (G, B, H, L, V)``): :func:`chunk_group` with the decays between tokens
+    of :func:`decayed_products`."""
+    f32 = jnp.float32
+    chunk, dk = q.shape[-2:]
+    cum = jnp.cumsum(g, axis=-2)                         # (G, B, H, L, K)
+    kb = k * beta
+
+    def system(a, b):
+        return jnp.einsum("...mik,...mjk->...mij", a, b, precision=HIGHEST)
+
+    def operands(a, b):
+        return jnp.einsum("...mik,...mjk->...mij", a.astype(cdt),
+                          b.astype(cdt), preferred_element_type=f32)
+    a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1),
+                  decayed_products(kb, k, cum, system), 0.0)
+    wu = jnp.einsum(
+        "cbhij,cbhjk->cbhik", unit_lower_inverse(a),
+        jnp.concatenate([kb * jnp.exp(cum), v * beta], axis=-1),
+        precision=HIGHEST)
+    w, u = wu[..., :dk], wu[..., dk:]
+    qk = decayed_products(q, k, cum, operands)
+    q_in = q * jnp.exp(cum)
+    k_out = k * jnp.exp(cum[..., -1:, :] - cum)
+    whole = jnp.exp(cum[..., -1, :])                     # (G, B, H, K)
+    return _chunks_in_turn(state, (w, u, qk, q_in, k_out, whole),
+                           lambda whole: whole[..., None, :], cdt)
 
 
 # -- the delta rule: the kernel tier ----------------------------------------
@@ -491,6 +607,13 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
     backward: what is kept a group is the state it starts from, and the
     float32 ``(chunks, B, H, L, L)`` and ``(chunks, B, H, L, K + V)``
     arrays of the chunked form are never whole."""
+    return _by_groups(chunk_group, q, k, v, g, beta, chunk, cdt)
+
+
+def _by_groups(group_fn, q, k, v, g, beta, chunk: int, cdt):
+    """A chunked rule over the sequence: ``group_fn`` (:func:`chunk_group`,
+    :func:`kda_group`) on ``GROUP`` chunks at a time, a ``lax.scan`` carrying
+    the state, each group rematerialised by itself."""
     bsz, t, h, _ = q.shape
     nc = t // chunk
     group = GROUP if nc % GROUP == 0 else nc
@@ -499,10 +622,26 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
         return a.reshape(bsz, nc // group, group, chunk, h, -1).transpose(
             1, 2, 0, 4, 3, 5)
     _, o = jax.lax.scan(
-        jax.checkpoint(lambda state, xs: chunk_group(state, *xs, cdt)),
+        jax.checkpoint(lambda state, xs: group_fn(state, *xs, cdt)),
         jnp.zeros((bsz, h, v.shape[-1], k.shape[-1]), jnp.float32),
         tuple(groups(a) for a in (q, k, v, g, beta)))
     return o.transpose(2, 0, 1, 4, 3, 5).reshape(bsz, t, h, v.shape[-1])
+
+
+def kda_rule(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
+    """``o_t = S_t q_t`` of ``S_t = S_{t-1} Diag(exp(g_t)) + beta_t (v_t -
+    S_{t-1} Diag(exp(g_t)) k_t) k_t^T`` in chunks of ``chunk`` tokens: ``q``,
+    ``k``, ``g (B, T, H, K)`` (``g`` not positive: a decay a key channel),
+    ``v (B, T, H, V)``, ``beta (B, T, H)``; float32 in and out.  The
+    ``jax.numpy`` chunked form on every tier (:func:`kda_group`, ``GROUP``
+    chunks at a time)."""
+    if q.shape[1] % chunk:
+        raise ValueError(f"sequence length {q.shape[1]} is no multiple of "
+                         f"the delta rule's chunk {chunk}")
+    if chunk % min(SUB, chunk):
+        raise ValueError(f"a chunk of {chunk} tokens is no multiple of the "
+                         f"sub-chunk {SUB}")
+    return _by_groups(kda_group, q, k, v, g, beta, chunk, cdt)
 
 
 def mixer(leaves, xn, cfg: dict, cdt=jnp.float32):
@@ -558,3 +697,74 @@ def gdn_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
     with jax.named_scope("gdn_block"):
         y = residual_block(x, g1, cfg, lambda xn: mixer(rest, xn, cfg, cdt))
     return y, {"gdn_tokens": jnp.asarray(bsz * t, jnp.int32)}
+
+
+def kda_mixer(leaves, xn, cfg: dict, cdt=jnp.float32):
+    """Kimi Delta Attention of its input ``xn (B, T, d)``: ``(B, T, d)``
+    float32; ``leaves``: :data:`KDA_LEAVES` without ``g1``.  Per head (``n``
+    the input)
+
+        q, k, v = silu(causal depthwise conv(n Wq | n Wk | n Wv)), no bias
+        q <- q / |q|_2 * K^(-1/2),  k <- k / |k|_2
+        beta_t = 2 sigmoid(n Wb)
+        g_t = -exp(A_log) softplus((n Wfa) Wfb + dt_bias)   (K a head)
+        o = kda_rule(q, k, v, g, beta)
+        out = (RMSNorm(o; g_o over a head's V) * sigmoid((n Wga) Wgb + bg)) Wo
+
+    The projections, convs and decays are rematerialised by themselves in
+    the backward, as :func:`mixer`'s are."""
+    (wq, wk, wv, wb, wfa, wfb, wga, wgb, bg, conv_q, conv_k, conv_v, a_log,
+     dt_bias, g_o, wo) = leaves
+    bsz, t, _ = xn.shape
+    h, dk, dv = cfg["heads"], cfg["key_dim"], cfg["value_dim"]
+    xc = xn.astype(cdt)
+
+    def dot(a, w):
+        return jnp.dot(a, w.astype(cdt), preferred_element_type=jnp.float32)
+
+    def low_rank(x, wa, wb):
+        return dot(dot(x, wa).astype(cdt), wb)
+
+    @jax.checkpoint
+    def inputs(xc, wq, wk, wv, wb, wfa, wfb, conv_q, conv_k, conv_v, a_log,
+               dt_bias):
+        with jax.named_scope("short_conv"):
+            q, k, v = (jax.nn.silu(causal_conv(dot(xc, w), taps, 0.0))
+                       for w, taps in ((wq, conv_q), (wk, conv_k),
+                                       (wv, conv_v)))
+        q = l2_norm(q.reshape(bsz, t, h, dk)) * dk ** -0.5
+        k = l2_norm(k.reshape(bsz, t, h, dk))
+        with jax.named_scope("kda_gates"):
+            beta = 2.0 * jax.nn.sigmoid(dot(xc, wb))
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                (low_rank(xc, wfa, wfb) + dt_bias).reshape(bsz, t, h, dk))
+        return q, k, v.reshape(bsz, t, h, dv), g, beta
+    rule_inputs = inputs(xc, wq, wk, wv, wb, wfa, wfb, conv_q, conv_k,
+                         conv_v, a_log, dt_bias)
+    with jax.named_scope("kda_rule"):
+        o = kda_rule(*rule_inputs, cfg["chunk"], cdt)
+    with jax.named_scope("kda_gates"):
+        gate = jax.nn.sigmoid(low_rank(xc, wga, wgb) + bg)
+    gated = rms_norm(o, g_o, cfg["eps"]) * gate.reshape(bsz, t, h, dv)
+    return dot(gated.reshape(bsz, t, h * dv).astype(cdt), wo)
+
+
+def kda_block_fwd(leaves, x, cfg: dict, cdt=jnp.float32):
+    """``kda_block``: ``x + scale * KDA(RMSNorm(x; g1))`` (``cfg["norm"]``
+    "pre"; "post": the norm on the mixer's output); leaves as
+    :data:`KDA_LEAVES` (shapes: :func:`kda_leaf_shapes`); ``cfg``:
+    ``heads``, ``key_dim``, ``value_dim``, ``conv``, ``chunk``, ``eps``,
+    ``norm``, ``scale`` (None: 1).  -> ``(y, counters)``; ``kda_tokens``
+    counts the tokens this layer scanned."""
+    g1, *rest = leaves
+    bsz, t, _ = x.shape
+    h, dk, dv = cfg["heads"], cfg["key_dim"], cfg["value_dim"]
+    if len(rest) != len(KDA_LEAVES) - 1 or rest[0].shape[1] != h * dk \
+            or rest[2].shape[1] != h * dv:
+        raise ValueError(f"kda_block of {h} heads, keys of {dk} and values "
+                         f"of {dv}: {len(leaves)} leaves, Wq "
+                         f"{rest[0].shape}, Wv {rest[2].shape}")
+    with jax.named_scope("kda_block"):
+        y = residual_block(x, g1, cfg,
+                           lambda xn: kda_mixer(rest, xn, cfg, cdt))
+    return y, {"kda_tokens": jnp.asarray(bsz * t, jnp.int32)}

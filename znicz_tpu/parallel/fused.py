@@ -53,6 +53,7 @@ SEQUENCE_FWD = {"embed": attn_ops.embed_fwd,
                 "attn_block": attn_ops.attn_block_fwd,
                 "mamba_block": ssm_ops.mamba_block_fwd,
                 "gdn_block": gdn_ops.gdn_block_fwd,
+                "kda_block": gdn_ops.kda_block_fwd,
                 "parallel_block": hybrid_ops.parallel_block_fwd,
                 "moe_block": moe_ops.moe_block_fwd,
                 "mlp_block": moe_ops.mlp_block_fwd,
@@ -69,7 +70,7 @@ class LayerSpec:
     #                               stochastic_abs_pool | lrn | lrn_pool |
     #                               dropout | activation | embed |
     #                               attn_block | mamba_block | gdn_block |
-    #                               parallel_block | moe_block |
+    #                               kda_block | parallel_block | moe_block |
     #                               mlp_block | lm_head
     activation: str               # activations.BY_NAME key; last fc layer
     include_bias: bool            # of a softmax model keeps "linear"
@@ -523,22 +524,23 @@ def attn_routes(spec: ModelSpec) -> str:
 
 #: the mixer kinds of a decoder's hidden layers, by the name the start
 #: record counts them under
-MIXERS = {"gdn_block": "linear", "mamba_block": "ssm",
+MIXERS = {"gdn_block": "linear", "kda_block": "kda", "mamba_block": "ssm",
           "parallel_block": "parallel"}
 
 
 def mixer_routes(spec: ModelSpec) -> str:
-    """``linear:<n> full:<m>`` (and ``ssm:``, ``parallel:``, ``window:``
-    where the model has such layers): the hidden layers of ``spec`` by the
-    mixer each runs (``parallel``: attention and a Mamba-2 mixer side by
-    side), attention rows as :func:`attn_routes` names them."""
+    """``linear:<n> full:<m>`` (and ``kda:``, ``ssm:``, ``parallel:``,
+    ``window:`` where the model has such layers): the hidden layers of
+    ``spec`` by the mixer each runs (``kda``: the delta rule with a decay a
+    key channel; ``parallel``: attention and a Mamba-2 mixer side by side),
+    attention rows as :func:`attn_routes` names them."""
     counts: dict = {}
     for layer in spec.layers:
         name = (attn_ops.attn_route(layer.cfg) if layer.kind == "attn_block"
                 else MIXERS.get(layer.kind))
         if name is not None:
             counts[name] = counts.get(name, 0) + 1
-    order = ("linear", "ssm", "parallel", "window", "full")
+    order = ("linear", "kda", "ssm", "parallel", "window", "full")
     return " ".join(f"{k}:{counts[k]}" for k in order if k in counts)
 
 
@@ -546,10 +548,16 @@ def rule_routes(spec: ModelSpec) -> str:
     """``kernel:<n> chunked:<m>``: the linear-attention rows of ``spec``
     whose delta rule runs as the Pallas kernels, and as the ``jax.numpy``
     chunked form (``ops/gdn.py``; the same rule the step applies as it is
-    traced)."""
+    traced); ``kda:<form>`` where the model has rows of the per-channel
+    rule, all of which take that form."""
+    parts = []
     rows = sum(la.kind == "gdn_block" for la in spec.layers)
-    kernel = rows if gdn_ops.rule_route() == "kernel" else 0
-    return f"kernel:{kernel} chunked:{rows - kernel}"
+    if rows:
+        kernel = rows if gdn_ops.rule_route() == "kernel" else 0
+        parts.append(f"kernel:{kernel} chunked:{rows - kernel}")
+    if any(la.kind == "kda_block" for la in spec.layers):
+        parts.append("kda:chunked")          # the per-channel rule's one form
+    return " ".join(parts)
 
 
 def embed_routes(spec: ModelSpec, mesh=None) -> str:
@@ -635,6 +643,11 @@ COUNTERS = {
         "train_gdn_tokens",
         "token-layer pairs the linear-attention (gated delta rule) layers "
         "scanned in the last epoch (tokens a step times such layers)")),
+    "kda_tokens": ("sum", lambda: REGISTRY.gauge(
+        "train_kda_tokens",
+        "token-layer pairs the linear-attention layers with a decay a key "
+        "channel (Kimi delta attention) scanned in the last epoch (tokens a "
+        "step times such layers)")),
     "parallel_tokens": ("sum", lambda: REGISTRY.gauge(
         "train_parallel_tokens",
         "token-layer pairs the parallel hybrid layers ran through both "

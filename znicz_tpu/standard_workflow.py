@@ -457,7 +457,7 @@ class StandardWorkflowBase(AcceleratedWorkflow):
         if any(la.kind in fused.MIXERS for la in spec.layers):
             # the hidden layers by the mixer each runs
             where["mixer_routes"] = fused.mixer_routes(spec)
-        if any(la.kind == "gdn_block" for la in spec.layers):
+        if any(la.kind in ("gdn_block", "kda_block") for la in spec.layers):
             # the linear ones by the form their delta rule takes
             # (ops/gdn.py)
             where["rule_routes"] = fused.rule_routes(spec)

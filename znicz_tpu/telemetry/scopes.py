@@ -57,7 +57,8 @@ PHASES = ("fwd", "bwd", "upd", "input", "loss", "accum")
 INNER = ("parallel_block", "attn_mixer", "mamba_mixer",
          "rope", "scores", "qk_norm", "route", "experts", "combine",
          "shared_expert", "mamba_block", "ssd_scan", "mlp_block",
-         "gdn_block", "short_conv", "delta_rule")
+         "gdn_block", "short_conv", "delta_rule", "kda_block", "kda_gates",
+         "kda_rule", "attn_gate")
 KERNELS = ("gmm", "splash")
 #: the device planes' lines, as the TPU profiler names them
 OPS_LINE = "XLA Ops"
