@@ -7,9 +7,12 @@ sequence (``ops/moe.CHUNK_TOKENS``), forward and backward; and the rows'
 way there and back around them (``dispatch_rows``, ``combine``) over the
 first piece of those rows; and the gated delta rule's kernels at the
 shapes of one of ``olmo-hybrid-7b``'s linear layers (4,096 tokens, 30
-heads, keys of 96, values of 192, chunks of 64), forward and backward.
-The TPU's own Mosaic and XLA compilers run here, with no chip; nothing
-runs, so this says nothing of results or times.
+heads, keys of 96, values of 192, chunks of 64), forward and backward;
+and Kimi delta attention's per-channel rule's kernels at the shapes of
+one of ``solar-open2-250b``'s KDA layers (4,096 tokens, 16 heads, keys and
+values of 128, chunks of 64), forward and backward.  The TPU's own
+Mosaic and XLA compilers run here, with no chip; nothing runs, so this
+says nothing of results or times.
 
 The topology is described inside a fixture, never at import: only the
 worker that is given this file loads the TPU's library."""
@@ -230,6 +233,30 @@ def test_delta_rule_kernels_compile_for_a_v5e(one_chip, mosaic, backward):
                           if backward else rule, *_rule_shapes(one_chip))
     assert ("pallas_delta_rule_bwd" in text) == backward
     assert "pallas_delta_rule_fwd" in text
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_kda_rule_kernels_compile_for_a_v5e(one_chip, mosaic, backward):
+    """Kimi delta attention's per-channel rule at the shapes of one of
+    ``solar-open2-250b``'s KDA layers (4,096 tokens, 16 heads, keys and
+    values of 128, chunks of 64, bfloat16 operands): the kernels, and no
+    loop of the chunked form left beside them."""
+    t, h, dk = 4096, 16, 128
+
+    def rule(*a):
+        return gdn.kda_rule(*a, 64, jnp.bfloat16)
+
+    def loss(*a):
+        return jnp.sum(rule(*a))
+    assert gdn.rule_route() == "kernel"
+    shapes = tuple(jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+                   for shape in ((1, t, h, dk),) * 4 + ((1, t, h),))
+    text = _compiled_text(jax.grad(loss, argnums=tuple(range(5)))
+                          if backward else rule, *shapes)
+    assert ("pallas_kda_rule_bwd" in text) == backward
+    assert "pallas_kda_rule_fwd" in text
+    assert not re.search(r"\bwhile\b", text)
 
 
 #: the four decoder cells' tables and tokens a step: rows, width, tokens

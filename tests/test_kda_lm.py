@@ -142,13 +142,13 @@ REF_KINDS = {"attn_block": "attention", "moe_block": "moe",
 
 
 # -- the chunked per-channel rule against the recurrence itself ---------------
-def _rule_case(deep: bool, t: int = 256):
-    """Two rows of three heads, keys of 8 and values of 16; per-channel
-    decays of ``exp(g)`` between 0.37 and 1 a token, and with ``deep`` the
-    tokens 64-127 at ``g`` in [-8, 0]: a chunk of 64 whose decays add up to
-    about -256 a channel (``exp(-c)`` would overflow float32 there);
-    ``beta`` on both sides of 1."""
-    b, h, dk, dv = 2, 3, 8, 16
+def _rule_case(deep: bool, t: int = 256, widths=(8, 16)):
+    """Two rows of three heads, keys of 8 and values of 16 (``widths``);
+    per-channel decays of ``exp(g)`` between 0.37 and 1 a token, and with
+    ``deep`` the tokens 64-127 at ``g`` in [-8, 0]: a chunk of 64 whose
+    decays add up to about -256 a channel (``exp(-c)`` would overflow
+    float32 there); ``beta`` on both sides of 1."""
+    (b, h), (dk, dv) = (2, 3), widths
     k = jax.random.split(jax.random.key(13), 6)
     g = -jnp.exp(jax.random.uniform(k[3], (b, t, h, dk), minval=np.log(1e-3),
                                     maxval=0.0))
@@ -224,6 +224,102 @@ def test_a_length_or_chunk_the_rule_cannot_take_is_refused():
         gdn.kda_rule(*args, 24)
     with pytest.raises(ValueError, match="no multiple of the delta rule's"):
         layer_units(config())[3].leaf_shapes((2, 48, 64))
+
+
+# -- the kernels (Pallas, interpreted here) against the chunked form ----------
+@pytest.fixture
+def kernels(monkeypatch):
+    """The rule's kernel tier: the Pallas kernels in interpret mode (set as
+    ``test_linear_lm.py``'s ``form`` sets it)."""
+    from znicz_tpu.ops import tuning
+    monkeypatch.setattr(tuning, "_INTERPRET", True)
+    assert gdn.rule_route() == "kernel"
+
+
+@pytest.mark.parametrize("chunk,widths,deep", [
+    (64, (8, 16), True), (32, (16, 8), False)],
+    ids=["deep-k8-v16", "shallow-k16-v8"])
+def test_the_kernels_are_the_chunked_form(kernels, chunk, widths, deep):
+    """Forward and every input's gradient of the kernels against the
+    ``jax.numpy`` chunked form, both float32 at ``highest``: the same algebra
+    summed in another order (1e-5 of the larger value).  With ``deep`` the
+    second chunk's decays add up below -200, and every value is finite."""
+    args = _rule_case(deep, t=128, widths=widths)
+    if deep:
+        assert float(jnp.min(jnp.sum(args[3][:, 64:128], axis=1))) < -200
+
+    @jax.jit
+    def both(*a):
+        with jax.default_matmul_precision("highest"):
+            def run(rule):
+                def fn(*x):
+                    return rule(*x, chunk)
+                return fn(*a), jax.grad(lambda *x: jnp.sum(jnp.sin(fn(*x))),
+                                        argnums=(0, 1, 2, 3, 4))(*a)
+            return run(gdn.kda_rule), run(gdn.kda_rule_chunked)
+    (got, got_g), (want, want_g) = both(*args)
+    for g_got, g_want in zip((got, *got_g), (want, *want_g)):
+        assert np.isfinite(np.asarray(g_got)).all()
+        np.testing.assert_allclose(
+            g_got, g_want, rtol=1e-5,
+            atol=1e-5 * float(jnp.max(jnp.abs(g_want))))
+
+
+def test_the_states_the_forward_keeps_are_the_chunked_forms(kernels):
+    """The state each chunk starts from and the inverse of its system, as
+    the forward kernel keeps them for the backward, against the state
+    :func:`gdn.kda_group` carries a chunk at a time (a chunk of decays
+    below -200 among them) and the inverse of its chunked form's system."""
+    q, k, v, g, beta = _rule_case(True, t=128)
+    chunk, (bsz, t, h, dk) = 32, q.shape
+    nc = t // chunk
+
+    def head_major(a):
+        return a.transpose(0, 2, 1, 3)
+
+    def chunk_of(a, n):     # (B, T, H, .) -> (1, B, H, L, .)
+        return head_major(a[:, n * chunk:(n + 1) * chunk])[None]
+    with jax.default_matmul_precision("highest"):
+        _, states, inverses = gdn._kda_fwd_call(
+            head_major(q), head_major(k), jnp.cumsum(head_major(g).reshape(
+                bsz, h, nc, chunk, dk), axis=-2).reshape(bsz, h, t, dk),
+            head_major(v * beta[..., None]),
+            beta.reshape(bsz, nc, chunk, h).transpose(0, 3, 1, 2),
+            jnp.float32, save=True)
+        state = jnp.zeros(states.shape[:2] + states.shape[3:])
+        for n in range(nc):
+            np.testing.assert_allclose(
+                states[:, :, n], state, rtol=1e-5,
+                atol=1e-5 * float(jnp.max(jnp.abs(state))) + 1e-30)
+            state, _ = gdn.kda_group(state, *(chunk_of(a, n) for a in (
+                q, k, v, g, beta[..., None])), jnp.float32)
+            qn, kn, gn, bn = (chunk_of(a, n)[0] for a in (
+                q, k, g, beta[..., None]))
+            system = jnp.tril(gdn.decayed_products(
+                kn * bn, kn, jnp.cumsum(gn, axis=-2), lambda a, b: jnp.einsum(
+                    "...mik,...mjk->...mij", a, b)), -1)
+            np.testing.assert_allclose(inverses[:, :, n],
+                                       gdn.unit_lower_inverse(system),
+                                       rtol=1e-5, atol=1e-5)
+    assert float(jnp.max(jnp.abs(states[:, :, -1]))) > 0.1
+
+
+def test_the_routes_name_the_kernels_the_step_traces(kernels):
+    """Under the kernel tier ``rule_routes`` reads ``kda:kernel`` and the
+    traced training step calls the two kernels once a KDA layer (a
+    ``custom_vjp``'s call holds its forward's jaxpr beside the primal's),
+    each kernel traced once (its call's jaxpr is shared), and no scalar
+    rule."""
+    cfg, spec, weights, x, y = setup()
+    assert fused.rule_routes(spec) == "kda:kernel"
+    text = str(jax.make_jaxpr(lambda p, a, b: fused.grad_minibatch(
+        spec, p, a, b))(weights, x[0], y[0]))
+    assert text.count("name=_kda_fwd_call") == 2 * 3
+    assert text.count("name=_kda_bwd_call") == 3
+    # the forward with and without the states it keeps, and the backward
+    assert text.count("name=pallas_kda_rule_fwd") == 2
+    assert text.count("name=pallas_kda_rule_bwd") == 1
+    assert "pallas_delta_rule" not in text
 
 
 # -- each kind against the reference -------------------------------------------

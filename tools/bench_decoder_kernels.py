@@ -10,13 +10,15 @@ there, three products, rows back) with its three products' time beside
 it, so that what the layer spends outside its kernels can be read
 without the step; and the gated delta rule at the shapes of one of
 ``olmo-hybrid-7b``'s linear layers (``ops/gdn.py``: the kernels against
-the ``jax.numpy`` chunked form); and the embedding's table gradient at
+the ``jax.numpy`` chunked form); and Kimi delta attention's per-channel
+rule the same way at the shapes of one of ``solar-open2-250b``'s KDA
+layers; and the embedding's table gradient at
 the four decoder cells' shapes (XLA's scatter-add, alone and into the
 update's decay term, against ``ops/attention.embed_grad``'s kernel).
 One JSON line a case; exits 3 off-TPU.
 
     chiprun -- python3 tools/bench_decoder_kernels.py [splash] [gmm] [layer]
-        [delta_rule] [embed]
+        [delta_rule] [kda_rule] [embed]
 """
 
 import json
@@ -49,7 +51,7 @@ def main(argv) -> int:
     ks = jax.random.split(jax.random.key(0), 8)
     cases = {"splash": splash, "gmm": grouped_products,
              "layer": expert_layer, "delta_rule": delta_rule,
-             "embed": embed}
+             "kda_rule": kda_rule, "embed": embed}
     for name in argv or list(cases):
         cases[name](ks)
     return 0
@@ -201,6 +203,19 @@ def delta_rule(keys) -> None:
     ops = 7.0 * t * h * dk * dv
     least = (max(ops / 197e12, 4.0 * t * h * (2 * dk + 2 * dv) / 819e9)
              + max(2 * ops / 197e12, 4.0 * t * h * (4 * dk + 3 * dv) / 819e9))
+    _forms_compared("delta_rule", (gdn.delta_rule_chunked,
+                                   gdn.delta_rule_kernel),
+                    args, do, chunk, least)
+
+
+def _forms_compared(name, forms, args, do, chunk, least) -> None:
+    """The rule ``name``'s chunked form and its kernels (``forms``) at
+    ``args``, ``chunk`` and bfloat16 operands: forward, and forward +
+    backward (the gradient of all five inputs against ``do``), each form's
+    share of the ``least`` time forward + backward, and the kernels' largest
+    gaps to the chunked form's output and gradients."""
+    import jax
+    import jax.numpy as jnp
 
     def measured(form):
         def fwd(*a):
@@ -209,17 +224,43 @@ def delta_rule(keys) -> None:
         fwd, grad = jax.jit(fwd), jax.jit(grad)
         return (fwd(*args), grad(*args), 1e3 * timed(fwd, *args, reps=10),
                 1e3 * timed(grad, *args, reps=10))
-    want_o, want_g, t_f, t_b = measured(gdn.delta_rule_chunked)
-    print(json.dumps({"kernel": "delta_rule_chunked", "fwd_ms": t_f,
+    want_o, want_g, t_f, t_b = measured(forms[0])
+    print(json.dumps({"kernel": f"{name}_chunked", "fwd_ms": t_f,
                       "fwd_bwd_ms": t_b,
                       "roofline_share": least / (t_b * 1e-3)}), flush=True)
-    o, g, t_f, t_b = measured(gdn.delta_rule_kernel)
+    o, g, t_f, t_b = measured(forms[1])
     gaps = [float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
             for a, b in zip((o, *g), (want_o, *want_g))]
-    print(json.dumps({"kernel": "delta_rule_kernel", "fwd_ms": t_f,
+    print(json.dumps({"kernel": f"{name}_kernel", "fwd_ms": t_f,
                       "fwd_bwd_ms": t_b,
                       "roofline_share": least / (t_b * 1e-3),
                       "gap_o_dq_dk_dv_dg_dbeta": gaps}), flush=True)
+
+
+def kda_rule(keys) -> None:
+    """One row of ``solar-open2-250b``'s KDA layer (4,096 tokens, 16 heads,
+    keys and values of 128, chunks of 64, bfloat16 operands), as
+    :func:`delta_rule` times the scalar rule; the share of the roofline is
+    ``benchmark/metrics/solar_kda_rule_roofline.py``'s count."""
+    import jax
+    import jax.numpy as jnp
+    from znicz_tpu.ops import gdn
+    t, h, dk, dv, chunk = 4096, 16, 128, 128, 64
+    ks = jax.random.split(keys[5], 6)
+    # decays a channel of -exp(A_log) softplus(.) at the Mamba-2 starting
+    # point: between about -1e-3 and -2 a token
+    args = (gdn.l2_norm(jax.random.normal(ks[0], (1, t, h, dk))) * dk ** -0.5,
+            gdn.l2_norm(jax.random.normal(ks[1], (1, t, h, dk))),
+            jax.random.normal(ks[2], (1, t, h, dv)),
+            -jnp.exp(jax.random.uniform(ks[3], (1, t, h, dk), minval=-7.0,
+                                        maxval=0.7)),
+            jax.random.uniform(ks[4], (1, t, h), minval=0.05, maxval=1.95))
+    do = jax.random.normal(ks[5], (1, t, h, dv))
+    ops = 7.0 * t * h * dk * dv
+    least = (max(ops / 197e12, 4.0 * t * h * (3 * dk + 2 * dv) / 819e9)
+             + max(2 * ops / 197e12, 4.0 * t * h * (6 * dk + 3 * dv) / 819e9))
+    _forms_compared("kda_rule", (gdn.kda_rule_chunked, gdn.kda_rule_kernel),
+                    args, do, chunk, least)
 
 
 #: the decoder cells' tables and tokens a step: rows, width, tokens

@@ -80,8 +80,26 @@ and ``exp(-c)`` overflows once a chunk's decays add up below -88, so no
 chunk the factor is split at the start ``b`` of the later one, ``exp(c_i -
 c_b) exp(c_b - c_j)``, both at most 1, and the sums are products of
 matrices; inside a sub-chunk the ``(SUB, SUB, K)`` decays are formed whole.
-It runs as the ``jax.numpy`` chunked form on every tier (``rule_routes``
-``kda:chunked``), each group of chunks rematerialised by itself.
+It takes one of two forms by the same rule as the scalar rule
+(``rule_routes`` ``kda:kernel`` | ``kda:chunked``):
+
+* TPU (and the Pallas interpreter): two Pallas kernels under a
+  ``custom_vjp`` (:func:`kda_rule_kernel`), a grid step a (row, head,
+  chunk), the state (backward: its gradient) in VMEM across the chunks.
+  HBM holds ``q``, ``k``, ``beta v`` (head-major), the running sums ``c``
+  a channel ``(B, H, T, K)``, beta and ``o`` -- and, under the
+  ``custom_vjp``'s forward alone, the state each chunk starts from and
+  the inverse of its system (float32, ``(B, H, chunks, V, K)`` and ``(B,
+  H, chunks, L, L)``).  A chunk's sub-chunk algebra is
+  :func:`decayed_products`' (the cross sums products of matrices, the
+  decays inside a sub-chunk formed whole, a column at a time); the
+  backward sets the chunk up again from its inputs, the saved state and
+  the saved inverse and writes ``dq``, ``dk``, ``d(beta v)``, ``dbeta`` and
+  ``dc`` a channel.  Its products are the forward's transposes at the
+  forward's precision.
+* elsewhere: the ``jax.numpy`` chunked form (:func:`kda_rule_chunked`),
+  :func:`kda_group` on ``GROUP`` chunks at a time, each group
+  rematerialised by itself: the reference the tests hold the kernels to.
 """
 
 from __future__ import annotations
@@ -564,8 +582,8 @@ _rule_kernel.defvjp(_rule_kernel_fwd, _rule_kernel_bwd)
 
 
 def rule_route() -> str:
-    """``kernel`` where the delta rule runs as the Pallas kernels, else
-    ``chunked`` (the ``jax.numpy`` form)."""
+    """``kernel`` where the delta rule (scalar and per-channel) runs as the
+    Pallas kernels, else ``chunked`` (the ``jax.numpy`` form)."""
     return "kernel" if tuning.use_pallas() else "chunked"
 
 
@@ -628,20 +646,330 @@ def _by_groups(group_fn, q, k, v, g, beta, chunk: int, cdt):
     return o.transpose(2, 0, 1, 4, 3, 5).reshape(bsz, t, h, v.shape[-1])
 
 
+# -- the per-channel rule: the kernel tier ----------------------------------
+def _kda_row(ref, i):
+    """Token ``i`` of a chunk's ``(1, 1, L, K)`` block, a ``(1, K)`` row."""
+    return ref[0, 0, pl.ds(i, 1), :]
+
+
+def _kda_parts(c_ref, c, lo, sub):
+    """The two factors of :func:`decayed_products`' cross sums for the
+    sub-chunk of rows ``lo`` to ``lo + sub``: ``exp(c_i - c_b)`` of its rows
+    and ``exp(c_b - c_j)`` of every row, ``b`` the running sum just before
+    the sub-chunk; None for the first sub-chunk, which has no tokens before
+    it.  The second is clamped at 1 where ``j`` lies at or after ``b``:
+    those columns are masked out of every product."""
+    if not lo:
+        return None
+    start = _kda_row(c_ref, lo - 1)
+    return (jnp.exp(c[lo:lo + sub] - start),
+            jnp.exp(jnp.minimum(start - c, 0.0)))
+
+
+def _kda_inner(c_ref, k_ref, cs, lo, j):
+    """Token ``lo + j``'s key against the rows ``cs`` of its sub-chunk:
+    ``(k_j exp(c_i - c_j), exp(c_i - c_j))``, ``(SUB, K)``, the decay
+    clamped at 1 for the rows before ``j`` (masked out of every product)."""
+    decay = jnp.exp(jnp.minimum(cs - _kda_row(c_ref, lo + j), 0.0))
+    return _kda_row(k_ref, lo + j) * decay, decay
+
+
+def _kda_products(lefts, cdts, k, c, c_ref, k_ref):
+    """:func:`decayed_products` of each of ``lefts (L, K)`` against ``k``
+    with the running sums ``c``, the cross sums of the sub-chunks products
+    of matrices with the operands in each of ``cdts`` (None: float32 at
+    ``highest``), the sums inside a sub-chunk float32 a column at a time.
+    -> one ``(L, L)`` a left; what stands above the diagonal is not
+    defined."""
+    n = k.shape[0]
+    sub = min(SUB, n)
+    col = jax.lax.broadcasted_iota(jnp.int32, (sub, n), 1)
+    rows = [[] for _ in lefts]
+    for lo in range(0, n, sub):
+        cs, parts = c[lo:lo + sub], _kda_parts(c_ref, c, lo, sub)
+        blocks = []
+        for left, cdt in zip(lefts, cdts):
+            if parts is None:
+                blocks.append(jnp.zeros((sub, n), jnp.float32))
+            else:
+                cross = _mm(left[lo:lo + sub] * parts[0], k * parts[1],
+                            tb=True, cdt=cdt)
+                blocks.append(jnp.where(col < lo, cross, 0.0))
+        for j in range(sub):
+            x, _ = _kda_inner(c_ref, k_ref, cs, lo, j)
+            blocks = [jnp.where(col == lo + j, jnp.sum(
+                left[lo:lo + sub] * x, axis=1, keepdims=True), block)
+                for left, block in zip(lefts, blocks)]
+        for out, block in zip(rows, blocks):
+            out.append(block)
+    return [jnp.concatenate(r, axis=0) for r in rows]
+
+
+def _kda_product_grads(grads, lefts, cdts, k, c, c_ref, k_ref):
+    """The transpose of :func:`_kda_products` at its precision: for the
+    gradients ``grads (L, L)`` of its products (zero above the diagonal
+    and wherever the product is not used) -> (the gradient of each left,
+    of ``k``).  That of the running sums is ``sum left * dleft - k * dk``:
+    the decay between two tokens is a function of their difference."""
+    n = k.shape[0]
+    sub = min(SUB, n)
+    col = jax.lax.broadcasted_iota(jnp.int32, (sub, n), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, k[:sub].shape, 0)
+    dlefts = [[] for _ in lefts]
+    dk_rows = []
+    dk = jnp.zeros_like(k)
+    for lo in range(0, n, sub):
+        cs, parts = c[lo:lo + sub], _kda_parts(c_ref, c, lo, sub)
+        pieces = [grad[lo:lo + sub] for grad in grads]
+        dl = [jnp.zeros_like(cs) for _ in lefts]
+        if parts is not None:
+            mine, before = parts
+            for i, (piece, left, cdt) in enumerate(zip(pieces, lefts, cdts)):
+                cross = jnp.where(col < lo, piece, 0.0)
+                dl[i] = _mm(cross, k * before, cdt=cdt) * mine
+                dk = dk + _mm(cross, left[lo:lo + sub] * mine, ta=True,
+                              cdt=cdt) * before
+        dk_sub = jnp.zeros_like(cs)
+        for j in range(sub):
+            x, decay = _kda_inner(c_ref, k_ref, cs, lo, j)
+            dk_j = jnp.zeros_like(cs[:1])
+            for i, (piece, left) in enumerate(zip(pieces, lefts)):
+                g = jnp.sum(jnp.where(col == lo + j, piece, 0.0), axis=1,
+                            keepdims=True)
+                dl[i] = dl[i] + g * x
+                dk_j = dk_j + jnp.sum(g * left[lo:lo + sub] * decay, axis=0,
+                                      keepdims=True)
+            dk_sub = jnp.where(row == j, dk_j, dk_sub)
+        for out, d in zip(dlefts, dl):
+            out.append(d)
+        dk_rows.append(dk_sub)
+    return ([jnp.concatenate(d, axis=0) for d in dlefts],
+            dk + jnp.concatenate(dk_rows, axis=0))
+
+
+def _kda_system(b_ref, q_ref, k_ref, c_ref, v_ref, n, cdt, t=None):
+    """What chunk ``n`` computes before its state (:func:`kda_group`'s
+    arrays): its blocks ``q``, ``k``, ``c`` (running sums) ``(L, K)``,
+    ``vb = beta v (L, V)`` and beta's row of the chunks -> a dict of float32
+    arrays; ``t``, the inverse of the chunk's system, where it is known."""
+    q, k, vb, c = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], c_ref[0, 0]
+    n_tok = q.shape[0]
+    ri = jax.lax.broadcasted_iota(jnp.int32, (n_tok, n_tok), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (n_tok, n_tok), 1)
+    eye, lower, strict = ri == ci, ci <= ri, ci < ri
+    b_col = _col(b_ref[0, 0, pl.ds(n, 1), :], eye)
+    kb = k * b_col
+    if t is None:
+        a, qk = _kda_products((kb, q), (None, cdt), k, c, c_ref, k_ref)
+        t = _inverse(jnp.where(strict, a, 0.0), ri, ci)
+    else:
+        qk, = _kda_products((q,), (cdt,), k, c, c_ref, k_ref)
+    e = jnp.exp(c)
+    last = _kda_row(c_ref, n_tok - 1)                    # (1, K)
+    f = jnp.exp(last - c)
+    return {"q": q, "k": k, "c": c, "eye": eye, "lower": lower,
+            "strict": strict, "b_col": b_col, "kb": kb, "t": t, "e": e,
+            "w": _mm(t, kb * e), "u": _mm(t, vb),
+            "qk": jnp.where(lower, qk, 0.0), "q_in": q * e, "f": f,
+            "k_out": k * f, "whole_row": jnp.exp(last)}
+
+
+def _kda_fwd_kernel(b_ref, q_ref, k_ref, c_ref, v_ref, *refs, cdt, save):
+    """Chunk ``n`` of one (row, head): the state in ``s_scr``, zeroed at the
+    row's first chunk; with ``save`` two more outputs take the state the
+    chunk starts from and the inverse of its system."""
+    n = pl.program_id(2)
+    o_ref, s_scr = refs[0], refs[-1]
+
+    @pl.when(n == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    sy = _kda_system(b_ref, q_ref, k_ref, c_ref, v_ref, n, cdt)
+    s = s_scr[...]
+    if save:
+        refs[1][0, 0, 0] = s
+        refs[2][0, 0, 0] = sy["t"]
+    new = _corrected(s, sy, cdt)
+    o_ref[0, 0] = (_mm(sy["q_in"], s, tb=True, cdt=cdt)
+                   + _mm(sy["qk"], new, cdt=cdt))
+    s_scr[...] = carried(s, sy["whole_row"]) + _mm(new, sy["k_out"],
+                                                   ta=True, cdt=cdt)
+
+
+def _kda_bwd_kernel(b_ref, q_ref, k_ref, c_ref, v_ref, st_ref, t_ref, do_ref,
+                    dq_ref, dk_ref, dc_ref, dv_ref, db_ref, ds_scr, *, cdt):
+    """Chunk ``n`` of one (row, head), the row's chunks walked from its
+    last: the chunk's arrays are made again from its inputs, the state it
+    started from and the inverse of its system, which the forward kept;
+    ``ds_scr`` carries the gradient of the state the chunk hands on."""
+    n = pl.num_programs(2) - 1 - pl.program_id(2)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    sy = _kda_system(b_ref, q_ref, k_ref, c_ref, v_ref, n, cdt,
+                     t_ref[0, 0, 0])
+    s, do, ds = st_ref[0, 0, 0], do_ref[0, 0], ds_scr[...]
+    q, k, c, w, u = sy["q"], sy["k"], sy["c"], sy["w"], sy["u"]
+    new = _corrected(s, sy, cdt)
+    # o = q_in s^T + qk new;  s' = s whole + new^T k_out;  new = u - w s^T
+    dnew = (_mm(sy["qk"], do, ta=True, cdt=cdt)
+            + _mm(sy["k_out"], ds, tb=True, cdt=cdt))
+    dqk = jnp.where(sy["lower"], _mm(do, new, tb=True, cdt=cdt), 0.0)
+    dk_out = _mm(new, ds, cdt=cdt)
+    dq_in = _mm(do, s, cdt=cdt)
+    d_whole = jnp.sum(ds * s, axis=0, keepdims=True)     # (1, K)
+    ds_scr[...] = (carried(ds, sy["whole_row"])
+                   + _mm(do, sy["q_in"], ta=True, cdt=cdt)
+                   - _mm(dnew, w, ta=True, cdt=cdt))
+    # [w | u] = t [kb exp(c) | beta v],  t = (I + a)^-1
+    drw = -_mm(sy["t"], _mm(dnew, s, cdt=cdt), ta=True)
+    dru = _mm(sy["t"], dnew, ta=True)
+    da = jnp.where(sy["strict"], -(_mm(drw, w, tb=True)
+                                   + _mm(dru, u, tb=True)), 0.0)
+    # a = strict(decayed(kb, k)),  qk = lower(decayed(q, k))
+    kb, e = sy["kb"], sy["e"]
+    (dkb_a, dq_qk), dk_dec = _kda_product_grads(
+        (da, dqk), (kb, q), (None, cdt), k, c, c_ref, k_ref)
+    dkb = dkb_a + drw * e
+    dq_ref[0, 0] = dq_qk + dq_in * e
+    dk_ref[0, 0] = dk_dec + dk_out * sy["f"] + dkb * sy["b_col"]
+    dv_ref[0, 0] = dru
+    # the running sums: the decays between tokens, exp(c), exp(c_L - c),
+    # exp(c_L)
+    z = dk_out * sy["k_out"]
+    d_last = jnp.sum(z, axis=0, keepdims=True) + d_whole * sy["whole_row"]
+    at_last = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0) == k.shape[0] - 1
+    dc_ref[0, 0] = (kb * dkb_a + q * dq_qk - k * dk_dec + drw * kb * e
+                    + dq_in * sy["q_in"] - z + jnp.where(at_last, d_last, 0.0))
+    db_ref[0, 0, pl.ds(n, 1), :] = _row(
+        jnp.sum(dkb * k, axis=1, keepdims=True), sy["eye"])
+
+
+# Both calls are jitted so that their kernel bodies, unrolled over the
+# tokens of a sub-chunk, are traced and lowered once a process and shape:
+# traced at every call (three layers, the custom_vjp's forward and backward,
+# every program an epoch builds) they added tens of seconds to a start.
+@functools.partial(jax.jit, static_argnames=("cdt", "save"))
+def _kda_fwd_call(q, k, c, vb, beta, cdt, save):
+    """The forward kernel on head-major ``q``, ``k``, the running sums ``c
+    (B, H, T, K)``, ``vb = beta v (B, H, T, V)`` and ``beta (B, H, nc, L)``:
+    ``o (B, H, T, V)`` (and, ``save``, the state each chunk starts from,
+    ``(B, H, nc, V, K)``, and the inverse of its system, ``(B, H, nc, L,
+    L)``)."""
+    b, h, t, dk = q.shape
+    dv = vb.shape[-1]
+    nc, chunk = beta.shape[2:]
+    whole, (qs, vs) = _specs(nc, chunk, (dk, dv))
+    out_specs = [vs]
+    out_shape = [jax.ShapeDtypeStruct((b, h, t, dv), jnp.float32)]
+    if save:
+        out_specs.append(_states_spec(nc, dv, dk))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, nc, dv, dk),
+                                              jnp.float32))
+        out_specs.append(_states_spec(nc, chunk, chunk))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, nc, chunk, chunk),
+                                              jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, cdt=cdt, save=save),
+        grid=(b, h, nc),
+        in_specs=[whole, qs, qs, qs, vs],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+        name="pallas_kda_rule_fwd",
+        interpret=tuning.interpret_mode(),
+    )(beta, q, k, c, vb)
+    return out if save else out[0]
+
+
+@functools.partial(jax.jit, static_argnames=("cdt",))
+def _kda_bwd_call(q, k, c, vb, beta, states, inverses, do, cdt):
+    """The backward kernel: ``(dq, dk, dc, dvb, dbeta)``, shaped as ``q``,
+    ``k``, ``c``, ``vb``, ``beta``."""
+    b, h, t, dk = q.shape
+    dv = vb.shape[-1]
+    nc, chunk = beta.shape[2:]
+    whole, (qs, vs) = _specs(nc, chunk, (dk, dv), reverse=True)
+    return pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, cdt=cdt),
+        grid=(b, h, nc),
+        in_specs=[whole, qs, qs, qs, vs,
+                  _states_spec(nc, dv, dk, reverse=True),
+                  _states_spec(nc, chunk, chunk, reverse=True), vs],
+        out_specs=[qs, qs, qs, vs, whole],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, jnp.float32)
+                   for a in (q, k, c, vb, beta)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+        name="pallas_kda_rule_bwd",
+        interpret=tuning.interpret_mode(),
+    )(beta, q, k, c, vb, states, inverses, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda_kernel(q, k, c, vb, beta, cdt):
+    return tuning.batch_sharded(
+        lambda *a: _kda_fwd_call(*a, cdt=cdt, save=False), q, k, c, vb, beta)
+
+
+def _kda_kernel_fwd(q, k, c, vb, beta, cdt):
+    o, states, inverses = tuning.batch_sharded(
+        lambda *a: tuple(_kda_fwd_call(*a, cdt=cdt, save=True)),
+        q, k, c, vb, beta)
+    return o, (q, k, c, vb, beta, states, inverses)
+
+
+def _kda_kernel_bwd(cdt, res, do):
+    return tuning.batch_sharded(
+        lambda *a: tuple(_kda_bwd_call(*a, cdt=cdt)), *res, do)
+
+
+_kda_kernel.defvjp(_kda_kernel_fwd, _kda_kernel_bwd)
+
+
+def kda_rule_kernel(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
+    """:func:`kda_rule` through the kernels: the same arguments and result.
+    ``q``, ``k`` and ``v`` go head-major, ``v`` as ``beta v``, ``g`` as its
+    running sums inside each chunk (JAX hands their gradients back to
+    ``v``, ``beta`` and ``g``)."""
+    bsz, t, h, dk = q.shape
+    nc = t // chunk
+
+    def head_major(a):      # (B, T, H, .) -> (B, H, T, .)
+        return a.transpose(0, 2, 1, 3)
+    c = jnp.cumsum(head_major(g).reshape(bsz, h, nc, chunk, dk),
+                   axis=-2).reshape(bsz, h, t, dk)
+    o = _kda_kernel(head_major(q), head_major(k), c,
+                    head_major(v * beta[..., None]),
+                    beta.reshape(bsz, nc, chunk, h).transpose(0, 3, 1, 2),
+                    cdt)
+    return head_major(o)
+
+
+def kda_rule_chunked(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
+    """:func:`kda_rule` in ``jax.numpy``: :func:`kda_group` on ``GROUP``
+    chunks at a time, each group rematerialised by itself."""
+    return _by_groups(kda_group, q, k, v, g, beta, chunk, cdt)
+
+
 def kda_rule(q, k, v, g, beta, chunk: int, cdt=jnp.float32):
     """``o_t = S_t q_t`` of ``S_t = S_{t-1} Diag(exp(g_t)) + beta_t (v_t -
     S_{t-1} Diag(exp(g_t)) k_t) k_t^T`` in chunks of ``chunk`` tokens: ``q``,
     ``k``, ``g (B, T, H, K)`` (``g`` not positive: a decay a key channel),
-    ``v (B, T, H, V)``, ``beta (B, T, H)``; float32 in and out.  The
-    ``jax.numpy`` chunked form on every tier (:func:`kda_group`, ``GROUP``
-    chunks at a time)."""
+    ``v (B, T, H, V)``, ``beta (B, T, H)``; float32 in and out.  The kernels
+    where Pallas runs (:func:`kda_rule_kernel`), else the ``jax.numpy``
+    chunked form (:func:`kda_rule_chunked`)."""
     if q.shape[1] % chunk:
         raise ValueError(f"sequence length {q.shape[1]} is no multiple of "
                          f"the delta rule's chunk {chunk}")
     if chunk % min(SUB, chunk):
         raise ValueError(f"a chunk of {chunk} tokens is no multiple of the "
                          f"sub-chunk {SUB}")
-    return _by_groups(kda_group, q, k, v, g, beta, chunk, cdt)
+    form = kda_rule_kernel if tuning.use_pallas() else kda_rule_chunked
+    return form(q, k, v, g, beta, chunk, cdt)
 
 
 def mixer(leaves, xn, cfg: dict, cdt=jnp.float32):

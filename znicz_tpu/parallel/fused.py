@@ -548,15 +548,16 @@ def rule_routes(spec: ModelSpec) -> str:
     """``kernel:<n> chunked:<m>``: the linear-attention rows of ``spec``
     whose delta rule runs as the Pallas kernels, and as the ``jax.numpy``
     chunked form (``ops/gdn.py``; the same rule the step applies as it is
-    traced); ``kda:<form>`` where the model has rows of the per-channel
-    rule, all of which take that form."""
+    traced); ``kda:<form>`` (``kernel`` | ``chunked``) where the model has
+    rows of the per-channel rule, all of which take that form by the same
+    rule."""
     parts = []
     rows = sum(la.kind == "gdn_block" for la in spec.layers)
     if rows:
         kernel = rows if gdn_ops.rule_route() == "kernel" else 0
         parts.append(f"kernel:{kernel} chunked:{rows - kernel}")
     if any(la.kind == "kda_block" for la in spec.layers):
-        parts.append("kda:chunked")          # the per-channel rule's one form
+        parts.append(f"kda:{gdn_ops.rule_route()}")
     return " ".join(parts)
 
 
