@@ -6,10 +6,12 @@ held against the benchmark's plain reference
 (``benchmark/lib/decoder_reference.py``, which imports nothing of the
 program) with seeded weights."""
 
+import contextlib
 import copy
 import json
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +24,7 @@ sys.path.insert(0, ROOT)
 from benchmark.lib import decoder_model as model          # noqa: E402
 from benchmark.lib import decoder_reference as reference  # noqa: E402
 from znicz_tpu.nn import decoder as units                 # noqa: E402
-from znicz_tpu.ops import attention, moe, tuning          # noqa: E402
+from znicz_tpu.ops import attention, moe, segment, tuning  # noqa: E402
 from znicz_tpu.parallel import fused                      # noqa: E402
 
 TRAFFIC = {"seq_len": 32, "minibatch": 2, "n_train": 12, "n_valid": 4,
@@ -187,17 +189,28 @@ def _dense_expert_sum(xn, weights, experts, wg, wu, wd, first):
     return out
 
 
-@pytest.mark.parametrize("expected", [1.0, 0.25],
-                         ids=["one_piece", "two_pieces"])
+@pytest.mark.parametrize("expected,d", [(1.0, 64), (0.25, 64), (0.125, 128)],
+                         ids=["one_piece", "two_pieces", "kernel"])
 @pytest.mark.parametrize("case", ["balanced", "none_held",
                                   "all_on_one_held_expert"])
-def test_the_way_there_and_back_has_the_dense_gradients(case, expected):
+def test_the_way_there_and_back_has_the_dense_gradients(monkeypatch, case,
+                                                        expected, d):
     """``dispatch_rows`` and ``combine`` with their hand-written backwards
     against ``jax.grad`` of the dense sum: the output and the gradients of
     the input, the ROUTING WEIGHTS and the three expert weights.  With
     every first choice on one held expert the 64 held pairs pass the first
-    piece of 48, so the later piece runs."""
-    n, d, f = 64, 64, 32
+    piece (48 rows, 24 in the kernel case), so the later piece runs.  In
+    the kernel case (``d`` 128, the Pallas interpreter, a first piece of
+    24 of the 128 pairs) the first piece's way back is ``pair_sum``'s
+    kernel (``combine_route``) and the later piece's the gather; its output
+    and gradients are then also the gather's at the same width to the bit
+    (the same float32 terms, two a token: no order of addition to differ
+    in)."""
+    monkeypatch.setattr(tuning, "_INTERPRET", d == 128)
+    first = moe.piece_rows(128, expected)[0]
+    assert moe.combine_route(d, first / 128) == (
+        "kernel" if d == 128 else "gather")
+    n, f = 64, 32
     ks = jax.random.split(jax.random.key(19), 5)
     xn = jax.random.normal(ks[0], (n, d))
     wg, wu = (jax.random.normal(k, (2, d, f)) * 0.1 for k in ks[1:3])
@@ -214,10 +227,24 @@ def test_the_way_there_and_back_has_the_dense_gradients(case, expected):
     args = (xn, weights, wg, wu, wd)
     np.testing.assert_allclose(ours(*args), dense(*args), rtol=1e-4,
                                atol=1e-6)
-    got = jax.grad(lambda *a: jnp.sum(ours(*a) * cot), range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), range(5))(*args)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * cot), range(5))(*args)
+    got, want = grads(ours), grads(dense)
+    if d == 128:
+        out = ours(*args)
+        monkeypatch.setattr(tuning, "_INTERPRET", False)
+        assert moe.combine_route(d, first / 128) == "gather"
+        for a, b in zip((out,) + got, (ours(*args),) + grads(ours)):
+            np.testing.assert_array_equal(a, b)
     for name, a, b in zip(("xn", "weights", "wg", "wu", "wd"), got, want):
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6, err_msg=name)
+        # at 128 columns the float32 rounding of the grouped products'
+        # backward, by either way back, reads up to 1.2 times the 64-wide
+        # tolerance at an element that cancels (against a float64 dense
+        # sum too): the absolute term scales with the gradient's size there
+        scale = 1.0 if d == 64 else float(np.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6 * scale,
+                                   err_msg=name)
     if case == "none_held":
         assert not any(np.asarray(a).any() for a in got)
     else:
@@ -527,6 +554,144 @@ def test_rows_no_kernel_wrote_reach_nothing(interpreter, monkeypatch,
     for name, a, b in zip(("xn", "weights", "wg", "wu", "wd"), got, want):
         assert np.isfinite(np.asarray(a)).all(), name
         np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-3, err_msg=name)
+
+
+def _piece_of(experts, first: int, e_held: int, expected: float,
+              later: bool):
+    """``(taken, row, live)`` of the first piece of the sorted pairs, or of
+    the later one, as ``moe.held_expert_sum`` takes them."""
+    n, top_k = experts.shape
+    local = experts - first
+    key = jnp.where((local >= 0) & (local < e_held), local,
+                    e_held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32).reshape(n, top_k)
+    counts = jnp.sum(key[:, None] == jnp.arange(e_held), axis=0)
+    first_rows, rest_rows = moe.piece_rows(n * top_k, expected)
+    lo, rows = (first_rows, rest_rows) if later else (0, first_rows)
+    live = jnp.clip(jnp.sum(counts) - lo, 0, rows).astype(jnp.int32)
+    return order[lo:lo + rows], place - lo, live
+
+
+def _pair_routing(case: str, n: int = 128, top_k: int = 4):
+    """``(experts, later)`` over 8 experts of which 0-3 are held; ``later``:
+    the case's live rows are those of the later piece."""
+    ks = jax.random.split(jax.random.key(41), 2)
+    absent = jnp.tile(jnp.arange(4, 8), (n, 1))
+    if case == "none_held":
+        return absent, False
+    if case == "every_pair_on_one_token":
+        return absent.at[37].set(jnp.arange(4)), False
+    if case == "later_piece":             # 3 of every 4 pairs held
+        return jnp.tile(jnp.asarray([0, 1, 2, 6]), (n, 1)), True
+    _, experts = jax.lax.top_k(jax.random.normal(ks[0], (n, 8)), top_k)
+    if case == "a_token_without_a_live_pair":
+        experts = experts.at[5].set(jnp.arange(4, 8))
+    return experts, False
+
+
+PAIR_CASES = ["balanced", "none_held", "every_pair_on_one_token",
+              "a_token_without_a_live_pair", "later_piece"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["combine", "dispatch_gradient"])
+@pytest.mark.parametrize("case", PAIR_CASES)
+def test_the_kernel_way_back_is_the_gather(interpreter, monkeypatch, case,
+                                           dtype):
+    """``pair_sum`` (the sorted segment sum over the live rows, the kernel
+    in interpret mode, blocks of 16 tokens and chunks of 8 rows) against
+    ``_sum_pair_rows`` (the gather over every pair): ``combine``'s forward
+    (float32 rows times their pairs' weights) and ``dispatch_rows``'
+    gradient (bfloat16 rows, no weights), with NaN in every row from
+    ``live`` on."""
+    monkeypatch.setattr(segment, "VMEM", 2 * 4 * 128 * 16)
+    monkeypatch.setattr(segment, "CHUNK", 8)
+    n, top_k, d = 128, 4, 128
+    assert segment.block_rows(n, d) == 16
+    experts, later = _pair_routing(case, n, top_k)
+    taken, row, live = _piece_of(experts, 0, 4, 0.25, later)
+    ks = jax.random.split(jax.random.key(43), 2)
+    src = jax.random.normal(ks[0], (taken.shape[0], d)).astype(dtype)
+    src = jnp.where((jnp.arange(src.shape[0]) < live)[:, None], src, jnp.nan)
+    weights = (jax.random.uniform(ks[1], (n, top_k), minval=0.1, maxval=0.4)
+               if dtype == jnp.float32 else None)
+    got = jax.jit(moe.pair_sum, static_argnums=(3, 4))(
+        src, taken, live, n, top_k, weights)
+    want = moe._sum_pair_rows(src, row, live, weights)
+    assert got.dtype == dtype and got.shape == (n, d)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=1e-6,
+                               atol=1e-6)
+    held_tokens = np.asarray(jnp.any(moe._live(row, live), axis=1))
+    assert not np.asarray(got, np.float32)[~held_tokens].any()
+    if case == "none_held":
+        assert int(live) == 0
+    if case == "every_pair_on_one_token":
+        assert int(live) == 4 and held_tokens.tolist() == [
+            t == 37 for t in range(n)]
+    if case == "a_token_without_a_live_pair":
+        assert not held_tokens[5] and held_tokens.sum() > n // 2
+    if case == "later_piece":
+        assert int(live) == 128 and moe.piece_rows(n * top_k, 0.25) == (
+            256, 256)
+
+
+@pytest.mark.parametrize("route", ["kernel", "gather", "mesh", "narrow",
+                                   "wide_share"])
+def test_the_combine_routes_name_the_way_back_the_step_traces(monkeypatch,
+                                                             route):
+    """``combine_routes`` counts the expert rows by their way back, and the
+    traced expert sum holds that form: the kernel on the Pallas tier (here
+    the interpreter) at a width of whole 128-lane tiles where the first
+    piece holds at most a quarter of the pairs (1 expert held of 8, 2 a
+    token: 24 rows of 128), the gather on the plain CPU, under a kernel
+    mesh, at a width of 64 and where the piece holds more (2 held: 48
+    rows)."""
+    monkeypatch.setattr(tuning, "_INTERPRET", route != "gather")
+    d = 64 if route == "narrow" else 128
+    held = 2 if route == "wide_share" else 1
+    cfg = config((2, held))
+    cfg["hidden_size"] = d
+    spec = spec_of(cfg)
+    forwards = [types.SimpleNamespace(input=np.zeros((2, 32, d)))
+                for _ in spec.layers]
+    mesh = (jax.sharding.Mesh(np.array(jax.devices()[:2]).reshape(2, 1),
+                              ("data", "model")) if route == "mesh" else None)
+    kernel = 4 * int(route == "kernel")
+    assert fused.combine_routes(spec, forwards, mesh) \
+        == f"kernel:{kernel} gather:{4 - kernel}"
+    xn, wg, wu, wd, cot = _expert_sum_case(d=d)
+    wg, wu, wd = wg[:held], wu[:held], wd[:held]
+    weights, experts = _routed("balanced", 64)
+
+    def loss(xn, weights, wg, wu, wd):
+        return jnp.sum(moe.held_expert_sum(
+            xn, weights, experts, wg, wu, wd, 2, jnp.float32, held / 8)[0]
+            * cot)
+    with (tuning.kernel_mesh(mesh) if mesh is not None
+          else contextlib.nullcontext()):
+        text = str(jax.make_jaxpr(jax.grad(loss, range(5)))(
+            xn, weights, wg, wu, wd))
+    # the first piece's forward, the forward and the input's gradient in
+    # its backward; the later piece takes the gather
+    assert text.count("name=pallas_pair_sum") == (3 if kernel else 0)
+
+
+def test_the_start_record_names_the_way_back(sample, tmp_path):
+    """Through the sample's workflow on the plain CPU: the start line and
+    every timeline row carry ``combine_routes``, the gather in all four
+    expert rows (width 64)."""
+    from znicz_tpu.backends import Device
+    from znicz_tpu.models import decoder_lm
+    wf = decoder_lm.DecoderLMWorkflow()
+    wf.initialize(device=Device.create("xla"))
+    path = str(tmp_path / "timeline.jsonl")
+    wf.train(fused=True, max_epochs=1, timeline_jsonl=path)
+    rows = [json.loads(line) for line in open(path)]
+    assert rows and all(r["combine_routes"] == "kernel:0 gather:4"
+                        for r in rows)
 
 
 # -- units, workflow, launcher ----------------------------------------------------

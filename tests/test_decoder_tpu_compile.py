@@ -185,32 +185,61 @@ def _hlo_scope_bytes():
     return module
 
 
-def test_a_skipped_later_piece_costs_no_leaf_sized_pass(one_chip, mosaic):
-    """One expert layer's backward at ``granite-4.0-h-small``'s widths, 9
-    held experts of 4096 x 768 over 2,048 tokens at 10 a token (pieces of
-    3,840 and 16,640 rows): beside the conditional, and in its branch that
-    does nothing, no ``broadcast`` and no ``copy`` makes an array of an
-    expert leaf's shape (56.6 MB each in bfloat16)."""
+@pytest.fixture(scope="module")
+def granite_expert_grads(one_chip):
+    """The compiled text of one expert layer's forward (its output kept)
+    and backward at ``granite-4.0-h-small``'s widths, 9 held experts of 4096 x 768 over
+    2,048 tokens at 10 a token (pieces of 3,840 and 16,640 rows), as a TPU
+    process dispatches it."""
     assert moe.piece_rows(G_N * G_TOP_K, G_HELD / G_EXPERTS) == (3840, 16640)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(tuning, "on_tpu", lambda: True)
 
-    def s(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        def s(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def grads(xn, weights, experts, wg, wu, wd, d_out):
-        return jax.vjp(lambda *a: moe.held_expert_sum(
-            a[0], a[1], experts, *a[2:], 0, jnp.bfloat16,
-            G_HELD / G_EXPERTS)[0], xn, weights, wg, wu, wd)[1](d_out)
-    text = _compiled_text(
-        grads, s((G_N, G_D)), s((G_N, G_TOP_K)),
-        s((G_N, G_TOP_K), jnp.int32), s((G_HELD, G_D, G_F)),
-        s((G_HELD, G_D, G_F)), s((G_HELD, G_F, G_D)), s((G_N, G_D)))
+        def layer(xn, weights, experts, wg, wu, wd, d_out):
+            out, vjp = jax.vjp(lambda *a: moe.held_expert_sum(
+                a[0], a[1], experts, *a[2:], 0, jnp.bfloat16,
+                G_HELD / G_EXPERTS)[0], xn, weights, wg, wu, wd)
+            return out, vjp(d_out)
+        return _compiled_text(
+            layer, s((G_N, G_D)), s((G_N, G_TOP_K)),
+            s((G_N, G_TOP_K), jnp.int32), s((G_HELD, G_D, G_F)),
+            s((G_HELD, G_D, G_F)), s((G_HELD, G_F, G_D)), s((G_N, G_D)))
+
+
+def test_a_skipped_later_piece_costs_no_leaf_sized_pass(granite_expert_grads):
+    """One expert layer's backward at ``granite-4.0-h-small``'s widths:
+    beside the conditional, and in its branch that does nothing, no
+    ``broadcast`` and no ``copy`` makes an array of an expert leaf's shape
+    (56.6 MB each in bfloat16)."""
     made = _hlo_scope_bytes().made_of_shape(
-        text, [f"{G_HELD},{G_D},{G_F}", f"{G_HELD},{G_F},{G_D}"])
+        granite_expert_grads,
+        [f"{G_HELD},{G_D},{G_F}", f"{G_HELD},{G_F},{G_D}"])
     assert sum(n for (where, opcode, _), n in made.items()
                if where == "later" and opcode.endswith("tgmm")) == 3, made
     passes = {key: n for key, n in made.items() if key[0] in ("step", "idle")
               and key[1] in ("broadcast", "copy")}
     assert not passes, passes
+
+
+def test_the_way_back_is_the_segment_sum_kernel(mosaic, granite_expert_grads):
+    """The same program takes the first piece's way back from sorted space
+    (3,840 rows of 20,480 pairs) as ``pallas_pair_sum``, ``combine``'s
+    forward and ``dispatch_rows``' gradient, and makes no array of every
+    token's ``top_k`` rows, ``(top_k, tokens, d)`` or ``(tokens * top_k,
+    d)``, but in the later piece's branch (16,640 rows: the gather): the
+    gather's way back writes ``f32[10,2048,4096]`` (335 MB) and reads it
+    again to sum it."""
+    assert moe.first_piece_route(G_N, G_D, G_TOP_K,
+                                 G_HELD / G_EXPERTS) == "kernel"
+    assert moe.combine_route(G_D, 16640 / 20480) == "gather"
+    assert "pallas_pair_sum" in granite_expert_grads
+    made = _hlo_scope_bytes().made_of_shape(
+        granite_expert_grads, [f"{G_TOP_K},{G_N},{G_D}",
+                               f"{G_N * G_TOP_K},{G_D}"])
+    assert not [key for key in made if key[0] != "later"], made
 
 
 def _rule_shapes(one_chip):

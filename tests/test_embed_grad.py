@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from benchmark.lib import decoder_model
-from znicz_tpu.ops import attention, tuning
+from znicz_tpu.ops import attention, segment, tuning
 from znicz_tpu.parallel import fused
 
 WIDTH = 128
@@ -27,10 +27,10 @@ def kernel(monkeypatch):
     chunks of 8 sorted rows at ``WIDTH``, so that a small table has many
     blocks and a block many chunks."""
     monkeypatch.setattr(tuning, "_INTERPRET", True)
-    monkeypatch.setattr(attention, "EMBED_VMEM", 2 * 4 * WIDTH * 16)
-    monkeypatch.setattr(attention, "EMBED_CHUNK", 8)
+    monkeypatch.setattr(segment, "VMEM", 2 * 4 * WIDTH * 16)
+    monkeypatch.setattr(segment, "CHUNK", 8)
     assert attention.embed_route(WIDTH) == "kernel"
-    assert attention.embed_block_rows(200, WIDTH) == 16
+    assert segment.block_rows(200, WIDTH) == 16
 
 
 def _markov(n_rows: int = 2, seq_len: int = 512, vocab: int = 200):

@@ -5,10 +5,12 @@ backward at three key-block sizes against the two-kernel backward, each
 a kernel this tool builds with its own block sizes) and the grouped
 expert product (the program's megablox ``gmm``/``tgmm`` and the shipped
 one at two other row tiles against ``jax.lax.ragged_dot``), forward and forward + backward; and the
-expert layer of one chunk alone (``ops/moe.held_expert_sum``: sort, rows
-there, three products, rows back) with its three products' time beside
-it, so that what the layer spends outside its kernels can be read
-without the step; and the gated delta rule at the shapes of one of
+expert layer of one chunk alone at the three sparse cells' shapes
+(``ops/moe.held_expert_sum``: sort, rows there, three products, rows back)
+with its three products' time beside it, so that what the layer spends
+outside its kernels can be read without the step; and the way back from
+sorted space alone at the same shapes (the gather over every pair against
+the segment sum over the live rows); and the gated delta rule at the shapes of one of
 ``olmo-hybrid-7b``'s linear layers (``ops/gdn.py``: the kernels against
 the ``jax.numpy`` chunked form); and Kimi delta attention's per-channel
 rule the same way at the shapes of one of ``solar-open2-250b``'s KDA
@@ -18,7 +20,7 @@ update's decay term, against ``ops/attention.embed_grad``'s kernel).
 One JSON line a case; exits 3 off-TPU.
 
     chiprun -- python3 tools/bench_decoder_kernels.py [splash] [gmm] [layer]
-        [delta_rule] [kda_rule] [embed]
+        [combine] [delta_rule] [kda_rule] [embed]
 """
 
 import json
@@ -50,8 +52,8 @@ def main(argv) -> int:
         return 3
     ks = jax.random.split(jax.random.key(0), 8)
     cases = {"splash": splash, "gmm": grouped_products,
-             "layer": expert_layer, "delta_rule": delta_rule,
-             "kda_rule": kda_rule, "embed": embed}
+             "layer": expert_layer, "combine": combine,
+             "delta_rule": delta_rule, "kda_rule": kda_rule, "embed": embed}
     for name in argv or list(cases):
         cases[name](ks)
     return 0
@@ -133,51 +135,116 @@ def grouped_products(ks) -> None:
             "fwd_bwd_share_of_peak": 3 * need / t_b / 197e12}), flush=True)
 
 
+#: the sparse cells' expert layers: tokens a chunk, experts a token, experts
+#: of the router, experts held, width, expert width
+EXPERT_SHAPES = {"granite-4.0-h-small": (2048, 10, 72, 9, 4096, 768),
+                 "solar-open2-250b": (4096, 8, 320, 8, 4096, 1280),
+                 "mellum2-12b-a2.5b": (4096, 8, 64, 16, 2304, 896)}
+
+
+def _routing(key, n, top_k, experts):
+    """A fresh router's choices: ``top_k`` distinct experts of ``experts``
+    a token, near even, and weights between 0.05 and 0.3."""
+    import jax
+    ks = jax.random.split(key, 2)
+    _, chosen = jax.lax.top_k(jax.random.normal(ks[0], (n, experts)), top_k)
+    return (jax.random.uniform(ks[1], (n, top_k), minval=0.05, maxval=0.3),
+            chosen)
+
+
 def expert_layer(keys) -> None:
-    """One chunk of ``CHUNK_TOKENS`` tokens through ``held_expert_sum`` at
-    a fresh router's routing (8 of 64 a token, 16 held), and the layer's
-    three grouped products alone at the same group sizes."""
+    """One chunk of each sparse cell's expert layer through
+    ``held_expert_sum`` at a fresh router's routing, and the layer's three
+    grouped products alone at the same group sizes."""
     import jax
     import jax.numpy as jnp
     from znicz_tpu.ops import moe
-    n = moe.CHUNK_TOKENS
-    ks = jax.random.split(keys[5], 6)
-    xn = jax.random.normal(ks[0], (n, D), jnp.float32)
-    _, experts = jax.lax.top_k(jax.random.normal(ks[1], (n, EXPERTS)), TOP_K)
-    weights = jax.random.uniform(ks[2], (n, TOP_K), minval=0.05, maxval=0.3)
-    wg, wu = (jax.random.normal(k, (HELD, D, F)) * 0.02 for k in ks[3:5])
-    wd = jax.random.normal(ks[5], (HELD, F, D)) * 0.02
+    for name, (n, top_k, all_experts, held, d, f) in EXPERT_SHAPES.items():
+        ks = jax.random.split(keys[5], 6)
+        xn = jax.random.normal(ks[0], (n, d), jnp.float32)
+        weights, experts = _routing(ks[1], n, top_k, all_experts)
+        wg, wu = (jax.random.normal(k, (held, d, f)) * 0.02 for k in ks[3:5])
+        wd = jax.random.normal(ks[5], (held, f, d)) * 0.02
 
-    def layer(xn, weights, wg, wu, wd):
-        return moe.held_expert_sum(xn, weights, experts, wg, wu, wd, 0,
-                                   jnp.bfloat16, HELD / EXPERTS)
-    out, counts, moved = jax.jit(layer)(xn, weights, wg, wu, wd)
-    rows = int(moved)
-    xs = jax.random.normal(ks[0], (rows, D), jnp.bfloat16)
+        def layer(xn, weights, wg, wu, wd, experts=experts, held=held,
+                  all_experts=all_experts):
+            return moe.held_expert_sum(xn, weights, experts, wg, wu, wd, 0,
+                                       jnp.bfloat16, held / all_experts)
+        out, counts, moved = jax.jit(layer)(xn, weights, wg, wu, wd)
+        rows = int(moved)
+        xs = jax.random.normal(ks[0], (rows, d), jnp.bfloat16)
 
-    def products(xs, wg, wu, wd):
-        hidden = (jax.nn.silu(moe.grouped_matmul(xs, wg, counts))
-                  * moe.grouped_matmul(xs, wu, counts)).astype(xs.dtype)
-        return moe.grouped_matmul(hidden, wd, counts)
-    half = tuple(w.astype(jnp.bfloat16) for w in (wg, wu, wd))
-    times = {}
-    for name, fn, args in (("layer", lambda *a: layer(*a)[0],
-                            (xn, weights, wg, wu, wd)),
-                           ("products", products, (xs,) + half)):
-        grad = jax.grad(lambda *a, fn=fn: jnp.sum(fn(*a) ** 2),
-                        tuple(range(len(args))))
-        times[name] = (1e3 * timed(jax.jit(fn), *args, reps=20),
-                       1e3 * timed(jax.jit(grad), *args, reps=20))
-    print(json.dumps({
-        "kernel": "expert_layer", "tokens": n, "pairs_held": int(counts.sum()),
-        "rows_moved": rows, "fwd_ms": times["layer"][0],
-        "fwd_bwd_ms": times["layer"][1],
-        "products_fwd_ms": times["products"][0],
-        "products_fwd_bwd_ms": times["products"][1],
-        "outside_products_fwd_ms": times["layer"][0] - times["products"][0],
-        "outside_products_fwd_bwd_ms": (times["layer"][1]
-                                        - times["products"][1])}),
-        flush=True)
+        def products(xs, wg, wu, wd, counts=counts):
+            hidden = (jax.nn.silu(moe.grouped_matmul(xs, wg, counts))
+                      * moe.grouped_matmul(xs, wu, counts)).astype(xs.dtype)
+            return moe.grouped_matmul(hidden, wd, counts)
+        half = tuple(w.astype(jnp.bfloat16) for w in (wg, wu, wd))
+        times = {}
+        for part, fn, args in (("layer", lambda *a, layer=layer: layer(*a)[0],
+                                (xn, weights, wg, wu, wd)),
+                               ("products", products, (xs,) + half)):
+            grad = jax.grad(lambda *a, fn=fn: jnp.sum(fn(*a) ** 2),
+                            tuple(range(len(args))))
+            times[part] = (1e3 * timed(jax.jit(fn), *args, reps=20),
+                           1e3 * timed(jax.jit(grad), *args, reps=20))
+        print(json.dumps({
+            "kernel": "expert_layer", "config": name, "tokens": n,
+            "pairs_held": int(counts.sum()), "rows_moved": rows,
+            "fwd_ms": times["layer"][0], "fwd_bwd_ms": times["layer"][1],
+            "products_fwd_ms": times["products"][0],
+            "products_fwd_bwd_ms": times["products"][1],
+            "outside_products_fwd_ms": (times["layer"][0]
+                                        - times["products"][0]),
+            "outside_products_fwd_bwd_ms": (times["layer"][1]
+                                            - times["products"][1])}),
+            flush=True)
+
+
+def combine(keys) -> None:
+    """The way back from sorted space alone, at each sparse cell's expert
+    layer (a chunk's first piece of sorted pairs at a fresh router's
+    routing): ``combine``'s forward (float32 rows times their weights) and
+    ``dispatch_rows``' gradient (bfloat16 rows), as the gather over every
+    token's ``top_k`` rows (``moe._sum_pair_rows``) and, where the program
+    has it, as the sorted segment sum over the live rows
+    (``moe.pair_sum``); the largest gap to the gather against the largest
+    value, and the share of the HBM bound: the live rows read once and the
+    ``(tokens, d)`` sum written once, over 819 GB/s."""
+    import jax
+    import jax.numpy as jnp
+    from znicz_tpu.ops import moe
+    for name, (n, top_k, all_experts, held, d, _) in EXPERT_SHAPES.items():
+        ks = jax.random.split(keys[4], 4)
+        weights, experts = _routing(ks[0], n, top_k, all_experts)
+        key = jnp.where(experts < held, experts, held).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        place = jnp.argsort(order).astype(jnp.int32).reshape(n, top_k)
+        rows = moe.piece_rows(n * top_k, held / all_experts)[0]
+        live = jnp.minimum(jnp.sum(key < held), rows).astype(jnp.int32)
+        taken = order[:rows]
+        for use, dtype, w in (("combine", jnp.float32, weights),
+                              ("dispatch_gradient", jnp.bfloat16, None)):
+            src = jax.random.normal(ks[1], (rows, d)).astype(dtype)
+            forms = {"gather": jax.jit(
+                lambda src, w, row=place: moe._sum_pair_rows(
+                    src, row, live, w))}
+            if hasattr(moe, "pair_sum"):
+                forms["kernel"] = jax.jit(
+                    lambda src, w: moe.pair_sum(src, taken, live, n, top_k,
+                                                w))
+            want = forms["gather"](src, w).astype(jnp.float32)
+            item = jnp.dtype(dtype).itemsize
+            least = (int(live) + n) * d * item / 819e9
+            for form, fn in forms.items():
+                got = fn(src, w).astype(jnp.float32)
+                t = timed(fn, src, w, reps=20)
+                print(json.dumps({
+                    "kernel": "way_back", "config": name, "use": use,
+                    "form": form, "tokens": n, "top_k": top_k, "rows": rows,
+                    "live": int(live), "width": d, "ms": 1e3 * t,
+                    "hbm_bound_share": least / t,
+                    "gap": float(jnp.max(jnp.abs(got - want))
+                                 / jnp.max(jnp.abs(want)))}), flush=True)
 
 
 def delta_rule(keys) -> None:
@@ -281,7 +348,7 @@ def embed(keys) -> None:
     import jax.numpy as jnp
     import numpy as np
     from benchmark.lib import decoder_model
-    from znicz_tpu.ops import attention
+    from znicz_tpu.ops import attention, segment
     for name, (rows, width, tokens) in EMBED_SHAPES.items():
         cfg = {"vocab_size": rows,
                "assumed": {"branching": 4, "chain_states": 1024}}
@@ -307,7 +374,7 @@ def embed(keys) -> None:
                 "kernel": "embed_grad", "config": name, "form": form,
                 "rows": rows, "width": width, "tokens": tokens,
                 "distinct_ids": int(jnp.unique(ids).size),
-                "block_rows": attention.embed_block_rows(rows, width),
+                "block_rows": segment.block_rows(rows, width),
                 "ms": 1e3 * t, "hbm_bound_share": least / t,
                 "gap": float(jnp.max(jnp.abs(got - want))
                              / jnp.max(jnp.abs(want)))}), flush=True)

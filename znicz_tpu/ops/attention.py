@@ -8,9 +8,10 @@ call, none for these three); their gradient is ``jax.vjp`` of the same
 function (a kernel brings its own ``custom_vjp``).
 
 The embedding's table gradient, on the Pallas tier, is :func:`embed_grad`:
-the ids sorted, and a kernel (``pallas_embed_grad``) that sums each block
-of table rows in VMEM and writes it once, where XLA's transpose of
-``jnp.take`` scatter-adds the rows into a whole table in HBM.
+the ids sorted, and ``segment.sorted_segment_sum``'s kernel (named
+``pallas_embed_grad`` here) that sums each block of table rows in VMEM and
+writes it once, where XLA's transpose of ``jnp.take`` scatter-adds the
+rows into a whole table in HBM.
 
 Attention never holds a T x T score matrix.  Two tiers, dispatched as
 the repo's other ops are:
@@ -35,10 +36,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from . import tuning
+from . import segment, tuning
 
 #: query rows a block of the plain tier and of the kernels
 BLOCK_Q = 512
@@ -221,13 +220,6 @@ def attention(q, k, v, window: int | None):
 
 
 # -- the embedding's gradient ------------------------------------------------
-#: bytes of VMEM the gradient's block of table rows takes, double-buffered
-#: (:func:`embed_block_rows`)
-EMBED_VMEM = 16 << 20
-#: sorted rows of the output gradient one DMA brings into VMEM
-EMBED_CHUNK = 64
-
-
 def embed_route(width: int) -> str:
     """``kernel`` where the gradient of a table of rows ``width`` wide is
     :func:`embed_grad`'s kernel: on the Pallas tier, for rows of whole
@@ -237,94 +229,26 @@ def embed_route(width: int) -> str:
             and tuning.traced_mesh() is None else "scatter")
 
 
-def embed_block_rows(rows: int, width: int) -> int:
-    """Table rows a grid step of :func:`embed_grad` sums: a power of two
-    whose float32 block fits ``EMBED_VMEM`` double-buffered, at least 8,
-    no more than the table's rows rounded up to 8."""
-    r = max(8, EMBED_VMEM // (2 * 4 * width))
-    return min(1 << (r.bit_length() - 1), tuning.round_up(rows, 8))
-
-
-def _embed_grad_kernel(starts_ref, ids_ref, dy_hbm, out_ref, buf, sem, *,
-                       chunk: int, n: int):
-    """Block ``b`` of table rows: zeroed in VMEM, then each of its tokens'
-    rows (sorted ``dy``, ``chunk`` at a time from HBM) added at its id, in
-    token order; Pallas writes the block once.  A chunk starts on a
-    multiple of 8 rows (HBM's tile) and inside the ``n`` rows; the first
-    one is on its way while the block is zeroed."""
-    b = pl.program_id(0)
-    r = out_ref.shape[0]
-    first, end = starts_ref[b], starts_ref[b + 1]
-
-    def fetch(t0):
-        base = jnp.minimum(t0 // 8 * 8, n - chunk)
-        return base, pltpu.make_async_copy(
-            dy_hbm.at[pl.ds(pl.multiple_of(base, 8), chunk)], buf, sem)
-
-    @pl.when(first < end)
-    def _():
-        fetch(first)[1].start()
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    def one_chunk(t0):
-        base, copy = fetch(t0)
-        copy.wait()
-        stop = jnp.minimum(end, base + chunk)
-
-        def add(t, c):
-            at = ids_ref[t] - b * r
-            out_ref[pl.ds(at, 1), :] += buf[pl.ds(t - base, 1), :]
-            return c
-        jax.lax.fori_loop(t0, stop, add, 0)
-
-        @pl.when(stop < end)
-        def _():
-            fetch(stop)[1].start()
-        return stop
-    jax.lax.while_loop(lambda t: t < end, one_chunk, first)
-
-
 def embed_grad(ids, dy, rows: int):
     """``zeros((rows, d)).at[ids].add(dy)`` in float32, the gradient of
     ``jnp.take(table, ids, axis=0)`` of a table of ``rows`` rows at
     ``dy``: ``ids (N,)`` int, ``dy (N, d)``.  An id below 0 counts from
     the end once and any other outside ``[0, rows)`` is dropped, as
     ``jnp.take``'s transpose does.  The ids are sorted stably with their
-    positions and ``dy``'s rows gathered in that order; a block's first
-    token is found by ``searchsorted`` at the block starts.  Each grid step
-    of the kernel adds its block's rows in float32 in token order (the
-    sums of a repeated id may round otherwise than XLA's scatter's) and
-    writes the block once; a block with no token is written as zeros."""
-    width = dy.shape[1]
-    r = embed_block_rows(rows, width)
-    blocks = pl.cdiv(rows, r)
-    dropped = blocks * r                # sorts after every block's tokens
+    positions, ``dy``'s rows gathered in that order, and
+    ``segment.sorted_segment_sum`` adds a block of table rows' tokens in
+    float32 in token order (the sums of a repeated id may round otherwise
+    than XLA's scatter's) and writes the block once."""
     ids = jnp.where(ids < 0, ids + rows, ids)
-    ids = jnp.where((ids >= 0) & (ids < rows), ids, dropped).astype(jnp.int32)
+    # an id outside the table is dropped: it sorts after every block's
+    ids = jnp.where((ids >= 0) & (ids < rows), ids, rows).astype(jnp.int32)
     # tokens padded to whole tiles of 8 with dropped ids
     n = tuning.round_up(max(ids.shape[0], 8), 8)
-    ids = jnp.pad(ids, (0, n - ids.shape[0]), constant_values=dropped)
+    ids = jnp.pad(ids, (0, n - ids.shape[0]), constant_values=rows)
     order = jnp.argsort(ids, stable=True)
-    ids = ids[order]
     dy = jnp.take(dy, order, axis=0, mode="clip").astype(jnp.float32)
-    starts = jnp.searchsorted(
-        ids, jnp.arange(blocks + 1, dtype=jnp.int32) * r).astype(jnp.int32)
-    chunk = min(EMBED_CHUNK, n)
-    return pl.pallas_call(
-        functools.partial(_embed_grad_kernel, chunk=chunk, n=n),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(blocks,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((r, width), lambda b, *_: (b, 0)),
-            scratch_shapes=[pltpu.VMEM((chunk, width), jnp.float32),
-                            pltpu.SemaphoreType.DMA(())]),
-        out_shape=jax.ShapeDtypeStruct((rows, width), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=2 * EMBED_VMEM),
-        name="pallas_embed_grad",
-        interpret=tuning.interpret_mode(),
-    )(starts, ids, dy)
+    return segment.sorted_segment_sum(ids[order], dy, rows,
+                                      "pallas_embed_grad")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))

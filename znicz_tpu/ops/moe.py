@@ -30,8 +30,13 @@ normalised input, gathered into expert order in the operand dtype
 (``dispatch_rows``), and the products' float32 rows, gathered back and
 summed ``top_k`` a token with their routing weights (``combine``); the
 backward of each is the other's movement, written by hand in sorted
-space.  A pair that is not live in a piece reads nothing, so the rows no
-kernel wrote are never zeroed and never looked at.  ``moe_rows_moved``
+space.  Where a piece holds a small share of its chunk's pairs
+(``combine_route``), the way back, ``combine``'s forward and
+``dispatch_rows``' gradient, is a sorted segment sum over the piece's live
+rows in a Pallas kernel (``pair_sum``, ``pallas_pair_sum``) and not a
+gather of every token's ``top_k`` rows.  A pair that is not live in a
+piece reads nothing, so the rows no kernel wrote are never zeroed and
+never looked at.  ``moe_rows_moved``
 counts the rows of the pieces that ran.  Two tiers, dispatched as the
 repo's other ops:
 
@@ -50,7 +55,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import tuning
+from . import segment, tuning
 from .attention import residual_block, rms_norm
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -157,16 +162,51 @@ def grouped_matmul(lhs, rhs, sizes):
     return impl(lhs, rhs, sizes)
 
 
-# -- rows there and back: gathers in both directions ----------------------------
+# -- rows there and back ---------------------------------------------------------
 # ``order`` sorts the ``tokens * top_k`` pairs by held expert (the pairs of
 # absent experts last); a piece takes ``rows`` of the sorted pairs
 # (``taken``), of which the first ``live`` are on held experts, and ``row
 # (tokens, top_k)`` gives every pair its row in the piece.  A pair whose row
 # is not in ``0 .. live`` (it is in another piece, or its expert is absent)
-# reads nothing: its index is clipped and its term masked, so the rows from
-# ``live`` on, which no kernel writes, are never looked at.
+# reads nothing, so the rows from ``live`` on, which no kernel writes, are
+# never looked at.  The way back to token space is :func:`pair_sum` where
+# :func:`combine_route` says ``kernel`` (a piece of a small share of the
+# pairs), else :func:`_sum_pair_rows`.
 def _live(row, live):
     return (row >= 0) & (row < live)
+
+
+#: the largest share of its chunk's pairs a piece may hold for its way back
+#: to take the kernel: the kernel's work follows the piece's rows (a gather
+#: of them in token order, then a load-add-store a live row), the gather's
+#: every pair's.  On a v5e, forward, 0.57 against 1.05 ms at a piece of
+#: 18.75% (``granite-4.0-h-small``'s), 0.23 against 1.68 at 3.9%
+#: (``solar-open2-250b``'s), but 1.13 against 0.96 at 37.5%
+#: (``mellum2-12b-a2.5b``'s, two live rows a token; PERF.md section 6, PR 44)
+KERNEL_SHARE = 0.25
+
+
+def combine_route(d: int, share: float) -> str:
+    """``kernel`` where the way back from sorted space of a piece that
+    holds ``share`` of its chunk's pairs, rows ``d`` wide, is
+    :func:`pair_sum`'s kernel: on the Pallas tier, for rows of whole
+    128-lane tiles, outside a mesh (Mosaic cannot partition a kernel), for
+    a share of at most ``KERNEL_SHARE``; else ``gather``,
+    :func:`_sum_pair_rows`."""
+    return ("kernel" if tuning.use_pallas() and d % 128 == 0
+            and tuning.traced_mesh() is None and share <= KERNEL_SHARE
+            else "gather")
+
+
+def first_piece_route(tokens: int, d: int, top_k: int,
+                      expected: float) -> str:
+    """:func:`combine_route` of the first piece of ``moe_block_fwd``'s
+    chunks over ``tokens`` tokens (``CHUNK_TOKENS`` at a time where it
+    takes them so) of width ``d``: the route the start record counts."""
+    if tokens > CHUNK_TOKENS and tokens % CHUNK_TOKENS == 0:
+        tokens = CHUNK_TOKENS
+    pairs = tokens * top_k
+    return combine_route(d, piece_rows(pairs, expected)[0] / pairs)
 
 
 def _sum_pair_rows(src, row, live, scale=None):
@@ -193,20 +233,55 @@ def _sum_pair_rows(src, row, live, scale=None):
     return out.astype(src.dtype)
 
 
+def pair_sum(src, taken, live, tokens: int, top_k: int, weights=None):
+    """The same sum as :func:`_sum_pair_rows` over the live rows alone:
+    row ``i < live`` of ``src (rows, d)`` (times ``weights.flat[taken[i]]``,
+    its pair's routing weight, where given) goes to token ``taken[i] //
+    top_k``, accumulated in float32 and rounded once to ``src``'s dtype.
+    The live rows are sorted by their pair ``taken``, which is token-major,
+    so a token's rows are added in the order of ``k``; ONE gather puts them
+    in that order (weighted as it goes) and ``segment.sorted_segment_sum``'s
+    kernel (``pallas_pair_sum``) adds them into a block of tokens at a time
+    and writes it once.  The rows from ``live`` on sort last with a dropped
+    token and no kernel reads them, so the kernel's work follows the pairs
+    held and not ``tokens * top_k``."""
+    rows = src.shape[0]
+    n = tuning.round_up(max(rows, 8), 8)
+    pairs = tokens * top_k
+    key = jnp.where(jnp.arange(rows, dtype=jnp.int32) < live, taken, pairs)
+    key = jnp.pad(key, (0, n - rows), constant_values=pairs)
+    order = jnp.argsort(key)
+    key = key[order]
+    back = jnp.take(src, order, axis=0, mode="clip").astype(jnp.float32)
+    if weights is not None:
+        back = back * jnp.take(weights.reshape(-1), key, mode="clip")[:, None]
+    return segment.sorted_segment_sum(key // top_k, back, tokens,
+                                      "pallas_pair_sum").astype(src.dtype)
+
+
+def _way_back(src, taken, row, live, weights=None):
+    """``src``'s live rows summed into their tokens, by the route
+    :func:`combine_route` takes for rows of ``src``'s width and a piece of
+    its rows."""
+    if combine_route(src.shape[1], src.shape[0] / row.size) == "kernel":
+        return pair_sum(src, taken, live, *row.shape, weights)
+    return _sum_pair_rows(src, row, live, weights)
+
+
 @jax.custom_vjp
 def dispatch_rows(x, taken, row, live):
     """``x[taken // top_k]``: row ``i`` is the token of the ``i``-th sorted
-    pair (``taken = order[lo:lo + rows]``).  The gradient is a gather and
-    a sum over ``top_k`` too, not a scatter."""
+    pair (``taken = order[lo:lo + rows]``).  The gradient is the way back
+    (:func:`_way_back`) over the live rows, not a scatter."""
     return jnp.take(x, taken // row.shape[1], axis=0, mode="clip")
 
 
 def _dispatch_fwd(x, taken, row, live):
-    return dispatch_rows(x, taken, row, live), (row, live)
+    return dispatch_rows(x, taken, row, live), (taken, row, live)
 
 
 def _dispatch_bwd(res, g):
-    return _sum_pair_rows(g, *res), None, None, None
+    return _way_back(g, *res), None, None, None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -215,13 +290,14 @@ dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 @jax.custom_vjp
 def combine(ys, weights, taken, row, live):
     """The way back: ``sum_k weights[t, k] * ys[row[t, k]]`` over the live
-    pairs of token ``t``: ``ys (rows, d)`` float32 -> ``(tokens, d)``.
+    pairs of token ``t``: ``ys (rows, d)`` float32 -> ``(tokens, d)``
+    (:func:`_way_back`).
     The backward is written in sorted space: the gradient of ``ys`` is a
     gather of ``rows`` rows of the ``(tokens, d)`` gradient times the
     row's weight, and the weights' gradient the row-wise product of the
     same gathered rows with ``ys``, carried to its pair by a gather of
     scalars."""
-    return _sum_pair_rows(ys, row, live, weights)
+    return _way_back(ys, taken, row, live, weights)
 
 
 def _combine_fwd(ys, weights, taken, row, live):

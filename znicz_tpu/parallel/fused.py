@@ -572,6 +572,27 @@ def embed_routes(spec: ModelSpec, mesh=None) -> str:
     return f"kernel:{kernel} scatter:{len(widths) - kernel}"
 
 
+def combine_routes(spec: ModelSpec, forwards, mesh=None) -> str:
+    """``kernel:<n> gather:<m>``: the expert rows of ``spec`` whose way back
+    from sorted space to token space is the Pallas kernel's sorted segment
+    sum over the live rows, and the gather of every token's ``top_k`` rows
+    (``moe_ops.first_piece_route``: the rule the step applies as it is
+    traced, at the tokens and width of the row's input in the workflow's
+    ``forwards`` units; on a ``mesh`` it takes the gather)."""
+    counts = {"kernel": 0, "gather": 0}
+    for i, layer in enumerate(spec.layers):
+        if layer.kind != "moe_block":
+            continue
+        *lead, d = forwards[spec.unit_index[i] if spec.unit_index
+                            else i].input.shape
+        cfg = layer.cfg
+        route = "gather" if mesh is not None else moe_ops.first_piece_route(
+            int(np.prod(lead)), d, cfg["top_k"],
+            cfg["experts_held"][1] / cfg["experts"])
+        counts[route] += 1
+    return " ".join(f"{k}:{v}" for k, v in counts.items())
+
+
 # -- pure math (all traced; spec is static) --------------------------------
 def _takes_window(x, cfg) -> bool:
     """``windowed_pairs``' rule on a pair's traced input: the batch as

@@ -464,6 +464,10 @@ class StandardWorkflowBase(AcceleratedWorkflow):
         if any(la.kind == "embed" for la in spec.layers):
             # how the embedding's table gradient is made (ops/attention.py)
             where["embed_routes"] = fused.embed_routes(spec, mesh)
+        if any(la.kind == "moe_block" for la in spec.layers):
+            # how the expert rows go back to token space (ops/moe.py)
+            where["combine_routes"] = fused.combine_routes(
+                spec, self.forwards, mesh)
         self.info("fused trainer on %s",
                   " ".join(f"{k}={v!r}" for k, v in where.items()))
         # host-vs-device time split (telemetry): every call of
