@@ -110,8 +110,7 @@ def measure_fused(wf, epochs: int, warm: int = 2, dtype: str | None = None,
     from znicz_tpu.parallel.mesh import mesh_shape_of, resolve_mesh
 
     mesh = resolve_mesh(mesh)
-    spec, params, vels = fused.extract_model(wf, mesh,
-                                             storage or "float32")
+    spec, params, vels = fused.extract_model(wf, storage or "float32")
     if dtype and dtype != spec.compute_dtype:
         spec = dataclasses.replace(spec, compute_dtype=dtype)
     dp, tp = mesh_shape_of(mesh)
@@ -1153,21 +1152,9 @@ def _kernel_cases():
         ("kohonen_argmin",
          lambda: som_ops.pallas_distance_argmin(xsom, wsom)[0],
          lambda: som_ops.xla_forward(xsom, wsom)[0], "exact"),
-        # the round-3 fused LRN+max-pool pair, at AlexNet L1-like
-        # geometry (stride-2 3x3 pool, cross-channel LRN)
-        ("lrn_maxpool",
-         lambda: lrn_pool_ops.pallas_lrn_maxpool(
-             xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0)[0],
-         lambda: lrn_pool_ops.xla_lrn_maxpool(
-             xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0)[0], "close"),
-        ("gd_lrn_maxpool",
-         lambda: lrn_pool_ops.pallas_gd_lrn_maxpool(
-             elp, olp, xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0),
-         lambda: lrn_pool_ops.xla_gd_lrn_maxpool(
-             elp, olp, xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0),
-         "close"),
-        # the same pair on the convolutions' own layout (PR 33): what a
-        # float32 batch that is a multiple of 8 runs
+        # the fused LRN+max-pool pair on the convolutions' own layout,
+        # at AlexNet L1-like geometry (stride-2 3x3 pool, cross-channel
+        # LRN)
         ("lrn_maxpool_window",
          lambda: lrn_pool_ops.pallas_lrn_maxpool_window(
              xlp, 5, 1e-4, 0.75, 2.0, (3, 3), (2, 2), 0)[0],

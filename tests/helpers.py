@@ -38,7 +38,7 @@ def routed(wf, *rewrites):
     """(spec, params, vels) of an initialized StandardWorkflow with only
     ``rewrites`` of ``parallel.fused`` applied to its rows, in that
     order: none is the unit graph's own rows (the reference every
-    rewrite is compared against), all three are what ``extract_model``
+    rewrite is compared against), both are what ``extract_model``
     ships."""
     from znicz_tpu.parallel import fused
     rows, params, vels = fused.workflow_rows(wf)

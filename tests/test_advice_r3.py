@@ -5,7 +5,8 @@ label geometry (the native scatter would otherwise memcpy out of
 bounds); plus the read_batch_into row-width guard.
 3 low — LMDB overflow EOF bound (tested in test_importers.py),
 host-only augment policies keep the host prefetch path under run_fused,
-and the ``ZNICZ_TPU_MXU=f32`` lever disables the bf16 MXU operand cast.
+and the bf16 MXU operand cast of the unit-graph matmul follows the
+platform alone (the environment lever that disabled it is gone).
 """
 
 import numpy as np
@@ -142,16 +143,17 @@ class TestHostOnlyAugmentFallback:
 
 class TestMXULever:
     def test_env_lever_disables_cast(self, monkeypatch):
-        """ADVICE r3: ZNICZ_TPU_MXU=f32 must disable the bf16 MXU
-        operand cast even on TPU (monkeypatched on_tpu)."""
+        """ADVICE r3 asked for an environment lever that disables the
+        bf16 MXU operand cast on TPU; the lever is gone, and the cast
+        follows the platform alone: on TPU (monkeypatched on_tpu) float32
+        operands are cast, bf16 ones are already the MXU's."""
         import jax.numpy as jnp
 
         from znicz_tpu.ops import matmul as mm
         from znicz_tpu.ops import tuning
         monkeypatch.setattr(tuning, "on_tpu", lambda: True)
         assert mm._mxu_cast(jnp.float32) == jnp.bfloat16
-        monkeypatch.setenv("ZNICZ_TPU_MXU", "f32")
-        assert mm._mxu_cast(jnp.float32) is None
+        assert mm._mxu_cast(jnp.bfloat16) is None
 
     def test_cpu_never_casts(self):
         import jax.numpy as jnp

@@ -2,8 +2,8 @@
 two kernels, compiled for a described v5e at the cell's shapes: the
 merged LRN+pool pair's forward and backward on the convolutions' own
 layout (the window kernels the cell runs since PR 33, 128x55x55x96 and
-128x27x27x256), the same over the column-parity halves (what a batch
-that is no multiple of 8 still runs), pool5's select and scatter
+128x27x27x256), the composed ops a pair the window rule refuses runs
+instead, pool5's select and scatter
 (128x13x13x256, 3x3/2, the tap stack), and a conv -> pair -> conv ->
 pair step whose text must show no layout copy beside the window
 kernels.  The TPU's own Mosaic and XLA compilers run here, with no
@@ -65,40 +65,6 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def _halves(shape, chip):
-    """The column-parity halves of a ``shape`` array: even columns, odd
-    columns."""
-    b, h, w, c = shape
-    return tuple(jax.ShapeDtypeStruct((b, h, cols, c), jnp.float32,
-                                      sharding=chip)
-                 for cols in (-(-w // 2), w // 2))
-
-
-@pytest.mark.parametrize("layer", sorted(PAIRS))
-def test_pair_forward_compiles_for_a_v5e(one_chip, mosaic, layer):
-    text = _compiled_text(
-        lambda xe, xo: lrn_pool.pallas_lrn_maxpool_split(
-            xe, xo, *LRN, *POOL), *_halves(PAIRS[layer], one_chip))
-    assert "tpu_custom_call" in text
-    assert "pallas_lrn_maxpool_split" in text
-
-
-@pytest.mark.parametrize("layer", sorted(PAIRS))
-def test_pair_backward_compiles_for_a_v5e(one_chip, mosaic, layer):
-    """As the step calls it: the conv's strict ReLU folded in, the
-    halves handed back un-interleaved."""
-    shape = PAIRS[layer]
-    pooled = pooling.pool_out_shape(shape, *POOL)
-    err = jax.ShapeDtypeStruct(pooled, jnp.float32, sharding=one_chip)
-    idx = jax.ShapeDtypeStruct(pooled, jnp.int32, sharding=one_chip)
-    text = _compiled_text(
-        lambda e, i, xe, xo: lrn_pool.pallas_gd_lrn_maxpool_split(
-            e, i, xe, xo, *LRN, *POOL, fold_act="strict_relu",
-            return_split=True), err, idx, *_halves(shape, one_chip))
-    assert "tpu_custom_call" in text
-    assert "pallas_gd_lrn_maxpool_split" in text
-
-
 @pytest.mark.parametrize("layer", sorted(PAIRS))
 def test_window_forward_compiles_for_a_v5e(one_chip, mosaic, layer):
     shape = PAIRS[layer]
@@ -107,7 +73,6 @@ def test_window_forward_compiles_for_a_v5e(one_chip, mosaic, layer):
     text = _compiled_text(lambda x: lrn_pool.lrn_maxpool(x, *LRN, *POOL), x)
     assert "tpu_custom_call" in text
     assert "pallas_lrn_maxpool_window" in text
-    assert "pallas_lrn_maxpool_split" not in text
 
 
 @pytest.mark.parametrize("layer", sorted(PAIRS))
@@ -123,14 +88,42 @@ def test_window_backward_compiles_for_a_v5e(one_chip, mosaic, layer):
             e, i, x, *LRN, *POOL, fold_act="strict_relu"), err, idx, x)
     assert "tpu_custom_call" in text
     assert "pallas_gd_lrn_maxpool_window" in text
-    assert "pallas_gd_lrn_maxpool_split" not in text
+
+
+#: a pair the window rule refuses, at the cell's shapes: a batch that fills
+#: no sublane tile, and bfloat16 activations
+REFUSED = {"batch of 124": (124, jnp.float32),
+           "bfloat16": (128, jnp.bfloat16)}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+@pytest.mark.parametrize("layer", sorted(PAIRS))
+def test_a_refused_pair_compiles_as_the_composed_ops(one_chip, mosaic,
+                                                     layer, case):
+    """Forward and backward as the step calls them: no Pallas kernel, and
+    the XLA:TPU compiler takes the composed ops at the cell's widths."""
+    b, dtype = REFUSED[case]
+    shape = (b,) + PAIRS[layer][1:]
+    assert not lrn_pool.windowed(shape, *POOL, dtype)
+    pooled = pooling.pool_out_shape(shape, *POOL)
+    err = jax.ShapeDtypeStruct(pooled, jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct(pooled, jnp.int32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(e, i, x):
+        y, offsets = lrn_pool.lrn_maxpool(x, *LRN, *POOL)
+        return y, offsets, lrn_pool.gd_lrn_maxpool(
+            e, i, x, *LRN, *POOL, fold_act="strict_relu")
+    text = _compiled_text(both, err, idx, x)
+    assert "tpu_custom_call" not in text
+    assert "lrn_maxpool_window" not in text
 
 
 def test_no_layout_copy_stands_beside_the_window_kernels(one_chip, mosaic):
     """The window kernels work on (H, W, B, C), the layout XLA's TPU
     convolutions emit and take: in a compiled conv -> pair -> conv ->
-    pair -> conv step (AlexNet's head at its widths, the convs whole as
-    ``extract_model`` leaves them at this batch) the kernels must take
+    pair -> conv step (AlexNet's head at its widths, the rows as
+    ``extract_model`` leaves them) the kernels must take
     the convs' own fusions, with no float32 copy, transpose or
     ``dynamic-update-slice`` of a pair's input, output or gradient —
     PR 32 measured what such passes cost when XLA makes them (13 ms a
@@ -170,7 +163,6 @@ def test_no_layout_copy_stands_beside_the_window_kernels(one_chip, mosaic):
                           shaped(2, b, dtype=jnp.int32))
     assert text.count("pallas_lrn_maxpool_window") >= 2
     assert text.count("pallas_gd_lrn_maxpool_window") >= 2
-    assert "maxpool_split" not in text
     # the pairs' inputs, outputs and gradients, in either dim order
     paired = {f"f32[{dims}]" for h, c in ((55, 96), (27, 96), (27, 256),
                                           (13, 256))

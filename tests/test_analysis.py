@@ -1727,8 +1727,8 @@ ENV_ROUTING_ALLOWED = """
     def use_pallas():
         if os.getenv("ZNICZ_TPU_NO_PALLAS", "0") == "1":
             return False
-        return os.environ["ZNICZ_TPU_MXU"] != "f32" \
-            or "ZNICZ_TPU_MXU" in os.environ
+        return os.environ["ZNICZ_TPU_PALLAS_INTERPRET"] != "1" \
+            or "ZNICZ_TPU_NO_PALLAS" in os.environ
 """
 
 
@@ -1740,6 +1740,13 @@ class TestEnvRouting:
         # get / in / getenv / subscript / the whole mapping
         assert len(found) == 5
         assert "'ZNICZ_TPU_POOL'" in found[0].message
+
+    def test_two_switches_stay(self):
+        """The Pallas interpreter and the XLA-tier fallback; the unit-graph
+        matmul's precision follows the platform (``matmul._mxu_cast``)."""
+        from znicz_tpu.analysis.envrouting import ALLOWED
+        assert ALLOWED == {"ZNICZ_TPU_PALLAS_INTERPRET",
+                           "ZNICZ_TPU_NO_PALLAS"}
 
     def test_the_switches_that_stay_are_silent(self, tmp_path):
         for rel in ("znicz_tpu/ops/tuning.py", "znicz_tpu/parallel/m.py"):

@@ -34,10 +34,13 @@ from znicz_tpu.telemetry.registry import REGISTRY
 #: model (conv, LRN+pool, dropout, two fc) as the commit before the held
 #: set traced them.  Off the TPU the set is passed as given, so the
 #: programs are those: a change that means to alter them replaces the
-#: digests and says so.
+#: digests and says so.  Replaced once since: the conv before the pair is
+#: one plain convolution on the XLA tier too, where it was two
+#: column-parity convolutions (3 convolutions in the train program, 1 in
+#: the eval program, where there were 6 and 2).
 EPOCH_PROGRAMS = {
-    "train": "f400a0b5a13c65139308c3111da6e2c7aeaae764e825248270a87acbce3b5908",
-    "eval": "fb2289fba280f44dcf9d527cc867e602f8bc6a899a35c5cd143e901ab3b6524b",
+    "train": "b70c56d09925291bf3bb1123bbef9da5102609fc469e78e4eac18ddf60b16c1c",
+    "eval": "3734ef65c19b11d3d1a2de15ec86984be0ebc9e9523f5523047d77abec7980d4",
 }
 HYP = (0.1, 0.0, 0.0, 0.9)
 BATCH = 8

@@ -1,6 +1,6 @@
 """The merged LRN+pool pair on the convolutions' own layout
 (``ops/lrn_pool.py`` header): the window kernels on the (H, W, B, C)
-view of an unsplit x, run here in the Pallas interpreter.
+view of x, run here in the Pallas interpreter.
 
 What is compared with what.  The winner OFFSETS equal the numpy golden
 path's everywhere.  With an LRN that is the identity (alpha 0, k 1: the
@@ -12,7 +12,7 @@ values are held to float32 rounding: XLA's CPU backend contracts
 numpy rounds twice, so an element in some ten thousand differs in its
 last bit — in today's kernels and in the composed XLA ops alike (the
 same tolerance class as ``tests/test_lrn_pool.py``).  Everything the
-rule refuses must keep the column-parity kernels."""
+rule refuses runs the composed XLA ops."""
 
 import json
 import types
@@ -46,8 +46,8 @@ def interpret_mode(monkeypatch):
 
 @pytest.fixture
 def kernels(monkeypatch):
-    """Counts the calls that reach each family of pair kernels."""
-    calls = {"window": 0, "split": 0}
+    """Counts the calls that reach the window kernels."""
+    calls = {"window": 0}
 
     def counted(name, family):
         fn = getattr(lrn_pool, name)
@@ -58,8 +58,6 @@ def kernels(monkeypatch):
         monkeypatch.setattr(lrn_pool, name, call)
     counted("pallas_lrn_maxpool_window", "window")
     counted("pallas_gd_lrn_maxpool_window", "window")
-    counted("pallas_lrn_maxpool_split", "split")
-    counted("pallas_gd_lrn_maxpool_split", "split")
     return calls
 
 
@@ -95,7 +93,7 @@ def test_identity_lrn_is_bit_equal_to_golden(kernels, h, w, c):
     got, want = _both_ways(_x((B, h, w, c), relu=True), IDENTITY, POOL)
     for g, r in zip(got, want):
         np.testing.assert_array_equal(g, r)
-    assert kernels == {"window": 2, "split": 0}
+    assert kernels == {"window": 2}
 
 
 @pytest.mark.parametrize("fold_act", [None, "strict_relu", "tanh"])
@@ -109,7 +107,7 @@ def test_alexnet_lrn_matches_golden(kernels, h, w, c, fold_act):
     np.testing.assert_array_equal(idx, idx_ref)
     np.testing.assert_allclose(y, y_ref, rtol=1e-6, atol=0)
     np.testing.assert_allclose(dx, dx_ref, rtol=1e-5, atol=1e-7)
-    assert kernels == {"window": 2, "split": 0}
+    assert kernels == {"window": 2}
 
 
 @pytest.mark.parametrize("h,w,c", SHAPES[:3])
@@ -140,7 +138,7 @@ def test_every_fusable_geometry(kernels, geometry, even):
                            (ksize, stride, 0), fold_act="strict_relu")
     for g, r in zip(got, want):
         np.testing.assert_array_equal(g, r)
-    assert kernels == {"window": 2, "split": 0}
+    assert kernels == {"window": 2}
 
 
 #: channels, VMEM budget, H: budgets a test-size array overflows, at which
@@ -204,7 +202,7 @@ def test_planted_ties_keep_the_first_of_the_tied(kernels, use_abs):
 
 
 #: shape, ksize, stride, padding, dtype of pairs the rule refuses
-SPLIT_CASES = {
+COMPOSED_CASES = {
     "batch of 4": ((4, 9, 9, 8), (3, 3), (2, 2), 0, jnp.float32),
     "batch of 12": ((12, 9, 9, 8), (3, 3), (2, 2), 0, jnp.float32),
     "bfloat16 activations": ((8, 9, 9, 8), (3, 3), (2, 2), 0,
@@ -212,33 +210,39 @@ SPLIT_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(SPLIT_CASES))
-def test_everything_else_keeps_the_parity_kernels(kernels, case):
-    shape, ksize, stride, padding, dtype = SPLIT_CASES[case]
+@pytest.mark.parametrize("case", sorted(COMPOSED_CASES))
+def test_everything_else_runs_the_composed_ops(kernels, case):
+    """A geometry ``fusable`` admits at a batch that fills no sublane
+    tile, or in bfloat16: the dispatchers hand back the composed ops'
+    results, and the program they trace to holds no Pallas call."""
+    shape, ksize, stride, padding, dtype = COMPOSED_CASES[case]
     assert lrn_pool.fusable(ksize, stride, padding)
     assert not lrn_pool.windowed(shape, ksize, stride, padding, dtype)
     x = jnp.asarray(_x(shape)).astype(dtype)
-    y, idx = lrn_pool.lrn_maxpool(x, *LRN, ksize, stride, padding)
-    y_ref, idx_ref = lrn_pool.xla_lrn_maxpool(x, *LRN, ksize, stride,
-                                              padding)
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(idx_ref))
-    assert y.dtype == dtype
-    err = jnp.asarray(_x(y.shape, seed=1))
-    dx = lrn_pool.gd_lrn_maxpool(err, idx, x, *LRN, ksize, stride,
-                                 padding, "strict_relu")
-    dx_ref = lrn_pool.xla_gd_lrn_maxpool(err, idx, x, *LRN, ksize, stride,
-                                         padding, "strict_relu")
-    packed = dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_ref),
-                               rtol=2e-2 if packed else 1e-5,
-                               atol=1e-2 if packed else 1e-6)
-    assert kernels == {"window": 0, "split": 2}
+
+    def both(x, err):
+        y, idx = lrn_pool.lrn_maxpool(x, *LRN, ksize, stride, padding)
+        return y, idx, lrn_pool.gd_lrn_maxpool(
+            err, idx, x, *LRN, ksize, stride, padding, "strict_relu")
+
+    def composed(x, err):
+        y, idx = lrn_pool.xla_lrn_maxpool(x, *LRN, ksize, stride, padding)
+        return y, idx, lrn_pool.xla_gd_lrn_maxpool(
+            err, idx, x, *LRN, ksize, stride, padding, "strict_relu")
+    pooled = lrn_pool.xla_lrn_maxpool(x, *LRN, ksize, stride, padding)[0]
+    err = jnp.asarray(_x(pooled.shape, seed=1))
+    got, want = both(x, err), composed(x, err)
+    assert got[0].dtype == dtype
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    text = jax.jit(both).lower(x, err).as_text()
+    assert "pallas" not in text and "tpu_custom_call" not in text
+    assert kernels == {"window": 0}
 
 
 @pytest.mark.parametrize("case", ["padded", "stride-W 3", "stride-W 1"])
 def test_what_no_pair_kernel_takes_stays_composed(kernels, case):
-    """``fusable`` refuses these before either family is asked: the rule
-    holds them off the window kernels as well."""
+    """``fusable`` refuses these, and with it the window rule."""
     ksize, stride, padding = {"padded": ((3, 3), (2, 2), 1),
                               "stride-W 3": ((3, 3), (3, 3), 0),
                               "stride-W 1": ((2, 2), (2, 1), 0)}[case]
@@ -250,7 +254,7 @@ def test_what_no_pair_kernel_takes_stays_composed(kernels, case):
                                              padding)
     np.testing.assert_array_equal(np.asarray(idx), idx_ref)
     np.testing.assert_allclose(np.asarray(y), y_ref, rtol=1e-6)
-    assert kernels == {"window": 0, "split": 0}
+    assert kernels == {"window": 0}
 
 
 def test_batch_sharded_under_a_mesh(kernels):
@@ -275,7 +279,7 @@ def test_batch_sharded_under_a_mesh(kernels):
     np.testing.assert_array_equal(np.asarray(y), y_ref)
     np.testing.assert_array_equal(np.asarray(idx), idx_ref)
     np.testing.assert_array_equal(np.asarray(dx), dx_ref)
-    assert kernels == {"window": 2, "split": 0}
+    assert kernels == {"window": 2}
 
 
 @pytest.mark.parametrize("c", [3, 96, 128, 192, 256])
@@ -317,25 +321,23 @@ def test_pair_routes_counts_each_family(monkeypatch):
     rows = (_pair_row(), _pair_row(fold_act="strict_relu"))
     spec = fused.ModelSpec(rows, "mse")
     units = [_unit((8, 13, 13, 4)), None, _unit((8, 7, 7, 4)), None]
-    assert fused.windowed_pairs(rows, units) == (0, 1)
-    assert fused.pair_routes(spec, units) == "window:2 split:0"
+    assert fused.pair_routes(spec, units) == "window:2 composed:0"
     # a batch that fills no sublane tile, in one pair of the two
     units[2] = _unit((4, 7, 7, 4))
-    assert fused.windowed_pairs(rows, units) == (0,)
-    assert fused.pair_routes(spec, units) == "window:1 split:1"
+    assert fused.pair_routes(spec, units) == "window:1 composed:1"
     # packed activations: neither
     packed = fused.ModelSpec(rows, "mse", storage_dtype="bfloat16")
-    assert fused.pair_routes(packed, units) == "window:0 split:2"
+    assert fused.pair_routes(packed, units) == "window:0 composed:2"
     # under a mesh a device holds its share of the batch
     mesh = make_mesh(n_data=4, n_model=2)
-    assert fused.pair_routes(spec, units, mesh) == "window:0 split:2"
+    assert fused.pair_routes(spec, units, mesh) == "window:0 composed:2"
     units[0] = units[2] = _unit((32, 7, 7, 4))
-    assert fused.pair_routes(spec, units, mesh) == "window:2 split:0"
+    assert fused.pair_routes(spec, units, mesh) == "window:2 composed:0"
     # a model without a pair, and any model off the Pallas tier
     assert fused.pair_routes(fused.ModelSpec((), "mse"), []) == \
-        "window:0 split:0"
+        "window:0 composed:0"
     monkeypatch.setattr(tuning, "_INTERPRET", False)
-    assert fused.pair_routes(spec, units) == "window:0 split:0"
+    assert fused.pair_routes(spec, units) == "window:0 composed:0"
 
 
 #: conv -> pair -> conv -> pair -> fc: AlexNet's head at test size
@@ -376,19 +378,21 @@ def test_a_two_pair_job_trains_as_the_composed_ops_do(tmp_path, kernels,
                                                       monkeypatch):
     rows, metrics = _train(tmp_path, "window")
     assert rows and all(r["kernel_tier"] == "pallas-interpret"
-                        and r["pair_routes"] == "window:2 split:0"
+                        and r["pair_routes"] == "window:2 composed:0"
                         and r["pool_routes"] == "windowed:0 taps:0"
                         for r in rows)
-    assert kernels["window"] >= 4 and kernels["split"] == 0
-    # a batch the rule refuses: today's rewrites and kernels
-    split_rows, _ = _train(tmp_path, "split", batch=12)
-    assert all(r["pair_routes"] == "window:0 split:2" for r in split_rows)
-    assert kernels["split"] >= 4
+    window_calls = kernels["window"]
+    assert window_calls >= 4
+    # a batch the rule refuses: the composed ops, no kernel
+    composed_rows, _ = _train(tmp_path, "composed", batch=12)
+    assert all(r["pair_routes"] == "window:0 composed:2"
+               for r in composed_rows)
+    assert kernels["window"] == window_calls
     # the same job on the XLA tier: the composed ops, no family claimed
     monkeypatch.setattr(tuning, "_INTERPRET", False)
     xla_rows, xla_metrics = _train(tmp_path, "xla")
     assert all(r["kernel_tier"] == "xla"
-               and r["pair_routes"] == "window:0 split:0"
+               and r["pair_routes"] == "window:0 composed:0"
                for r in xla_rows)
     for got, want in zip(metrics, xla_metrics):
         np.testing.assert_allclose(got["train_loss"], want["train_loss"],
@@ -398,18 +402,17 @@ def test_a_two_pair_job_trains_as_the_composed_ops_do(tmp_path, kernels,
 
 
 def test_the_rows_of_a_windowed_pair(monkeypatch):
-    """Where the rule holds the conv before the pair stays whole (its
-    activation's derivative still folded into the pair's backward);
-    where it does not, rewrite (iii) splits it as before."""
+    """The rows follow from the layer list alone: at a batch the rule
+    admits, at one it refuses and on the XLA tier, the conv before each
+    pair stays whole and its activation's derivative is folded into the
+    pair's backward."""
     def marks(batch):
         wf = helpers.tiny_workflow(TWO_PAIRS, (15, 15, 3), batch)
         spec = fused.extract_model(wf)[0]
-        return [sorted(k for k in ("act_folded", "split_out", "fold_act",
-                                   "emit_split") if k in la.cfg)
+        return [sorted(k for k in ("act_folded", "fold_act") if k in la.cfg)
                 for la in spec.layers[:4]]
     whole = [["act_folded"], ["fold_act"]] * 2
-    split = [["act_folded", "split_out"], ["emit_split", "fold_act"]] * 2
     assert marks(8) == whole
-    assert marks(12) == split
+    assert marks(12) == whole
     monkeypatch.setattr(tuning, "_INTERPRET", False)     # the XLA tier
-    assert marks(8) == split
+    assert marks(8) == whole

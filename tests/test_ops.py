@@ -40,14 +40,14 @@ class TestMatmul:
 
 class TestMXUCastPath:
     """VERDICT r3 weak item 3: the bf16 MXU operand cast only activates
-    on real TPU, so no CI run had ever EXECUTED the cast path.
-    ZNICZ_TPU_MXU=bf16 forces it anywhere — interpret mode here runs
-    the exact astype(bf16) kernel code first chip contact runs."""
+    on real TPU, so no CI run had ever EXECUTED the cast path.  A
+    platform that says TPU, with the kernels in the interpreter, runs
+    here the exact astype(bf16) kernel code the chip runs."""
 
     @pytest.fixture
     def forced_cast(self, monkeypatch):
-        monkeypatch.setattr(tuning, "_INTERPRET", True)
-        monkeypatch.setenv("ZNICZ_TPU_MXU", "bf16")
+        monkeypatch.setattr(tuning, "on_tpu", lambda: True)
+        monkeypatch.setattr(tuning, "interpret_mode", lambda: True)
         yield
 
     def test_cast_matmul_close_to_f32(self, forced_cast):
@@ -60,11 +60,6 @@ class TestMXUCastPath:
         # with sqrt(K) through cancellation
         np.testing.assert_allclose(g, p, rtol=2e-2, atol=1e-1)
         assert np.max(np.abs(g - p)) > 0.0   # the cast really happened
-
-    def test_f32_lever_wins_over_tpu(self, monkeypatch):
-        monkeypatch.setenv("ZNICZ_TPU_MXU", "f32")
-        monkeypatch.setattr(tuning, "on_tpu", lambda: True)
-        assert matmul._mxu_cast(jnp.float32) is None
 
 
 class TestSoftmax:

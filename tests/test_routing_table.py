@@ -3,11 +3,9 @@
 ``parallel/fused.extract_model`` decides from the layer list which rows
 merge into an LRN+pool pair and which conv hands the pair its
 activation's derivative; from the shapes one device holds, the storage
-dtype and the kernel tier (``windowed_pairs``) which pairs take the
-window kernels on the convolutions' own layout, leaving their conv whole,
-and which keep the column-parity kernels, fed by a conv that emits the
-halves; ``pool_routes`` and ``pair_routes`` say which pools and pairs
-took which kernels.  Here each model of ``znicz_tpu/models/`` (its
+dtype and the kernel tier, ``pool_routes`` and ``pair_routes`` say which
+pools and pairs take which kernels (a pair: the window kernels on the
+convolutions' own layout, or the composed XLA ops).  Here each model of ``znicz_tpu/models/`` (its
 shipped layer list over a few rows of its input shape, cut where the
 shape is large) and each image configuration of the benchmark (its layer
 list read as data, at a cut input size) has its rows written out, so a
@@ -43,7 +41,8 @@ def _bench_layers(name):
         return json.load(fh)["layers"]
 
 
-def _table(rows, pool_routes, units=None, pair_routes="window:0 split:0"):
+def _table(rows, pool_routes, units=None,
+           pair_routes="window:0 composed:0"):
     return {"rows": rows, "pool_routes": pool_routes,
             "pair_routes": pair_routes,
             "units": units or tuple(range(len(rows)))}
@@ -52,23 +51,19 @@ def _table(rows, pool_routes, units=None, pair_routes="window:0 split:0"):
 _ALEXNET_UNITS = (0, 1, 3, 4, 6, 7, 8, 9, 10, 11, 12, 13, 14)
 _ALEXNET_TAIL = ["conv", "conv", "conv", "max_pool", "dropout", "fc",
                  "dropout", "fc", "fc"]
+_ALEXNET_ROWS = (["conv[act_folded]", "lrn_pool[fold_act=strict_relu]",
+                  "conv[act_folded]", "lrn_pool[fold_act=strict_relu]"]
+                 + _ALEXNET_TAIL)
 #: both LRN+pool pairs merged, folded into their convs and, at a batch
-#: of whole sublane tiles, on the window kernels: the convs stay whole;
-#: pool5 (3x3/2) on the tap stack
-ALEXNET = _table(
-    ["conv[act_folded]", "lrn_pool[fold_act=strict_relu]",
-     "conv[act_folded]", "lrn_pool[fold_act=strict_relu]"]
-    + _ALEXNET_TAIL, "windowed:0 taps:1", units=_ALEXNET_UNITS,
-    pair_routes="window:2 split:0")
-#: at a batch the rule refuses: the column-parity kernels, fed split
-#: halves by their convs (what every batch had before PR 33)
-ALEXNET_SPLIT = _table(
-    ["conv[act_folded,split_out]",
-     "lrn_pool[fold_act=strict_relu,emit_split]",
-     "conv[act_folded,split_out]",
-     "lrn_pool[fold_act=strict_relu,emit_split]"]
-    + _ALEXNET_TAIL, "windowed:0 taps:1", units=_ALEXNET_UNITS,
-    pair_routes="window:0 split:2")
+#: of whole sublane tiles, on the window kernels; pool5 (3x3/2) on the
+#: tap stack
+ALEXNET = _table(_ALEXNET_ROWS, "windowed:0 taps:1", units=_ALEXNET_UNITS,
+                 pair_routes="window:2 composed:0")
+#: at a batch the rule refuses: the same rows, the pairs on the composed
+#: ops
+ALEXNET_COMPOSED = _table(_ALEXNET_ROWS, "windowed:0 taps:1",
+                          units=_ALEXNET_UNITS,
+                          pair_routes="window:0 composed:2")
 
 #: name -> (layer list, one row's shape, loss, the table[, the batch]).  A
 #: row reads
@@ -82,7 +77,7 @@ CASES = {
                       "softmax", ALEXNET),
     "bench-alexnet at a batch of 12": (
         lambda: _bench_layers("alexnet"), (67, 67, 3), "softmax",
-        ALEXNET_SPLIT, 12),
+        ALEXNET_COMPOSED, 12),
     "bench-vgg11": (lambda: _bench_layers("vgg11"), (32, 32, 3), "softmax",
                     _table(["conv", "max_pool", "conv", "max_pool", "conv",
                             "conv", "max_pool", "conv", "conv", "max_pool",
@@ -117,8 +112,7 @@ def _describe(spec):
     for la in spec.layers:
         cfg = la.cfg
         marks = [k if cfg[k] is True else f"{k}={cfg[k]}"
-                 for k in ("act_folded", "split_out", "fold_act",
-                           "emit_split", "tie") if k in cfg]
+                 for k in ("act_folded", "fold_act", "tie") if k in cfg]
         rows.append(la.kind + (f"[{','.join(marks)}]" if marks else ""))
     return rows
 
@@ -143,8 +137,8 @@ def test_off_the_pallas_tier_the_rows_are_the_layer_lists():
     claimed."""
     wf = helpers.tiny_workflow(_bench_layers("alexnet"), (67, 67, 3), BATCH)
     spec = fused.extract_model(wf)[0]
-    assert _describe(spec) == ALEXNET_SPLIT["rows"]
-    assert fused.pair_routes(spec, wf.forwards) == "window:0 split:0"
+    assert _describe(spec) == ALEXNET["rows"]
+    assert fused.pair_routes(spec, wf.forwards) == "window:0 composed:0"
     assert fused.pool_routes(spec, wf.forwards) == "windowed:0 taps:0"
 
 
@@ -166,8 +160,8 @@ def test_the_start_record_states_both_routes(monkeypatch, tmp_path):
     start = [ln for ln in lines if ln.startswith("fused trainer on")]
     assert len(start) == 1
     assert "pool_routes='windowed:0 taps:1'" in start[0]
-    assert "pair_routes='window:2 split:0'" in start[0]
+    assert "pair_routes='window:2 composed:0'" in start[0]
     rows = [json.loads(ln) for ln in path.read_text().splitlines()]
-    assert rows and all(r["pair_routes"] == "window:2 split:0"
+    assert rows and all(r["pair_routes"] == "window:2 composed:0"
                         and r["pool_routes"] == "windowed:0 taps:1"
                         for r in rows)

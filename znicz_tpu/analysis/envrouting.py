@@ -12,8 +12,7 @@ one of the switches that stay:
 * ``ZNICZ_TPU_PALLAS_INTERPRET`` — the Pallas interpreter off-TPU, how
   the tests run the kernels' logic;
 * ``ZNICZ_TPU_NO_PALLAS`` — the operator's fallback to the XLA tier,
-  which is also the tests' reference;
-* ``ZNICZ_TPU_MXU`` — the operand precision of the unit-graph matmul.
+  which is also the tests' reference.
 
 A deployment setting read there (an address, a path) is suppressed
 inline with its reason, like any other rule.
@@ -29,8 +28,7 @@ from .core import Rule, dotted
 SCOPE = ("znicz_tpu/ops/", "znicz_tpu/parallel/")
 
 #: the switches that stay (module docstring)
-ALLOWED = frozenset({"ZNICZ_TPU_PALLAS_INTERPRET", "ZNICZ_TPU_NO_PALLAS",
-                     "ZNICZ_TPU_MXU"})
+ALLOWED = frozenset({"ZNICZ_TPU_PALLAS_INTERPRET", "ZNICZ_TPU_NO_PALLAS"})
 
 
 def _const_str(node) -> str | None:

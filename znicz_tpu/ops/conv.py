@@ -134,97 +134,6 @@ def xla_conv2d_grad_weights(x, err, w_shape, stride=1, padding=0):
     return dw[:kh, :kw].astype(jnp.float32)
 
 
-# -- column-parity variants (rewrite (iii) of parallel/fused.py) -----------
-# A conv whose output feeds a merged LRN+max-pool pair can emit the
-# pair's column-parity halves DIRECTLY: the even/odd output columns of a
-# stride-s conv are themselves convs with W-stride 2s and a ±s·p input
-# offset (expressed as negative/asymmetric padding, which XLA supports).
-# This removes the pair forward's split pass over the net's biggest
-# activation, and the matching gradient decompositions let the pair
-# backward hand its (dxe, dxo) halves straight to the conv grads — no
-# interleave pass either.  All pure XLA; exactness pinned against the
-# plain conv + split composition in tests.
-
-def _parity_out_w(w: int, kw: int, sw: int, pw: int) -> tuple[int, int]:
-    ow = out_size(w, kw, sw, pw)
-    return -(-ow // 2), ow // 2          # even count, odd count
-
-
-def xla_conv2d_split(x, w, stride=1, padding=0, out_dtype=None):
-    """→ (y_even, y_odd): the column-parity halves of xla_conv2d."""
-    kh, kw, _, oc = w.shape
-    (sh, sw), (ph, pw) = _norm2(stride), _norm2(padding)
-    _, h_in, w_in, _ = x.shape
-    oh = out_size(h_in, kh, sh, ph)
-    halves = []
-    for p, target in zip((0, 1), _parity_out_w(w_in, kw, sw, pw)):
-        if target == 0:
-            # output width 1: the odd half is empty — mirror the
-            # gradient twins' guard instead of building an impossible
-            # negative-padding conv
-            halves.append(jnp.zeros(
-                (x.shape[0], oh, 0, oc), out_dtype or x.dtype))
-            continue
-        pl = pw - p * sw
-        pr = (target - 1) * 2 * sw + kw - w_in - pl
-        y = lax.conv_general_dilated(
-            x, w, window_strides=(sh, 2 * sw),
-            padding=((ph, ph), (pl, pr)), dimension_numbers=_DIMNUMS,
-            preferred_element_type=jnp.float32)
-        halves.append(y.astype(out_dtype or x.dtype))
-    return halves[0], halves[1]
-
-
-def xla_conv2d_grad_weights_split(x, err_e, err_o, w_shape, stride=1,
-                                  padding=0):
-    """Weight grad from parity-split output error halves — sums the two
-    rhs-dilated convs (dilation 2·sw, input offset p·sw)."""
-    kh, kw, c, oc = w_shape
-    (sh, sw), (ph, pw) = _norm2(stride), _norm2(padding)
-    dw = None
-    for p, err in ((0, err_e), (1, err_o)):
-        if err.shape[2] == 0:
-            continue
-        pl = pw - p * sw
-        g = lax.conv_general_dilated(
-            x, err, window_strides=(1, 1),
-            padding=((ph, ph), (pl, pw + 2 * sw)),
-            rhs_dilation=(sh, 2 * sw),
-            dimension_numbers=lax.ConvDimensionNumbers(
-                lhs_spec=(3, 0, 1, 2), rhs_spec=(3, 0, 1, 2),
-                out_spec=(2, 3, 0, 1)),
-            preferred_element_type=jnp.float32)[:kh, :kw]
-        dw = g if dw is None else dw + g
-    return dw.astype(jnp.float32)
-
-
-def xla_conv2d_grad_input_split(err_e, err_o, w, x_shape, stride=1,
-                                padding=0):
-    """Input grad from parity-split output error halves — sums the two
-    transposed convs (lhs_dilation 2·sw, offset-adjusted padding)."""
-    kh, kw, c, oc = w.shape
-    (sh, sw), (ph, pw) = _norm2(stride), _norm2(padding)
-    _, h, w_in, _ = x_shape
-    w_flip = jnp.transpose(w[::-1, ::-1, :, :], (0, 1, 3, 2))
-    dx = None
-    for p, err in ((0, err_e), (1, err_o)):
-        ow_p = err.shape[2]
-        if ow_p == 0:
-            continue
-        _, oh, _, _ = err.shape
-        lo_h = kh - 1 - ph
-        hi_h = h + ph - ((oh - 1) * sh + 1) - (kh - 1) + kh - 1
-        lo_w = kw - 1 - (pw - p * sw)
-        hi_w = w_in - 1 + kw - lo_w - ((ow_p - 1) * 2 * sw + 1)
-        g = lax.conv_general_dilated(
-            err, w_flip, window_strides=(1, 1),
-            padding=((lo_h, hi_h), (lo_w, hi_w)),
-            lhs_dilation=(sh, 2 * sw), dimension_numbers=_DIMNUMS,
-            preferred_element_type=jnp.float32)
-        dx = g if dx is None else dx + g
-    return dx.astype(jnp.float32)
-
-
 #: the one tier of each direction on an XLA device, under the names the
 #: fused step and ``nn/`` call
 conv2d = xla_conv2d

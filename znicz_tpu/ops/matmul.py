@@ -43,19 +43,8 @@ def _mxu_cast(dtype):
 
     Consequence for callers of the dispatching ``matmul()``: on TPU,
     f32 inputs are NOT multiplied in full f32 precision on the Pallas
-    tier (accumulation stays f32).  ``ZNICZ_TPU_MXU=f32`` disables the
-    cast for on-chip A/B and precision triage — set it BEFORE the first
-    matmul of the process: the value is read at trace time, so a jitted
-    shape that already compiled keeps its cast decision (A/B runs
-    therefore use separate processes, as bench.py does)."""
-    import os
-    lever = os.environ.get("ZNICZ_TPU_MXU", "").lower()
-    if lever == "f32":
-        return None
-    if jnp.dtype(dtype) == jnp.float32 and (lever == "bf16"
-                                            or tuning.on_tpu()):
-        # =bf16 forces the cast anywhere (interpret-mode CI executes
-        # the exact astype path the chip runs)
+    tier (accumulation stays f32)."""
+    if jnp.dtype(dtype) == jnp.float32 and tuning.on_tpu():
         return jnp.bfloat16
     return None
 

@@ -305,18 +305,14 @@ def workflow_rows(workflow) -> tuple[list, list, list]:
     return layers, params, vels
 
 
-def extract_model(workflow, mesh=None, storage_dtype: str = "float32"
+def extract_model(workflow, storage_dtype: str = "float32"
                   ) -> tuple[ModelSpec, list, list]:
     """Read (spec, params, velocities) out of an initialized
-    StandardWorkflow: its rows (``workflow_rows``) through the three
+    StandardWorkflow: its rows (``workflow_rows``) through the two
     rewrites below, in this order.  Which rows merge and fold follows
-    from the layer list alone; a pair that takes the window kernels
-    (``windowed_pairs``: from the shapes one device of ``mesh`` holds,
-    ``storage_dtype`` and the kernel tier) leaves its conv whole."""
+    from the layer list alone."""
     layers, params, vels = workflow_rows(workflow)
     rows = fold_pair_act(merge_lrn_pool(layers))
-    rows = split_pair_conv(rows, whole=windowed_pairs(
-        rows, workflow.forwards, mesh, storage_dtype))
     spec, params, vels = model_of_rows(rows, params, vels,
                                        workflow.loss_function)
     return (dataclasses.replace(spec, storage_dtype=storage_dtype),
@@ -405,25 +401,6 @@ def fold_pair_act(layers) -> list:
     return out
 
 
-def split_pair_conv(layers, whole=()) -> list:
-    """Rewrite (iii): a conv row that (ii) folded emits the pair's
-    column-parity halves directly (two stride-doubled convs,
-    ``split_out``) and takes the pair's split gradient halves back
-    (``emit_split`` on the pair): the pair forward's split pass and the
-    backward's interleave go.  The parity convs are allclose (atol 1e-5),
-    not bit-equal, to the plain conv.  A folded deconv stays whole, and
-    so does the conv of a pair row in ``whole`` (``windowed_pairs``:
-    its kernels take x unsplit, so there is nothing to save)."""
-    out = list(layers)
-    for i in range(1, len(out)):
-        prev = out[i - 1]
-        if prev.kind == "conv" and prev.cfg.get("act_folded") \
-                and "fold_act" in out[i].cfg and i not in whole:
-            out[i - 1] = _with_config(prev, split_out=True)
-            out[i] = _with_config(out[i], emit_split=True)
-    return out
-
-
 # -- names in the device trace ----------------------------------------------
 def layer_label(spec: ModelSpec, i: int) -> str:
     """``L<unit>.<kind>`` of spec row ``i``: ``unit`` is the index of the
@@ -476,40 +453,28 @@ def pool_routes(spec: ModelSpec, forwards, mesh=None) -> str:
     return " ".join(f"{k}:{v}" for k, v in counts.items())
 
 
-def windowed_pairs(rows, forwards, mesh=None,
-                   storage_dtype: str = "float32") -> tuple:
-    """The ``lrn_pool`` rows of ``rows`` whose kernels run on the
-    (H, W, B, C) view of an unsplit x (``ops/lrn_pool.py`` header), by
-    index: on the Pallas tier, where ``lrn_pool_ops.windowed`` admits
-    the pair's input as one device of ``mesh`` holds it — the rule the
-    step applies again to its operands as it is traced."""
-    if not tuning.use_pallas():
-        return ()
+def pair_routes(spec: ModelSpec, forwards, mesh=None) -> str:
+    """``window:<n> composed:<m>``: how many merged LRN+pool rows of
+    ``spec`` take the window kernels on the (H, W, B, C) view of x
+    (``ops/lrn_pool.py`` header) and how many the composed XLA ops.  The
+    choice is made as the step is traced, from the operands, so it is
+    counted here by the same rule (``lrn_pool_ops.windowed``) from the
+    shapes of the workflow's ``forwards`` units, the batch as one device
+    of ``mesh`` holds it, and ``spec.storage_dtype``.  Both read 0 off
+    the Pallas tier, where every pair is the composed ops."""
+    counts = {"window": 0, "composed": 0}
     dp = mesh_lib.mesh_shape_of(mesh)[0]
-    units = row_units(rows)
-    out = []
-    for i, layer in enumerate(rows):
-        if layer.kind != "lrn_pool":
+    units = row_units(spec.layers)
+    routed = "lrn_pool" if tuning.use_pallas() else None
+    for i, layer in enumerate(spec.layers):
+        if layer.kind != routed:
             continue
         b, *rest = forwards[units[i]].input.shape
         cfg = layer.cfg
-        if lrn_pool_ops.windowed((b // dp, *rest), cfg["ksize"],
-                                 cfg["stride"], cfg["padding"],
-                                 storage_dtype):
-            out.append(i)
-    return tuple(out)
-
-
-def pair_routes(spec: ModelSpec, forwards, mesh=None) -> str:
-    """``window:<n> split:<m>``: how many merged LRN+pool rows of
-    ``spec`` take the window kernels and how many the column-parity
-    ones (``windowed_pairs``).  Both read 0 off the Pallas tier, where
-    a pair is the composed XLA ops."""
-    pairs = sum(la.kind == "lrn_pool" for la in spec.layers) \
-        if tuning.use_pallas() else 0
-    window = len(windowed_pairs(spec.layers, forwards, mesh,
-                                spec.storage_dtype))
-    return f"window:{window} split:{pairs - window}"
+        counts["window" if lrn_pool_ops.windowed(
+            (b // dp, *rest), cfg["ksize"], cfg["stride"], cfg["padding"],
+            spec.storage_dtype) else "composed"] += 1
+    return " ".join(f"{k}:{v}" for k, v in counts.items())
 
 
 def attn_routes(spec: ModelSpec) -> str:
@@ -594,15 +559,6 @@ def combine_routes(spec: ModelSpec, forwards, mesh=None) -> str:
 
 
 # -- pure math (all traced; spec is static) --------------------------------
-def _takes_window(x, cfg) -> bool:
-    """``windowed_pairs``' rule on a pair's traced input: the batch as
-    one device of the mesh being traced under holds it."""
-    b, *rest = x.shape
-    return tuning.use_pallas() and lrn_pool_ops.windowed(
-        (tuning.device_rows(b), *rest), cfg["ksize"], cfg["stride"],
-        cfg["padding"], x.dtype)
-
-
 def _sequence_call(spec: ModelSpec, layer: LayerSpec):
     """``(leaves, x) -> (y, counters)`` of a sequence kind: the ``ops``
     function with the layer's static config and the compute dtype."""
@@ -712,11 +668,7 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
         w, b = (None, None) if layer.kind in SEQUENCE_FWD else leaves
         with layer_scope("fwd", spec, i):
             x_in, aux = h, None
-            if isinstance(h, tuple):     # split-out conv → pair handoff:
-                b_, h_, we, c_ = h[0].shape          # record logical shape
-                in_shapes.append((b_, h_, we + h[1].shape[2], c_))
-            else:
-                in_shapes.append(tuple(h.shape))
+            in_shapes.append(tuple(h.shape))
             cfg = layer.cfg
             is_last = i == n - 1
             if layer.kind == "fc":
@@ -730,23 +682,12 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
                 else:
                     h = spec.act(i).fwd(pre, jnp)
             elif layer.kind == "conv":
-                if cfg.get("split_out"):
-                    # phase-2: emit the column-parity halves the merged
-                    # pair consumes — the split pass over the conv output
-                    # never exists (ops/conv.py parity decomposition)
-                    pe, po = conv_ops.xla_conv2d_split(
-                        h.astype(cdt), w.astype(cdt), cfg["stride"],
-                        cfg["padding"], out_dtype=jnp.float32)
-                    if b is not None:
-                        pe, po = pe + b, po + b
-                    h = (spec.act(i).fwd(pe, jnp), spec.act(i).fwd(po, jnp))
-                else:
-                    pre = conv_ops.conv2d(h.astype(cdt), w.astype(cdt),
-                                          cfg["stride"], cfg["padding"],
-                                          out_dtype=jnp.float32)
-                    if b is not None:
-                        pre = pre + b
-                    h = spec.act(i).fwd(pre, jnp)
+                pre = conv_ops.conv2d(h.astype(cdt), w.astype(cdt),
+                                      cfg["stride"], cfg["padding"],
+                                      out_dtype=jnp.float32)
+                if b is not None:
+                    pre = pre + b
+                h = spec.act(i).fwd(pre, jnp)
             elif layer.kind == "deconv":
                 wt = w if w is not None else params[cfg["tie"]][0]
                 pre = deconv_ops.deconv2d(h.astype(cdt), wt.astype(cdt),
@@ -796,26 +737,11 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
             elif layer.kind == "lrn_pool":
                 # fused pair: the LRN output never touches HBM — the kernel
                 # normalizes in VMEM and pools in the same pass; aux is the
-                # pool's winner-offset tensor (depooling-tie compatible).
-                # With the activation folded, NOTHING downstream needs the
-                # unsplit x (the conv below skips its activation backward),
-                # so the cache keeps the column-parity halves the kernel
-                # consumed — the backward never re-splits x.  A pair on
-                # the window kernels has no halves: it caches x whole
-                if isinstance(h, tuple) or (
-                        "fold_act" in cfg and not _takes_window(h, cfg)):
-                    xe, xo = (h if isinstance(h, tuple)   # split-out conv
-                              else lrn_pool_ops.split_cols(h))
-                    x_in = (xe, xo)
-                    h, aux = lrn_pool_ops.lrn_maxpool_split(
-                        xe, xo, cfg["n"], cfg["alpha"], cfg["beta"],
-                        cfg["k"], cfg["ksize"], cfg["stride"],
-                        cfg["padding"], cfg["use_abs"])
-                else:
-                    h, aux = lrn_pool_ops.lrn_maxpool(
-                        h, cfg["n"], cfg["alpha"], cfg["beta"], cfg["k"],
-                        cfg["ksize"], cfg["stride"], cfg["padding"],
-                        cfg["use_abs"])
+                # pool's winner-offset tensor (depooling-tie compatible)
+                h, aux = lrn_pool_ops.lrn_maxpool(
+                    h, cfg["n"], cfg["alpha"], cfg["beta"], cfg["k"],
+                    cfg["ksize"], cfg["stride"], cfg["padding"],
+                    cfg["use_abs"])
             elif layer.kind == "dropout":
                 if train:
                     # aux stays None: the backward REGENERATES the mask from
@@ -842,8 +768,7 @@ def forward(spec: ModelSpec, params, x, *, want_caches: bool,
                 # its backward cache) live in sdt; the last layer's output
                 # stays f32 so the loss head and its error are full
                 # precision
-                h = (tuple(t.astype(sdt) for t in h)
-                     if isinstance(h, tuple) else h.astype(sdt))
+                h = h.astype(sdt)
             auxes.append(aux)
             if want_caches:
                 caches.append((x_in, aux))
@@ -927,8 +852,6 @@ def backward(spec: ModelSpec, params, caches, out, err, epoch=0, ctr=0,
                 # fold through the fused activation (last layer already is
                 # pre-activation — see docstring); act_folded: the merged
                 # lrn_pool ABOVE already applied this derivative in-kernel
-                # and returned a full-shape dx (y_i may be its split-halves
-                # cache tuple — never consumed here)
                 if i == n - 1 or cfg.get("act_folded"):
                     err_pre = err
                 else:
@@ -946,28 +869,14 @@ def backward(spec: ModelSpec, params, caches, out, err, epoch=0, ctr=0,
                 elif layer.kind == "conv":
                     # grads accumulate in f32 (preferred_element_type inside
                     # the conv ops); cdt only feeds the MXU operands
-                    if cfg.get("split_out"):
-                        # phase-2: err arrives as the pair's parity halves
-                        # (never interleaved) — parity-decomposed grads
-                        ee, eo = (e.astype(cdt) for e in err_pre)
-                        gw = conv_ops.xla_conv2d_grad_weights_split(
-                            x_in.astype(cdt), ee, eo, w.shape,
-                            cfg["stride"], cfg["padding"])
-                        gb = (jnp.sum(err_pre[0], axis=(0, 1, 2))
-                              + jnp.sum(err_pre[1], axis=(0, 1, 2))
-                              if b is not None else None)
-                        err = conv_ops.xla_conv2d_grad_input_split(
-                            ee, eo, w.astype(cdt), x_in.shape,
-                            cfg["stride"], cfg["padding"])
-                    else:
-                        gw = conv_ops.conv2d_grad_weights(
-                            x_in.astype(cdt), err_pre.astype(cdt), w.shape,
-                            cfg["stride"], cfg["padding"])
-                        gb = (jnp.sum(err_pre, axis=(0, 1, 2))
-                              if b is not None else None)
-                        err = conv_ops.conv2d_grad_input(
-                            err_pre.astype(cdt), w.astype(cdt), x_in.shape,
-                            cfg["stride"], cfg["padding"])
+                    gw = conv_ops.conv2d_grad_weights(
+                        x_in.astype(cdt), err_pre.astype(cdt), w.shape,
+                        cfg["stride"], cfg["padding"])
+                    gb = (jnp.sum(err_pre, axis=(0, 1, 2))
+                          if b is not None else None)
+                    err = conv_ops.conv2d_grad_input(
+                        err_pre.astype(cdt), w.astype(cdt), x_in.shape,
+                        cfg["stride"], cfg["padding"])
                 else:                                         # deconv
                     gw = deconv_ops.deconv2d_grad_weights(
                         err_pre.astype(cdt), x_in.astype(cdt), w.shape,
@@ -996,19 +905,10 @@ def backward(spec: ModelSpec, params, caches, out, err, epoch=0, ctr=0,
                 # winner offsets and folds through the LRN derivative (and
                 # optionally the preceding conv's activation derivative) in
                 # one kernel — err_y never materializes
-                if isinstance(x_in, tuple):      # split-halves cache (fold)
-                    err = lrn_pool_ops.gd_lrn_maxpool_split(
-                        err.reshape(y_i.shape), aux, x_in[0], x_in[1],
-                        cfg["n"], cfg["alpha"], cfg["beta"], cfg["k"],
-                        cfg["ksize"], cfg["stride"], cfg["padding"],
-                        cfg.get("fold_act"),
-                        return_split=bool(cfg.get("emit_split")))
-                else:
-                    err = lrn_pool_ops.gd_lrn_maxpool(
-                        err.reshape(y_i.shape), aux, x_in, cfg["n"],
-                        cfg["alpha"], cfg["beta"], cfg["k"], cfg["ksize"],
-                        cfg["stride"], cfg["padding"],
-                        cfg.get("fold_act"))
+                err = lrn_pool_ops.gd_lrn_maxpool(
+                    err.reshape(y_i.shape), aux, x_in, cfg["n"],
+                    cfg["alpha"], cfg["beta"], cfg["k"], cfg["ksize"],
+                    cfg["stride"], cfg["padding"], cfg.get("fold_act"))
             elif layer.kind == "depooling":
                 err = pool_ops.gd_depooling(
                     err.reshape(y_i.shape), aux, cfg["ksize"], cfg["stride"],
@@ -1345,8 +1245,8 @@ class FusedTrainer:
                 raise NotImplementedError(
                     f"layer kinds {sorted(set(held))} on a mesh need an "
                     "'expert' axis in parallel/mesh.py and the all-to-all "
-                    "that exchanges tokens between the chips' experts "
-                    "(ROADMAP Reach 4); on one chip the expert layer runs "
+                    "that exchanges tokens between the chips' experts, "
+                    "neither written yet; on one chip the expert layer runs "
                     "the experts it holds without the exchange")
             self._param_shardings = []
             pidx = 0   # alternate TP axis over *parameterized* layers only

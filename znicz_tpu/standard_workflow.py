@@ -358,7 +358,7 @@ class StandardWorkflowBase(AcceleratedWorkflow):
 
         assert self.initialized, "initialize() first"
         spec, params, vels = fused.extract_model(
-            self, mesh, storage_dtype or "float32")
+            self, storage_dtype or "float32")
         if compute_dtype is not None:
             spec = dataclasses.replace(spec, compute_dtype=compute_dtype)
         from .loader.streaming import StreamingLoader
@@ -439,7 +439,7 @@ class StandardWorkflowBase(AcceleratedWorkflow):
                  "pool_routes": fused.pool_routes(spec, self.forwards,
                                                   mesh),
                  # merged LRN+pool rows on the window kernels, and on
-                 # the column-parity ones (ops/lrn_pool.py)
+                 # the composed XLA ops (ops/lrn_pool.py)
                  "pair_routes": fused.pair_routes(spec, self.forwards,
                                                   mesh),
                  # how the trainer holds the resident set: as given, or
